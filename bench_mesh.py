@@ -1,38 +1,30 @@
-"""Sharded-vs-local expansion throughput on the virtual 8-device mesh
-(VERDICT r3 item 4: a recorded ratio at a 21M-scale predicate).
+"""Sharded-vs-local expansion throughput over every device JAX gives this
+process (VERDICT r3 item 4: a recorded ratio at a 21M-scale predicate).
 
-Runs on the CPU backend with xla_force_host_platform_device_count=8 —
-the same harness the driver's dryrun uses — so the ratio measures the
-SPMD program structure (shard_map + all_gather + device reassembly), not
-chip count: 8 virtual devices share one host's cores, so the expected
-win is bounded by core utilization, and the interesting numbers are
-(a) sharded ≈ local (no pathological collective overhead) and (b) the
-per-level host reassembly of round 2 is gone (one packed transfer).
+The mesh is built from ``len(jax.devices())``: the four chips of a TPU
+host, or — for a rehearsal of the SPMD program structure (shard_map +
+all_gather + device reassembly) — however many virtual CPU devices the
+caller asked for:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        python bench_mesh.py
+
+On virtual devices that share one host's cores the ratio says only that
+the collectives are not pathological; on chips it is the ICI crossover.
+The result names the platform, device kind and device count it was
+taken on.
 
 Usage: python bench_mesh.py   (env: BM_EDGES, default 21_000_000)
 """
 
-import os
-
-# BM_PLATFORM=tpu runs on real hardware (a pod slice exposes its chips as
-# the mesh; the ICI crossover curve in PARITY.md comes from that mode);
-# default is the 8-device virtual CPU mesh for structure validation
-_REAL = os.environ.get("BM_PLATFORM", "cpu") != "cpu"
-if not _REAL:
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-    ).strip()
-
 import json
+import os
 import time
 
 import jax
-
-if not _REAL:
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
+from bench import device_identity
 from dgraph_tpu import ops
 from dgraph_tpu.models.arena import csr_dense_from_edges
 from dgraph_tpu.parallel.mesh import (
@@ -43,6 +35,7 @@ from dgraph_tpu.parallel.mesh import (
 
 
 def main():
+    dev = device_identity()
     n_edges = int(os.environ.get("BM_EDGES", 21_000_000))
     n_nodes = max(1024, n_edges // 10)
     rng = np.random.default_rng(5)
@@ -52,9 +45,9 @@ def main():
     a = csr_dense_from_edges(src, dst, n_nodes)
     build_s = time.time() - t0
 
-    mesh = make_mesh(8, data=1)
+    mesh = make_mesh(len(jax.devices()), data=1)
     t0 = time.time()
-    sa = shard_arena_rows(a.h_src, a.h_offsets, a.host_dst(), 8)
+    sa = shard_arena_rows(a.h_src, a.h_offsets, a.host_dst(), mesh)
     shard_s = time.time() - t0
 
     frontiers = [
@@ -133,6 +126,8 @@ def main():
         """The executor's ArenaManager surface, minimally: one already
         sharded predicate (the bench controls placement explicitly)."""
 
+        mesh_fault = None  # no elastic fault domain: a fixed mesh
+
         def __init__(self, mesh, sa):
             self.mesh = mesh
             self._sa = sa
@@ -190,8 +185,7 @@ def main():
         "sharded_ms": round(sharded_s / len(frontiers) * 1e3, 1),
         "local_ms": round(local_s / len(frontiers) * 1e3, 1),
         "ratio_local_over_sharded": round(local_s / sharded_s, 2),
-        "n_devices": 8,
-        "platform": jax.devices()[0].platform + ("-mesh" if _REAL else "-virtual-mesh"),
+        **dev,
         "build_s": round(build_s, 1),
         "shard_s": round(shard_s, 1),
         "crossover_curve": curve,

@@ -3,7 +3,8 @@ docs/ROOFLINE.md, reproducible in one command.
 
 Measures, at headline-bench-like shapes (200-query batches):
   - expand_inline_grouped      (XLA slot-map)
-  - expand_inline_grouped_pallas (Pallas slot-map; interpret off-TPU)
+  - expand_inline_grouped_pallas (Pallas slot-map; CPU backend only, in
+    interpret mode — the TPU compiler refuses the kernel)
   - sort_unique dedup at the hop-2 width
   - member_mask set membership
 plus the BATCHED-vs-PER-OP comparison for the fused hop executor
@@ -17,15 +18,15 @@ PR 16 adds the resident-tier A/B: the Pallas segment-gather over an
 HBM-pinned ResidentArena vs expand_csr staged and vs expand_csr paying
 the post-mutation re-staging tax, plus intersect_pallas vs
 intersect_many at k ∈ {2,4,8} (env: BO_RES_NODES/BO_RES_EDGES/
-BO_RES_FRONTIER/BO_RES_SETLEN).  Off-TPU the Pallas arms run in
-interpret mode and emit mode=interpret / perf_claim=false — those rows
+BO_RES_FRONTIER/BO_RES_SETLEN).  On the CPU backend the Pallas arms run
+in interpret mode and emit mode=interpret / perf_claim=false — those rows
 prove the harness and the dispatch discipline, not a speedup.
 
 One JSON line per measurement: {"kernel", "value", "unit", "platform",
-...extras}.
+"device_kind", "device_count", ...extras}.
 
-Usage: python bench_ops.py    (env: BO_NODES/BO_EDGES/BO_Q scale it;
-same wedged-TPU probe contract as bench.py)
+Usage: python bench_ops.py    (env: BO_NODES/BO_EDGES/BO_Q scale it; runs
+on the backend JAX gives it — JAX_PLATFORMS=cpu for a rehearsal)
 """
 
 import json
@@ -414,12 +415,13 @@ def bench_resident_tier(platform, emit):
     plus the k-way intersect kernel vs intersect_many.  Dispatch and
     compile counts per arm, warm-path timed.
 
-    Honest per-backend note: off-TPU the Pallas kernels run in
-    INTERPRET mode — correctness speed, not a perf claim (the emitted
-    rows carry mode=interpret so nobody graphs them as one).  The
-    numbers that matter come from this same harness on the TPU arm
-    (Mosaic lowering is the next chip session's measure-first task);
-    the dispatch/compile discipline pins hold on any backend."""
+    Honest per-backend note: on the CPU backend the Pallas kernels run
+    in INTERPRET mode — correctness speed, not a perf claim (the emitted
+    rows carry mode=interpret so nobody graphs them as one).  On a TPU
+    the gather compiles through Mosaic; the intersect kernel is refused
+    by the v5e compiler (ops/pallas_intersect.py Status) and its row
+    says so instead of running.  The dispatch/compile discipline pins
+    hold on any backend."""
     import jax
     import jax.numpy as jnp
 
@@ -430,7 +432,7 @@ def bench_resident_tier(platform, emit):
     n_nodes = int(os.environ.get("BO_RES_NODES", 200_000))
     n_edges = int(os.environ.get("BO_RES_EDGES", 1_500_000))
     nf = int(os.environ.get("BO_RES_FRONTIER", 2048))
-    interp = platform != "tpu"
+    interp = platform == "cpu"
     note = {"mode": "interpret" if interp else "mosaic",
             "perf_claim": not interp}
 
@@ -505,14 +507,22 @@ def bench_resident_tier(platform, emit):
         mat = jnp.asarray(np.stack([
             np.asarray(ops.pad_to(s_, L)) for s_ in setsk
         ]))
-        with DispatchCounter() as c:
-            s, compiles, disp = timed(c, lambda c, m=mat: c.call(
-                ops.intersect_pallas, m, interpret=interp
-            ))
-        emit("intersect_pallas", k * L / s, "elems/s", {
-            **note, "k": k, "L": L,
-            "dispatches": disp, "compiles": compiles,
-        })
+        if interp:
+            with DispatchCounter() as c:
+                s, compiles, disp = timed(c, lambda c, m=mat: c.call(
+                    ops.intersect_pallas, m, interpret=True
+                ))
+            emit("intersect_pallas", k * L / s, "elems/s", {
+                **note, "k": k, "L": L,
+                "dispatches": disp, "compiles": compiles,
+            })
+        else:
+            emit("intersect_pallas", float("nan"), "elems/s", {
+                "k": k, "L": L, "mode": "refused",
+                "refusal": "Mosaic: cannot statically prove that index "
+                           "in dimension 0 is a multiple of 1024 "
+                           "(ops/pallas_intersect.py Status)",
+            })
         with DispatchCounter() as c:
             s, compiles, disp = timed(c, lambda c, m=mat: c.call(
                 ops.intersect_many, m
@@ -523,20 +533,20 @@ def bench_resident_tier(platform, emit):
 
 
 def main():
-    from bench import ensure_backend
+    from bench import build_graph, device_identity
 
-    platform = ensure_backend()
+    dev = device_identity()
+    platform = dev["platform"]
     import jax
     import jax.numpy as jnp
 
     from dgraph_tpu import ops
     from dgraph_tpu.ops.sets import SENT
-    from bench import build_graph
 
     def emit(kernel, value, unit, extra=None):
         rec = {
             "kernel": kernel, "value": round(value, 1), "unit": unit,
-            "platform": platform,
+            **dev,
         }
         if extra:
             rec.update(extra)
@@ -581,10 +591,14 @@ def main():
             b = min(b, time.time() - t0)
         return b
 
-    for name, expander in (
-        ("expand_inline_grouped", ops.expand_inline_grouped),
-        ("expand_inline_grouped_pallas", ops.expand_inline_grouped_pallas),
-    ):
+    expanders = [("expand_inline_grouped", ops.expand_inline_grouped)]
+    if platform == "cpu":
+        # interpret mode; the TPU v5e compiler refuses the slot-map kernel
+        # (ops/pallas_slotmap.py Status)
+        expanders.append(
+            ("expand_inline_grouped_pallas", ops.expand_inline_grouped_pallas)
+        )
+    for name, expander in expanders:
         run = jax.jit(jax.vmap(lambda r: expander(metap, ov, r, capc, pcap)))
         s = best(lambda: run(rows))
         emit(name, edges_total / s, "edges/s")

@@ -69,7 +69,7 @@ import time
 
 import numpy as np
 
-from bench import _serving_store, ensure_backend
+from bench import _serving_store, device_identity
 
 
 # ---------------------------------------------------------------- backend
@@ -1268,9 +1268,9 @@ def smoke_check(out: dict) -> None:
 
 
 def main() -> None:
-    platform = ensure_backend()
-    print(f"# backend: {platform}", file=sys.stderr)
-    out = run_slo_bench()
+    dev = device_identity()
+    print(f"# backend: {dev}", file=sys.stderr)
+    out = {**run_slo_bench(), **dev}
     if os.environ.get("SLO_SMOKE") == "1":
         smoke_check(out)
         print("# slo smoke: OK", file=sys.stderr)

@@ -126,20 +126,9 @@ class Violation:
 # -- jaxpr introspection ------------------------------------------------------
 
 
-def _core():
-    import jax
-
-    try:
-        from jax.extend import core  # newer spellings first
-        if hasattr(core, "Jaxpr"):
-            return core
-    except Exception:  # noqa: BLE001 — version-dependent import surface
-        pass
-    return jax.core
-
-
 def _sub_jaxprs(param):
-    core = _core()
+    from jax.extend import core
+
     out = []
 
     def rec(x):
@@ -197,6 +186,7 @@ def _trace(inst: ProgramInstance):
 
 
 _SRC_LOC = re.compile(r"\S+\.py:\d+(:\d+)?")
+_FROZENSET = re.compile(r"frozenset\(\{([^{}]*)\}\)")
 
 
 def fingerprint_of(closed) -> str:
@@ -208,6 +198,12 @@ def fingerprint_of(closed) -> str:
     a comment edit above the kernel or a different checkout path."""
     norm = _SRC_LOC.sub("<src>", str(closed))
     norm = re.sub(r"\s+", " ", norm.strip())
+    # a frozenset param (shard_map's manual_axes) prints in hash order,
+    # which string-hash randomization changes from process to process
+    norm = _FROZENSET.sub(
+        lambda m: "frozenset({%s})" % ", ".join(sorted(m.group(1).split(", "))),
+        norm,
+    )
     return hashlib.sha256(norm.encode()).hexdigest()[:16]
 
 
@@ -341,7 +337,11 @@ def check_contract(
                         "scan even 'unrolled', so a kernel that adds a "
                         "binary search must re-declare)")
         if "callback" in checks:
-            for p in ("pure_callback", "io_callback", "debug_callback"):
+            for p in (
+                "pure_callback", "io_callback",
+                "debug_callback",  # jax.debug.callback
+                "debug_print",     # jax.debug.print
+            ):
                 if p in prims:
                     bad(inst.key, "callback",
                         f"host callback `{p}` inside a compiled kernel: "
@@ -520,6 +520,26 @@ def load_goldens(path: Optional[Path] = None) -> dict:
     return json.loads(p.read_text()).get("programs", {})
 
 
+def goldens_release_mismatch(path: Optional[Path] = None) -> Optional[str]:
+    """Why the goldens cannot be compared in this process, or None.  A
+    jaxpr's text — and hence every fingerprint — changes with the JAX
+    release, so goldens blessed under another release say nothing about
+    this tree: the remedy is to re-bless, not to read N drifted hashes."""
+    import jax
+
+    p = Path(path) if path else GOLDENS_PATH
+    if not p.exists():
+        return None
+    blessed = json.loads(p.read_text()).get("jax")
+    if blessed == jax.__version__:
+        return None
+    return (
+        f"goldens were blessed under jax {blessed}, this is jax "
+        f"{jax.__version__}: re-bless with `python -m dgraph_tpu.analysis "
+        "--update-programs`"
+    )
+
+
 def write_goldens(fingerprints: dict, path: Optional[Path] = None) -> None:
     import jax
 
@@ -573,6 +593,10 @@ def run_check(
     goldens = load_goldens(goldens_path)
     active = tuple(c for c in checks if not (update and c == "golden"))
     all_violations: List[Violation] = []
+    stale = goldens_release_mismatch(goldens_path)
+    if stale and "golden" in active:
+        all_violations.append(Violation("*", "*", "golden", stale))
+        active = tuple(c for c in active if c != "golden")
     all_fps: Dict[str, Dict[str, str]] = {}
     n_programs = 0
     for name in sorted(reg):
@@ -947,7 +971,7 @@ def _b_mesh_multi_hop() -> List[ProgramInstance]:
         )
     mesh = make_mesh(8, data=1)
     h_src, h_offsets, h_dst, _, _ = _small_csr()
-    sa = shard_arena_rows(h_src, h_offsets, h_dst, 8)
+    sa = shard_arena_rows(h_src, h_offsets, h_dst, mesh)
     f32 = jnp.asarray(sets.pad_to(np.array([0, 1, 3], np.int64), 32))
     f64 = jnp.asarray(sets.pad_to(np.array([0, 1, 3], np.int64), 64))
     return [
@@ -1175,8 +1199,8 @@ def _slotmap_inst(raw_capc: int) -> ProgramInstance:
 
 def _resident_fixture():
     """Tiny CSR in the ResidentArena storage layout (models/arena.py):
-    bucketed offsets, dst SENT-padded to _resident_cap's 128-granule +
-    slack-tile contract — what ops/pallas_gather.py walks in HBM."""
+    bucketed offsets, dst SENT-padded to _resident_cap's 1024-granule +
+    slack-tile-group contract — what ops/pallas_gather.py walks in HBM."""
     jnp, np = _jnp()
     from dgraph_tpu.models.arena import _resident_cap
     from dgraph_tpu.ops import sets
@@ -1519,12 +1543,12 @@ REGISTRY: Dict[str, ProgramContract] = {
             dtypes=_INT,
             bucket_probe=_slotmap_probe(),
             notes="PROMOTED (PR 16): wired into the grouped-expansion "
-                  "path behind DGRAPH_TPU_SLOTMAP (ops/sets.py "
+                  "path behind DGRAPH_TPU_SLOTMAP=force (ops/sets.py "
                   "expand_inline_grouped_auto), full checks — transfer, "
-                  "cost, bucket probe — in interpret mode; Mosaic "
-                  "lowering itself is still the next chip session's "
-                  "measure-first task (which is why auto mode stays "
-                  "TPU-backend-gated).",
+                  "cost, bucket probe — in interpret mode.  The TPU "
+                  "v5e compiler refuses it (cumsum has no Pallas TPU "
+                  "lowering; tests/test_chip_compile.py strict xfail), "
+                  "so auto mode selects it nowhere.",
         ),
         ProgramContract(
             name="pallas.gather",
@@ -1533,18 +1557,18 @@ REGISTRY: Dict[str, ProgramContract] = {
                 f"{_OPS}/pallas_gather.py::gather_pallas_packed",
             ),
             build=_b_pallas_gather,
-            scan_free=False,   # the per-row DMA loop is a fori_loop
-            # int16: interpret mode models the kernel's DMA semaphores
-            # (pltpu.SemaphoreType.DMA scratch) as int16 avals — kernel
-            # data stays strictly int32
-            dtypes=_INT | {"int16"},
+            scan_free=False,   # per-tile row walk: fori + while loops
+            # dma_sem: the kernel's DMA semaphore scratch
+            # (pltpu.SemaphoreType.DMA) — kernel data stays strictly int32
+            dtypes=_INT | {"dma_sem"},
             bucket_probe=_gather_probe(),
             notes="device-resident posting gather (PR 16, the "
-                  "route:resident walk primitive): double-buffered "
-                  "HBM->VMEM span copies over ResidentArena's pinned "
-                  "CSR, byte-identical to expand_csr; checked in "
-                  "interpret mode (Mosaic lowering is the next chip "
-                  "session's A/B).",
+                  "route:resident walk primitive): 8-row-aligned "
+                  "HBM->VMEM window copies over ResidentArena's pinned "
+                  "CSR, realigned onto output tiles by lane rotation, "
+                  "byte-identical to expand_csr; contract-checked in "
+                  "interpret mode, compiled for TPU v5e in "
+                  "tests/test_chip_compile.py.",
         ),
         ProgramContract(
             name="pallas.intersect",
@@ -1555,7 +1579,9 @@ REGISTRY: Dict[str, ProgramContract] = {
             notes="k-way (k<=8) sorted-set intersect over the stored "
                   "layout (PR 16, EmptyHeaded-style probe + VPU "
                   "membership tiles), byte-identical to intersect_many; "
-                  "checked in interpret mode.",
+                  "checked in interpret mode.  The TPU v5e compiler "
+                  "refuses it (1-D vector_store alignment; "
+                  "tests/test_chip_compile.py strict xfail).",
         ),
         ProgramContract(
             name="resident.merge",

@@ -30,8 +30,7 @@ packed device paths already concatenate into a single transfer
 code.  Caching THOSE arrays — rather than device handles — means a hit
 pays no device interaction at all: the round trip was paid once at
 fill time, and a device-array entry would force a fresh device→host
-fetch per hit (strictly worse on every backend, catastrophically so
-through a remote-transport tunnel).  Entries pin host RAM, not HBM, so
+fetch per hit (strictly worse on every backend).  Entries pin host RAM, not HBM, so
 the byte budget rides beside the arena budget instead of competing
 with it.  Entries hold exactly the arrays the expansion returned — the
 engine treats

@@ -109,8 +109,7 @@ def load_file(
             return
         if marks:
             marks.begin(path, chunk_end)
-        for q in pending:
-            client.batch_set(q)
+        client.batch_set_block(pending)
         in_flight.append(chunk_end)
         pending = []
         if len(in_flight) >= max(1, window):

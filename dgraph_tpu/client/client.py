@@ -200,7 +200,9 @@ class DgraphClient:
     def __init__(self, transport: Transport, opts: BatchMutationOptions = BatchMutationOptions()):
         self.transport = transport
         self.opts = opts
-        self._set_q: "queue.Queue[Optional[str]]" = queue.Queue(maxsize=opts.size * opts.pending)
+        # items: one N-Quad line, a block of lines (batch_set_block), or
+        # None (close() waking a worker)
+        self._set_q: "queue.Queue[str | List[str] | None]" = queue.Queue(maxsize=opts.size * opts.pending)
         self._del_q: "queue.Queue[Optional[str]]" = queue.Queue(maxsize=opts.size * opts.pending)
         self._err: Optional[BaseException] = None
         self._last_op: Optional[str] = None
@@ -224,6 +226,18 @@ class DgraphClient:
         with self._prod_lock:
             self._op_barrier("set")
             self._set_q.put(e.nquad() if isinstance(e, Edge) else str(e))
+
+    def batch_set_block(self, nquads: List[str]) -> None:
+        """Enqueue an already-batched block of N-Quad lines: one worker
+        submits it as exactly one mutation.  The bulk loader's path — a
+        queue hand-off per block instead of per quad (per-quad hand-offs
+        capped a load at ~22k quads/s against a server applying 250k/s)."""
+        if not nquads:
+            return
+        self._check_err()
+        with self._prod_lock:
+            self._op_barrier("set")
+            self._set_q.put(list(nquads))
 
     def batch_delete(self, e) -> None:
         self._check_err()
@@ -283,8 +297,27 @@ class DgraphClient:
             if item is None:
                 q.task_done()
                 continue
+            if isinstance(item, list):
+                # a whole block behind single quads: it stays one
+                # mutation of its own — submit it now, then go on
+                self._run_batch(q, 1, item, [])
+                continue
             batch.append(item)
         return batch
+
+    def _run_batch(self, q: "queue.Queue", n_items: int, sets, dels) -> None:
+        """Submit one mutation for ``n_items`` queue items and mark them
+        done, publishing a failure for the producer to raise."""
+        try:
+            self._submit(sets, dels)
+        except BaseException as e:  # noqa: BLE001
+            # several workers can fail at once: publish the error under
+            # the client lock, not as a bare store
+            with self._lock:
+                self._err = e
+        finally:
+            for _ in range(n_items):
+                q.task_done()
 
     def _submit(self, sets: List[str], dels: List[str]) -> None:
         parts = []
@@ -307,26 +340,13 @@ class DgraphClient:
                 except queue.Empty:
                     continue
                 dels = self._drain(self._del_q, dfirst)
-                try:
-                    self._submit([], dels)
-                except BaseException as e:  # noqa: BLE001
-                    # several workers can fail at once: publish the
-                    # error under the client lock, not as a bare store
-                    with self._lock:
-                        self._err = e
-                finally:
-                    for _ in dels:
-                        self._del_q.task_done()
+                self._run_batch(self._del_q, len(dels), [], dels)
                 continue
             if first is None:
                 self._set_q.task_done()
                 continue
-            sets = self._drain(self._set_q, first)
-            try:
-                self._submit(sets, [])
-            except BaseException as e:  # noqa: BLE001
-                with self._lock:
-                    self._err = e
-            finally:
-                for _ in sets:
-                    self._set_q.task_done()
+            if isinstance(first, list):  # batch_set_block: one mutation
+                self._run_batch(self._set_q, 1, first, [])
+            else:
+                sets = self._drain(self._set_q, first)
+                self._run_batch(self._set_q, len(sets), sets, [])

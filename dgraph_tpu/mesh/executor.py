@@ -221,10 +221,9 @@ class MeshExecutor:
         self, attr, reverse, src, n_hops, cap, stats, resumed
     ):
         from dgraph_tpu.mesh.programs import mesh_multi_hop_step
+        from dgraph_tpu.parallel.mesh import put_replicated
         from dgraph_tpu.sched import segments
         from dgraph_tpu.utils.failpoints import fail
-
-        import jax.numpy as jnp
 
         dom = self.arenas.mesh_fault
         retries = self._retries()
@@ -238,8 +237,8 @@ class MeshExecutor:
                 # the chip-loss probe of the PR 15 chaos suite fires on
                 # the guard's worker, same as the one-hop kernel path
                 fail.point("device.mesh")
-                f = jnp.asarray(
-                    ops.pad_to(np.asarray(src, dtype=np.int64), cap)
+                f = put_replicated(
+                    self.mesh, ops.pad_to(np.asarray(src, dtype=np.int64), cap)
                 )
                 with obs.stage(stats, "chain_ms"):
                     fs, totals, _final = step(
@@ -265,10 +264,9 @@ class MeshExecutor:
         self, attr, reverse, src, n_hops, cap, seg_k, stats, resumed
     ):
         from dgraph_tpu.mesh.programs import mesh_multi_hop_step
+        from dgraph_tpu.parallel.mesh import put_replicated
         from dgraph_tpu.sched import segments
         from dgraph_tpu.utils.failpoints import fail
-
-        import jax.numpy as jnp
 
         dom = self.arenas.mesh_fault
         retries = self._retries()
@@ -279,7 +277,7 @@ class MeshExecutor:
         # (== the donated final frontier, value-for-value) — so a drain
         # never fetches the donated device buffer at all
         f_host = ops.pad_to(np.asarray(src, dtype=np.int64), cap)
-        f = jnp.asarray(f_host)
+        f = put_replicated(self.mesh, f_host)
         fs_parts, tot_parts = [], []
         done = 0
         while done < n_hops:
@@ -366,13 +364,13 @@ class MeshExecutor:
         under the new epoch's plan (new width ⇒ sharded_csr rebuilds —
         the survivor re-seed path) and rebuild the device carry from
         its host mirror."""
-        import jax.numpy as jnp
+        from dgraph_tpu.parallel.mesh import put_replicated
 
         dom.note_drain(1)
         try:
             sharded = self.arenas.sharded_csr(attr, reverse=reverse)
             fence = dom.fence()
-            f = jnp.asarray(f_host)
+            f = put_replicated(self.mesh, f_host)
         finally:
             dom.note_drain(-1)
         return sharded, fence, f

@@ -387,6 +387,7 @@ class MeshFaultDomain:
         from dgraph_tpu.mesh.programs import mesh_multi_hop_step
         from dgraph_tpu.ops.sets import SENT
         from dgraph_tpu.parallel.mesh import (
+            put_replicated,
             seg_expand_packed_step,
             shard_arena_rows,
         )
@@ -401,7 +402,7 @@ class MeshFaultDomain:
                     np.array([1], dtype=np.int64),
                     np.array([0, 0], dtype=np.int64),
                     np.empty(0, dtype=np.int64),
-                    int(mesh.shape["model"]),
+                    mesh,
                 ),
                 0,
             )]
@@ -414,14 +415,14 @@ class MeshFaultDomain:
                 if shape[0] == "hop":
                     _kind, cap, hops = shape
                     step = mesh_multi_hop_step(mesh, cap, hops)
-                    f = jnp.full((cap,), SENT, dtype=jnp.int32)
+                    f = put_replicated(mesh, np.full((cap,), SENT, np.int32))
                     out = step(sa.src, sa.offsets, sa.dst, f)
                 else:
                     _kind, cap, fcap = shape
                     step, _slots = seg_expand_packed_step(
                         mesh, cap, fcap
                     )
-                    f = jnp.full((fcap,), SENT, dtype=jnp.int32)
+                    f = put_replicated(mesh, np.full((fcap,), SENT, np.int32))
                     out = step(sa.src, sa.offsets, sa.dst, f)
                 jax.block_until_ready(out)
 

@@ -104,14 +104,20 @@ class MeshPlan:
         PREVIEWED placement before the plan itself re-targets)."""
         if off == 0:
             return sharded
+        import jax
         import jax.numpy as jnp
 
         from dgraph_tpu.parallel.mesh import ShardedArena
 
+        def roll(x):
+            # the roll moves shards between chips; its result keeps the
+            # one-shard-per-chip placement of its input
+            return jax.device_put(jnp.roll(x, off, axis=0), x.sharding)
+
         return ShardedArena(
-            src=jnp.roll(sharded.src, off, axis=0),
-            offsets=jnp.roll(sharded.offsets, off, axis=0),
-            dst=jnp.roll(sharded.dst, off, axis=0),
+            src=roll(sharded.src),
+            offsets=roll(sharded.offsets),
+            dst=roll(sharded.dst),
             n_shards=sharded.n_shards,
         )
 

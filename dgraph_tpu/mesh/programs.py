@@ -39,7 +39,7 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from dgraph_tpu import ops
 
@@ -88,7 +88,7 @@ def mesh_multi_hop_step(mesh: Mesh, cap: int, n_hops: int):
         mesh=mesh,
         in_specs=(P("model", None), P("model", None), P("model", None), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     # the [cap] final-frontier output exists exactly so the donated
     # seed buffer has something to alias — the scan's internal carry

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from dgraph_tpu import ops
 from dgraph_tpu.obs import ledger as _ledger
-from dgraph_tpu.utils.metrics import ARENA_EVICTIONS
+from dgraph_tpu.utils.metrics import ARENA_EVICTIONS, RESIDENT_EPOCHS
 from dgraph_tpu.ops.sets import SENT
 from dgraph_tpu import tok as tokmod
 from dgraph_tpu.models.store import PostingStore
@@ -557,6 +557,7 @@ class CSRArena:
                     )
                     nra._prev = (ra.off, ra.dst)
                     self._resident = nra
+                    RESIDENT_EPOCHS.add("reseed")
                 else:
                     # device-side delta application: only the (row, dst)
                     # delta pairs cross host→device; the merge program
@@ -578,6 +579,7 @@ class CSRArena:
                     ar, ad = _pack(adds)
                     dr, dd = _pack(dels)
                     ra.apply_delta(ar, ad, dr, dd, self.n_edges)
+                    RESIDENT_EPOCHS.add("merge")
         self._device_stale = True
 
     def _degrees_of_uids(self, uids: np.ndarray) -> np.ndarray:
@@ -656,12 +658,12 @@ def _ivm_repair_gate(n_delta: int, entry_edges: float) -> bool:
 def _resident_cap(n_edges: int) -> int:
     """Capacity of the resident dst buffer: live edges plus growth
     headroom (~1/8th, floor 1024) so point-mutation bursts merge on
-    device instead of reseeding, rounded to the gather kernel's 128-lane
-    granule PLUS one slack tile — the layout contract of
-    ops/pallas_gather.py (a row's tail tile may read up to 127 lanes
-    past its span without bounds checks)."""
+    device instead of reseeding, rounded to whole (8, 128) int32 tiles
+    PLUS one slack tile group — the layout contract of
+    ops/pallas_gather.py (the buffer bitcasts to [NT, 128] with NT % 8
+    == 0, and every 16-row window a live span can need lies inside it)."""
     head = max(n_edges // 8, 1024)
-    return ((n_edges + head + 127) // 128) * 128 + 128
+    return ((n_edges + head + 1023) // 1024) * 1024 + 1024
 
 
 @jax.jit
@@ -1482,9 +1484,8 @@ class ArenaManager:
         pkey = ("~" + pred) if reverse else pred
 
         def build():
-            n_model = self.mesh.shape["model"]
             sa = shard_arena_rows(
-                a.h_src, a.h_offsets, a.host_dst(), n_model
+                a.h_src, a.h_offsets, a.host_dst(), self.mesh
             )
             off = 0
             if self.mesh_plan is not None:
@@ -1507,6 +1508,21 @@ class ArenaManager:
             self._sharded, (pred, reverse), build, valid=valid,
             gen_key=pred,
         )[1]
+
+    def sharded_bytes_by_device(self) -> Dict[str, int]:
+        """Bytes of the cached mesh-sharded views each device holds, read
+        off the arrays' addressable shards (device id → bytes) — the
+        placement check behind /debug/device: a row-sharded arena must
+        sit a 1/width share on every chip, not whole on the first."""
+        with self._cache_lock:
+            views = [e[1] for e in self._sharded.values()]
+        out: Dict[str, int] = {}
+        for sa in views:
+            for t in (sa.src, sa.offsets, sa.dst):
+                for sh in t.addressable_shards:
+                    key = str(sh.device.id)
+                    out[key] = out.get(key, 0) + int(sh.data.nbytes)
+        return out
 
     def mesh_executor(self):
         """The memoized serving-path executor (dgraph_tpu/mesh) over
@@ -1578,7 +1594,7 @@ class ArenaManager:
             a = self.reverse(pred) if reverse else self.data(pred)
             pkey = ("~" + pred) if reverse else pred
             sa = shard_arena_rows(
-                a.h_src, a.h_offsets, a.host_dst(), n_model
+                a.h_src, a.h_offsets, a.host_dst(), mesh
             )
             off = preview.get(pkey, 0) % n_model
             staged.views[(pred, reverse)] = (

@@ -10,6 +10,7 @@ fall back to the pure-Python path (images without a toolchain).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,6 +21,7 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "nquad_scan.cpp")
 _SO = os.path.join(_HERE, "libnquad.so")
+_STAMP = _SO + ".sha256"  # content hash of the source the .so was built from
 
 _lock = threading.Lock()
 _lib = None
@@ -37,19 +39,41 @@ F_LIT_ESCAPED = 1 << 7
 F_HAS_LABEL = 1 << 8
 
 
+def _source_digest() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
 def _build() -> Optional[str]:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+    """The scanner library built from THIS source, or None without a
+    toolchain.  The binary is a build product outside git, and a copy of
+    the tree carries whatever binary lay on disk with mtimes of its own —
+    so freshness is the source's content hash stored beside the binary,
+    never a timestamp compare."""
+    want = _source_digest()
+    try:
+        with open(_STAMP) as f:
+            if os.path.exists(_SO) and f.read().strip() == want:
+                return _SO
+    except OSError:
+        pass
+    # per-process temporaries: test workers of a fresh checkout all build
+    # at once, and must not write through each other's output
+    so_tmp = os.path.join(_HERE, f"libnquad.{os.getpid()}.so.tmp")
+    stamp_tmp = f"{_STAMP}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _SO + ".tmp", _SRC],
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", so_tmp, _SRC],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        # build-cache artifact, not durable state: atomicity only guards
+        # build-cache artifacts, not durable state: atomicity only guards
         # against a concurrent builder, no fsync contract needed
-        os.replace(_SO + ".tmp", _SO)  # graftlint: ignore[naked-atomic-write]
+        os.replace(so_tmp, _SO)  # graftlint: ignore[naked-atomic-write]
+        with open(stamp_tmp, "w") as f:
+            f.write(want)
+        os.replace(stamp_tmp, _STAMP)  # graftlint: ignore[naked-atomic-write]
         return _SO
     except (OSError, subprocess.SubprocessError):
         return None
@@ -76,6 +100,11 @@ def scanner():
         lib.nq_scan.restype = ctypes.c_long
         _lib = lib
         return _lib
+
+
+def scanner_name() -> str:
+    """Which N-Quad scanner serves this process: "native" or "python"."""
+    return "native" if scanner() is not None else "python"
 
 
 class ScanResult:

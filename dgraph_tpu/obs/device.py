@@ -26,9 +26,11 @@ Served at ``GET /debug/device`` and folded into ``GET /debug/bundle``
 
 from __future__ import annotations
 
+import sys
 import threading
 
 from dgraph_tpu.obs import ledger as _ledger
+from dgraph_tpu.utils import devguard
 from dgraph_tpu.utils.metrics import (
     BUILD_INFO,
     HBM_BUDGET_BYTES,
@@ -43,13 +45,27 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _install_lock = threading.Lock()
 _installed = False
+_longest_compile_s = 0.0  # guarded by _install_lock
+
+
+def _on_compile_begin(name: str, value: float, **kw) -> None:
+    # JAX records the start time of a bracketed stage as a scalar under
+    # the stage's event name when it enters the bracket
+    if name == _COMPILE_EVENT:
+        devguard.note_compile_begin()
 
 
 def _on_event_duration(name: str, secs: float, **kw) -> None:
+    global _longest_compile_s
     if name != _COMPILE_EVENT:
         return
+    # the bracket reports its duration from __exit__, so a failed
+    # compile's exception is the one being handled right now
+    devguard.note_compile_end(secs, sys.exc_info()[1])
     XLA_COMPILES.add(1)
     XLA_COMPILE_SECONDS.observe(secs)
+    with _install_lock:
+        _longest_compile_s = max(_longest_compile_s, secs)
     led = _ledger.current()
     if led is not None:
         # compiles land on whichever request's thread triggered them —
@@ -70,6 +86,7 @@ def install_compile_listener() -> None:
         jax.monitoring.register_event_duration_secs_listener(
             _on_event_duration
         )
+        jax.monitoring.register_scalar_listener(_on_compile_begin)
         _installed = True
 
 
@@ -86,6 +103,17 @@ def stamp_build_info() -> None:
     )
 
 
+def _memory_of(dev):
+    stats = dev.memory_stats()
+    if not stats:
+        return None
+    return {
+        k: int(stats[k])
+        for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+        if k in stats
+    }
+
+
 def snapshot(server=None) -> dict:
     """One device-telemetry snapshot (the /debug/device body), updating
     the gauges as a side effect so a scrape that never hits the debug
@@ -95,15 +123,26 @@ def snapshot(server=None) -> dict:
     None degrades to the process-wide (backend + compile) view."""
     import jax
 
-    from dgraph_tpu.utils import devguard
+    from dgraph_tpu.utils import jaxcache
 
+    devs = jax.devices()
+    with _install_lock:
+        longest = _longest_compile_s
     out: dict = {
         "backend": jax.default_backend(),
-        "devices": len(jax.devices()),
+        "device_kind": devs[0].device_kind,
+        "devices": len(devs),
         "jax": jax.__version__,
         "compiles": {
             "total": XLA_COMPILES.value(),
             "seconds_sum": round(XLA_COMPILE_SECONDS.snapshot()[1], 3),
+            "seconds_max": round(longest, 3),
+        },
+        "compile_cache": jaxcache.in_use(),
+        # allocator's view per device (None where the backend reports
+        # none, e.g. cpu): peak HBM is the number a deployment sizes to
+        "memory": {
+            str(d.id): _memory_of(d) for d in devs
         },
         # device fault domain (utils/devguard.py): state machine +
         # fault/failover/probe counters per domain
@@ -123,4 +162,9 @@ def snapshot(server=None) -> dict:
         for kind, n in res["program_caches"].items():
             PROGRAM_CACHE_ENTRIES.set(kind, n)
         out["arenas"] = res
+        if arenas.mesh is not None:
+            out["mesh"] = {
+                "width": int(arenas.mesh.shape["model"]),
+                "sharded_bytes_by_device": arenas.sharded_bytes_by_device(),
+            }
     return out

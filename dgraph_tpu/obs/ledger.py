@@ -52,6 +52,7 @@ from typing import Dict, Optional
 from dgraph_tpu.utils.metrics import (
     EDGES_TRAVERSED,
     LEDGER_BYTES,
+    LEDGER_HOP_EDGES,
     LEDGER_HOPS,
     LEDGER_STAGE_US,
     LEDGERS_CREATED,
@@ -85,7 +86,7 @@ class Ledger:
     hand-off argument SchedRequest.span relies on."""
 
     __slots__ = (
-        "tenant", "edges", "hops", "host_ms", "device_ms",
+        "tenant", "edges", "hops", "hop_edges", "host_ms", "device_ms",
         "device_sync_ms", "bytes_h2d", "bytes_d2h", "compiles",
         "cache_hits", "cache_misses", "cache_hit_bytes", "repairs",
         "coalesced", "exchange_bytes", "mesh_ms", "mesh_chips",
@@ -97,8 +98,8 @@ class Ledger:
     # slot, and the arm-time wraps on activate()/SchedRequest.complete/
     # fail reset the epoch at exactly the happens-before edges this
     # class's contract names (handler -> flush worker -> handler).
-    # ``hops`` is a dict (item writes bypass __setattr__) and is
-    # covered by the same epochs as the scalars it travels with.
+    # ``hops``/``hop_edges`` are dicts (item writes bypass __setattr__)
+    # and are covered by the same epochs as the scalars they travel with.
     # ``compiles`` is deliberately NOT listed: the jax.monitoring
     # compile listener (obs/device.py) increments it from whichever
     # engine-pool thread triggered the compile, concurrently with the
@@ -116,12 +117,14 @@ class Ledger:
     def __init__(self):
         LEDGERS_CREATED.add(1)
         self.hops: Dict[str, int] = {}
+        self.hop_edges: Dict[str, int] = {}
         self.reset()
 
     def reset(self) -> None:
         self.tenant = ""
         self.edges = 0
         self.hops.clear()
+        self.hop_edges.clear()
         self.host_ms = 0.0
         self.device_ms = 0.0
         self.device_sync_ms = 0.0
@@ -144,8 +147,9 @@ class Ledger:
 
     # -- instrumentation sites (callers checked current() is not None) ------
 
-    def note_hop(self, route: str) -> None:
+    def note_hop(self, route: str, edges: int) -> None:
         self.hops[route] = self.hops.get(route, 0) + 1
+        self.hop_edges[route] = self.hop_edges.get(route, 0) + edges
 
     def note_cache(self, tier: str, event: str, nbytes: int) -> None:
         """One cache-tier probe outcome (tier ∈ hop/result; event is the
@@ -177,6 +181,9 @@ class Ledger:
         lv = int(stats.get("chain_fused_levels", 0))
         if lv:
             self.hops["chain"] = self.hops.get("chain", 0) + lv
+            self.hop_edges["chain"] = self.hop_edges.get("chain", 0) + int(
+                stats.get("chain_edges", 0)
+            )
         mxu = sum(
             1 for r in stats.get("join_routes", ())
             if isinstance(r, dict) and r.get("route") == "mxu"
@@ -195,6 +202,7 @@ class Ledger:
         d = {
             "edges": self.edges,
             "hops": dict(self.hops),
+            "hop_edges": dict(self.hop_edges),
             "host_ms": round(self.host_ms, 3),
             "device_ms": round(self.device_ms, 3),
             "device_sync_ms": round(self.device_sync_ms, 3),
@@ -262,6 +270,9 @@ def finish(led: Ledger) -> dict:
         EDGES_TRAVERSED.add(label, led.edges)
     for route, n in led.hops.items():
         LEDGER_HOPS.add(route, n)
+    for route, n in led.hop_edges.items():
+        if n:
+            LEDGER_HOP_EDGES.add(route, n)
     if led.host_ms:
         LEDGER_STAGE_US.add("host", int(led.host_ms * 1e3))
     if led.device_ms:
@@ -298,6 +309,7 @@ def aggregate_summary() -> dict:
     return {
         "edges_by_tenant": EDGES_TRAVERSED.snapshot(),
         "hops_by_route": LEDGER_HOPS.snapshot(),
+        "hop_edges_by_route": LEDGER_HOP_EDGES.snapshot(),
         "stage_us": LEDGER_STAGE_US.snapshot(),
         "bytes": LEDGER_BYTES.snapshot(),
         "structs_created": LEDGERS_CREATED.value(),

@@ -11,12 +11,18 @@ other k-1 rows by a tiled VPU compare (the rows sit whole in VMEM — a
 bitonic sort compacts survivors.  Survivors of row 0 are already sorted-
 unique, so the result is byte-identical to ``intersect_many``.
 
-Status: correctness-verified in Pallas interpret mode on CPU
-(tests/test_pallas.py, the `pallas-interpret` CI tier).  Mosaic lowering
-is unverified until the next real-chip session (the [128 x L] broadcast
-compare may want explicit tiling); the TPU A/B measurement is wired in
-bench_ops.py and the kernel is registered in the device-program contract
-registry (analysis/programs.py "pallas.intersect").
+Status (PR 21): REFUSED by the TPU v5e compiler; no served path calls it
+(bench_ops.py and tests only).  Compiled for a described v5e in the
+sandbox: ``MosaicError: INTERNAL: Mosaic failed to compile TPU kernel:
+cannot statically prove that index in dimension 0 is a multiple of 1024``
+on the ``tpu.vector_store`` of the 1-D ``(128,)`` survivor block — a 1-D
+int32 buffer is tiled ``(1024)`` in VMEM, so the output wants a
+``[L/128, 128]`` shape (tests/test_chip_compile.py pins the message as a
+strict xfail; ROADMAP S5 owns the repair).  Correctness is verified in
+Pallas interpret mode on CPU (tests/test_pallas.py, the
+`pallas-interpret` CI tier), and the kernel is registered in the
+device-program contract registry (analysis/programs.py
+"pallas.intersect").
 """
 
 from __future__ import annotations

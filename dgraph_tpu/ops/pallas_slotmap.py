@@ -17,19 +17,20 @@ Pallas fused segmented-scan kernel"):
 Per 128-slot block the kernel takes the prefix offset plus a <=128-row
 window max — a [128 x 128] VPU tile — instead of global scans/scatters.
 
-Status: PROMOTED (PR 16).  Wired into the grouped-expansion path behind
-the DGRAPH_TPU_SLOTMAP knob (ops/sets.py expand_inline_grouped_auto /
-use_slotmap_pallas; bench.py's device-dedup pipeline selects it, and the
-legacy BENCH_PALLAS=1 override still works): '1' auto enables the kernel
-on the TPU backend only, 'force' runs it anywhere under the interpreter
-— the mode the parity property tests pin (tests/test_pallas.py, vs both
-the XLA slot-map and slotmap_reference).  The contract registry entry
-(analysis/programs.py "pallas.slotmap") is FULL: golden fingerprint,
-callback/dtype/transfer audits, a cost entry and a bucket probe.  Mosaic
-lowering itself remains a measure-first task for the next chip session
-(interpret mode skips Mosaic; the 1-D scratch reshape / dynamic slices
-here are constructs it may want reshaped) — which is why auto mode stays
-backend-gated rather than unconditional.
+Status (PR 21): REFUSED by the TPU v5e compiler, off every default path.
+Compiled for a described v5e in the sandbox, the Pallas TPU lowering
+stops before Mosaic runs: ``NotImplementedError: Unimplemented primitive
+in Pallas TPU lowering for KernelType.TC: cumsum``
+(tests/test_chip_compile.py pins the message as a strict xfail; ROADMAP
+S5 owns the repair — the in-kernel prefix sums need a lane-rotate
+scan or an MXU triangular matmul in their place).  Until then
+DGRAPH_TPU_SLOTMAP's auto mode selects the XLA slot-map on every backend
+(ops/sets.py use_slotmap_pallas); 'force' runs the kernel anywhere — under
+the interpreter on CPU, the mode the parity property tests pin
+(tests/test_pallas.py, vs both the XLA slot-map and slotmap_reference).
+The contract registry entry (analysis/programs.py "pallas.slotmap") stays
+FULL in interpret mode: golden fingerprint, callback/dtype/transfer
+audits, a cost entry and a bucket probe.
 """
 
 from __future__ import annotations

@@ -546,8 +546,9 @@ def _ov_slot_map_pallas(cs: jnp.ndarray, cd: jnp.ndarray, capc: int):
     """Slot→chunk map via the Pallas kernel (ops/pallas_slotmap.py): one
     VMEM-resident pass replaces the XLA scatter + three O(n log n) scans
     (docs/ROOFLINE.md Path-onward #2, ~15-20% of device time).  Inputs
-    pad up to the kernel's 128-lane granularity; off-TPU backends run the
-    kernel in interpret mode so the path stays testable everywhere.
+    pad up to the kernel's 128-lane granularity; the CPU backend runs the
+    kernel in interpret mode so the path stays testable there (on a TPU
+    it compiles through Mosaic or fails — see pallas_slotmap.py Status).
 
     Returns (chunkid[capc] clipped to >= 0, ok[capc])."""
     from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas
@@ -594,18 +595,14 @@ def expand_inline_grouped_pallas(
 
 def use_slotmap_pallas() -> bool:
     """Should grouped expansions route their slot-map through the Pallas
-    kernel?  DGRAPH_TPU_SLOTMAP (utils/planconfig.py): '0' never, '1'
-    auto (TPU backend only — Mosaic is where the kernel pays off; the
-    interpreter is correctness-speed), 'force' any backend (interpret
-    mode off-TPU, the parity-test mode)."""
+    kernel?  DGRAPH_TPU_SLOTMAP (utils/planconfig.py): 'force' = yes, on
+    any backend (interpret mode on CPU — the parity-test mode; on a TPU
+    it compiles through Mosaic or fails).  '0' and the default '1' = no:
+    auto no longer selects the kernel on the TPU backend, because the
+    chip's compiler refuses it (ops/pallas_slotmap.py Status)."""
     from dgraph_tpu.utils import planconfig
 
-    mode = planconfig.slotmap_pallas()
-    if mode == "0":
-        return False
-    if mode == "force":
-        return True
-    return jax.default_backend() == "tpu"
+    return planconfig.slotmap_pallas() == "force"
 
 
 def expand_inline_grouped_auto(

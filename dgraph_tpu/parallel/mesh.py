@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from dgraph_tpu import ops
 from dgraph_tpu.ops.sets import SENT
@@ -63,14 +63,31 @@ class ShardedArena:
         )
 
 
-def shard_arena_rows(h_src: np.ndarray, h_offsets: np.ndarray, h_dst: np.ndarray, n_shards: int) -> ShardedArena:
-    """Split CSR rows into n contiguous uid-range shards (host-side)."""
+def put_sharded(mesh: Mesh, x: np.ndarray) -> jnp.ndarray:
+    """Place a [n_model, ...] host array with one row per model-axis
+    device.  A plain ``jnp.asarray`` would put all of it on the first
+    device and leave the jitted ``shard_map`` to re-shard it on every
+    call."""
+    spec = P("model", *([None] * (x.ndim - 1)))
+    return jax.device_put(x, NamedSharding(mesh, spec))
+
+
+def put_replicated(mesh: Mesh, x: np.ndarray) -> jnp.ndarray:
+    """Place a host array (a frontier) whole on every device of the mesh."""
+    return jax.device_put(x, NamedSharding(mesh, P()))
+
+
+def shard_arena_rows(
+    h_src: np.ndarray, h_offsets: np.ndarray, h_dst: np.ndarray, mesh: Mesh
+) -> ShardedArena:
+    """Split CSR rows into contiguous uid-range shards, one per device of
+    the mesh's model axis, and place each shard on its device."""
+    n_shards = int(mesh.shape["model"])
     S = len(h_src)
     per = -(-S // n_shards) if S else 1
     Sp = ops.bucket(max(1, per))
     degs = h_offsets[1:] - h_offsets[:-1] if S else np.empty(0, np.int64)
     Ep = 1
-    slices = []
     for i in range(n_shards):
         lo, hi = i * per, min(S, (i + 1) * per)
         e = int(degs[lo:hi].sum()) if hi > lo else 0
@@ -90,7 +107,9 @@ def shard_arena_rows(h_src: np.ndarray, h_offsets: np.ndarray, h_dst: np.ndarray
         e0, e1 = int(h_offsets[lo]), int(h_offsets[hi])
         dsts[i, : e1 - e0] = h_dst[e0:e1]
     return ShardedArena(
-        src=jnp.asarray(srcs), offsets=jnp.asarray(offs), dst=jnp.asarray(dsts),
+        src=put_sharded(mesh, srcs),
+        offsets=put_sharded(mesh, offs),
+        dst=put_sharded(mesh, dsts),
         n_shards=n_shards,
     )
 
@@ -120,7 +139,7 @@ def sharded_expand_step(mesh: Mesh, cap: int):
         mesh=mesh,
         in_specs=(P("model", None), P("model", None), P("model", None), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -179,7 +198,7 @@ def seg_expand_packed_step(mesh: Mesh, cap: int, fcap: int):
         mesh=mesh,
         in_specs=(P("model", None), P("model", None), P("model", None), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn), total_slots
 
@@ -220,7 +239,9 @@ def sharded_expand_segments(
 
     fail.point("device.mesh")
     fcap = _fcap_bucket(len(frontier))
-    f = jnp.asarray(ops.pad_to(np.asarray(frontier, dtype=np.int64), fcap))
+    f = put_replicated(
+        mesh, ops.pad_to(np.asarray(frontier, dtype=np.int64), fcap)
+    )
     step, total_slots = seg_expand_packed_step(mesh, cap, fcap)
     packed = np.asarray(step(sharded.src, sharded.offsets, sharded.dst, f))
     seg_ptr_full = packed[total_slots:]
@@ -262,7 +283,7 @@ def batched_hop_step(mesh: Mesh, cap: int, cap_out: int, n_hops: int):
         mesh=mesh,
         in_specs=(P(), P(), P("data", None)),
         out_specs=(P("data", None), P("data", None)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -306,7 +327,9 @@ def sharded_two_hop(mesh: Mesh, arena: ShardedArena, frontier: np.ndarray, cap1:
     """Two-hop sharded traversal: returns (hop1 uids, hop2 uids) padded."""
     step1 = sharded_expand_step(mesh, cap1)
     step2 = sharded_expand_step(mesh, cap2)
-    f = jnp.asarray(ops.pad_to(frontier, ops.bucket(max(1, len(frontier)))))
+    f = put_replicated(
+        mesh, ops.pad_to(frontier, ops.bucket(max(1, len(frontier))))
+    )
     h1 = step1(arena.src, arena.offsets, arena.dst, f)
     h2 = step2(arena.src, arena.offsets, arena.dst, h1)
     return h1, h2
@@ -369,7 +392,7 @@ def tile_expand_step(mesh: Mesh, kp: int, t: int, m: int):
             P(),
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -101,8 +101,11 @@ def boot(measure_now: bool = False) -> Calibration:
     """Install the best available calibration.
 
     ``measure_now=False`` (every server construction): load a valid
-    persisted file — the warm-boot path that skips the measurement pass
-    — else keep the current rates (priors on a cold process).
+    persisted file — the warm-boot path that skips the measurement pass.
+    With no such file, a ``cpu`` backend keeps the current rates (the
+    shipped priors ARE cpu-backend numbers); any other backend measures
+    now and persists, because priors taken on a CPU must never price
+    routes on a chip they were not taken on.
 
     ``measure_now=True`` (``DGRAPH_TPU_CALIBRATE=1`` boots, every
     bench.py round): RE-measure unconditionally and persist, replacing
@@ -110,17 +113,16 @@ def boot(measure_now: bool = False) -> Calibration:
     so it must never be short-circuited by the very file it is meant to
     refresh."""
     global _CAL
-    path = planconfig.calibration_file()
-    backend = None
-    if path or measure_now:
-        try:
-            import jax
+    import jax
 
-            backend = jax.default_backend()
-        except Exception:  # noqa: BLE001 — no backend = keep priors
-            backend = None
+    path = planconfig.calibration_file()
+    backend = jax.default_backend()
     cal = None
-    if measure_now and backend is not None:
+    if path and not measure_now:
+        # the backend gate is unconditional: a TPU calibration must
+        # never price a CPU boot, nor the reverse
+        cal = load(path, backend=backend)
+    if cal is None and (measure_now or backend != "cpu"):
         cal = measure()
         PLANNER_CALIBRATIONS.add()
         if path:
@@ -128,11 +130,6 @@ def boot(measure_now: bool = False) -> Calibration:
                 save(cal, path)
             except OSError:
                 pass  # read-only disk: serve from the in-memory rates
-    if cal is None and path and backend:
-        # the backend gate is unconditional: with no known backend the
-        # file is NOT loaded (a TPU calibration must never price a CPU
-        # boot, and an unknown boot must never trust either kind)
-        cal = load(path, backend=backend)
     if cal is not None:
         with _LOCK:
             _CAL = cal

@@ -1337,8 +1337,13 @@ def _store_stats(store: PostingStore) -> dict:
             "edges": sum(len(s) for s in pd.edges.values()),
             "values": len(pd.values),
         }
+    from dgraph_tpu import native
+
     return {
         "predicates": preds,
         "uids": len(store.uids),
         "max_uid": store.uids.max_uid,
+        # which N-Quad scanner bulk loads ride (the native one is built
+        # on demand and silently absent without a toolchain)
+        "nquad_scanner": native.scanner_name(),
     }

@@ -16,7 +16,9 @@ Three sources, in trust order:
   ``DGRAPH_TPU_CALIBRATION_FILE`` (rejected when the backend or format
   version differs — a TPU calibration must never price a CPU boot);
 - ``prior`` — shipped defaults distilled from the r4/r9 bench rounds
-  (CPU-backend numbers; deliberately conservative).
+  (CPU-backend numbers, valid for the ``cpu`` backend only: a server on
+  any other backend with no matching file measures at boot,
+  query/planner.py ``boot``).
 
 The calibration is a starting point, not the whole story: the planner
 refines the edge/element rates ONLINE from the per-hop stage timings the
@@ -206,7 +208,17 @@ def measure(edges: int = 1 << 16, reps: int = 5) -> Calibration:
         device_edge_us=device_edge_us,
         host_edge_us=host_edge_us,
         host_intersect_us=host_intersect_us,
-        # device fold shares the gather engine; scale the prior ratio
+        # the resident gather, the mesh expansion and the device fold
+        # share the gather engine: scale their prior ratios to it until
+        # the online refinement has seen each route run
+        resident_edge_us=max(
+            device_edge_us * (PRIORS.resident_edge_us / PRIORS.device_edge_us),
+            1e-5,
+        ),
+        mesh_edge_us=max(
+            device_edge_us * (PRIORS.mesh_edge_us / PRIORS.device_edge_us),
+            1e-5,
+        ),
         device_intersect_us=max(
             device_edge_us * (PRIORS.device_intersect_us / PRIORS.device_edge_us),
             1e-5,

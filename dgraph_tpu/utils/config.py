@@ -75,7 +75,9 @@ class Options:
 
     # persistent XLA compilation cache: first-compile of a query shape
     # costs seconds on TPU; caching across restarts makes repeat cold
-    # starts warm.  "auto" = <postings_dir>/.jitcache, "" disables.
+    # starts warm.  "auto" = the fixed in-checkout directory of
+    # utils/jaxcache.py (JAX_COMPILATION_CACHE_DIR wins when set), ""
+    # disables.
     compile_cache: str = "auto"
 
     # directory for per-query execution-shape dumps (--dumpsg,

@@ -27,7 +27,9 @@ discipline for the device:
 - **Dispatch watchdog** — :meth:`DeviceGuard.run` executes the
   dispatch+fetch closure on a guard-owned worker thread and waits at
   most ``DGRAPH_TPU_DEVICE_HANG_MS`` (default 30s — generous enough for
-  a cold multi-second XLA compile, far below "forever").  On overrun
+  a cold dispatch; time the worker spends inside XLA compiles is not
+  counted, since compiling is host work and a cold program at deployed
+  widths compiles for longer than that).  On overrun
   the caller abandons the wedged worker (it keeps blocking — nothing
   can interrupt a stuck XLA call — but it is no longer anyone's
   problem), latches the domain SICK and raises
@@ -136,15 +138,31 @@ _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "out of memory", "Out of memory")
 # class names that mean "the XLA runtime itself failed" across jaxlib
 # layouts (jaxlib.xla_extension.XlaRuntimeError, jax.errors aliases)
 _XLA_CLASS_MARKERS = ("XlaRuntimeError", "JaxRuntimeError")
+# a program the compiler refuses is a bug in the program, whatever class
+# carries the refusal: Pallas lowering (NotImplementedError), Mosaic
+# (MosaicError / VerificationError), XLA:TPU ("compile permanent error")
+_COMPILER_CLASS_MARKERS = ("MosaicError", "VerificationError")
+_COMPILER_TEXT_MARKERS = ("Mosaic failed to compile", "compile permanent error")
+# set on an exception that left a backend compile (note_compile_end)
+_COMPILE_FAILURE_ATTR = "_dgraph_compile_failure"
 
 
 def classify(exc: BaseException) -> Optional[str]:
     """Sort a dispatch failure: "oom" / "transient" device faults, or
     None for everything that is NOT the device's fault (shape bugs,
-    ValueErrors) — those re-raise unwrapped, never masked by failover."""
+    ValueErrors, programs the compiler refuses — whatever memory space
+    or error class the refusal names) — those re-raise unwrapped, never
+    masked by failover."""
     if isinstance(exc, DeviceFaultError):
         return exc.kind
     text = f"{type(exc).__name__}: {exc}"
+    if (
+        getattr(exc, _COMPILE_FAILURE_ATTR, False)
+        or isinstance(exc, NotImplementedError)
+        or any(m in type(exc).__name__ for m in _COMPILER_CLASS_MARKERS)
+        or any(m in text for m in _COMPILER_TEXT_MARKERS)
+    ):
+        return None
     if any(m in text for m in _OOM_MARKERS):
         return "oom"
     if any(m in type(exc).__name__ for m in _XLA_CLASS_MARKERS):
@@ -154,6 +172,39 @@ def classify(exc: BaseException) -> Optional[str]:
         # OSError inside a dispatch closure is transport-shaped too
         return "transient"
     return None
+
+
+# -- XLA compile accounting -----------------------------------------------------
+#
+# Fed by obs/device.py's jax.monitoring listeners (installed at server
+# boot).  Compiling is host work: it cannot wedge the device, and a cold
+# program at deployed widths compiles for longer than the hang deadline
+# (sandbox compiles for v5e at film-21M widths, PR 21: resident.merge
+# 93 s, batch.multi_hop 23 s) — so the watchdog does not count it, and an
+# exception that leaves a compile is marked as the compiler's refusal.
+
+_tls = threading.local()
+
+
+def note_compile_begin() -> None:
+    """A backend compile started on this thread."""
+    job = getattr(_tls, "job", None)
+    if job is not None:
+        job.compile_t0 = time.monotonic()
+
+
+def note_compile_end(secs: float, exc: Optional[BaseException]) -> None:
+    """The backend compile on this thread ended after ``secs``; ``exc``
+    is the exception leaving it, if it failed."""
+    if exc is not None:
+        try:
+            setattr(exc, _COMPILE_FAILURE_ATTR, True)
+        except (AttributeError, TypeError):
+            pass  # slotted foreign class: the text markers still apply
+    job = getattr(_tls, "job", None)
+    if job is not None:
+        job.compile_t0 = None
+        job.compile_s += secs
 
 
 # chip attribution: XLA device errors sometimes name the failing device
@@ -182,7 +233,8 @@ def chip_of(exc: BaseException) -> Optional[int]:
 
 class _Job:
     __slots__ = (
-        "fn", "done", "result", "exc", "abandoned", "lock", "_race_serial",
+        "fn", "done", "result", "exc", "abandoned", "lock",
+        "compile_t0", "compile_s", "_race_serial",
     )
 
     # graftcheck tier 3: the dispatcher creates the job, ONE worker
@@ -198,6 +250,14 @@ class _Job:
         self.exc: Optional[BaseException] = None
         self.abandoned = False
         self.lock = threading.Lock()
+        # compile accounting: written by the worker (through the
+        # jax.monitoring listeners), read by the waiting dispatcher
+        self.compile_t0: Optional[float] = None  # in-flight compile start
+        self.compile_s = 0.0                     # finished compile seconds
+
+    def compile_seconds(self) -> float:
+        t0 = self.compile_t0
+        return self.compile_s + (time.monotonic() - t0 if t0 is not None else 0.0)
 
 
 class DeviceGuard:
@@ -351,7 +411,7 @@ class DeviceGuard:
         if self.state == SICK:
             raise DeviceSickError(self.domain, op)
         job = self._submit(fn)
-        if not job.done.wait(self.hang_ms / 1000.0):
+        if not self._wait(job):
             with job.lock:
                 if not job.done.is_set():
                     job.abandoned = True
@@ -378,6 +438,21 @@ class DeviceGuard:
             ) from job.exc
         self.note_ok()
         return job.result
+
+    def _wait(self, job: _Job) -> bool:
+        """Wait for ``job`` up to the hang deadline, not counting the
+        time its worker spends inside XLA compiles (see "XLA compile
+        accounting" above)."""
+        limit = self.hang_ms / 1000.0
+        t0 = time.monotonic()
+        while True:
+            left = limit + job.compile_seconds() - (time.monotonic() - t0)
+            if left <= 0:
+                return job.done.is_set()
+            # a compile in flight keeps ``left`` constant: re-check at
+            # least every 50 ms of it instead of spinning on a sliver
+            if job.done.wait(max(left, 0.05)):
+                return True
 
     def _submit(self, fn) -> _Job:
         import contextvars
@@ -532,11 +607,13 @@ class _IdleWorker:
     def _loop(self) -> None:
         while True:
             job = self.inbox.get()
+            _tls.job = job
             try:
                 job.result = job.fn()
             except BaseException as e:  # noqa: BLE001 — transported to
                 # the waiting caller verbatim, classified there
                 job.exc = e
+            _tls.job = None
             with job.lock:
                 job.done.set()
                 abandoned = job.abandoned

@@ -745,7 +745,9 @@ SUBS_SHED = metrics.labeled(
 # the BASELINE north-star metric (edges traversed per second) a live
 # per-tenant series instead of a bench artifact; LEDGER_HOPS{route}
 # counts hop dispatches by the route the expander took
-# (cache/merged/mesh/host/classed/inline/csr/chain/mxu);
+# (cache/merged/mesh/host/resident/classed/inline/csr/chain/mxu) and
+# LEDGER_HOP_EDGES{route} the edges those hops traversed — which route
+# actually carries a deployment's traffic, in the unit users pay for;
 # LEDGER_STAGE_US{stage} accumulates host/device/device_sync time in
 # integer microseconds; LEDGER_BYTES{dir} the staged h2d/d2h bytes and
 # cache-hit payload bytes.  LEDGERS_CREATED counts Ledger STRUCTS
@@ -757,6 +759,9 @@ EDGES_TRAVERSED = metrics.labeled(
     "dgraph_edges_traversed_total", label="tenant"
 )
 LEDGER_HOPS = metrics.labeled("dgraph_ledger_hops_total", label="route")
+LEDGER_HOP_EDGES = metrics.labeled(
+    "dgraph_ledger_hop_edges_total", label="route"
+)
 LEDGER_STAGE_US = metrics.labeled(
     "dgraph_ledger_stage_us_total", label="stage"
 )
@@ -772,6 +777,10 @@ LEDGERS_CREATED = metrics.counter("dgraph_ledger_structs_total")
 # via jax.monitoring (count + seconds as a histogram, so compile storms
 # show up as a rate AND a duration distribution).
 HBM_RESIDENT_BYTES = metrics.gauge("dgraph_hbm_resident_bytes")
+# resident arenas (models/arena.py ResidentArena): how each write reached
+# the pinned buffers — "merge" = the on-device delta merge (only the delta
+# pairs crossed h2d), "reseed" = a structural change re-uploaded the CSR
+RESIDENT_EPOCHS = metrics.labeled("dgraph_resident_epochs_total", label="how")
 HBM_BUDGET_BYTES = metrics.gauge("dgraph_hbm_budget_bytes")
 HBM_TILE_BYTES = metrics.gauge("dgraph_hbm_tile_bytes")
 ARENA_EVICTIONS = metrics.counter("dgraph_arena_evictions_total")

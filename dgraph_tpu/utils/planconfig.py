@@ -67,10 +67,11 @@ DGRAPH_TPU_RESIDENT           "1"    device-resident Pallas hop tier
                                      CPU; the parity-test mode)
 DGRAPH_TPU_SLOTMAP            "1"    Pallas slot-map kernel in grouped
                                      inline expansions (ops/sets.py
-                                     expand_inline_grouped_auto): 0 XLA
-                                     scan/scatter always / 1 auto (TPU
-                                     backend only) / force (any backend,
-                                     interpret mode off-TPU)
+                                     expand_inline_grouped_auto): 0 / 1
+                                     XLA scan/scatter (auto selects the
+                                     kernel nowhere while the TPU
+                                     compiler refuses it) / force (any
+                                     backend, interpret mode on CPU)
 DGRAPH_TPU_IVM_REPAIR         "1"    IVM delta repair of cached hop
                                      entries / tile blocks: 0 drop-only /
                                      1 cost-gated / force (skip the
@@ -218,11 +219,11 @@ def resident() -> str:
 
 
 def slotmap_pallas() -> str:
-    """DGRAPH_TPU_SLOTMAP: grouped-expansion slot-map backend ('0' XLA
-    scan/scatter chain always / '1' auto: the Pallas kernel on the TPU
-    backend only, so default CPU serving compiles no interpret-mode
-    programs / 'force': the Pallas kernel on any backend, interpret mode
-    off-TPU — the mode the parity tests pin)."""
+    """DGRAPH_TPU_SLOTMAP: grouped-expansion slot-map backend ('0' and
+    the default '1': the XLA scan/scatter chain — auto selects the
+    Pallas kernel nowhere while the TPU compiler refuses it / 'force':
+    the Pallas kernel on any backend, interpret mode on CPU — the mode
+    the parity tests pin)."""
     return os.environ.get("DGRAPH_TPU_SLOTMAP", "1")
 
 

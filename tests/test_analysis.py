@@ -1439,7 +1439,7 @@ def test_naked_collective_flagged_outside_mesh_dirs():
 
     bad = textwrap.dedent("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def hop(mesh, f):
@@ -1471,7 +1471,7 @@ def test_naked_collective_counterexamples_clean():
 
     homed = textwrap.dedent("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def step(mesh, t):
             fn = shard_map(lambda x: x, mesh=mesh, in_specs=(),

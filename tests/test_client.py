@@ -58,6 +58,23 @@ def test_batching_client_http(srv):
     c.close()
 
 
+def test_batch_set_block_is_one_mutation_per_block(srv):
+    """The bulk loader's hand-off: a block of lines crosses the queue as
+    ONE item and one worker submits it as one mutation — also when single
+    quads are queued around it."""
+    c = DgraphClient(EmbeddedTransport(srv), BatchMutationOptions(size=4, pending=2))
+    c.batch_set(ClientEdge.value("0x1", "name", "single before"))
+    c.batch_set_block([f'<0x{i + 10:x}> <name> "block a {i}" .' for i in range(50)])
+    c.batch_set_block([f'<0x{i + 100:x}> <name> "block b {i}" .' for i in range(30)])
+    c.batch_set_block([])  # nothing to send
+    c.batch_set(ClientEdge.value("0x2", "name", "single after"))
+    c.flush()
+    assert len(c.query("{ q(func: has(name)) { name } }")["q"]) == 82
+    # 2 blocks + at most 2 batches of singles — never one mutation per line
+    assert 3 <= c.mutation_count() <= 4
+    c.close()
+
+
 def test_batch_delete(srv):
     c = DgraphClient(EmbeddedTransport(srv), BatchMutationOptions(size=4, pending=2))
     c.batch_set(ClientEdge.value("0x1", "name", "temp"))

@@ -138,6 +138,73 @@ def test_classifier():
         pass
 
 
+class MosaicError(Exception):
+    """Stands in for jax's Pallas class of the same name (matched by name)."""
+
+
+class XlaRuntimeError(RuntimeError):
+    """Stands in for jaxlib's runtime error class (matched by name)."""
+
+
+def _marked_by_a_failed_compile(exc):
+    devguard.note_compile_end(0.1, exc)
+    return exc
+
+
+@pytest.mark.parametrize(
+    "exc, kind",
+    [
+        # what the compiler refuses is a bug in the program, whatever
+        # class or memory space the refusal names — never failed over
+        (NotImplementedError("Unimplemented primitive in Pallas TPU lowering: cumsum"), None),
+        (MosaicError("INTERNAL: Mosaic failed to compile TPU kernel: ..."), None),
+        (XlaRuntimeError("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. "
+                         "Ran out of memory in memory space hbm"), None),
+        (_marked_by_a_failed_compile(XlaRuntimeError(
+            "RESOURCE_EXHAUSTED: Allocation (size=268435456) would exceed "
+            "memory (size=134217728) ... space=vmem")), None),
+        # the same classes at run time stay device faults
+        (XlaRuntimeError("RESOURCE_EXHAUSTED: Error allocating device buffer"), "oom"),
+        (XlaRuntimeError("INTERNAL: device halted"), "transient"),
+    ],
+)
+def test_classifier_compiler_refusals_are_bugs_not_faults(exc, kind):
+    assert devguard.classify(exc) == kind
+
+
+def test_watchdog_does_not_count_time_spent_compiling():
+    """Compiling is host work and outlasts the hang deadline at deployed
+    widths: the deadline bounds the dispatch, not the compile before it."""
+    g = DeviceGuard("t", hang_ms=150, cooldown_s=10.0)
+
+    def cold_dispatch():
+        devguard.note_compile_begin()
+        time.sleep(0.6)  # an XLA compile, as obs/device.py reports one
+        devguard.note_compile_end(0.6, None)
+        time.sleep(0.05)  # the dispatch itself
+        return 7
+
+    assert g.run("op", cold_dispatch) == 7
+    assert g.state == "healthy" and not g.faults
+    # the same wall time without a compile in it is a hang
+    with pytest.raises(DeviceHangError):
+        g.run("op", lambda: time.sleep(0.65) or 7)
+
+
+def test_failed_compile_inside_the_guard_raises_unwrapped():
+    g = DeviceGuard("t", hang_ms=500, cooldown_s=10.0)
+
+    def refused():
+        devguard.note_compile_begin()
+        exc = XlaRuntimeError("RESOURCE_EXHAUSTED: ... space=smem")
+        devguard.note_compile_end(0.01, exc)
+        raise exc
+
+    with pytest.raises(XlaRuntimeError):
+        g.run("op", refused)
+    assert g.state == "healthy" and not g.faults and g.failovers == 0
+
+
 def test_suspect_then_sick_then_probe_readmits():
     g = DeviceGuard("t", hang_ms=500, cooldown_s=0.05, sick_after=2)
 

@@ -291,12 +291,13 @@ def test_ledger_pool_roundtrip_unit():
     led = ledgermod.start("t1")
     assert led is not None
     led.edges = 5
-    led.note_hop("host")
+    led.note_hop("host", 5)
     before = EDGES_TRAVERSED.snapshot().get("t1", 0)
     summary = ledgermod.finish(led)
     assert summary["edges"] == 5
+    assert summary["hop_edges"] == {"host": 5}
     assert EDGES_TRAVERSED.snapshot().get("t1", 0) - before == 5
     # the recycled struct carries nothing forward
     again = ledgermod.start("t2")
-    assert again.edges == 0 and not again.hops
+    assert again.edges == 0 and not again.hops and not again.hop_edges
     ledgermod.finish(again)

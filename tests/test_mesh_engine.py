@@ -66,28 +66,14 @@ def test_mesh_steps_compile_once():
     q = QUERIES[0]
     first = eng.run(q)
 
-    import jax._src.test_util as jtu
+    from dgraph_tpu.analysis.pytest_budget import compile_count
 
-    with jtu.count_jit_compilation_cache_miss() as misses:
-        second = eng.run(q)
+    c0 = compile_count()
+    second = eng.run(q)
     assert second == first
-    # jtu.count_jit_compilation_cache_miss yields a one-element counter
-    # list, not a callable — misses() was a TypeError on every run.
-    # With the counter actually read, the seed engine turns out to
-    # recompile 3 NON-mesh helper programs on an identical re-run; the
-    # mesh steps themselves are memoized (identity asserts above).
-    # Pin the seed baseline so a recompile REGRESSION still fails, and
-    # xfail the pre-existing wart instead of hiding it:
-    assert misses[0] <= 3, (
-        f"identical mesh query recompiled {misses[0]} program(s) — "
-        "worse than the seed baseline of 3"
+    assert compile_count() == c0, (
+        f"identical mesh query compiled {compile_count() - c0} new program(s)"
     )
-    if misses[0]:
-        pytest.xfail(
-            f"identical query recompiled {misses[0]} non-mesh helper "
-            "program(s) — pre-existing at seed, masked by the misses() "
-            "TypeError until now"
-        )
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8-device mesh")
@@ -103,7 +89,7 @@ def test_sharded_reassembly_is_device_side(monkeypatch):
     dst = rng.integers(1, 500, size=4000)
     a = csr_from_edges(src, dst)
     m = make_mesh(8, data=1)
-    sa = mesh_mod.shard_arena_rows(a.h_src, a.h_offsets, a.host_dst(), 8)
+    sa = mesh_mod.shard_arena_rows(a.h_src, a.h_offsets, a.host_dst(), m)
     frontier = np.unique(rng.integers(1, 500, size=40))
     cap = int(a.degree_of_rows(a.rows_for_uids_host(frontier)).sum()) or 1
     from dgraph_tpu import ops as _ops
@@ -136,7 +122,7 @@ def test_mesh_mixed_stream_bounded_traces():
     dst = rng.integers(1, 3000, size=20000)
     a = csr_from_edges(src, dst)
     m = make_mesh(8, data=1)
-    sa = mesh_mod.shard_arena_rows(a.h_src, a.h_offsets, a.host_dst(), 8)
+    sa = mesh_mod.shard_arena_rows(a.h_src, a.h_offsets, a.host_dst(), m)
 
     mesh_mod.seg_expand_packed_step.cache_clear()
     cap = 1 << 15  # fixed cap: isolate the fcap dimension
@@ -168,3 +154,28 @@ def test_mesh_engine_correct_after_mutation():
     plain.run('mutation { set { <0x1> <link> <0x3e8> . <0x3e8> <name> "NEW" . } }')
     assert eng.run(q) == plain.run(q)
     assert eng.run(q) != before  # the mutation is visible
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8-device mesh")
+def test_sharded_arena_is_placed_one_shard_per_chip():
+    """A row-sharded arena sits one shard on each chip of the model axis
+    from the moment it is built — not whole on the first device, to be
+    re-sharded by every jitted call — and a MeshPlan roll keeps it so."""
+    from dgraph_tpu.mesh.plan import MeshPlan
+    from dgraph_tpu.models.arena import csr_from_edges
+    from dgraph_tpu.parallel import mesh as mesh_mod
+
+    rng = np.random.default_rng(3)
+    a = csr_from_edges(rng.integers(1, 900, 6000), rng.integers(1, 900, 6000))
+    m = make_mesh(8, data=1)
+    sa = mesh_mod.shard_arena_rows(a.h_src, a.h_offsets, a.host_dst(), m)
+    rolled = MeshPlan.rolled(sa, 3)
+    for arena in (sa, rolled):
+        for t in (arena.src, arena.offsets, arena.dst):
+            shards = t.addressable_shards
+            assert len({s.device for s in shards}) == 8
+            assert all(s.data.shape == (1,) + t.shape[1:] for s in shards)
+    assert np.array_equal(np.asarray(rolled.dst), np.roll(np.asarray(sa.dst), 3, axis=0))
+    f = mesh_mod.put_replicated(m, np.arange(16, dtype=np.int32))
+    assert all(s.data.shape == (16,) for s in f.addressable_shards)
+    assert len(f.addressable_shards) == 8

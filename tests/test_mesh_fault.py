@@ -406,7 +406,7 @@ def test_epoch_flip_adds_only_bounded_program_shapes(monkeypatch):
     program shapes (one compile round at the new width); the SECOND
     pass at that width — and the flip back to the memoized boot mesh —
     compile nothing."""
-    import jax._src.test_util as jtu
+    from dgraph_tpu.analysis.pytest_budget import compile_count
 
     monkeypatch.setenv("DGRAPH_TPU_DEVICE_COOLDOWN_S", "0.2")
     devguard.reset_for_tests()
@@ -429,25 +429,26 @@ def test_epoch_flip_adds_only_bounded_program_shapes(monkeypatch):
             out.pop("degraded", None)
             first[q] = out
         assert first == baseline
-        with jtu.count_jit_compilation_cache_miss() as misses:
-            for q in QUERIES:
-                out = _ask(srv, q)
-                out.pop("degraded", None)
-                assert out == baseline[q]
-        assert misses[0] == 0, (
-            f"repeat queries on the settled sub-mesh recompiled "
-            f"{misses[0]} program(s)"
+        c0 = compile_count()
+        for q in QUERIES:
+            out = _ask(srv, q)
+            out.pop("degraded", None)
+            assert out == baseline[q]
+        assert compile_count() == c0, (
+            f"repeat queries on the settled sub-mesh compiled "
+            f"{compile_count() - c0} new program(s)"
         )
         # rejoin flips back to the MEMOIZED boot mesh: the lru-cached
         # programs hash-hit, so repeat queries compile nothing at all
         fail.disarm("mesh.warm")
         assert _until(lambda: dom.width == 8), dom.status()
         _ask(srv, QUERIES[0])  # settle (sharded views re-adopted/built)
-        with jtu.count_jit_compilation_cache_miss() as misses:
-            for q in QUERIES:
-                assert _ask(srv, q) == baseline[q]
-        assert misses[0] == 0, (
-            f"post-rejoin repeat queries recompiled {misses[0]} program(s)"
+        c0 = compile_count()
+        for q in QUERIES:
+            assert _ask(srv, q) == baseline[q]
+        assert compile_count() == c0, (
+            f"post-rejoin repeat queries compiled {compile_count() - c0} "
+            "new program(s)"
         )
     finally:
         fail.reset()

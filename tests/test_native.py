@@ -186,3 +186,32 @@ def test_bad_delete_applies_no_sets(monkeypatch):
             Mutation(set_nquads='<0x1> <name> "x" .', del_nquads="<0x1> <p> <0xzz> ."),
         )
     assert st.value("name", 1) is None
+
+
+def test_binary_is_rebuilt_when_the_source_hash_differs(tmp_path, monkeypatch):
+    """The .so is a build product outside git: a copy of the tree may carry
+    a stale binary with any mtime.  Freshness is the source's content hash
+    stored beside the binary — a mismatch (or no stamp) rebuilds, a match
+    does not."""
+    import shutil
+
+    from dgraph_tpu import native
+
+    if shutil.which("g++") is None:
+        pytest.skip("no toolchain: the scanner falls back to Python")
+    src = tmp_path / "nquad_scan.cpp"
+    shutil.copy(native._SRC, src)
+    so = tmp_path / "libnquad.so"
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_STAMP", str(so) + ".sha256")
+    so.write_bytes(b"a stale binary, newer than the source")
+    assert native._build() == str(so)            # no stamp: rebuilt
+    built = so.read_bytes()
+    assert built != b"a stale binary, newer than the source"
+    assert (tmp_path / "libnquad.so.sha256").read_text() == native._source_digest()
+    so.write_bytes(b"tampered")                  # stamp matches: kept as is
+    assert native._build() == str(so) and so.read_bytes() == b"tampered"
+    src.write_text(src.read_text() + "\n// edited\n")
+    assert native._build() == str(so) and so.read_bytes() != b"tampered"

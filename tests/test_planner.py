@@ -92,6 +92,34 @@ def test_boot_loads_persisted_calibration(tmp_path, monkeypatch):
     assert planner.calibration_info()["rates"]["dispatch_us"] == 42.0
 
 
+@pytest.mark.parametrize(
+    "backend, want_source", [("cpu", "prior"), ("tpu", "measured")]
+)
+def test_boot_without_a_file_measures_on_every_backend_but_cpu(
+    tmp_path, monkeypatch, backend, want_source
+):
+    """The shipped priors are CPU-backend numbers: a cpu boot serves on
+    them (and compiles nothing), any other backend measures at boot and
+    persists — rates taken on a CPU never price routes on a chip."""
+    path = tmp_path / "calib.json"
+    monkeypatch.setenv("DGRAPH_TPU_CALIBRATION_FILE", str(path))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    ran = []
+
+    def fake_measure():
+        ran.append(1)
+        return replace(PRIORS, dispatch_us=7.0, backend=backend, source="measured")
+
+    monkeypatch.setattr(planner, "measure", fake_measure)
+    cal = planner.boot()
+    assert cal.source == want_source
+    assert len(ran) == (want_source == "measured")
+    assert path.exists() == (want_source == "measured")
+    if want_source == "measured":
+        # the next boot on that backend is warm: the file, no second pass
+        assert planner.boot().source == "file" and len(ran) == 1
+
+
 def test_micro_calibration_measures_positive_rates():
     cal = measure(edges=1 << 12, reps=2)
     assert cal.source == "measured" and cal.backend == jax.default_backend()

@@ -78,8 +78,33 @@ def test_fingerprints_stable_and_match_shipped_goldens():
     fp1 = programs.collect_fingerprints()
     fp2 = programs.collect_fingerprints()
     assert fp1 == fp2
+    # fingerprints are jaxpr text: goldens blessed under another JAX
+    # release fail here with the remedy, not as N drifted hashes
+    assert programs.goldens_release_mismatch() is None, (
+        programs.goldens_release_mismatch()
+    )
     shipped = json.loads(programs.GOLDENS_PATH.read_text())["programs"]
     assert fp1 == shipped
+
+
+def test_goldens_of_another_jax_release_ask_for_a_rebless(
+    monkeypatch, tmp_path, capsys
+):
+    """Fingerprints are jaxpr text and change with the JAX release: goldens
+    blessed under another one fail with ONE finding that names both
+    releases and the remedy — not one drifted hash per program."""
+    name = "sets.union_many"
+    shipped = json.loads(programs.GOLDENS_PATH.read_text())
+    stale = tmp_path / "g.json"
+    stale.write_text(json.dumps({
+        "jax": "0.4.37", "programs": {name: shipped["programs"][name]},
+    }))
+    monkeypatch.setattr(programs, "REGISTRY", {name: programs.REGISTRY[name]})
+    rc = analysis_cli.main(["--programs", "--programs-goldens", str(stale)])
+    out = capsys.readouterr().out
+    assert rc != 0
+    assert "blessed under jax 0.4.37" in out and "re-bless" in out
+    assert out.count("[golden]") == 1, out
 
 
 def test_full_checker_clean_on_shipped_tree(capsys):
