@@ -230,10 +230,9 @@ def main(argv=None) -> int:
     else:
         store = DurableStore(opts.postings_dir, sync_writes=opts.sync_writes)
     if opts.trace_ratio > 0 and not os.environ.get("DGRAPH_TPU_TRACE_RATIO"):
-        # --trace drives BOTH samplers: the legacy /debug/requests ring
-        # (below, via DgraphServer) and the flight recorder's head
-        # sampler (obs/spans.py) — one operator knob, the env var wins
-        # when set explicitly
+        # --trace drives the flight recorder's head sampler
+        # (obs/spans.py: one sampler, one ring, /debug/traces) — the
+        # env var wins when set explicitly
         from dgraph_tpu import obs
 
         obs.configure(ratio=opts.trace_ratio)
@@ -242,7 +241,6 @@ def main(argv=None) -> int:
         port=opts.port,
         bind=opts.bind,
         export_path=opts.export_path,
-        trace_ratio=opts.trace_ratio,
         expose_trace=opts.expose_trace,
         tls_cert=opts.tls_cert,
         tls_key=opts.tls_key,

@@ -31,7 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dgraph_tpu import ops
+from dgraph_tpu import obs, ops
 from dgraph_tpu.obs import ledger as _ledger
 from dgraph_tpu.utils.metrics import ARENA_EVICTIONS, RESIDENT_EPOCHS
 from dgraph_tpu.ops.sets import SENT
@@ -46,6 +46,15 @@ from dgraph_tpu.models.types import TypeID, TypedValue, numeric
 # lock (vs per-arena) keeps CSRArena a plain dataclass; contention is
 # limited to cold-cache bursts.
 _BUILD_LOCK = threading.RLock()
+
+
+def _book_h2d(arrays) -> None:
+    """Bytes just put on the device (a lazily built layout, a re-upload,
+    a resident seed or delta), booked to the request on whose behalf
+    they crossed (none active: an embedded engine, a boot)."""
+    led = _ledger.current()
+    if led is not None:
+        led.bytes_h2d += sum(int(a.nbytes) for a in arrays)
 
 
 @dataclass
@@ -203,7 +212,9 @@ class CSRArena:
         (docs/ROOFLINE.md round 4)."""
         if self._inline is not None:
             return self._inline
-        with _BUILD_LOCK:
+        # stage h2d: built and put on first use — the request that meets
+        # the layout missing pays for it (or waits out another's build)
+        with obs.stage(None, "h2d_ms"), _BUILD_LOCK:
             if self._inline is not None:
                 return self._inline
             INL = ops.INLINE
@@ -238,6 +249,7 @@ class CSRArena:
                 e = starts[rowid] + INL + within
                 ov[coff[rowid] + (within >> 3), within & 7] = h_dst[e]
             self._inline = (jnp.asarray(metap), jnp.asarray(ov))
+            _book_h2d(self._inline)
             return self._inline
 
     def ov_chunk_degree_of_rows(self, rows: np.ndarray) -> np.ndarray:
@@ -283,7 +295,9 @@ class CSRArena:
                 valid = tab != SENT
                 u = tab[valid]
                 tab[valid] = skey_encode(u, has_ov_of_uid[u])
-            self._inline_grouped = (jnp.asarray(metap), jnp.asarray(ov))
+            with obs.stage(None, "h2d_ms"):
+                self._inline_grouped = (jnp.asarray(metap), jnp.asarray(ov))
+            _book_h2d(self._inline_grouped)
             return self._inline_grouped
 
     # -- MXU join tier (ops/spgemm.py) --------------------------------------
@@ -371,7 +385,7 @@ class CSRArena:
         need = ops.bucket(max(1, universe + 1))
         if self._lut is not None and self._lut.shape[0] >= need:
             return self._lut
-        with _BUILD_LOCK:
+        with obs.stage(None, "h2d_ms"), _BUILD_LOCK:  # as inline_layout
             cur = self._lut
             if cur is not None and cur.shape[0] >= need:
                 return cur
@@ -380,6 +394,7 @@ class CSRArena:
                 keys = self.h_src[self.h_src <= universe]
                 t[keys] = np.arange(len(keys), dtype=np.int32)
             self._lut = jnp.asarray(t)
+            _book_h2d((self._lut,))
             return self._lut
 
     def rows_for_uids_host(self, uids: np.ndarray) -> np.ndarray:
@@ -629,13 +644,9 @@ class CSRArena:
             self.offsets = fresh.offsets
             self.dst = fresh.dst
             self._device_stale = False
-            led = _ledger.current()
-            if led is not None:
-                # the re-upload is this request's staging cost: the CSR
-                # triple just crossed host→device on its behalf
-                led.bytes_h2d += int(
-                    self.src.nbytes + self.offsets.nbytes + self.dst.nbytes
-                )
+            # the re-upload is this request's staging cost: the CSR
+            # triple just crossed host→device on its behalf
+            _book_h2d((self.src, self.offsets, self.dst))
 
 
 def _ivm_repair_gate(n_delta: int, entry_edges: float) -> bool:
@@ -754,9 +765,7 @@ class ResidentArena:
         if E:
             dstp[:E] = np.asarray(h_dst[:E], dtype=np.int32)
         ra = cls(jnp.asarray(off), jnp.asarray(dstp), E)
-        led = _ledger.current()
-        if led is not None:
-            led.bytes_h2d += int(ra.off.nbytes + ra.dst.nbytes)
+        _book_h2d((ra.off, ra.dst))
         return ra
 
     def apply_delta(self, add_r, add_d, del_r, del_d, n_edges: int) -> None:
@@ -766,11 +775,7 @@ class ResidentArena:
         new_off, new_dst = _resident_merge(
             self.off, self.dst, add_r, add_d, del_r, del_d
         )
-        led = _ledger.current()
-        if led is not None:
-            led.bytes_h2d += int(
-                add_r.nbytes + add_d.nbytes + del_r.nbytes + del_d.nbytes
-            )
+        _book_h2d((add_r, add_d, del_r, del_d))
         # the flip: previous epoch's buffers become the shadow (readers
         # holding them stay consistent; the NEXT flip releases them)
         self._prev = (self.off, self.dst)

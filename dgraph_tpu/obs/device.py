@@ -16,7 +16,11 @@ endpoint:
   ``jax.monitoring`` event the per-test compile budgets count
   (`/jax/core/compile/backend_compile_duration`), as a process counter
   + duration histogram, and onto the active request's ledger so a
-  compile-storm query is attributable;
+  compile-storm query is attributable.  That bracket closes round a
+  program READ BACK from the persistent cache too, so
+  `dgraph_xla_cache_reads_total` counts JAX's
+  `/jax/compilation_cache/cache_hits` beside it: compiles less reads
+  is what the backend compiled cold;
 - **build identity** — `dgraph_build_info{version,backend,jax}` = 1,
   stamped once the backend is known.
 
@@ -37,11 +41,13 @@ from dgraph_tpu.utils.metrics import (
     HBM_RESIDENT_BYTES,
     HBM_TILE_BYTES,
     PROGRAM_CACHE_ENTRIES,
+    XLA_CACHE_READS,
     XLA_COMPILE_SECONDS,
     XLA_COMPILES,
 )
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _install_lock = threading.Lock()
 _installed = False
@@ -53,6 +59,11 @@ def _on_compile_begin(name: str, value: float, **kw) -> None:
     # the stage's event name when it enters the bracket
     if name == _COMPILE_EVENT:
         devguard.note_compile_begin()
+
+
+def _on_event(name: str, **kw) -> None:
+    if name == _CACHE_HIT_EVENT:
+        XLA_CACHE_READS.add(1)
 
 
 def _on_event_duration(name: str, secs: float, **kw) -> None:
@@ -87,6 +98,7 @@ def install_compile_listener() -> None:
             _on_event_duration
         )
         jax.monitoring.register_scalar_listener(_on_compile_begin)
+        jax.monitoring.register_event_listener(_on_event)
         _installed = True
 
 
@@ -135,6 +147,7 @@ def snapshot(server=None) -> dict:
         "jax": jax.__version__,
         "compiles": {
             "total": XLA_COMPILES.value(),
+            "cache_reads": XLA_CACHE_READS.value(),
             "seconds_sum": round(XLA_COMPILE_SECONDS.snapshot()[1], 3),
             "seconds_max": round(longest, 3),
         },

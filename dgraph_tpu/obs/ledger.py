@@ -33,6 +33,12 @@ Design constraints, in PR-7 discipline order:
 4. **Bounded label spaces.**  Tenant goes through qos.metric_label
    (cardinality-capped), routes and stages are fixed small sets.
 
+``stages`` is the request's time by catalogue stage (STAGES below):
+every request, sampled or not, says how long it spent at each boundary
+from admission to the encoder.  ``host_ms``/``device_ms`` are the
+coarse parents: HOST wall-clock time inside host / device routes —
+``device_ms`` is not device time.
+
 ``device_sync_ms`` is populated only on SAMPLED requests: the
 unsampled path never blocks on device results by design (the fetch
 overlaps host bookkeeping), so there is nothing to measure without
@@ -62,6 +68,27 @@ _current: "contextvars.ContextVar[Optional[Ledger]]" = contextvars.ContextVar(
     "dgraph_tpu_ledger", default=None
 )
 
+# The stage catalogue (docs/deploy.md "Stage catalogue"): one name per
+# boundary of a request's time, admission to the socket.  Each is
+# bracketed ONCE, by ``obs.stage`` (or, for ``queue``, which crosses
+# threads, by two monotonic stamps in SchedRequest.end_queue_wait), and
+# no bracket nests in another, so a request's stages sum to no more than
+# its wall time.  The same names label dgraph_ledger_stage_us_total and
+# the ``dgraph.<stage>`` annotations on the profiler's host plane.
+STAGES = (
+    "parse", "result_cache", "queue", "merge_wait", "plan", "host_expand",
+    "h2d", "dispatch", "fetch", "convert", "assemble", "encode", "handoff",
+    "http_write",
+)
+_STAGE_KEYS = tuple((s, s + "_ms") for s in STAGES)
+# every label a scraper may diff is there at zero from boot: a family
+# absent until first incremented reads as "no such metric" to the first
+# scrape of a window
+for _s in STAGES:
+    LEDGER_STAGE_US.add(_s, 0)
+for _d in ("h2d", "d2h"):
+    LEDGER_BYTES.add(_d, 0)
+
 
 def enabled() -> bool:
     """The DGRAPH_TPU_LEDGER gate (default ON)."""
@@ -89,7 +116,7 @@ class Ledger:
         "tenant", "edges", "hops", "hop_edges", "host_ms", "device_ms",
         "device_sync_ms", "bytes_h2d", "bytes_d2h", "compiles",
         "cache_hits", "cache_misses", "cache_hit_bytes", "repairs",
-        "coalesced", "exchange_bytes", "mesh_ms", "mesh_chips",
+        "coalesced", "exchange_bytes", "mesh_ms", "mesh_chips", "stages",
         "_race_serial",
     )
 
@@ -98,8 +125,9 @@ class Ledger:
     # slot, and the arm-time wraps on activate()/SchedRequest.complete/
     # fail reset the epoch at exactly the happens-before edges this
     # class's contract names (handler -> flush worker -> handler).
-    # ``hops``/``hop_edges`` are dicts (item writes bypass __setattr__)
-    # and are covered by the same epochs as the scalars they travel with.
+    # ``hops``/``hop_edges``/``stages`` are dicts (item writes bypass
+    # __setattr__) and are covered by the same epochs as the scalars they
+    # travel with.
     # ``compiles`` is deliberately NOT listed: the jax.monitoring
     # compile listener (obs/device.py) increments it from whichever
     # engine-pool thread triggered the compile, concurrently with the
@@ -118,6 +146,7 @@ class Ledger:
         LEDGERS_CREATED.add(1)
         self.hops: Dict[str, int] = {}
         self.hop_edges: Dict[str, int] = {}
+        self.stages: Dict[str, float] = {}
         self.reset()
 
     def reset(self) -> None:
@@ -144,6 +173,10 @@ class Ledger:
         self.exchange_bytes = 0
         self.mesh_ms = 0.0
         self.mesh_chips = 0
+        # catalogue stage milliseconds (STAGES): fixed keys, zeroed in
+        # place — the pooled struct allocates nothing per request
+        for s in STAGES:
+            self.stages[s] = 0.0
 
     # -- instrumentation sites (callers checked current() is not None) ------
 
@@ -178,6 +211,14 @@ class Ledger:
             + stats.get("mxu_join_ms", 0.0)
             + stats.get("tile_build_ms", 0.0)
         )
+        # the engine-side catalogue stages (plan, host_expand, h2d,
+        # dispatch, fetch, convert, assemble, encode) were bracketed into
+        # the shell's stats — on the request's thread or on a devguard
+        # worker — and join the request's stages here, once
+        for s, key in _STAGE_KEYS:
+            ms = stats.get(key)
+            if ms:
+                self.stages[s] += ms
         lv = int(stats.get("chain_fused_levels", 0))
         if lv:
             self.hops["chain"] = self.hops.get("chain", 0) + lv
@@ -219,6 +260,13 @@ class Ledger:
             d["mesh_ms"] = round(self.mesh_ms, 3)
             d["mesh_chips"] = self.mesh_chips
             d["exchange_bytes"] = self.exchange_bytes
+        # the stages this request passed through (a result-cache hit
+        # shows ``parse`` and ``result_cache`` alone); ``http_write`` runs
+        # after the account is rendered and lives in the metric family
+        # only
+        d["stages"] = {
+            s: round(ms, 3) for s, ms in self.stages.items() if ms
+        }
         return d
 
 
@@ -243,6 +291,18 @@ def start(tenant: str = "") -> Optional[Ledger]:
         led = Ledger()
     led.tenant = tenant
     return led
+
+
+def note_stage(stage: str, ms: float) -> None:
+    """``obs.stage``'s sink for a bracket with no engine stats in scope:
+    the active request's account, or — no request active (the HTTP
+    handler after run_query returned, an arena built at boot) — the
+    metric family directly."""
+    led = _current.get()
+    if led is not None:
+        led.stages[stage] += ms
+    else:
+        LEDGER_STAGE_US.add(stage, int(ms * 1e3))
 
 
 def activate(led: Ledger):
@@ -288,6 +348,9 @@ def finish(led: Ledger) -> dict:
         LEDGER_STAGE_US.add(
             "mesh_chip", int(led.mesh_ms * 1e3) * max(1, led.mesh_chips)
         )
+    for s, ms in led.stages.items():
+        if ms:
+            LEDGER_STAGE_US.add(s, int(ms * 1e3))
     if led.bytes_h2d:
         LEDGER_BYTES.add("h2d", led.bytes_h2d)
     if led.bytes_d2h:

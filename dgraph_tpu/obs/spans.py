@@ -1,9 +1,9 @@
 """Query flight recorder: propagated spans with device-time attribution.
 
 The reference engine's only latency story is a flat per-request event
-ring (utils/trace.py, mirroring golang.org/x/net/trace) plus the
-``{parsing, processing, json}`` map — but after the cohort scheduler,
-the two cache tiers, the fused device programs, group commit and the
+ring (golang.org/x/net/trace; its port here went at PR 26) plus the
+``{parsing, processing, json}`` map (utils/trace.py) — but after the
+cohort scheduler, the two cache tiers, the fused device programs, group commit and the
 retried peer RPCs, a query's wall time is spent in places neither can
 name.  This module supplies the substrate every later planner/perf PR
 reads its numbers from (Banyan's *scoped* accounting argument,
@@ -55,6 +55,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from dgraph_tpu.obs import ledger as _ledger
 from dgraph_tpu.utils.env import env_float
 from dgraph_tpu.utils.metrics import SLOW_QUERIES, SPANS_RECORDED, TRACES_RECORDED
 
@@ -310,31 +311,71 @@ def child(name: str):
 
 # -------------------------------------------------------------- stage timer
 
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _annotation(name: str):
+    """``dgraph.<stage>`` on the profiler's own host plane — the clock
+    the device's ``XLA Ops`` line is on.  One inactive TraceMe (~0.5us)
+    when no profiler session is open.  Bound lazily: importing obs must
+    not import JAX (the platform is still the user's to choose)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation("dgraph." + name)
+
+
 class _Stage:
-    """Accumulating stage timer for the engine's per-request stats dicts
-    (host_expand_ms / device_expand_ms / ...).  This is the ONE
-    sanctioned home of perf_counter stage bracketing outside obs spans
-    (graftlint: naked-stage-timing): timing code stays attributable and
-    greppable, and the sampled twin of every number it accumulates rides
-    the hop spans."""
+    """Accumulating stage timer: ONE bracket, three sinks.  This is the
+    ONE sanctioned home of perf_counter stage bracketing outside obs
+    spans (graftlint: naked-stage-timing): timing code stays
+    attributable and greppable.
 
-    __slots__ = ("stats", "key", "t0")
+    - the interval is a ``dgraph.<stage>`` TraceAnnotation (see
+      ``_annotation``), whenever a profiler session is open;
+    - handed a ``stats`` dict (the engine's per-request stats: the
+      coarse route keys ``chain_ms``/``device_expand_ms``/... and the
+      engine-side catalogue stages ``plan_ms``/``host_expand_ms``/
+      ``h2d_ms``/``dispatch_ms``/``fetch_ms``/``convert_ms``/
+      ``assemble_ms``/``encode_ms``), the milliseconds accumulate there — also on a devguard worker thread, where a
+      wedged closure waking late may only ever touch the shell's own
+      dict — and ``Ledger.merge_engine_stats`` folds the catalogue keys
+      into the request's account at completion;
+    - handed ``None`` (no engine shell in scope: ``parse_ms``,
+      ``result_cache_ms``, ``merge_wait_ms``, ``http_write_ms``, a lazily
+      built arena layout's ``h2d_ms``), they go to the active request's ``Ledger.stages``, or
+      — no request active — straight to
+      ``dgraph_ledger_stage_us_total{stage}``.
 
-    def __init__(self, stats: dict, key: str):
+    Catalogue brackets never nest in one another, so a request's stages
+    add up to no more than its wall time (docs/deploy.md).  ``key`` is
+    the stage's name with ``_ms``: the stats key it accumulates under."""
+
+    __slots__ = ("stats", "key", "t0", "_ann")
+
+    def __init__(self, stats: Optional[dict], key: str):
         self.stats = stats
         self.key = key
 
     def __enter__(self) -> "_Stage":
+        self._ann = _annotation(self.key[:-3])
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb) -> None:
-        self.stats[self.key] = self.stats.get(self.key, 0.0) + (
-            (time.perf_counter() - self.t0) * 1e3
-        )
+        ms = (time.perf_counter() - self.t0) * 1e3
+        self._ann.__exit__(et, ev, tb)
+        stats = self.stats
+        if stats is not None:
+            stats[self.key] = stats.get(self.key, 0.0) + ms
+        else:
+            _ledger.note_stage(self.key[:-3], ms)
 
 
-def stage(stats: dict, key: str) -> _Stage:
+def stage(stats: Optional[dict], key: str) -> _Stage:
     return _Stage(stats, key)
 
 

@@ -42,7 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dgraph_tpu import ops
+from dgraph_tpu import obs, ops
+from dgraph_tpu.obs import ledger as _ledger
 from dgraph_tpu.ops.sets import SENT
 from dgraph_tpu.utils import planconfig
 from dgraph_tpu.utils.failpoints import fail
@@ -326,23 +327,27 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
             rj.append(reason)
         return False
 
-    if len(src) == 0 or not eligible_level(engine, child):
-        return reject("root level not fusable" if len(src) else "empty frontier")
     from dgraph_tpu.utils import devguard
 
-    if not devguard.get().allowed():
-        # device fault domain latched sick: every fused route below is a
-        # device program, so decline the whole chain up front — the
-        # per-level path then rides the host mirrors until the half-open
-        # probe re-admits the backend (the planner's cost factor makes
-        # the same call when it is armed; this is the static-path seam)
-        return reject("device sick: per-level host execution (devguard)")
-    src = np.asarray(src)
-    if not np.all(src[1:] > src[:-1]):
-        # expand_chunked's slot mapping requires an ascending-distinct
-        # frontier; an order-by at the root permutes dest_uids, so fusing
-        # would corrupt the matrices — fall back
-        return reject("frontier not ascending-distinct")
+    with obs.stage(engine.stats, "plan_ms"):  # eligibility: host, every child
+        if len(src) == 0 or not eligible_level(engine, child):
+            return reject(
+                "root level not fusable" if len(src) else "empty frontier"
+            )
+        if not devguard.get().allowed():
+            # device fault domain latched sick: every fused route below is
+            # a device program, so decline the whole chain up front — the
+            # per-level path then rides the host mirrors until the
+            # half-open probe re-admits the backend (the planner's cost
+            # factor makes the same call when it is armed; this is the
+            # static-path seam)
+            return reject("device sick: per-level host execution (devguard)")
+        src = np.asarray(src)
+        if not np.all(src[1:] > src[:-1]):
+            # expand_chunked's slot mapping requires an ascending-distinct
+            # frontier; an order-by at the root permutes dest_uids, so
+            # fusing would corrupt the matrices — fall back
+            return reject("frontier not ascending-distinct")
     # MXU join tier (query/joinplan.py): light chains — including the
     # cyclic triangle shape (two legs + a globally-resolvable closing
     # @filter the gather chain below can't fuse) — may run as ONE
@@ -354,7 +359,8 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
 
     if try_mxu_route(engine, child, src, resolver):
         return True
-    levels = collect_chain(engine, child)
+    with obs.stage(engine.stats, "plan_ms"):
+        levels = collect_chain(engine, child)
     if len(levels) < 2:
         return reject("chain shorter than 2 levels")
     # --- fused mesh multi-hop (dgraph_tpu/mesh) ---
@@ -368,103 +374,107 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
     got = _try_mesh_chain(engine, levels, src, reject)
     if got is not None:
         return got
-    arenas = []
-    universe = 0
-    for sg in levels:
-        a = (
-            engine.arenas.reverse(sg.attr)
-            if sg.reverse
-            else engine.arenas.data(sg.attr)
-        )
-        if a.n_edges == 0 or engine.arenas.use_mesh_for(a):
-            break  # truncate the chain here; the tail runs per-level
-        arenas.append(a)
-        if a.n_rows:
-            # any uid owning a row in some chain arena is ≤ this bound, so
-            # LUT misses beyond it are exactly the row-less uids
-            universe = max(universe, int(a.h_src[-1]))
-    levels = levels[: len(arenas)]
-    if len(levels) < 2:
-        return reject("chain truncated below 2 levels (empty/mesh arena)")
+    # stage plan: arenas, the fan-out estimate, the route decision and the
+    # fused filters' keep sets and order specs — all host work, before any
+    # byte moves (the keep sets are put on the device after it, stage h2d)
+    with obs.stage(engine.stats, "plan_ms"):
+        arenas = []
+        universe = 0
+        for sg in levels:
+            a = (
+                engine.arenas.reverse(sg.attr)
+                if sg.reverse
+                else engine.arenas.data(sg.attr)
+            )
+            if a.n_edges == 0 or engine.arenas.use_mesh_for(a):
+                break  # truncate the chain here; the tail runs per-level
+            arenas.append(a)
+            if a.n_rows:
+                # any uid owning a row in some chain arena is ≤ this bound, so
+                # LUT misses beyond it are exactly the row-less uids
+                universe = max(universe, int(a.h_src[-1]))
+        levels = levels[: len(arenas)]
+        if len(levels) < 2:
+            return reject("chain truncated below 2 levels (empty/mesh arena)")
 
-    # --- capacity planning (overflow-free) ---
-    rows0 = arenas[0].rows_for_uids_host(src)
-    est_edges = int(arenas[0].degree_of_rows(rows0).sum())
-    # whole-chain fan-out estimate: propagate by average out-degree so a
-    # modest first level doesn't hide a multi-million-edge tail
-    est_total = est_u = est_edges
-    for a in arenas[1:]:
-        est_u = min(est_u, a.n_rows)
-        lvl = int(est_u * (a.n_edges / max(1, a.n_rows)))
-        est_total += lvl
-        est_u = lvl
-    # route decision: calibrated cost compare by default, the static
-    # threshold when the planner is off or the knob is pinned
-    # (query/planner.py::chain_route; plan_dec is None on the static
-    # path so the legacy reject message stays byte-identical)
-    from dgraph_tpu.query import planner
+        # --- capacity planning (overflow-free) ---
+        rows0 = arenas[0].rows_for_uids_host(src)
+        est_edges = int(arenas[0].degree_of_rows(rows0).sum())
+        # whole-chain fan-out estimate: propagate by average out-degree so a
+        # modest first level doesn't hide a multi-million-edge tail
+        est_total = est_u = est_edges
+        for a in arenas[1:]:
+            est_u = min(est_u, a.n_rows)
+            lvl = int(est_u * (a.n_edges / max(1, a.n_rows)))
+            est_total += lvl
+            est_u = lvl
+        # route decision: calibrated cost compare by default, the static
+        # threshold when the planner is off or the knob is pinned
+        # (query/planner.py::chain_route; plan_dec is None on the static
+        # path so the legacy reject message stays byte-identical)
+        from dgraph_tpu.query import planner
 
-    fuse, plan_dec = planner.chain_route(engine, est_total, len(levels))
-    if not fuse:
-        if plan_dec is not None:
-            # the per-level verdict is final — record it now
-            planner.record(engine.stats, plan_dec)
+        fuse, plan_dec = planner.chain_route(engine, est_total, len(levels))
+        if not fuse:
+            if plan_dec is not None:
+                # the per-level verdict is final — record it now
+                planner.record(engine.stats, plan_dec)
+                return reject(
+                    f"fan-out estimate {est_total}: calibrated model favors "
+                    f"per-level ({plan_dec['est_other_us']}us fused vs "
+                    f"{plan_dec['est_chosen_us']}us per-level)"
+                )
             return reject(
-                f"fan-out estimate {est_total}: calibrated model favors "
-                f"per-level ({plan_dec['est_other_us']}us fused vs "
-                f"{plan_dec['est_chosen_us']}us per-level)"
+                f"fan-out estimate {est_total} below threshold "
+                f"{engine.chain_threshold}"
             )
-        return reject(
-            f"fan-out estimate {est_total} below threshold "
-            f"{engine.chain_threshold}"
+        # a fuse=True decision is recorded only at the SUCCESS sites below:
+        # a structural reject past this point (unresolvable filter, capacity
+        # over cap) falls back to per-level execution, and the ring/metric
+        # must not claim a fused chain that never ran (chain_reject already
+        # explains those falls)
+        # var blocks encode nothing, so result matrices never leave the device
+        # (unless a level participates in @cascade, which prunes matrices)
+        light = bool(
+            getattr(engine, "_cur_block_internal", False)
+            and not any(sg.params.cascade for sg in levels)
         )
-    # a fuse=True decision is recorded only at the SUCCESS sites below:
-    # a structural reject past this point (unresolvable filter, capacity
-    # over cap) falls back to per-level execution, and the ring/metric
-    # must not claim a fused chain that never ran (chain_reject already
-    # explains those falls)
-    # var blocks encode nothing, so result matrices never leave the device
-    # (unless a level participates in @cascade, which prunes matrices)
-    light = bool(
-        getattr(engine, "_cur_block_internal", False)
-        and not any(sg.params.cascade for sg in levels)
-    )
-    max_capc = CHAIN_MAX_CAPC_LIGHT if light else CHAIN_MAX_CAPC
-    # pre-resolve fused filters to keep-sets + order specs (host, once).
-    # Resolution happens only after the fan-out threshold check above, so
-    # small queries never pay it.
-    from dgraph_tpu.query.functions import QueryError
+        max_capc = CHAIN_MAX_CAPC_LIGHT if light else CHAIN_MAX_CAPC
+        # pre-resolve fused filters to keep-sets + order specs (host, once).
+        # Resolution happens only after the fan-out threshold check above, so
+        # small queries never pay it.
+        from dgraph_tpu.query.functions import QueryError
 
-    keeps: List = []
-    orders: List = []
-    order_statics: List = []
-    for sg in levels:
-        keep = None
-        if sg.filter is not None:
-            if resolver is None:
-                return reject("filtered level without a resolver")
-            try:
-                kset = _resolve_filter_global(engine, sg.filter, resolver)
-            except QueryError:
-                return reject("filter keep-set resolution failed")
-            keep = jnp.asarray(
-                ops.pad_to(np.asarray(kset), ops.bucket(max(1, len(kset))))
-            )
-        keeps.append(keep)
-        p = sg.params
-        if p.order_attr or p.first or p.offset:
-            has_vals = bool(p.order_attr)
-            order_statics.append(
-                (bool(p.order_desc), int(p.offset or 0), int(p.first or 0), has_vals)
-            )
-            if has_vals:
-                va = engine.arenas.values(p.order_attr)
-                orders.append((va.src, va.ranks))
+        keeps: List = []
+        orders: List = []
+        order_statics: List = []
+        for sg in levels:
+            keep = None
+            if sg.filter is not None:
+                if resolver is None:
+                    return reject("filtered level without a resolver")
+                try:
+                    kset = _resolve_filter_global(engine, sg.filter, resolver)
+                except QueryError:
+                    return reject("filter keep-set resolution failed")
+                keep = ops.pad_to(
+                    np.asarray(kset), ops.bucket(max(1, len(kset)))
+                )
+            keeps.append(keep)
+            p = sg.params
+            if p.order_attr or p.first or p.offset:
+                has_vals = bool(p.order_attr)
+                order_statics.append(
+                    (bool(p.order_desc), int(p.offset or 0), int(p.first or 0), has_vals)
+                )
+                if has_vals:
+                    va = engine.arenas.values(p.order_attr)
+                    orders.append((va.src, va.ranks))
+                else:
+                    orders.append(None)
             else:
+                order_statics.append(None)
                 orders.append(None)
-        else:
-            order_statics.append(None)
-            orders.append(None)
 
     # --- lax.scan multi-hop fast path (ops/batch.py) ---
     # Light, same-arena, undecorated chains (the `v as x { friend {
@@ -491,41 +501,72 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
         engine._pending_chain_dec = plan_dec
         return True
 
-    caps: List[Tuple[int, int, int, bool, bool, Optional[tuple]]] = []
-    B = ops.bucket(max(1, len(src)))  # row-vector length entering level i
-    m = len(src)  # bound on the unique frontier entering each level
-    for i, a in enumerate(arenas):
-        if i == 0:
-            capc = int(arenas[0].ov_chunk_degree_of_rows(rows0).sum())
-        else:
-            capc = int(_topm_ov_chunk_sum(a, m))
-        capc = ops.bucket(max(1, capc))
-        if capc > max_capc:
-            return reject(
-                f"level {i} overflow capacity {capc} exceeds "
-                f"{'light' if light else 'full'} cap {max_capc}"
+    with obs.stage(engine.stats, "plan_ms"):
+        caps: List[Tuple[int, int, int, bool, bool, Optional[tuple]]] = []
+        B = ops.bucket(max(1, len(src)))  # row-vector length entering level i
+        m = len(src)  # bound on the unique frontier entering each level
+        for i, a in enumerate(arenas):
+            if i == 0:
+                capc = int(arenas[0].ov_chunk_degree_of_rows(rows0).sum())
+            else:
+                capc = int(_topm_ov_chunk_sum(a, m))
+            capc = ops.bucket(max(1, capc))
+            if capc > max_capc:
+                return reject(
+                    f"level {i} overflow capacity {capc} exceeds "
+                    f"{'light' if light else 'full'} cap {max_capc}"
+                )
+            # unique next-frontier ≤ total output slots, ≤ the arena's distinct
+            # target count (NOT the source-uid universe: row-less leaf uids
+            # exceed it, and truncating them would corrupt light-mode dest
+            # sets and var bindings)
+            slots = B * ops.INLINE + capc * ops.CHUNK
+            nd = max(1, a.n_distinct_dst())
+            # clamp to the actual slot count: slots is no longer a power of
+            # two, and a cap_u above it would make the device's [:cap_u]
+            # slice SHORTER than the host parser reads (buffer misalignment)
+            cap_u = min(ops.bucket(max(1, min(slots, nd))), slots)
+            sg = levels[i]
+            # does anything on the host consume this level's dest set?
+            need_dest = (
+                bool(sg.params.var)
+                or len(sg.children) > 1
+                or i == len(levels) - 1
             )
-        # unique next-frontier ≤ total output slots, ≤ the arena's distinct
-        # target count (NOT the source-uid universe: row-less leaf uids
-        # exceed it, and truncating them would corrupt light-mode dest
-        # sets and var bindings)
-        slots = B * ops.INLINE + capc * ops.CHUNK
-        nd = max(1, a.n_distinct_dst())
-        # clamp to the actual slot count: slots is no longer a power of
-        # two, and a cap_u above it would make the device's [:cap_u]
-        # slice SHORTER than the host parser reads (buffer misalignment)
-        cap_u = min(ops.bucket(max(1, min(slots, nd))), slots)
-        sg = levels[i]
-        # does anything on the host consume this level's dest set?
-        need_dest = (
-            bool(sg.params.var)
-            or len(sg.children) > 1
-            or i == len(levels) - 1
-        )
-        decorated = keeps[i] is not None or order_statics[i] is not None
-        caps.append((B, capc, cap_u, need_dest, decorated, order_statics[i]))
-        m = min(slots, nd)
-        B = cap_u
+            decorated = keeps[i] is not None or order_statics[i] is not None
+            caps.append((B, capc, cap_u, need_dest, decorated, order_statics[i]))
+            m = min(slots, nd)
+            B = cap_u
+
+    h2d = 0
+    if not undecorated:
+        # stage h2d, part one: the keep sets of the fused filters
+        with obs.stage(engine.stats, "h2d_ms"):
+            for i, k in enumerate(keeps):
+                if k is not None:
+                    keeps[i] = jnp.asarray(k)
+                    h2d += int(keeps[i].nbytes)
+
+    # The closures below run on the device guard's worker thread: their
+    # stages (h2d, dispatch, fetch) go into the shell's own stats dict
+    # and they return plain data — the ledger's bytes are booked on the
+    # caller's thread, after the guard has handed the result back.
+    st = engine.stats
+
+    def _layouts(lo, hi):
+        # an arena builds and puts its inline layout / LUT on first use,
+        # under its own h2d bracket (models/arena.py)
+        metas, ovs, luts = [], [], []
+        for a in arenas[lo:hi]:
+            mp, ov = a.inline_layout()
+            metas.append(mp)
+            ovs.append(ov)
+            luts.append(a.lut(universe))
+        return tuple(metas), tuple(ovs), tuple(luts)
+
+    def _put_root():
+        with obs.stage(st, "h2d_ms"):
+            return jnp.asarray(ops.pad_to(src, caps[0][0]))
 
     def _dispatch():
         # staging + dispatch + the ONE fetch, all inside the device
@@ -533,20 +574,17 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
         # classifies like a dispatch OOM, a wedged program times out
         # here instead of blocking the flush worker
         fail.point("device.chain")
-        metas, ovs, luts = [], [], []
-        for a in arenas:
-            mp, ov = a.inline_layout()
-            metas.append(mp)
-            ovs.append(ov)
-            luts.append(a.lut(universe))
-        root_vec = jnp.asarray(ops.pad_to(src, caps[0][0]))
-        return np.asarray(  # ONE device round trip for the whole chain
-            _run_fused(
-                root_vec, tuple(metas), tuple(ovs), tuple(luts),
+        metas, ovs, luts = _layouts(0, len(arenas))
+        root_vec = _put_root()
+        with obs.stage(st, "dispatch_ms"):
+            dev = _run_fused(
+                root_vec, metas, ovs, luts,
                 tuple(keeps), tuple(orders), tuple(caps),
                 light=light,
             )
-        )
+        with obs.stage(st, "fetch_ms"):
+            # ONE device round trip for the whole chain
+            return np.asarray(dev), int(root_vec.nbytes)
 
     # segmented dataflow (PR 18): k consecutive levels per dispatched
     # program, the final level's deduped frontier threaded (device-
@@ -563,24 +601,24 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
 
     def _dispatch_segment(root_vec, lo, hi, want_carry):
         fail.point("device.chain")
-        metas, ovs, luts = [], [], []
-        for a in arenas[lo:hi]:
-            mp, ov = a.inline_layout()
-            metas.append(mp)
-            ovs.append(ov)
-            luts.append(a.lut(universe))
-        return _run_fused(
-            root_vec, tuple(metas), tuple(ovs), tuple(luts),
-            tuple(keeps[lo:hi]), tuple(orders[lo:hi]),
-            tuple(caps[lo:hi]), light=light, carry=want_carry,
-        )
+        metas, ovs, luts = _layouts(lo, hi)
+        with obs.stage(st, "dispatch_ms"):
+            return _run_fused(
+                root_vec, metas, ovs, luts,
+                tuple(keeps[lo:hi]), tuple(orders[lo:hi]),
+                tuple(caps[lo:hi]), light=light, carry=want_carry,
+            )
 
     try:
         if seg_k <= 0 or seg_k >= len(levels):
-            packed = devguard.get().run("device.chain", _dispatch)
+            packed, put = devguard.get().run("device.chain", _dispatch)
+            h2d += put
+            d2h = int(packed.nbytes)
         else:
             host_parts = []
-            root_vec = jnp.asarray(ops.pad_to(src, caps[0][0]))
+            root_vec = _put_root()
+            h2d += int(root_vec.nbytes)
+            d2h = 0
             lo = 0
             while lo < len(levels):
                 if lo:
@@ -593,71 +631,83 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
                         _dispatch_segment(rv, lo, hi, wc)
                     ),
                 )
+                with obs.stage(st, "fetch_ms"):
+                    # the whole buffer crosses, the carry tail included
+                    host = np.asarray(dev)
+                d2h += int(host.nbytes)
                 if want_carry:
                     tail = caps[hi - 1][2]  # cap_u of the segment-final level
                     root_vec = dev[-tail:]  # stays device-resident
-                    host_parts.append(np.asarray(dev)[:-tail])
-                else:
-                    host_parts.append(np.asarray(dev))
+                    host = host[:-tail]
+                host_parts.append(host)
                 lo = hi
-            packed = np.concatenate(host_parts)
+            with obs.stage(st, "convert_ms"):
+                packed = np.concatenate(host_parts)
     except devguard.DeviceFaultError:
         return reject("device fault: chain fell back to per-level")
+    led = _ledger.current()
+    if led is not None:
+        # what THIS request moved: keep sets and root vector up (a layout
+        # built on first use booked itself), the packed buffer(s) down —
+        # capacity-sized, whatever the answer holds
+        led.bytes_h2d += h2d
+        led.bytes_d2h += d2h
 
     # --- host conversion: packed buffer → engine results per level ---
-    src_list = np.asarray(src, dtype=np.int64)
-    pos = 0
-    for sg, (B, capc, cap_u, need_dest, decorated, _ostat) in zip(levels, caps):
-        # the fused program already applied these; the engine must not
-        # re-apply them to the stashed matrices
-        sg.chain_filtered = decorated and sg.filter is not None
-        sg.chain_ordered = decorated and _ostat is not None
-        if light:
-            dest = None
-            if need_dest:
-                nxt = packed[pos : pos + cap_u]
-                pos += cap_u
-                dest = nxt[nxt != SENT].astype(np.int64)
-            total = int(packed[pos])
-            pos += 1
-            # src_list None = "trusted": the previous level's dest stayed
-            # on device, so the consumer skips the alignment check
-            sg.chain_stash = ("light", dest, src_list, total)
-            src_list = dest
-            continue
-        n_src = len(src_list)
-        if decorated:
-            flat_len = B * ops.INLINE + capc * ops.CHUNK
-            flat = packed[pos : pos + flat_len]
-            pos += flat_len
-            owner = packed[pos : pos + flat_len]
-            pos += flat_len
-            valid = flat != SENT
-            out_flat = flat[valid].astype(np.int64)
-            owner = owner[valid]
-            counts = np.bincount(owner, minlength=n_src)[:n_src]
-            # per-parent order survives, but slots of one parent may be
-            # interleaved with SENT gaps: regroup stably by owner
-            grp = np.argsort(owner, kind="stable")
-            out_flat = out_flat[grp]
-        else:
-            inline = packed[pos : pos + B * ops.INLINE].reshape(B, ops.INLINE)
-            pos += B * ops.INLINE
-            ovflat = packed[pos : pos + capc * ops.CHUNK]
-            pos += capc * ops.CHUNK
-            ovseg = packed[pos : pos + capc]
-            pos += capc
-            out_flat, seg_ptr0 = inline_to_matrix(inline, ovflat, ovseg, n_src)
-        nxt = packed[pos : pos + cap_u]
-        pos += cap_u
-        pos += 1  # total (unused in full mode: lengths say it)
-        if decorated:
-            seg_ptr = np.zeros(n_src + 1, dtype=np.int64)
-            np.cumsum(counts, out=seg_ptr[1:])
-        else:
-            seg_ptr = seg_ptr0
-        sg.chain_stash = ("full", out_flat, seg_ptr, src_list)
-        src_list = nxt[nxt != SENT].astype(np.int64)
+    with obs.stage(st, "convert_ms"):
+        src_list = np.asarray(src, dtype=np.int64)
+        pos = 0
+        for sg, (B, capc, cap_u, need_dest, decorated, _ostat) in zip(levels, caps):
+            # the fused program already applied these; the engine must not
+            # re-apply them to the stashed matrices
+            sg.chain_filtered = decorated and sg.filter is not None
+            sg.chain_ordered = decorated and _ostat is not None
+            if light:
+                dest = None
+                if need_dest:
+                    nxt = packed[pos : pos + cap_u]
+                    pos += cap_u
+                    dest = nxt[nxt != SENT].astype(np.int64)
+                total = int(packed[pos])
+                pos += 1
+                # src_list None = "trusted": the previous level's dest stayed
+                # on device, so the consumer skips the alignment check
+                sg.chain_stash = ("light", dest, src_list, total)
+                src_list = dest
+                continue
+            n_src = len(src_list)
+            if decorated:
+                flat_len = B * ops.INLINE + capc * ops.CHUNK
+                flat = packed[pos : pos + flat_len]
+                pos += flat_len
+                owner = packed[pos : pos + flat_len]
+                pos += flat_len
+                valid = flat != SENT
+                out_flat = flat[valid].astype(np.int64)
+                owner = owner[valid]
+                counts = np.bincount(owner, minlength=n_src)[:n_src]
+                # per-parent order survives, but slots of one parent may be
+                # interleaved with SENT gaps: regroup stably by owner
+                grp = np.argsort(owner, kind="stable")
+                out_flat = out_flat[grp]
+            else:
+                inline = packed[pos : pos + B * ops.INLINE].reshape(B, ops.INLINE)
+                pos += B * ops.INLINE
+                ovflat = packed[pos : pos + capc * ops.CHUNK]
+                pos += capc * ops.CHUNK
+                ovseg = packed[pos : pos + capc]
+                pos += capc
+                out_flat, seg_ptr0 = inline_to_matrix(inline, ovflat, ovseg, n_src)
+            nxt = packed[pos : pos + cap_u]
+            pos += cap_u
+            pos += 1  # total (unused in full mode: lengths say it)
+            if decorated:
+                seg_ptr = np.zeros(n_src + 1, dtype=np.int64)
+                np.cumsum(counts, out=seg_ptr[1:])
+            else:
+                seg_ptr = seg_ptr0
+            sg.chain_stash = ("full", out_flat, seg_ptr, src_list)
+            src_list = nxt[nxt != SENT].astype(np.int64)
     if plan_dec is not None:
         planner.record(engine.stats, plan_dec)
     engine._pending_chain_dec = plan_dec

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from dgraph_tpu import gql, ivm, obs, ops
@@ -76,6 +77,17 @@ def _make_packed_inline():
 # ascending-distinct rows, expand_csr accepts any order.
 _packed_expand_csr = _make_packed_expand()
 _packed_expand_inline = _make_packed_inline()
+
+
+def _unpack_out_seg(packed: np.ndarray, cap: int, total: int, n: int):
+    """The packed ``out|seg`` buffer of the csr / resident programs →
+    the engine's (out_flat, seg_ptr) uid matrix."""
+    out = packed[:total].astype(np.int64)
+    seg = packed[cap : cap + total].astype(np.int64)
+    counts = np.bincount(seg, minlength=n)
+    seg_ptr = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(counts, out=seg_ptr[1:])
+    return out, seg_ptr
 
 
 def _pallas_interpret() -> bool:
@@ -503,18 +515,22 @@ class DeviceExpander:
                 fail.point("device.hop")
                 # plain-data return: ledger/span writes stay on the
                 # caller thread (see _dispatch_inline's note)
-                with obs.stage(eng.stats, "device_expand_ms"):
+                st = eng.stats
+                with obs.stage(st, "device_expand_ms"):
                     ra = arena.resident()
-                    dev = ra.expand_packed(
-                        ops.pad_rows(rows, ops.bucket(n)).astype(np.int32),
-                        cap, interpret=interp,
-                    )
+                    with obs.stage(st, "h2d_ms"):
+                        rows_d = jnp.asarray(
+                            ops.pad_rows(rows, ops.bucket(n)).astype(np.int32)
+                        )
+                    with obs.stage(st, "dispatch_ms"):
+                        dev = ra.expand_packed(rows_d, cap, interpret=interp)
                     sync_ms = (
                         obs.block_ready_ms(dev)
                         if self._span is not None else None
                     )
                     # one fetch: out|seg concatenated on device
-                    return np.asarray(dev), sync_ms
+                    with obs.stage(st, "fetch_ms"):
+                        return np.asarray(dev), sync_ms
 
             got = self._run_guarded("device.hop", _dispatch_resident)
             if got is None:
@@ -528,11 +544,8 @@ class DeviceExpander:
             if led is not None:
                 led.bytes_h2d += int(rows.nbytes)
                 led.bytes_d2h += int(packed.nbytes)
-            out = packed[:total].astype(np.int64)
-            seg = packed[cap : cap + total].astype(np.int64)
-            counts = np.bincount(seg, minlength=n)
-            seg_ptr = np.zeros(n + 1, dtype=np.int64)
-            np.cumsum(counts, out=seg_ptr[1:])
+            with obs.stage(eng.stats, "convert_ms"):
+                out, seg_ptr = _unpack_out_seg(packed, cap, total, n)
             eng.stats["edges"] += len(out)
             return out, seg_ptr
         # big single-device expansion.  The inline-head fast path (one
@@ -550,9 +563,12 @@ class DeviceExpander:
                 with obs.stage(eng.stats, "device_expand_ms"):
                     arena.ensure_device()  # re-upload after deltas
                     ce = ops.classed_for_arena(arena)
-                    return ce.expand_rows(
-                        rows, arena.degree_of_rows(rows)
-                    )
+                    # the classed program dispatches, fetches and unpacks
+                    # per degree class inside ops/batch.py: one bracket
+                    with obs.stage(eng.stats, "dispatch_ms"):
+                        return ce.expand_rows(
+                            rows, arena.degree_of_rows(rows)
+                        )
 
             got = self._run_guarded("device.hop", _dispatch_classed)
             if got is None:
@@ -580,10 +596,14 @@ class DeviceExpander:
                 # worker waking up later can never scribble on a pooled
                 # struct a newer request now owns
                 metap, ov_chunks = arena.inline_layout()
-                with obs.stage(eng.stats, "device_expand_ms"):
-                    dev = _packed_expand_inline(
-                        metap, ov_chunks, ops.pad_rows(rows, B), capov
-                    )
+                st = eng.stats
+                with obs.stage(st, "device_expand_ms"):
+                    with obs.stage(st, "h2d_ms"):
+                        rows_d = jnp.asarray(ops.pad_rows(rows, B))
+                    with obs.stage(st, "dispatch_ms"):
+                        dev = _packed_expand_inline(
+                            metap, ov_chunks, rows_d, capov
+                        )
                     # sampled: split pure device time from the host
                     # fetch (the unsampled path stays dispatch-async —
                     # asarray overlaps compute with bookkeeping)
@@ -592,7 +612,8 @@ class DeviceExpander:
                         if self._span is not None else None
                     )
                     # one fetch: inline|ov|ovseg concatenated on device
-                    return np.asarray(dev), sync_ms
+                    with obs.stage(st, "fetch_ms"):
+                        return np.asarray(dev), sync_ms
 
             got = self._run_guarded("device.hop", _dispatch_inline)
             if got is None:
@@ -608,7 +629,8 @@ class DeviceExpander:
                 led.bytes_d2h += int(packed.nbytes)
             from dgraph_tpu.query.chain import packed_inline_to_matrix
 
-            out, seg_ptr = packed_inline_to_matrix(packed, B, capov, n)
+            with obs.stage(eng.stats, "convert_ms"):
+                out, seg_ptr = packed_inline_to_matrix(packed, B, capov, n)
             eng.stats["edges"] += len(out)
             return out, seg_ptr
         self._route = "csr"
@@ -617,18 +639,22 @@ class DeviceExpander:
             fail.point("device.hop")
             # plain-data return: ledger/span writes stay on the caller
             # thread (see _dispatch_inline's abandoned-worker note)
-            with obs.stage(eng.stats, "device_expand_ms"):
+            st = eng.stats
+            with obs.stage(st, "device_expand_ms"):
                 arena.ensure_device()  # re-upload after host deltas
-                dev = _packed_expand_csr(
-                    arena.offsets, arena.dst,
-                    ops.pad_rows(rows, ops.bucket(n)), cap,
-                )
+                with obs.stage(st, "h2d_ms"):
+                    rows_d = jnp.asarray(ops.pad_rows(rows, ops.bucket(n)))
+                with obs.stage(st, "dispatch_ms"):
+                    dev = _packed_expand_csr(
+                        arena.offsets, arena.dst, rows_d, cap
+                    )
                 sync_ms = (
                     obs.block_ready_ms(dev)
                     if self._span is not None else None
                 )
                 # one fetch: out|seg concatenated on device
-                return np.asarray(dev), sync_ms
+                with obs.stage(st, "fetch_ms"):
+                    return np.asarray(dev), sync_ms
 
         got = self._run_guarded("device.hop", _dispatch_csr)
         if got is None:
@@ -642,11 +668,8 @@ class DeviceExpander:
         if led is not None:
             led.bytes_h2d += int(rows.nbytes)
             led.bytes_d2h += int(packed.nbytes)
-        out = packed[:total].astype(np.int64)
-        seg = packed[cap : cap + total].astype(np.int64)
-        counts = np.bincount(seg, minlength=n)
-        seg_ptr = np.zeros(n + 1, dtype=np.int64)
-        np.cumsum(counts, out=seg_ptr[1:])
+        with obs.stage(eng.stats, "convert_ms"):
+            out, seg_ptr = _unpack_out_seg(packed, cap, total, n)
         eng.stats["edges"] += len(out)
         return out, seg_ptr
 
@@ -848,7 +871,8 @@ class QueryEngine:
     def execute(self, parsed: gql.ParsedResult) -> dict:
         uid_vars: Dict[str, np.ndarray] = {}
         value_vars: Dict[str, Dict[int, TypedValue]] = {}
-        blocks = [build_subgraph(q) for q in parsed.queries]
+        with obs.stage(self.stats, "plan_ms"):
+            blocks = [build_subgraph(q) for q in parsed.queries]
         deps = parsed.query_vars
 
         done = [False] * len(blocks)
@@ -879,16 +903,17 @@ class QueryEngine:
             from dgraph_tpu.query.subgraph import dump_dict
 
             self.last_dump = [dump_dict(sg) for sg in blocks]
-        for sg in blocks:
-            if sg.params.is_internal:
-                continue
-            name = sg.params.alias or "me"
-            if sg.params.is_shortest:
-                outputnode.encode_path(self.store, sg, out)
-                continue
-            out.setdefault(name, []).extend(
-                outputnode.encode_block(self.store, sg)
-            )
+        with obs.stage(self.stats, "encode_ms"):
+            for sg in blocks:
+                if sg.params.is_internal:
+                    continue
+                name = sg.params.alias or "me"
+                if sg.params.is_shortest:
+                    outputnode.encode_path(self.store, sg, out)
+                    continue
+                out.setdefault(name, []).extend(
+                    outputnode.encode_block(self.store, sg)
+                )
         return out
 
     # -- block execution ---------------------------------------------------
@@ -907,10 +932,14 @@ class QueryEngine:
             shortest_path(self, sg, resolver)
             self._collect_vars(sg, uid_vars, value_vars)
             return
-        dest = self._root_uids(sg, resolver)
-        if sg.filter is not None:
-            dest = self._apply_root_filter(sg, dest, resolver)
-        dest = self._order_and_paginate_root(sg, dest, value_vars)
+        # stage plan: the block's root — function, filter, order — on
+        # the host, before any child expands (a root function that
+        # itself expands rides resolver_expand_ms / kway_ms inside it)
+        with obs.stage(self.stats, "plan_ms"):
+            dest = self._root_uids(sg, resolver)
+            if sg.filter is not None:
+                dest = self._apply_root_filter(sg, dest, resolver)
+            dest = self._order_and_paginate_root(sg, dest, value_vars)
         sg.dest_uids = dest
         if sg.params.is_groupby:
             from dgraph_tpu.query.groupby import process_groupby
@@ -1042,12 +1071,15 @@ class QueryEngine:
                         m[int(d)] = fs[key]
                 value_vars[var] = m
 
-    def _exec_child_inner(self, child: SubGraph, src: np.ndarray, resolver, uid_vars, value_vars):
+    def _exec_leaf(self, child: SubGraph, src: np.ndarray, resolver, value_vars) -> bool:
+        """The children that expand nothing: uid, val(), math(),
+        _predicate_, checkpwd, count(pred) and value leaves.  True where
+        ``child`` was one of them (and is done)."""
         attr = child.attr
         p = child.params
         if attr in ("_uid_", "uid", ""):
             child.src_uids = src
-            return
+            return True
         if attr == "val":
             # val(x) fetch: values come from the variable map
             v = child.needs_var[0] if child.needs_var else ""
@@ -1056,11 +1088,11 @@ class QueryEngine:
             child.values = {int(u): vmap[int(u)] for u in src.tolist() if int(u) in vmap}
             if p.agg_func:
                 self._aggregate(child, src, value_vars)
-            return
+            return True
         if attr == "math":
             child.src_uids = src
             child.values = self._eval_math(child.math_exp, src, value_vars)
-            return
+            return True
         if attr == "_predicate_":
             child.src_uids = src
             # one vectorized membership probe per predicate (cached sorted
@@ -1081,7 +1113,7 @@ class QueryEngine:
                 int(u): TypedValue(TypeID.STRING, acc[i])
                 for i, u in enumerate(src64)
             }
-            return
+            return True
         if child.func is not None and child.func.name == "checkpwd":
             child.src_uids = src
             ok = resolver.resolve(child.func, src)
@@ -1089,7 +1121,7 @@ class QueryEngine:
             child.values = {
                 int(u): TypedValue(TypeID.BOOL, int(u) in okset) for u in src.tolist()
             }
-            return
+            return True
 
         tid = self.store.schema.type_of(attr)
         is_uid_pred = tid == TypeID.UID or (
@@ -1101,7 +1133,7 @@ class QueryEngine:
             rows = arena.rows_for_uids_host(src)
             child.src_uids = src
             child.counts = arena.degree_of_rows(rows).astype(np.int64)
-            return
+            return True
 
         if not is_uid_pred:
             # value leaf: fetch typed values for each src uid — direct
@@ -1140,7 +1172,18 @@ class QueryEngine:
                     for u in src.tolist()
                     if int(u) in pd.value_facets
                 }
-            return
+            return True
+        return False
+
+    def _exec_child_inner(self, child: SubGraph, src: np.ndarray, resolver, uid_vars, value_vars):
+        attr = child.attr
+        p = child.params
+        # stage assemble: a level's host work that is not an expansion —
+        # here the leaves (values, counts, val/math), below the level's
+        # uniques, filter, facets and ordering
+        with obs.stage(self.stats, "assemble_ms"):
+            if self._exec_leaf(child, src, resolver, value_vars):
+                return
 
         # uid expansion on device.  Big plain chains fuse into one device
         # program (query/chain.py) staged here and consumed level by level
@@ -1198,26 +1241,27 @@ class QueryEngine:
         else:
             arena = self.arenas.reverse(attr) if child.reverse else self.arenas.data(attr)
             out_flat, seg_ptr = self._expand(arena, src, attr=attr, reverse=child.reverse)
-        child.src_uids = src
-        child.out_flat = out_flat
-        child.seg_ptr = seg_ptr
-        dest = np.unique(out_flat)
+        with obs.stage(self.stats, "assemble_ms"):
+            child.src_uids = src
+            child.out_flat = out_flat
+            child.seg_ptr = seg_ptr
+            dest = np.unique(out_flat)
 
-        if child.filter is not None and not getattr(child, "chain_filtered", False):
-            dest = self._apply_filter(child.filter, dest, resolver)
-            self._mask_matrix(child, dest)
-        self._load_edge_facets(child)
-        if child.params.facets_filter is not None:
-            self._apply_facet_filter(child)
-        if not getattr(child, "chain_ordered", False):
-            self._order_and_paginate_child(child, value_vars)
-        child.dest_uids = np.unique(child.out_flat)
+            if child.filter is not None and not getattr(child, "chain_filtered", False):
+                dest = self._apply_filter(child.filter, dest, resolver)
+                self._mask_matrix(child, dest)
+            self._load_edge_facets(child)
+            if child.params.facets_filter is not None:
+                self._apply_facet_filter(child)
+            if not getattr(child, "chain_ordered", False):
+                self._order_and_paginate_child(child, value_vars)
+            child.dest_uids = np.unique(child.out_flat)
 
-        if p.is_groupby:
-            from dgraph_tpu.query.groupby import process_groupby
+            if p.is_groupby:
+                from dgraph_tpu.query.groupby import process_groupby
 
-            process_groupby(self, child, value_vars)
-            return
+                process_groupby(self, child, value_vars)
+                return
         self._exec_children(child, resolver, uid_vars, value_vars)
 
     def _expand(

@@ -37,7 +37,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from dgraph_tpu.utils.metrics import SCHED_MERGED_HOPS
+from dgraph_tpu import obs
+from dgraph_tpu.utils.metrics import SCHED_MERGED_HOPS, SCHED_QUEUE_WAIT
 
 
 class SchedOverloadError(RuntimeError):
@@ -126,6 +127,7 @@ class SchedRequest:
         "parsed", "debug", "deadline", "enqueued", "key",
         "_done", "result", "stats", "error", "span", "queue_span",
         "tenant", "cancel", "ledger", "slot_held", "slot_released",
+        "queue_s", "done_at",
     )
 
     def __init__(self, parsed, debug: bool = False,
@@ -154,6 +156,13 @@ class SchedRequest:
         # request's fate — execution, shed, or singleflight dealing.
         self.span = None
         self.queue_span = None
+        # stage ``queue``: admission to whoever decides this request's
+        # fate first (seconds; None while it still waits)
+        self.queue_s: Optional[float] = None
+        # stage ``handoff``: the verdict (complete/fail, on a flush
+        # worker) to the admitting thread running again — under
+        # concurrent callers, its wait for the GIL
+        self.done_at = 0.0
         # per-query resource ledger (obs/ledger.py): the admitting
         # request's pooled account, re-activated on whichever flush
         # worker executes it (None when DGRAPH_TPU_LEDGER=0 — then the
@@ -173,8 +182,19 @@ class SchedRequest:
         )
 
     def end_queue_wait(self, outcome: str) -> None:
-        """Close the queue-wait span; first closer's outcome wins
-        (execution start beats the completion fallback)."""
+        """Close the queue wait; first closer's outcome wins (execution
+        start beats the completion fallback).  THE definition of queue
+        wait: admission to the start of execution — the cohort's flush
+        deadline, the engine-lock and worker waits included — or, for a
+        request that never executes (shed, dealt a twin's result), to
+        its verdict.  It crosses threads, so it is two monotonic stamps
+        and no profiler annotation; the histogram and the request's
+        ``queue`` stage both take it from here."""
+        if self.queue_s is None:
+            self.queue_s = time.monotonic() - self.enqueued
+            SCHED_QUEUE_WAIT.observe(self.queue_s)
+            if self.ledger is not None:
+                self.ledger.stages["queue"] += self.queue_s * 1e3
         qs = self.queue_span
         if qs is not None and qs.t1 is None:
             qs.set_attr("outcome", outcome)
@@ -184,16 +204,23 @@ class SchedRequest:
         self.end_queue_wait("done")
         self.result = result
         self.stats = stats
+        self.done_at = time.monotonic()
         self._done.set()
 
     def fail(self, exc: BaseException) -> None:
         self.end_queue_wait(type(exc).__name__)
         self.error = exc
+        self.done_at = time.monotonic()
         self._done.set()
 
     def wait(self) -> Tuple[dict, dict]:
         """Block until executed; raises the execution error if any."""
         self._done.wait()
+        if self.ledger is not None:
+            # the ledger is this thread's again (single-writer hand-off)
+            self.ledger.stages["handoff"] += (
+                time.monotonic() - self.done_at
+            ) * 1e3
         if self.error is not None:
             raise self.error
         return self.result, self.stats
@@ -297,7 +324,8 @@ class HopMerger:
                 self._cond.notify_all()
         if leader:
             stop = time.monotonic() + self.window_s
-            with self._cond:
+            # stage merge_wait: the leader waiting out the merge window
+            with obs.stage(None, "merge_wait_ms"), self._cond:
                 while not g.closed and len(g.entries) < self._expected:
                     left = stop - time.monotonic()
                     if left <= 0:
@@ -319,9 +347,14 @@ class HopMerger:
                 g.error = e
             finally:
                 g.done.set()
-        elif not g.done.wait(timeout=600.0):
-            # leader died (should not happen): never hang — expand solo
-            return expand_fn(src)
+        else:
+            # stage merge_wait: a follower blocked on the leader's merged
+            # dispatch (whose own stages land on the leader's account)
+            with obs.stage(None, "merge_wait_ms"):
+                done = g.done.wait(timeout=600.0)
+            if not done:
+                # leader died (should not happen): never hang — expand solo
+                return expand_fn(src)
         if g.error is not None:
             raise g.error
         return g.results[idx]

@@ -105,7 +105,6 @@ from dgraph_tpu.utils.metrics import (
     SCHED_COHORT_OCCUPANCY,
     SCHED_FLUSHES,
     SCHED_QUEUE_DEPTH,
-    SCHED_QUEUE_WAIT,
     SCHED_SHED,
     SEGMENT_PREEMPT_US,
     SEGMENT_YIELDS,
@@ -298,12 +297,15 @@ class CohortScheduler:
         ):
             from dgraph_tpu.cache import cacheable
 
-            if cacheable(parsed):
-                rc_key = key
-                rc_ver = _ivm.result_version(self._server.store, parsed)
-                hit = rc.get(rc_key, rc_ver)
-                if hit is not None:
-                    return hit
+            # stage result_cache: the probe here and the put below (whose
+            # footprint walk grows with the answer)
+            with obs.stage(None, "result_cache_ms"):
+                if cacheable(parsed):
+                    rc_key = key
+                    rc_ver = _ivm.result_version(self._server.store, parsed)
+                    hit = rc.get(rc_key, rc_ver)
+                    if hit is not None:
+                        return hit
         # timeout_s None = no budget; <= 0 = budget ALREADY spent (a
         # gRPC deadline that lapsed in transit, X-Dgraph-Timeout: 0) —
         # that sheds immediately rather than silently running unbounded
@@ -339,7 +341,8 @@ class CohortScheduler:
         if rc_key is not None:
             # sharing the response dict is safe by the singleflight
             # argument: handlers only encode results, never mutate them
-            rc.put(rc_key, rc_ver, result, stats)
+            with obs.stage(None, "result_cache_ms"):
+                rc.put(rc_key, rc_ver, result, stats)
         return result, stats
 
     def _admit(self, req: SchedRequest, sig: tuple, key) -> None:
@@ -577,9 +580,10 @@ class CohortScheduler:
         shed: List[SchedRequest] = []
         max_wait = 0.0
         for req in cohort.reqs:
-            w = now - req.enqueued
-            max_wait = max(max_wait, w)
-            SCHED_QUEUE_WAIT.observe(w)
+            # (dgraph_sched_queue_wait_seconds is observed where the
+            # wait ENDS, SchedRequest.end_queue_wait — not here, before
+            # the engine-lock and worker waits)
+            max_wait = max(max_wait, now - req.enqueued)
             if req.expired(now):
                 self._shed_deadline(req, now)
                 shed.append(req)

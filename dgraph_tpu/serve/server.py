@@ -49,7 +49,6 @@ from dgraph_tpu.sched import (
     sched_enabled,
 )
 from dgraph_tpu.sched import qos as _qos
-from dgraph_tpu.utils.trace import Tracer
 
 _CORS = {
     "Access-Control-Allow-Origin": "*",
@@ -73,7 +72,6 @@ class DgraphServer:
         port: int = 0,
         bind: str = "127.0.0.1",
         export_path: str = "export",
-        trace_ratio: float = 0.0,
         expose_trace: bool = True,
         tls_cert: str = "",
         tls_key: str = "",
@@ -120,7 +118,6 @@ class DgraphServer:
             arena_budget_bytes=(arena_budget_mb * (1 << 20)) or None,
         )
         self.health = HealthGate()
-        self.tracer = Tracer(trace_ratio)
         self.export_path = export_path
         self.expose_trace = expose_trace
         # RW lock: read-only queries run CONCURRENTLY over the shared
@@ -340,7 +337,6 @@ class DgraphServer:
 
         NUM_QUERIES.add(1)
         PENDING_QUERIES.add(1)
-        tr = self.tracer.begin()
         lat = Latency()
         t0 = time.monotonic()
         sched = self.scheduler
@@ -368,11 +364,9 @@ class DgraphServer:
         led = _ledger.start(tenant)
         ltoken = _ledger.activate(led) if led is not None else None
         try:
-            with obs.child("parsing"):
+            with obs.child("parsing"), obs.stage(None, "parse_ms"):
                 parsed = gql.parse(text, variables)
             lat.record_parsing()
-            tr.printf("parsed: %d queries, mutation=%s", len(parsed.queries),
-                      parsed.mutation is not None)
             if parsed.mutation is not None:
                 # disk-fault read-only mode: shed mutations BEFORE they
                 # queue on the write lock (reads keep flowing below);
@@ -437,7 +431,6 @@ class DgraphServer:
                         if barrier is not None:
                             barrier()
             lat.record_processing()
-            tr.printf("processed")
             # json encode happens in the handler; pre-record here so the
             # latency map is complete before attaching it
             lat.record_json()
@@ -506,7 +499,6 @@ class DgraphServer:
             # exemplar (utils/metrics.py): the bucket this request
             # landed in links straight to /debug/traces/<id>
             QUERY_LATENCY.observe(dur, trace_id=trace_id or slow_tid)
-            self.tracer.finish(tr, "query", text[:120])
 
     _dump_seq = itertools.count()
 
@@ -791,10 +783,6 @@ def _make_handler(srv: DgraphServer):
                         metrics.prometheus_text().encode(),
                         "text/plain; version=0.0.4; charset=utf-8",
                     )
-            elif path == "/debug/requests":
-                if not srv.expose_trace:
-                    return self._err(403, "tracing not exposed")
-                self._reply(200, json.dumps(srv.tracer.recent()).encode())
             elif path == "/debug/traces" or path.startswith("/debug/traces/"):
                 # the flight-recorder ring (obs/spans.py): listing, one
                 # trace's merged span tree, or the Chrome trace_event
@@ -1175,18 +1163,24 @@ def _make_handler(srv: DgraphServer):
                         cancel_probe=self._disconnect_probe(),
                         ledger_out=want_ledger,
                     )
-                    accept = self.headers.get("Accept", "")
-                    if "application/protobuf" in accept or "application/x-protobuf" in accept:
-                        # binary client surface: protobuf wire-format
-                        # Response (graphresponse.proto), hand-encoded —
-                        # see serve/proto.py
-                        from dgraph_tpu.serve import proto as _proto
+                    # stage http_write: run_query's clock has stopped and
+                    # its ledger is drained; encoding the answer and the
+                    # socket write are the server's last share of the
+                    # client's latency
+                    with obs.stage(None, "http_write_ms"):
+                        accept = self.headers.get("Accept", "")
+                        if "application/protobuf" in accept or "application/x-protobuf" in accept:
+                            # binary client surface: protobuf wire-format
+                            # Response (graphresponse.proto), hand-encoded
+                            # — see serve/proto.py
+                            from dgraph_tpu.serve import proto as _proto
 
-                        self._reply(
-                            200, _proto.encode_response(out), "application/protobuf"
-                        )
-                    else:
-                        self._reply(200, json.dumps(out).encode())
+                            self._reply(
+                                200, _proto.encode_response(out),
+                                "application/protobuf",
+                            )
+                        else:
+                            self._reply(200, json.dumps(out).encode())
                 except SchedQuotaError as e:
                     # per-TENANT quota shed: still a 429, but with a
                     # Retry-After sized to that tenant's own backlog —

@@ -12,7 +12,7 @@ from dgraph_tpu.utils.metrics import (
     MetricsRegistry,
     metrics,
 )
-from dgraph_tpu.utils.trace import RequestTrace, Latency, Tracer
+from dgraph_tpu.utils.trace import Latency
 from dgraph_tpu.utils.watermark import WaterMark
 from dgraph_tpu.utils.health import HealthGate
 from dgraph_tpu.utils.config import Options
@@ -23,9 +23,7 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "metrics",
-    "RequestTrace",
     "Latency",
-    "Tracer",
     "WaterMark",
     "HealthGate",
     "Options",

@@ -748,8 +748,10 @@ SUBS_SHED = metrics.labeled(
 # (cache/merged/mesh/host/resident/classed/inline/csr/chain/mxu) and
 # LEDGER_HOP_EDGES{route} the edges those hops traversed — which route
 # actually carries a deployment's traffic, in the unit users pay for;
-# LEDGER_STAGE_US{stage} accumulates host/device/device_sync time in
-# integer microseconds; LEDGER_BYTES{dir} the staged h2d/d2h bytes and
+# LEDGER_STAGE_US{stage} accumulates, in integer microseconds, the
+# coarse host/device/device_sync route times AND the stage catalogue of
+# obs/ledger.py STAGES (parse, queue, ..., http_write), which are there
+# at zero from boot; LEDGER_BYTES{dir} the staged h2d/d2h bytes and
 # cache-hit payload bytes.  LEDGERS_CREATED counts Ledger STRUCTS
 # constructed — the pooled-struct twin of dgraph_trace_spans_total:
 # tests assert a zero delta across warm requests, so "one pooled struct
@@ -788,6 +790,10 @@ PROGRAM_CACHE_ENTRIES = metrics.labeled_gauge(
     "dgraph_program_cache_entries", label="kind"
 )
 XLA_COMPILES = metrics.counter("dgraph_xla_compiles_total")
+# programs read back from JAX's persistent compilation cache: each also
+# counts in dgraph_xla_compiles_total (the backend-compile bracket closes
+# round a read too), so compiles less reads = cold compiles
+XLA_CACHE_READS = metrics.counter("dgraph_xla_cache_reads_total")
 XLA_COMPILE_SECONDS = metrics.histogram(
     "dgraph_xla_compile_seconds",
     (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),

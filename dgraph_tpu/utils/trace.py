@@ -1,17 +1,12 @@
-"""Request tracing and latency breakdown.
-
-Equivalent of the reference's golang.org/x/net/trace usage: sampled
-per-request traces with lazy event strings (dgraph/server.go:120-125),
-plus the client-visible latency map {parsing, processing, json}
-(query/query.go:102-119).
+"""The client-visible latency map {parsing, processing, json}
+(query/query.go:102-119).  Sampled request traces are the flight
+recorder's (obs/spans.py, ``/debug/traces``); where a request's time
+went, stage by stage, is the ledger's (obs/ledger.py STAGES).
 """
 
 from __future__ import annotations
 
-import threading
 import time
-from collections import deque
-from typing import Deque, List, Optional, Tuple
 
 
 def _fmt_ns(ns: int) -> str:
@@ -64,66 +59,3 @@ class Latency:
         if self.json_ns:
             out["json"] = _fmt_ns(self.json_ns)
         return out
-
-
-class RequestTrace:
-    """One request's event log; cheap no-op unless sampled."""
-
-    __slots__ = ("active", "events", "t0")
-
-    def __init__(self, active: bool):
-        self.active = active
-        self.events: List[Tuple[int, str]] = []
-        self.t0 = time.perf_counter_ns() if active else 0
-
-    def printf(self, fmt: str, *args) -> None:
-        if self.active:
-            self.events.append(
-                (time.perf_counter_ns() - self.t0, fmt % args if args else fmt)
-            )
-
-
-class Tracer:
-    """Sampled tracing, ratio as in --trace (cmd/dgraph/main.go:250-255).
-    Finished traces are kept in a bounded ring served at /debug/requests.
-
-    Sampling goes through an OWNED seeded sampler (obs.spans.Sampler —
-    one implementation of the discipline, shared with the flight
-    recorder's head sampler) instead of the global ``random`` module:
-    deterministic under a pinned ``seed`` / ``DGRAPH_TPU_TRACE_SEED``,
-    thread-safe, and decoupled from every other consumer of the
-    process-wide random stream."""
-
-    def __init__(self, ratio: float = 0.0, keep: int = 64,
-                 seed: Optional[int] = None):
-        # lazy import: utils/__init__ imports this module, and obs.spans
-        # imports utils submodules — binding at call time keeps the
-        # package import order a non-issue
-        from dgraph_tpu.obs.spans import Sampler
-
-        self.ratio = ratio
-        self._sampler = Sampler(ratio=ratio, seed=seed)
-        self._done: Deque[dict] = deque(maxlen=keep)
-        self._lock = threading.Lock()
-
-    def begin(self) -> RequestTrace:
-        self._sampler.ratio = self.ratio  # tests tweak .ratio live
-        return RequestTrace(self._sampler.decide())
-
-    def finish(self, tr: RequestTrace, family: str, title: str) -> None:
-        if not tr.active:
-            return
-        with self._lock:
-            self._done.append(
-                {
-                    "family": family,
-                    "title": title,
-                    "events": [
-                        {"at": _fmt_ns(at), "msg": msg} for at, msg in tr.events
-                    ],
-                }
-            )
-
-    def recent(self) -> List[dict]:
-        with self._lock:
-            return list(self._done)

@@ -301,3 +301,113 @@ def test_ledger_pool_roundtrip_unit():
     again = ledgermod.start("t2")
     assert again.edges == 0 and not again.hops and not again.hop_edges
     ledgermod.finish(again)
+
+
+# ------------------------------------------------------- stage catalogue
+
+CHAIN_Q = "{ q(func: uid(0x1, 0x2, 0x3)) { follows { follows { uid } } } }"
+RING = 40  # uids 1..40, each follows the next three: every degree <= INLINE
+
+
+def _ring_seed() -> str:
+    quads = [
+        f"<0x{u:x}> <follows> <0x{(u - 1 + d) % RING + 1:x}> ."
+        for u in range(1, RING + 1) for d in (1, 2, 3)
+    ]
+    return (
+        "mutation { schema { follows: uid . } set { %s } }" % "\n".join(quads)
+    )
+
+
+@pytest.fixture(scope="module")
+def chain_answer():
+    """One served chain-route query with ?ledger=true, its wall time on
+    the client's clock, and the same text asked again (a result-cache
+    hit).  Scheduler, QoS and result cache armed; the fused chain route
+    forced (threshold 0) and monolithic (no segments), so the 2-level
+    query is ONE program with ONE packed buffer."""
+    import time
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("DGRAPH_TPU_SCHED", "1")
+    mp.setenv("DGRAPH_TPU_CACHE", "1")
+    mp.setenv("DGRAPH_TPU_QOS", "1")
+    mp.setenv("DGRAPH_TPU_SEGMENT", "0")
+    server = DgraphServer(PostingStore())
+    server.engine.chain_threshold = 0
+    server.start()
+    try:
+        _post(server.addr, "/query", _ring_seed())
+        _post(server.addr, "/query", CHAIN_Q.replace("q(", "warm("))
+        t0 = time.monotonic()
+        first = _post(server.addr, "/query?ledger=true&debug=true", CHAIN_Q)
+        wall_ms = (time.monotonic() - t0) * 1e3
+        again = _post(server.addr, "/query?ledger=true&debug=true", CHAIN_Q)
+        yield first, wall_ms, again
+    finally:
+        server.stop()
+        mp.undo()
+
+
+@pytest.mark.parametrize("stage", [
+    "parse", "queue", "plan", "h2d", "dispatch", "fetch", "convert",
+    "assemble", "encode",
+])
+def test_chain_route_request_carries_every_stage(chain_answer, stage):
+    """The account of a request's time: a served chain-route query
+    passes through each boundary of the catalogue, and says how long."""
+    first, _wall, _again = chain_answer
+    assert first["server_latency"]["engine"]["chain_fused_levels"] == 2
+    stages = first["extensions"]["ledger"]["stages"]
+    assert stages.get(stage, 0.0) > 0.0, stages
+
+
+def test_stages_sum_to_no_more_than_the_wall_time(chain_answer):
+    """Catalogue brackets never nest, so they add up — to at most what
+    the client waited."""
+    first, wall_ms, _again = chain_answer
+    stages = first["extensions"]["ledger"]["stages"]
+    assert set(stages) <= set(ledgermod.STAGES)
+    assert 0.0 < sum(stages.values()) <= wall_ms, (stages, wall_ms)
+
+
+def test_result_cache_hit_carries_parse_and_probe_alone(chain_answer):
+    """A tier-2 hit returns before admission: it was parsed and probed,
+    and nothing else happened to it."""
+    _first, _wall, again = chain_answer
+    led = again["extensions"]["ledger"]
+    assert led["cache_hits"] == 1 and led["edges"] == 0
+    assert set(led["stages"]) == {"parse", "result_cache"}, led["stages"]
+
+
+def test_http_write_grows_with_every_answer_written(srv):
+    from dgraph_tpu.utils.metrics import LEDGER_STAGE_US
+
+    q = "{ q(func: uid(0x1)) { follows { uid } } }"
+    seen = [LEDGER_STAGE_US.snapshot()["http_write"]]
+    for _ in range(3):
+        _post(srv.addr, "/query", q)
+        seen.append(LEDGER_STAGE_US.snapshot()["http_write"])
+    assert all(b > a for a, b in zip(seen, seen[1:])), seen
+
+
+def test_chain_route_books_the_bytes_it_moved(chain_answer):
+    """bytes_d2h is the packed buffer, to the byte, computed here from
+    the chain's caps (every degree <= INLINE, so no overflow chunks; a
+    full-mode undecorated level packs inline | ovflat | ovseg | next
+    frontier | total); bytes_h2d holds at least the root vector."""
+    from dgraph_tpu import ops
+
+    first, _wall, _again = chain_answer
+    led = first["extensions"]["ledger"]
+    capc = ops.bucket(1)
+    elems, B = 0, ops.bucket(3)
+    root_bytes = B * 4
+    for _level in range(2):
+        slots = B * ops.INLINE + capc * ops.CHUNK
+        cap_u = min(ops.bucket(min(slots, RING)), slots)
+        elems += slots + capc + cap_u + 1
+        B = cap_u
+    assert led["hops"] == {"chain": 2}
+    assert led["bytes_d2h"] == elems * 4, (led["bytes_d2h"], elems)
+    assert led["bytes_h2d"] >= root_bytes

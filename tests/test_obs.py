@@ -21,7 +21,6 @@ from dgraph_tpu import obs
 from dgraph_tpu.models import PostingStore
 from dgraph_tpu.serve.server import DgraphServer
 from dgraph_tpu.utils.metrics import SLOW_QUERIES, SPANS_RECORDED
-from dgraph_tpu.utils.trace import Tracer
 
 
 @pytest.fixture(autouse=True)
@@ -131,23 +130,6 @@ def test_sampler_deterministic_under_pinned_seed():
     a2 = obs.Sampler(ratio=0.5, seed=42)
     b2 = obs.Sampler(ratio=0.5, seed=42)
     assert a2.new_id(128) == b2.new_id(128)
-
-
-def test_legacy_tracer_sampler_owns_seeded_rng():
-    a = Tracer(ratio=0.5, seed=7)
-    b = Tracer(ratio=0.5, seed=7)
-    seq_a = [a.begin().active for _ in range(100)]
-    seq_b = [b.begin().active for _ in range(100)]
-    assert seq_a == seq_b
-    assert any(seq_a) and not all(seq_a)
-    # and pinning the tracer's seed must not touch the global RNG stream
-    import random
-
-    random.seed(123)
-    before = random.random()
-    random.seed(123)
-    Tracer(ratio=0.5, seed=7).begin()
-    assert random.random() == before
 
 
 # ---------------------------------------------------------- span mechanics
@@ -701,3 +683,149 @@ def test_cluster_malformed_traceparent_ignored(cluster2):
         headers={"Traceparent": "00-zzzz-yyyy-01"},
     )
     assert "m" in out
+
+
+# ------------------------------------------------- stages on the profiler's clock
+
+def _ring_engine():
+    """An embedded engine over a 40-uid ring (each follows the next
+    three), the fused chain route forced."""
+    from dgraph_tpu.query.engine import QueryEngine
+
+    store = PostingStore()
+    eng = QueryEngine(store)
+    eng.run(
+        "mutation { schema { follows: uid . } set { %s } }" % "\n".join(
+            f"<0x{u:x}> <follows> <0x{(u - 1 + d) % 40 + 1:x}> ."
+            for u in range(1, 41) for d in (1, 2, 3)
+        )
+    )
+    eng.chain_threshold = 0
+    return eng
+
+
+def test_stages_are_events_on_the_profilers_host_plane(tmp_path, monkeypatch):
+    """obs.stage enters a ``dgraph.<stage>`` TraceAnnotation for the
+    same interval it times: with a profiler session open round one chain
+    query, ``dgraph.dispatch`` then ``dgraph.fetch`` sit on one host
+    thread (the device guard's worker), inside the trace's extent — the
+    same clock as the device's operations."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    monkeypatch.setenv("DGRAPH_TPU_SEGMENT", "0")
+    eng = _ring_engine()
+    q = "{ q(func: uid(0x1, 0x2, 0x3)) { follows { follows { uid } } } }"
+    eng.run(q)  # compile outside the session
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.run(q)
+    finally:
+        jax.profiler.stop_trace()
+    assert eng.stats["chain_fused_levels"] == 2
+    (pb,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    lo, hi, lines = float("inf"), float("-inf"), []
+    for plane in ProfileData.from_file(pb).planes:
+        for ln in plane.lines:
+            ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in ln.events]
+            if ev:
+                lo = min(lo, min(s for _, s, _ in ev))
+                hi = max(hi, max(t for _, _, t in ev))
+            if plane.name.startswith("/host:"):
+                lines.append([e for e in ev if e[0].startswith("dgraph.")])
+    both = [
+        ln for ln in lines
+        if {"dgraph.dispatch", "dgraph.fetch"} <= {name for name, _, _ in ln}
+    ]
+    assert len(both) == 1, lines
+    (dispatch,) = [e for e in both[0] if e[0] == "dgraph.dispatch"]
+    (fetch,) = [e for e in both[0] if e[0] == "dgraph.fetch"]
+    assert lo <= dispatch[1] < dispatch[2] <= fetch[1] < fetch[2] <= hi
+    # and every stage of the engine's account is there by name
+    seen = {name for ln in lines for name, _, _ in ln}
+    assert {"dgraph.plan", "dgraph.h2d", "dgraph.convert", "dgraph.encode",
+            "dgraph.chain"} <= seen, seen
+
+
+def test_warm_requests_allocate_nothing_and_answers_are_unchanged(srv):
+    """No profiler session, ratio 0: 50 warm requests construct no span
+    and no ledger struct, and an answer that did not ask for the ledger
+    is, outside its latency map, byte for byte what it was before the
+    stages existed."""
+    from dgraph_tpu.utils.metrics import LEDGERS_CREATED
+
+    obs.configure(ratio=0.0)
+    q = "{ q(func: uid(0x1)) { name follows { name follows { name } } } }"
+    _post(srv.addr, "/query", q)
+    spans, structs = SPANS_RECORDED.value(), LEDGERS_CREATED.value()
+    for _ in range(50):
+        out = _post(srv.addr, "/query", q)
+    assert SPANS_RECORDED.value() == spans
+    assert LEDGERS_CREATED.value() == structs
+    assert set(out["server_latency"]) <= {"total", "parsing", "processing", "json"}
+    del out["server_latency"]
+    assert json.dumps(out) == (
+        '{"q": [{"name": "Alice", "follows": [{"name": "Bob", '
+        '"follows": [{"name": "Carol"}]}]}]}'
+    )
+
+
+@pytest.fixture(scope="module")
+def boot_metrics():
+    """/debug/prometheus_metrics of a server, in a process of its own,
+    that has answered nothing."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import urllib.request\n"
+        "from dgraph_tpu.models import PostingStore\n"
+        "from dgraph_tpu.serve.server import DgraphServer\n"
+        "s = DgraphServer(PostingStore()); s.start()\n"
+        "print(urllib.request.urlopen(s.addr + '/debug/prometheus_metrics')"
+        ".read().decode())\n"
+        "s.stop()\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root, JAX_PLATFORMS="cpu")
+    return subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, timeout=120, check=True,
+    ).stdout.splitlines()
+
+
+@pytest.mark.parametrize("sample", [
+    *(f'dgraph_ledger_stage_us_total{{stage="{s}"}} 0' for s in obs.ledger.STAGES),
+    'dgraph_ledger_bytes_total{dir="h2d"} 0',
+    'dgraph_ledger_bytes_total{dir="d2h"} 0',
+    "dgraph_xla_cache_reads_total 0",
+    "dgraph_xla_compiles_total 0",
+])
+def test_catalogue_is_exposed_at_zero_from_boot(boot_metrics, sample):
+    """A scraper's first read of a window finds every label it will
+    diff: a family absent until first incremented reads as no metric."""
+    assert sample in boot_metrics
+
+
+def test_cache_read_event_counts_apart_from_compiles():
+    """JAX fires /jax/compilation_cache/cache_hits for a program read
+    back from the persistent cache; it moves
+    dgraph_xla_cache_reads_total and leaves dgraph_xla_compiles_total
+    (every backend-compile bracket) alone."""
+    import jax.monitoring
+
+    from dgraph_tpu.obs import device
+    from dgraph_tpu.utils.metrics import XLA_CACHE_READS, XLA_COMPILES
+
+    device.install_compile_listener()
+    reads, compiles = XLA_CACHE_READS.value(), XLA_COMPILES.value()
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event("/jax/compilation_cache/compile_requests_use_cache")
+    assert XLA_CACHE_READS.value() == reads + 1
+    assert XLA_COMPILES.value() == compiles

@@ -32,7 +32,6 @@ def srv(tmp_path_factory):
     server = DgraphServer(
         PostingStore(),
         export_path=str(tmp_path_factory.mktemp("export")),
-        trace_ratio=1.0,
     )
     server.start()
     _post(server.addr, "/query", """
@@ -84,12 +83,6 @@ def test_debug_store(srv):
 def test_prometheus_metrics(srv):
     text = _get(srv.addr, "/debug/prometheus_metrics", raw=True).decode()
     assert "dgraph_num_queries_total" in text
-
-
-def test_trace_requests(srv):
-    _post(srv.addr, "/query", '{ q(func: has(name)) { name } }')
-    traces = _get(srv.addr, "/debug/requests")
-    assert any(t["family"] == "query" for t in traces)
 
 
 def test_share_roundtrip(srv):

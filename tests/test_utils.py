@@ -6,7 +6,7 @@ import pytest
 
 from dgraph_tpu.utils import Options, WaterMark
 from dgraph_tpu.utils.metrics import MetricsRegistry
-from dgraph_tpu.utils.trace import Latency, Tracer, _fmt_ns
+from dgraph_tpu.utils.trace import Latency, _fmt_ns
 
 
 def test_watermark_contiguous():
@@ -147,19 +147,6 @@ def test_fmt_ns():
     assert _fmt_ns(500) == "500ns"
     assert _fmt_ns(79_300_000) == "79.3ms"
     assert _fmt_ns(2_000_000_000) == "2s"
-
-
-def test_tracer_sampling():
-    t = Tracer(ratio=1.0)
-    tr = t.begin()
-    tr.printf("step %d", 1)
-    t.finish(tr, "query", "q1")
-    assert t.recent()[0]["events"][0]["msg"] == "step 1"
-    t0 = Tracer(ratio=0.0)
-    tr0 = t0.begin()
-    tr0.printf("never")
-    t0.finish(tr0, "query", "q2")
-    assert t0.recent() == []
 
 
 def test_options_yaml_merge(tmp_path):
