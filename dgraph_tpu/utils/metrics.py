@@ -770,6 +770,12 @@ LEDGER_STAGE_US = metrics.labeled(
 LEDGER_BYTES = metrics.labeled("dgraph_ledger_bytes_total", label="dir")
 LEDGERS_CREATED = metrics.counter("dgraph_ledger_structs_total")
 
+# result encoder (query/outputnode.py): result objects emitted, by the
+# path that built them — "level" (a level at a time, the general
+# encoder) or "walk" (depth first: @normalize, @ignorereflex).  One
+# increment a block; both labels at zero from boot.
+ENCODE_OBJECTS = metrics.labeled("dgraph_encode_objects_total", label="path")
+
 
 # device telemetry (obs/device.py + models/arena.py): HBM residency
 # under the ArenaManager budget (resident/budget gauges — headroom is
