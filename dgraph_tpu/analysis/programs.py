@@ -953,6 +953,39 @@ def _b_multi_hop() -> List[ProgramInstance]:
     ]
 
 
+def _b_path_search() -> List[ProgramInstance]:
+    """The path search's four programs over a 16-uid layout built the way
+    models/arena.py PathLayout builds it (row = uid, 0-padded esrc)."""
+    jnp, np = _jnp()
+    from dgraph_tpu.ops import bfs, sets
+
+    h_src, h_offsets, h_dst, _, _ = _small_csr()
+    deg = np.diff(h_offsets)
+    off = np.zeros(17, np.int32)
+    np.cumsum(np.bincount(h_src, weights=deg, minlength=16), out=off[1:])
+    off = jnp.asarray(off)
+    dst = jnp.asarray(sets.pad_to(h_dst, 32))
+    esrc = jnp.asarray(sets.pad_to(np.repeat(h_src, deg), 32, fill=0))
+    cap, chunk = bfs.capacities(32, int(deg.max()))
+    src, to = jnp.int32(0), jnp.int32(7)
+    st = bfs.start(off, src, cap, chunk)
+    state = tuple(range(3, 3 + len(st)))   # flat args of the donated state
+    return [
+        ProgramInstance("U16xC%dxK%d" % (cap, chunk), bfs.start, (off, src),
+                        {"cap": cap, "chunk": chunk}, scan_free=True),
+        # all levels in one program, and the one-level segment the segment
+        # loop dispatches: the same jit, bucketed on the shapes alone
+        ProgramInstance("U16xE32_all", bfs.run_levels,
+                        (off, dst, esrc, st, to, jnp.int32(1 << 30)), {"chunk": chunk},
+                        donate=state),
+        ProgramInstance("U16xE32_seg", bfs.run_levels,
+                        (off, dst, esrc, bfs.start(off, src, cap, chunk), to, jnp.int32(1)),
+                        {"chunk": chunk}, donate=state),
+        ProgramInstance("U16_walk", bfs.walk_back, (st["par"], to), {}),
+        ProgramInstance("U16_finish", bfs.finish, (bfs.start(off, src, cap, chunk), to), {}),
+    ]
+
+
 def _b_mesh_multi_hop() -> List[ProgramInstance]:
     jnp, np = _jnp()
     import jax
@@ -1449,6 +1482,26 @@ REGISTRY: Dict[str, ProgramContract] = {
                   "behind ops/batch.py's scoped handling of JAX's "
                   "unusable-donation warning (the frontier carry, arg "
                   "2, MUST alias)." + _SS_NOTE,
+        ),
+        ProgramContract(
+            name="bfs.path_search",
+            covers=(
+                f"{_OPS}/bfs.py::run_levels",
+                f"{_OPS}/bfs.py::start",
+                f"{_OPS}/bfs.py::walk_back",
+                f"{_OPS}/bfs.py::finish",
+            ),
+            build=_b_path_search,
+            scan_free=False,   # the level loop IS the design: lax.while_loop
+            dtypes=_INT,
+            notes="shortest(from:, to:) at unit cost: level-synchronous BFS "
+                  "over the listed predicates' merged layout, levels driven "
+                  "by lax.while_loop, each done as a gather (chunks of the "
+                  "frontier list) or a sweep (every edge) by the frontier's "
+                  "out-degree sum against the layout's size; parents are "
+                  "scatter-min'ed (least uid), the path is walked back on "
+                  "the device.  The state is donated from segment to "
+                  "segment (run_levels' instances declare it).",
         ),
         ProgramContract(
             name="batch.classed_expander",

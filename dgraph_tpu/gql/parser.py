@@ -282,6 +282,22 @@ class _Parser:
                     self.expect("punct", ")")
                     gq.args[key] = "val:" + v
                     gq.needs_var.append(VarRef(v, VALUE_VAR))
+                elif (
+                    key in ("from", "to")
+                    and self.peek().kind == "name"
+                    and self.peek().text == "uid"
+                    and self.peek(1).text == "("
+                ):
+                    # shortest(from: uid(A), to: uid(B)): an endpoint named
+                    # by an earlier block's uid variable (clients of a graph
+                    # keyed by names know no uids); the engine binds it and
+                    # wants exactly one uid there
+                    self.next()
+                    self.expect("punct", "(")
+                    v = self.expect("name").text
+                    self.expect("punct", ")")
+                    gq.args[key] = "var:" + v
+                    gq.needs_var.append(VarRef(v, UID_VAR))
                 else:
                     v = self._value_token()
                     if key in ("orderasc", "orderdesc"):

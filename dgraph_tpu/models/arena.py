@@ -104,6 +104,16 @@ class CSRArena:
             )
         return self._n_distinct_dst
 
+    _max_uid: Optional[int] = None
+
+    def max_uid(self) -> int:
+        """The largest uid this arena holds, as a row or as a target (lazy):
+        how far a table over the uid space would have to reach."""
+        if self._max_uid is None:
+            top = int(self.h_src[-1]) if len(self.h_src) else 0
+            self._max_uid = max(top, int(self.host_dst().max()) if self.n_edges else 0)
+        return self._max_uid
+
     def expand_host(self, rows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Vectorized numpy CSR expansion over the host mirror: returns
         (out, seg_ptr) in the engine's layout — out grouped by input row
@@ -515,6 +525,7 @@ class CSRArena:
         self._inline_grouped = None
         self._lut = None
         self._n_distinct_dst = None
+        self._max_uid = None
         for attr in (
             "_topm_cdeg", "_topm_ovdeg", "_topm_deg", "_classed",
             "_tile_blocks",
@@ -801,6 +812,40 @@ class ResidentArena:
         return n
 
 
+class PathLayout:
+    """The arenas of a path search's listed predicates merged into ONE CSR
+    over the uid space (``ops/bfs.py``): row = uid, so a frontier uid is
+    its own row and a level table is indexed by what the edges hold; a
+    uid's edges lie in the order the predicates were listed, each
+    predicate's ascending.  ``esrc`` is the source uid of every edge slot
+    (0 on padding: no uid), for the level done as a sweep.  Built on the
+    host from the arenas' mirrors on first use and kept by the
+    ``ArenaManager`` until one of its arenas changes.  Its tables are DENSE
+    over the uid space, as a search's level and parent tables are:
+    ``planner.path_route`` sends a block to the host where that space is
+    wider than the arenas hold (``path_extent``)."""
+
+    def __init__(self, arenas: List[CSRArena]):
+        srcs = [np.repeat(a.h_src, np.diff(a.h_offsets)) for a in arenas]
+        dsts = [a.host_dst()[: a.n_edges].astype(np.int64) for a in arenas]
+        src = np.concatenate(srcs) if srcs else np.empty(0, np.int64)
+        dst = np.concatenate(dsts) if dsts else np.empty(0, np.int64)
+        self.n_edges = int(len(src))
+        self.universe = int(max(src.max(), dst.max())) if self.n_edges else 0
+        self.ub = ops.bucket_fine(self.universe + 2)
+        order = np.argsort(src, kind="stable")
+        counts = np.bincount(src, minlength=self.ub)
+        self.max_degree = int(counts.max()) if self.n_edges else 0
+        off = np.zeros(self.ub + 1, dtype=np.int32)
+        np.cumsum(counts, out=off[1:])
+        eb = ops.bucket(max(1, self.n_edges))
+        self.off = jnp.asarray(off)
+        self.dst = jnp.asarray(ops.pad_to(dst[order], eb))
+        self.esrc = jnp.asarray(ops.pad_to(src[order], eb, fill=0))
+        self.key = tuple((id(a), a.epoch) for a in arenas)
+        _book_h2d((self.off, self.dst, self.esrc))
+
+
 def _build_csr(rows_to_dsts: Dict[int, np.ndarray]) -> CSRArena:
     """Build a CSR arena from {row_key: array-of-dst} (host)."""
     keys = np.array(sorted(rows_to_dsts.keys()), dtype=np.int64)
@@ -1045,6 +1090,9 @@ class ArenaManager:
         self._index: Dict[Tuple[str, str], IndexArena] = {}
         self._values: Dict[str, ValueArena] = {}
         self._sharded: Dict[Tuple[str, bool], tuple] = {}
+        # merged layouts of path searches (PathLayout), by the listed
+        # predicates: a handful at most, dropped whole under HBM pressure
+        self._path_layouts: Dict[tuple, PathLayout] = {}
         # protects the cache dicts + refresh bookkeeping ONLY — heavy
         # arena builds run outside it under per-key build locks
         # (_get_or_build), so one cold predicate never stalls readers of
@@ -1219,7 +1267,8 @@ class ArenaManager:
         budget eviction; the device copy is freed when the last
         reference dies."""
         with self._cache_lock:
-            dropped = 0
+            dropped = len(self._path_layouts)
+            self._path_layouts.clear()
             while dropped < n and len(self._lru) > 1:
                 if not self._pop_lru_victim():
                     break
@@ -1667,6 +1716,36 @@ class ArenaManager:
             return csr_from_edges(dst, src)  # inverted: one lexsort, no
             # per-target python append loop (posting/index.go:152)
         return _build_csr({})
+
+    def path_extent(self, preds: tuple) -> Tuple[int, int]:
+        """(the largest uid the arenas of ``preds`` hold, the rows and edges
+        they hold): what a search's dense tables would span against what
+        the store already keeps — ``planner.path_route`` weighs them."""
+        arenas = [self.reverse(a) if rev else self.data(a) for a, rev in preds]
+        return (max((a.max_uid() for a in arenas), default=0),
+                sum(a.n_rows + a.n_edges for a in arenas))
+
+    def path_layout(self, preds: tuple) -> PathLayout:
+        """The merged layout of ``preds`` — ((attr, reverse), ...) in the
+        order listed — for ``ops/bfs.py``.  Valid while every arena it
+        was built from is the cached one at the same epoch (a delta bumps
+        the epoch, a rebuild replaces the object)."""
+        arenas = [self.reverse(a) if rev else self.data(a) for a, rev in preds]
+        key = tuple((id(a), a.epoch) for a in arenas)
+        with self._cache_lock:
+            lay = self._path_layouts.get(preds)
+        if lay is not None and lay.key == key:
+            return lay
+        with obs.stage(None, "h2d_ms"), _BUILD_LOCK:  # as CSRArena.lut
+            with self._cache_lock:
+                lay = self._path_layouts.get(preds)
+            if lay is None or lay.key != key:
+                lay = PathLayout(arenas)
+                with self._cache_lock:
+                    while len(self._path_layouts) >= 4:
+                        self._path_layouts.pop(next(iter(self._path_layouts)))
+                    self._path_layouts[preds] = lay
+        return lay
 
     # -- secondary indexes ---------------------------------------------------
 

@@ -554,6 +554,41 @@ def segment_route(
     return k, dec
 
 
+def path_route(
+    k: int, decorated: bool, faceted: bool, unusable: bool,
+    universe: int = 0, held: int = 0,
+) -> Tuple[bool, dict]:
+    """A ``shortest`` block: the device's level-synchronous BFS
+    (ops/bfs.py) or the host's Dijkstra (query/shortest.py)?  Decided by
+    what the routes can DO, from what the block and the store show — no
+    rate, no knob: the BFS answers one path at unit cost over plain
+    predicates; k paths, a facet on a listed predicate (a weight, or one
+    the hop has to render) and a decorated child (filter, pagination,
+    order, count, ...) are the Dijkstra's.  So is a store whose uid space
+    is wider than its arenas: the BFS keeps a level and a parent for EVERY
+    uid up to the largest (``universe``), for each search in flight, and
+    where that outgrows the rows and edges the listed arenas hold
+    (``held``) — one uid near 2^30 in a store of a thousand edges — the
+    tables would cost what no arena does; the Dijkstra touches only what
+    it reaches.  How a level is done once on the device is chosen there,
+    per level (ops/bfs.py)."""
+    why = (
+        "numpaths > 1: k paths are the Dijkstra's" if k > 1
+        else "a listed predicate carries facets (weights, rendered facets)" if faceted
+        else "a child is decorated (filter, pagination, ...)" if decorated
+        else "device unavailable (sick, or an arena is sharded over the mesh)" if unusable
+        else "the uid space is wider than the listed arenas hold" if universe > held
+        else ""
+    )
+    dec = {
+        "kind": "path",
+        "route": "host" if why else "device",
+        "units": int(k),
+        "reason": why or "one path at unit cost over plain predicates",
+    }
+    return not why, dec
+
+
 def mxu_fanout_ok(engine, est_total: int, n_levels: int) -> bool:
     """The MXU tier's fan-out admission: is this chain big enough to
     leave the host at all?  Shares chain_route's model (and its override

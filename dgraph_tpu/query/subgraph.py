@@ -45,6 +45,8 @@ class Params:
     depth: int = 0
     path_from: int = 0
     path_to: int = 0
+    path_from_var: str = ""       # from: uid(var)
+    path_to_var: str = ""
     num_paths: int = 1
 
 
@@ -170,8 +172,14 @@ def build_subgraph(gq: GraphQuery) -> SubGraph:
         p.is_recurse = True
     if gq.alias == "shortest":
         p.is_shortest = True
-        p.path_from = _uid_of(args.get("from", "0"))
-        p.path_to = _uid_of(args.get("to", "0"))
+        # an endpoint is a literal uid or ``uid(var)`` (parser: "var:<name>"),
+        # which the engine binds when the block runs
+        for key in ("from", "to"):
+            v = args.get(key, "0")
+            if v.startswith("var:"):
+                setattr(p, f"path_{key}_var", v[4:])
+            else:
+                setattr(p, f"path_{key}", _uid_of(v))
         p.num_paths = int(args.get("numpaths", "1"))
 
     if gq.uid_list:

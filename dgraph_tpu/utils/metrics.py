@@ -770,6 +770,20 @@ LEDGER_STAGE_US = metrics.labeled(
 LEDGER_BYTES = metrics.labeled("dgraph_ledger_bytes_total", label="dir")
 LEDGERS_CREATED = metrics.counter("dgraph_ledger_structs_total")
 
+# path search (query/shortest.py): PATH_SEARCHES{route} counts shortest
+# blocks by the route that answered them — "device" (ops/bfs.py: unit
+# cost, one path) or "host" (the Dijkstra: numpaths > 1, a weight facet,
+# a decorated child); PATH_LEVELS the levels the device route expanded
+# and PATH_FRONTIER_ROWS the uids in them, so rows / levels is a level's
+# mean width.  Their edges ride LEDGER_HOP_EDGES{route="path"}.  Every
+# label is there at zero from boot.
+PATH_SEARCHES = metrics.labeled("dgraph_path_searches_total", label="route")
+PATH_LEVELS = metrics.counter("dgraph_path_levels_total")
+PATH_FRONTIER_ROWS = metrics.counter("dgraph_path_frontier_rows_total")
+for _r in ("device", "host"):
+    PATH_SEARCHES.add(_r, 0)
+LEDGER_HOP_EDGES.add("path", 0)
+
 # result encoder (query/outputnode.py): result objects emitted, by the
 # path that built them — "level" (a level at a time, the general
 # encoder) or "walk" (depth first: @normalize, @ignorereflex).  One

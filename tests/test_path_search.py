@@ -1,0 +1,391 @@
+"""shortest(from:, to:) on the device route (ops/bfs.py via query/shortest.py)
+against the host's Dijkstra and against a numpy BFS, on seeded random
+graphs of several predicates walked both ways: length, validity hop by hop,
+the tie rule (walking back from ``to``, the least predecessor), the ledger's
+``edges`` / ``rows`` by their definition, the route choice, ``uid(var)``
+endpoints and cancellation at a level boundary.
+"""
+
+import json
+import urllib.error
+import urllib.request
+
+import numpy as np
+import pytest
+
+from dgraph_tpu.models import PostingStore
+from dgraph_tpu.ops import bfs
+from dgraph_tpu.query import planner
+from dgraph_tpu.query.engine import QueryEngine
+from dgraph_tpu.sched import CancelToken, QueryCancelledError
+from dgraph_tpu.utils.metrics import PATH_FRONTIER_ROWS, PATH_LEVELS, PATH_SEARCHES
+
+PREDS = ("a", "b", "c")
+LISTED = "a ~a b ~c"          # c is walked backwards only
+
+
+def make_graph(seed: int, n: int, per_pred: int, hub: int = 0, chain: int = 0):
+    """{pred: set of (src, dst)} over uids 1..n: random edges, optionally a
+    hub (one uid with ``hub`` out-edges under ``a``: the level that holds it
+    outgrows the gather's list) and a chain of ``chain`` uids beyond n (long
+    distances).  Uids n+chain+1 .. n+chain+4 form an island of their own."""
+    rng = np.random.default_rng(seed)
+    g = {p: set() for p in PREDS}
+    for p in PREDS:
+        for u, v in rng.integers(1, n + 1, (per_pred, 2)).tolist():
+            if u != v:
+                g[p].add((u, v))
+    for v in rng.choice(np.arange(2, n + 1), size=min(hub, n - 1), replace=False).tolist():
+        g["a"].add((1, int(v)))
+    for i in range(chain):
+        g["b"].add((n + i, n + i + 1))
+    base = n + chain
+    g["a"] |= {(base + 1, base + 2), (base + 2, base + 3)}
+    g["c"].add((base + 4, base + 3))
+    return g, base + 4
+
+
+def load(g) -> QueryEngine:
+    e = QueryEngine(PostingStore())
+    lines = [f"<0x{u:x}> <{p}> <0x{v:x}> ." for p in PREDS for u, v in sorted(g[p])]
+    e.run("mutation { schema { a: uid @reverse . b: uid . c: uid . } set { %s } }"
+          % "\n".join(lines))
+    return e
+
+
+def neighbours(g):
+    """uid -> [(neighbour, listed predicate)] under ``LISTED``, listed order."""
+    out = {}
+    for tok in LISTED.split():
+        rev, p = tok.startswith("~"), tok.lstrip("~")
+        for u, v in g[p]:
+            s, d = (v, u) if rev else (u, v)
+            out.setdefault(s, []).append((d, p))
+    return out
+
+
+def numpy_bfs(g, src, dst):
+    """(path by the tie rule or None, edges, rows, levels) by definition."""
+    nb = neighbours(g)
+    level = {src: 0}
+    frontier, edges, rows, levels = [src], 0, 0, 0
+    while frontier and dst not in level:
+        rows += len(frontier)
+        levels += 1
+        nxt = set()
+        for u in frontier:
+            edges += len(nb.get(u, ()))
+            for v, _ in nb.get(u, ()):
+                if v not in level:
+                    nxt.add(v)
+        for v in nxt:
+            level[v] = levels
+        frontier = sorted(nxt)
+    if dst not in level:
+        return None, edges, rows, levels
+    path = [dst]
+    while path[-1] != src:
+        v = path[-1]
+        path.append(min(u for u in level if level[u] == level[v] - 1
+                        and any(w == v for w, _ in nb.get(u, ()))))
+    return path[::-1], edges, rows, levels
+
+
+def path_of(answer):
+    """[(uid, predicate of the hop out of it)] of the one ``_path_``."""
+    node, out = answer["_path_"][0], []
+    while True:
+        keys = [k for k in node if k not in ("_uid_", "@facets")]
+        out.append((int(node["_uid_"], 16), keys[0] if keys else None))
+        if not keys:
+            return out
+        assert len(keys) == 1 and len(node[keys[0]]) == 1
+        node = node[keys[0]][0]
+
+
+def on_host(monkeypatch):
+    monkeypatch.setattr(
+        planner, "path_route",
+        lambda k, *a: (False, {"kind": "path", "route": "host", "units": k, "reason": "test"}))
+
+
+CASES = [
+    # seed, n, edges a predicate, hub, chain
+    pytest.param(1, 60, 50, 0, 0, id="sparse"),
+    pytest.param(2, 60, 50, 0, 0, id="sparse-2"),
+    pytest.param(3, 60, 120, 0, 0, id="dense"),
+    pytest.param(4, 200, 150, 0, 0, id="wide"),
+    pytest.param(5, 200, 150, 150, 0, id="hub"),
+    pytest.param(6, 200, 400, 190, 0, id="hub-dense"),
+    pytest.param(7, 40, 30, 0, 10, id="chain"),
+    pytest.param(8, 40, 30, 30, 9, id="hub-chain"),
+    pytest.param(9, 500, 300, 0, 0, id="large"),
+    pytest.param(10, 500, 300, 450, 0, id="large-hub"),
+]
+
+
+@pytest.mark.parametrize("seed,n,per_pred,hub,chain", CASES)
+def test_device_route_agrees_with_the_dijkstra_and_the_definition(
+        monkeypatch, seed, n, per_pred, hub, chain):
+    g, top = make_graph(seed, n, per_pred, hub, chain)
+    e = load(g)
+    rng = np.random.default_rng(100 + seed)
+    pairs = [tuple(p) for p in rng.integers(1, n + 1, (10, 2)).tolist()]
+    pairs += [(1, int(rng.integers(2, n + 1))), (top - 3, top - 1), (top - 3, top),
+              (top - 1, 1), (1, top)]
+    pairs.append(sorted(g["a"])[0])                  # one hop
+    if chain:
+        pairs += [(n, n + chain), (1, n + chain), (n + 2, n + chain - 1)]
+    nb = neighbours(g)
+    seen_d, sweeps, mid_level = set(), 0, 0
+    for src, dst in pairs:
+        text = "{ shortest(from: 0x%x, to: 0x%x) { %s } }" % (src, dst, LISTED)
+        want, edges, rows, levels = numpy_bfs(g, src, dst)
+        before = (PATH_SEARCHES.snapshot()["device"], PATH_LEVELS.value(),
+                  PATH_FRONTIER_ROWS.value())
+        got = e.run(text)
+        assert PATH_SEARCHES.snapshot()["device"] == before[0] + 1, (src, dst)
+        if src == dst:
+            assert [u for u, _ in path_of(got)] == [src]
+            continue
+        # the ledger's account, by the definition
+        assert e.stats["edges"] == edges, (src, dst)
+        assert PATH_LEVELS.value() - before[1] == levels
+        assert PATH_FRONTIER_ROWS.value() - before[2] == rows
+        sweeps += e.stats.get("path_sweeps", 0)
+        if want is None:
+            assert got.get("_path_", []) == [], (src, dst)
+        else:
+            hops = path_of(got)
+            uids = [u for u, _ in hops]
+            assert uids == want, (src, dst)          # length AND the tie rule
+            assert len(set(uids)) == len(uids)
+            for (u, p), v in zip(hops, uids[1:]):    # each hop a stored edge,
+                first = next(q for w, q in nb[u] if w == v)
+                assert p == first                    # under the first listed predicate
+            seen_d.add(len(uids) - 1)
+            level_of_dst = numpy_level(g, src, len(uids) - 1)
+            mid_level += 0 < level_of_dst.index(dst) < len(level_of_dst) - 1
+        # the Dijkstra says the same, byte for byte
+        with monkeypatch.context() as m:
+            on_host(m)
+            assert e.run(text) == got, (src, dst)
+    assert seen_d, "no pair was reachable"
+    assert 1 in seen_d
+    if chain:
+        assert max(seen_d) >= chain
+    if hub >= 150:
+        assert sweeps > 0, "no level went to the sweep"
+    assert mid_level > 0, "`to` was never met in the middle of a level"
+
+
+def numpy_level(g, src, d):
+    """The uids of level ``d`` from ``src``, ascending."""
+    nb = neighbours(g)
+    seen, frontier = {src}, [src]
+    for _ in range(d):
+        nxt = {v for u in frontier for v, _ in nb.get(u, ())} - seen
+        seen |= nxt
+        frontier = sorted(nxt)
+    return frontier
+
+
+def test_both_ways_of_doing_a_level_run_and_agree():
+    """A hub's level goes to the sweep, its neighbours' to the gather; the
+    distances from uid 1 are those of the numpy BFS for every target."""
+    g, _ = make_graph(5, 200, 150, 150, 0)
+    e = load(g)
+    gathers = sweeps = 0
+    for dst in range(2, 60):
+        want, edges, _, levels = numpy_bfs(g, 1, dst)
+        got = e.run("{ shortest(from: 0x1, to: 0x%x) { %s } }" % (dst, LISTED))
+        assert (len(path_of(got)) - 1 if want else None) == (len(want) - 1 if want else None)
+        assert e.stats["edges"] == edges
+        sweeps += e.stats.get("path_sweeps", 0)
+        gathers += levels - e.stats.get("path_sweeps", 0)
+    assert sweeps > 0 and gathers > 0
+
+
+def test_a_path_longer_than_one_walk_back():
+    """The device hands back ``bfs.PATH_CAP`` uids a walk; a longer path is
+    walked on from where the last walk ended."""
+    n = 2 * bfs.PATH_CAP + 7
+    e = QueryEngine(PostingStore())
+    e.run("mutation { schema { a: uid . } set { %s } }"
+          % "\n".join(f"<0x{u:x}> <a> <0x{u + 1:x}> ." for u in range(1, n)))
+    got = e.run("{ shortest(from: 0x1, to: 0x%x) { a } }" % n)
+    assert [u for u, _ in path_of(got)] == list(range(1, n + 1))
+    assert e.stats["edges"] == n - 1
+
+
+def test_capacities_follow_the_layouts_size():
+    cap, chunk = bfs.capacities(8 * 1024 * 1024, 97_734)
+    assert cap * bfs._ACCESS_PER_SLOT <= 8 * 1024 * 1024 * bfs._ACCESS_PER_EDGE
+    assert chunk >= 97_734 and chunk <= cap
+    cap, chunk = bfs.capacities(16, 40)       # a uid wider than the list: the chunk holds it
+    assert chunk >= 40 and cap >= chunk
+
+
+ROUTES = [
+    pytest.param("{ shortest(from: 0x1, to: 0x9) { a ~a b } }", "device", id="plain"),
+    pytest.param("{ shortest(from: 0x1, to: 0x9, numpaths: 2) { a ~a b } }", "host",
+                 id="numpaths-2"),
+    pytest.param("{ shortest(from: 0x1, to: 0x9) { a @filter(uid(0x2, 0x3, 0x9)) b } }",
+                 "host", id="filtered-child"),
+    pytest.param("{ shortest(from: 0x1, to: 0x9) { a (first: 2) b } }", "host",
+                 id="paginated-child"),
+    pytest.param("{ shortest(from: 0x1, to: 0x9) { w b } }", "host", id="weight-facet"),
+]
+
+
+@pytest.fixture(scope="module")
+def small():
+    g, _ = make_graph(1, 30, 40)
+    e = load(g)
+    e.run("mutation { set { <0x1> <w> <0x2> (weight=0.5) . <0x2> <w> <0x9> (weight=0.25) . } }")
+    return e
+
+
+@pytest.mark.parametrize("text,route", ROUTES)
+def test_route_choice_is_read_from_the_block_and_the_store(small, text, route):
+    before = PATH_SEARCHES.snapshot()
+    small.run(text)
+    after = PATH_SEARCHES.snapshot()
+    other = "host" if route == "device" else "device"
+    assert after[route] == before[route] + 1 and after[other] == before[other]
+    assert [d["route"] for d in small.stats["planner"] if d["kind"] == "path"] == [route]
+
+
+def test_a_uid_space_wider_than_the_arenas_is_searched_on_the_host():
+    """One explicit uid near 2^30 in a store of five edges: the BFS's tables
+    are dense over the uid space (gigabytes here, for every search in
+    flight), so the planner, which sees the largest uid beside what the
+    arenas hold, leaves the block to the Dijkstra — and no layout is built."""
+    far = (1 << 30) + 5
+    e = QueryEngine(PostingStore())
+    hops = [(1, 2), (2, 3), (3, far), (far, 4), (1, 7)]
+    e.run("mutation { schema { a: uid @reverse . } set { %s } }"
+          % "\n".join(f"<0x{u:x}> <a> <0x{v:x}> ." for u, v in hops))
+    before = PATH_SEARCHES.snapshot()
+    got = e.run("{ shortest(from: 0x1, to: 0x4) { a ~a } }")
+    after = PATH_SEARCHES.snapshot()
+    assert [u for u, _ in path_of(got)] == [1, 2, 3, far, 4]
+    assert after["host"] == before["host"] + 1 and after["device"] == before["device"]
+    (dec,) = [d for d in e.stats["planner"] if d["kind"] == "path"]
+    assert dec["route"] == "host" and "uid space" in dec["reason"]
+    assert not e.arenas._path_layouts
+    assert e.arenas.path_extent((("a", False), ("a", True))) == (far, (4 + 5) + (5 + 5))   # rows + edges, forward and reverse
+
+
+def test_weighted_search_answers_as_before(small):
+    got = small.run("{ shortest(from: 0x1, to: 0x9) { w } }")
+    hops = path_of(got)
+    assert [u for u, _ in hops] == [1, 2, 9]
+    assert got["_path_"][0]["w"][0]["@facets"]["_"]["weight"] == 0.5
+
+
+@pytest.fixture(scope="module")
+def srv():
+    from dgraph_tpu.serve.server import DgraphServer
+
+    server = DgraphServer(PostingStore())
+    server.start()
+    names = "\n".join(f'<0x{u:x}> <name> "N{u % 7}" .' for u in range(1, 15))
+    edges = "\n".join(f"<0x{u:x}> <a> <0x{u + 1:x}> ." for u in range(1, 14))
+    post(server.addr, "/query",
+         "mutation { schema { name: string @index(exact) . a: uid @reverse . } "
+         "set { %s %s <0x1> <name> \"Only\" . <0x9> <name> \"Nine\" . } }" % (names, edges))
+    yield server
+    server.stop()
+
+
+def post(addr, path, body):
+    req = urllib.request.Request(addr + path, data=body.encode(), method="POST")
+    with urllib.request.urlopen(req, timeout=60) as r:
+        return json.loads(r.read().decode())
+
+
+BY_NAME = """{
+  A as var(func: eq(name, "%s")) { name }
+  B as var(func: eq(name, "%s")) { name }
+  path as shortest(from: uid(A), to: uid(B)) { a ~a }
+  hops(func: uid(path)) { name }
+}"""
+
+
+def test_endpoints_by_uid_variable_over_http_with_a_ledger(srv):
+    before = PATH_SEARCHES.snapshot()["device"]
+    out = post(srv.addr, "/query?ledger=true", BY_NAME % ("Only", "Nine"))
+    assert [u for u, _ in path_of(out)] == list(range(1, 10))
+    assert len(out["hops"]) == 9
+    assert PATH_SEARCHES.snapshot()["device"] == before + 1
+    led = out["extensions"]["ledger"]
+    # levels 0..7 of a chain walked both ways: 1 + 2 * 7 edges, 8 rows
+    assert led["edges"] == 15 and led["hop_edges"] == {"path": 15} and led["hops"]["path"] == 1
+    assert led["bytes_d2h"] > 0
+    for stage in ("plan", "dispatch", "fetch"):
+        assert led["stages"].get(stage, 0) > 0, stage
+
+
+@pytest.mark.parametrize("a,b,n", [
+    pytest.param("Nobody", "Nine", 0, id="from-binds-0"),
+    pytest.param("Only", "N3", 2, id="to-binds-2"),
+])
+def test_an_endpoint_variable_has_to_bind_one_uid(srv, a, b, n):
+    with pytest.raises(urllib.error.HTTPError) as err:
+        post(srv.addr, "/query", BY_NAME % (a, b))
+    assert err.value.code == 400
+    assert f"binds {n} uids" in err.value.read().decode()
+
+
+def test_endpoints_by_uid_variable_over_grpc(srv):
+    pytest.importorskip("grpc")
+    from dgraph_tpu.client import GrpcTransport
+    from dgraph_tpu.serve.grpc_server import GrpcServer
+
+    gsrv = GrpcServer(srv, port=0)
+    gsrv.start()
+    try:
+        before = PATH_SEARCHES.snapshot()["device"]
+        out = GrpcTransport(f"127.0.0.1:{gsrv.port}").run(BY_NAME % ("Nine", "Only"))
+        assert PATH_SEARCHES.snapshot()["device"] == before + 1
+        assert [u for u, _ in path_of(out)] == list(range(9, 0, -1))
+    finally:
+        gsrv.stop()
+
+
+def test_literal_endpoints_and_missing_ones_as_before(small):
+    assert "_path_" in small.run("{ shortest(from: 1, to: 0x9) { a ~a b } }")
+    for text in ("{ shortest(to: 0x2) { a } }", "{ shortest(from: 0x1) { a } }"):
+        with pytest.raises(ValueError, match="from: and to:"):
+            small.run(text)
+
+
+def test_cancellation_lands_at_a_level_boundary(monkeypatch):
+    """One level a dispatch: a token flipped while the first level runs is
+    seen before the second is dispatched."""
+    from dgraph_tpu.utils.failpoints import fail
+
+    monkeypatch.setenv("DGRAPH_TPU_SEGMENT", "force")
+    monkeypatch.setenv("DGRAPH_TPU_SEGMENT_K", "1")
+    g, _ = make_graph(7, 40, 30, 0, 9)
+    e = load(g)
+    text = "{ shortest(from: 0x%x, to: 0x%x) { %s } }" % (40, 49, LISTED)
+    assert len(path_of(e.run(text))) == 10           # 9 levels, uncancelled
+    e.cancel = tok = CancelToken()
+    real = e.checkpoint
+    fail.arm("device.path", "delay(ms=1)")           # armed: the site counts its hits
+    h0 = fail.hits("device.path")
+
+    def checkpoint():
+        if fail.hits("device.path") - h0 >= 2:       # start + the first level
+            tok.cancel("admin")
+        real()
+
+    monkeypatch.setattr(e, "checkpoint", checkpoint)
+    try:
+        with pytest.raises(QueryCancelledError):
+            e.run(text)
+        assert fail.hits("device.path") - h0 == 2    # no second level ran
+    finally:
+        fail.disarm("device.path")
