@@ -17,9 +17,15 @@ the cache-less path byte-identically).
 
 from dgraph_tpu.cache.core import VersionedLFUCache, cache_enabled
 from dgraph_tpu.cache.hop import HopCache, frontier_digest
-from dgraph_tpu.cache.result import ResultCache, cacheable, request_digest
+from dgraph_tpu.cache.result import (
+    Answer,
+    ResultCache,
+    cacheable,
+    request_digest,
+)
 
 __all__ = [
+    "Answer",
     "VersionedLFUCache",
     "HopCache",
     "ResultCache",

@@ -3,18 +3,24 @@
 The scheduler's singleflight (sched/scheduler.py) already collapses
 identical requests that overlap in time; this tier extends the reuse
 window from "while a twin is in flight" to "until the next mutation":
-``(request key, store version) → (response dict, engine stats)``.  A
+``(request key, store version) → the answer's encoded body``.  A
 hit skips parsing's downstream entirely — no admission, no cohort
 wait, no engine shell, no read-lock acquisition — which under zipf
 traffic converts the head of the popularity curve into dict probes.
 
+The UNIT is the body as it is sent: ``json.dumps`` of the answer's
+blocks, the bytes that go on the socket, priced at their length.  A
+request's blocks are serialised once (``Answer``): the miss hands those
+bytes to the cache and to the socket, singleflight twins share them,
+and a hit splices the per-request tail (``server_latency``,
+``extensions``) round the stored bytes — no tree is kept alive and none
+is walked.  Surfaces that need the tree (protobuf, gRPC, subscriptions)
+decode the stored body on a hit.
+
 The request key is the serving layer's singleflight key — query text +
 canonical (sorted-JSON) variables + debug flag — digested so the cache
-holds no unbounded query texts.  Sharing the cached response dict is
-safe by the same argument the scheduler's singleflight documents:
-handlers only encode results, never mutate them.  Responses that
-depend on wall-clock (``math(since(...))``) are detected at parse
-shape and never cached.
+holds no unbounded query texts.  Responses that depend on wall-clock
+(``math(since(...))``) are detected at parse shape and never cached.
 
 Invalidation is the shared snapshot-version scheme (cache/core.py),
 SCOPED since IVM (dgraph_tpu/ivm/): the scheduler keys each entry on
@@ -33,7 +39,9 @@ this tier only).
 from __future__ import annotations
 
 import hashlib
-from typing import Optional, Tuple
+import json
+import threading
+from typing import Callable, Optional, Tuple
 
 from dgraph_tpu import obs
 from dgraph_tpu.cache.core import VersionedLFUCache, env_bytes
@@ -57,11 +65,17 @@ def request_digest(key) -> bytes:
     return h.digest()
 
 
+# what the server appends to an answer's blocks, per request
+TAIL_KEYS = ("server_latency", "extensions")
+
+
 def cacheable(parsed) -> bool:
     """A parsed request whose response is a pure function of (query,
     store snapshot): read-only and free of wall-clock math.  Mutations
     never reach the scheduler path, but the guard is cheap and keeps
-    this module's contract self-contained."""
+    this module's contract self-contained.  A block NAMED like one of
+    the tail's keys is refused too: its reply is no splice (the tail's
+    value takes the block's place, ``Answer.reply``)."""
     if parsed.mutation is not None:
         return False
 
@@ -77,23 +91,81 @@ def cacheable(parsed) -> bool:
             return False
         return all(walk(c) for c in q.children)
 
-    return all(walk(q) for q in parsed.queries)
+    return all(
+        q.alias not in TAIL_KEYS and q.attr not in TAIL_KEYS and walk(q)
+        for q in parsed.queries
+    )
 
 
-def _approx_bytes(obj) -> int:
-    """Rough recursive footprint of a response dict — budget accounting,
-    not accounting-grade (strings dominate real responses)."""
-    if isinstance(obj, dict):
-        return 64 + sum(
-            _approx_bytes(k) + _approx_bytes(v) for k, v in obj.items()
-        )
-    if isinstance(obj, (list, tuple)):
-        return 56 + sum(_approx_bytes(v) for v in obj)
-    if isinstance(obj, str):
-        return 49 + len(obj)
-    if isinstance(obj, (bytes, bytearray)):
-        return 33 + len(obj)
-    return 28
+class Answer:
+    """One execution's answer blocks (what ``run_parsed`` returns, before
+    the server appends its tail) in the two forms the surfaces ask for:
+    the tree, and the encoded body — ``json.dumps`` of the tree, the
+    bytes that go on the socket and the unit the result cache holds.
+
+    Whichever form is missing is made on first use, ONCE: singleflight
+    twins are dealt the same Answer, so K twins cost one serialisation,
+    and a cache hit is an Answer of the stored bytes that decodes only
+    for a surface that needs the tree.  Read-only, like the tree."""
+
+    __slots__ = ("_tree", "_body", "_lock", "_keep")
+
+    def __init__(
+        self, tree: Optional[dict] = None, body: Optional[bytes] = None
+    ):
+        self._tree = tree
+        self._body = body
+        self._lock = threading.Lock()
+        self._keep: Optional[Callable[[bytes], None]] = None
+
+    def tree(self) -> dict:
+        tree = self._tree
+        if tree is None:
+            tree = self._tree = json.loads(self._body)
+        return tree
+
+    def body(self) -> bytes:
+        body = self._body
+        if body is None:
+            with self._lock:  # twins wait here for the one encoding
+                body = self._body
+                keep = None
+                if body is None:
+                    body = self._body = json.dumps(self._tree).encode()
+                    keep, self._keep = self._keep, None
+            if keep is not None:
+                keep(body)
+        return body
+
+    def keep(self, put: Callable[[bytes], None]) -> None:
+        """Hand the body to ``put`` (the result cache's) where the
+        serialisation happens, whoever pays it: now if it has, else
+        from ``body()``.  One is enough: a twin's later ``put`` is
+        dropped."""
+        with self._lock:
+            body = self._body
+            if body is None:
+                if self._keep is None:
+                    self._keep = put
+                return
+        put(body)
+
+    def reply(self, tail: dict) -> bytes:
+        """The response as sent: the body with the per-request ``tail``
+        (``TAIL_KEYS``) spliced in before its closing brace — byte for
+        byte ``json.dumps({**tree, **tail})``, with no tree needed."""
+        tree = self._tree
+        if tree is not None and not tail.keys().isdisjoint(tree):
+            # a block named like a tail key (never cached, so the tree
+            # is at hand): the tail's value in the block's place
+            return json.dumps({**tree, **tail}).encode()
+        body = self.body()
+        if not tail:
+            return body
+        end = json.dumps(tail).encode()
+        if len(body) == 2:  # b"{}": no block, no comma
+            return end
+        return b"".join((memoryview(body)[:-1], b", ", memoryview(end)[1:]))
 
 
 class ResultCache:
@@ -125,17 +197,17 @@ class ResultCache:
     def hits(self) -> int:
         return QCACHE_RESULT_EVENTS.snapshot().get("hit", 0)
 
-    def get(self, key, version: int) -> Optional[Tuple[dict, dict]]:
-        """(response, stats) for the request ``key`` at ``version``, or
-        None.  The returned response is SHARED — read-only downstream."""
+    def get(self, key, version: int) -> Optional[Tuple[bytes, dict]]:
+        """(body, stats) for the request ``key`` at ``version``, or None.
+        ``stats`` is the execution's engine stats where the entry was
+        stored with them (a ``debug`` key), else empty."""
         sp = obs.current_span()
         if sp is None:  # unsampled hot path: probe only
             hit, ev, nb = self._c.get_ev(request_digest(key), version)
         else:
             # sampled: a tier-2 hit is the single most latency-deciding
             # event a request can have — the span says so explicitly
-            # (outcome + the STORED size: re-walking the response here
-            # would add O(response) work to the fastest path we have)
+            # (outcome + the stored size)
             with sp.child("cache.result") as cs:
                 hit, ev, nb = self._c.get_ev(request_digest(key), version)
                 cs.set_attr("outcome", ev)
@@ -149,24 +221,23 @@ class ResultCache:
             led.note_cache("result", ev, nb or 0)
         if hit is None:
             return None
-        value, age = hit
+        (body, stats), age = hit
         QCACHE_HIT_AGE.observe(age)
-        return value
+        return body, json.loads(stats) if stats else {}
 
-    def put(self, key, version: int, response: dict, stats: dict) -> None:
+    def put(
+        self, key, version: int, body: bytes, stats: Optional[dict] = None
+    ) -> None:
+        """Store an answer's encoded ``body`` at its length.  ``stats``
+        (the engine's, for a ``debug`` request's latency map) is kept
+        encoded beside it and counted the same way."""
         k = request_digest(key)
-        # singleflight deals one result to K coalesced twins and each
-        # calls put on return — one stored it already, so the other K-1
-        # skip the footprint walk (benign race: a double put is a no-op
-        # re-store of the same value)
+        # singleflight twins and surfaces race to store one answer: the
+        # first did (benign: a double put re-stores the same value)
         if self._c.contains(k, version):
             return
-        self._c.put(
-            k,
-            version,
-            (response, stats),
-            _approx_bytes(response) + _approx_bytes(stats),
-        )
+        blob = json.dumps(stats).encode() if stats else b""
+        self._c.put(k, version, (body, blob), len(body) + len(blob))
         # admissions and sweeps change occupancy without a get-event
         QCACHE_RESULT_BYTES.set(self._c.occupancy_bytes)
 

@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -144,7 +144,7 @@ class SchedRequest:
         self.tenant = tenant
         self.cancel = cancel
         self._done = threading.Event()
-        self.result: Optional[dict] = None
+        self.result = None                # cache.Answer, shared by twins
         self.stats: Optional[dict] = None
         self.error: Optional[BaseException] = None
         # flight recorder (obs/spans.py): ``span`` is the admitting
@@ -200,7 +200,7 @@ class SchedRequest:
             qs.set_attr("outcome", outcome)
             qs.finish()
 
-    def complete(self, result: dict, stats: dict) -> None:
+    def complete(self, result, stats: dict) -> None:
         self.end_queue_wait("done")
         self.result = result
         self.stats = stats
@@ -213,8 +213,9 @@ class SchedRequest:
         self.done_at = time.monotonic()
         self._done.set()
 
-    def wait(self) -> Tuple[dict, dict]:
-        """Block until executed; raises the execution error if any."""
+    def wait(self) -> tuple:
+        """Block until executed: (``cache.Answer``, engine stats); raises
+        the execution error if any."""
         self._done.wait()
         if self.ledger is not None:
             # the ledger is this thread's again (single-writer hand-off)

@@ -85,6 +85,7 @@ from typing import Dict, List, Optional
 
 from dgraph_tpu import ivm as _ivm
 from dgraph_tpu import obs
+from dgraph_tpu.cache import Answer, ResultCache, cache_enabled, cacheable
 from dgraph_tpu.obs import ledger as _ledgermod
 from dgraph_tpu.sched import qos as _qos
 from dgraph_tpu.sched.cohort import (
@@ -139,10 +140,8 @@ class CohortScheduler:
     ):
         self._server = server
         # tier-2 result cache (cache/result.py): probed before admission
-        # in run(); None when DGRAPH_TPU_CACHE=0 (or zero budget) — the
+        # in run_answer(); None when DGRAPH_TPU_CACHE=0 (or zero budget) — the
         # admission path is then byte-identical to the pre-cache code
-        from dgraph_tpu.cache import ResultCache, cache_enabled
-
         self.result_cache = ResultCache() if cache_enabled() else None
         self.max_batch = int(
             max_batch
@@ -252,16 +251,39 @@ class CohortScheduler:
         tenant: str = "",
         cancel=None,
     ):
+        """``run_answer`` for the surfaces that need the TREE (protobuf,
+        gRPC, subscriptions, embedded callers): returns (response dict,
+        engine stats).  A result-cache hit is decoded from the stored
+        body and a miss is encoded for the cache here, both inside stage
+        ``result_cache`` — equal answers either way."""
+        answer, stats = self.run_answer(
+            parsed, debug=debug, timeout_s=timeout_s, key=key,
+            tenant=tenant, cancel=cancel, tree=True,
+        )
+        return answer.tree(), stats
+
+    def run_answer(
+        self,
+        parsed,
+        debug: bool = False,
+        timeout_s: Optional[float] = None,
+        key=None,
+        tenant: str = "",
+        cancel=None,
+        tree: bool = False,
+    ):
         """Admit a read-only parsed request and block until its cohort
         executed.  ``key`` (query text + canonical vars + debug) enables
         singleflight AND tier-2 result caching: equal-key cohort members
         execute once, and a repeat of an already-executed key over the
         same store snapshot skips admission entirely.  ``tenant`` /
         ``cancel`` are the QoS scope and CancelToken (sched/qos.py; ""
-        and None when QoS is off).  Returns (response dict, engine
-        stats); raises SchedOverloadError / SchedQuotaError /
-        SchedDeadlineError on shed and QueryCancelledError on a flipped
-        token."""
+        and None when QoS is off).  Returns (``cache.Answer``, engine
+        stats): the answer's blocks, encoded once however many twins
+        share them — the cache stores the body where that serialisation
+        happens (the caller's ``Answer.reply``, or here under ``tree``).
+        Raises SchedOverloadError / SchedQuotaError / SchedDeadlineError
+        on shed and QueryCancelledError on a flipped token."""
         # cancel-before-admission: a token that already flipped (client
         # vanished in transit, admin raced the request) does no work at
         # all — no queue span, no cache probe, no admission bookkeeping
@@ -271,11 +293,11 @@ class CohortScheduler:
         # them schedulable, merely coalescing across mutation boundaries
         # their own read path already treats as eventually consistent.
         # This read feeds the ADMISSION signature (snapshot bucketing for
-        # cohorts + singleflight), never a cache key — the tier-2 key
-        # below is predicate-scoped through ivm/versions.py.
+        # cohorts + singleflight; built after the probe, a hit needs
+        # none), never a cache key — the tier-2 key below is
+        # predicate-scoped through ivm/versions.py.
         # graftlint: ignore[naked-version-key]
         store_ver = getattr(self._server.store, "version", None)
-        sig = hop_signature(parsed, store_ver or 0)
         # tier-2 probe BEFORE admission: the version in the key is
         # captured pre-execution, so a racing mutation can only strand
         # an entry under an old version — never serve stale.  The key
@@ -295,17 +317,18 @@ class CohortScheduler:
             and store_ver is not None
             and getattr(self._server.store, "strict_snapshot_versions", False)
         ):
-            from dgraph_tpu.cache import cacheable
-
-            # stage result_cache: the probe here and the put below (whose
-            # footprint walk grows with the answer)
+            # stage result_cache: the probe here and, for a caller that
+            # wants the tree, the decode of a hit / the encode of a miss
             with obs.stage(None, "result_cache_ms"):
                 if cacheable(parsed):
                     rc_key = key
                     rc_ver = _ivm.result_version(self._server.store, parsed)
                     hit = rc.get(rc_key, rc_ver)
                     if hit is not None:
-                        return hit
+                        answer = Answer(body=hit[0])
+                        if tree:
+                            answer.tree()
+                        return answer, hit[1]
         # timeout_s None = no budget; <= 0 = budget ALREADY spent (a
         # gRPC deadline that lapsed in transit, X-Dgraph-Timeout: 0) —
         # that sheds immediately rather than silently running unbounded
@@ -331,19 +354,27 @@ class CohortScheduler:
         # single-writer hand-off)
         req.ledger = _ledgermod.current()
         try:
-            self._admit(req, sig, key)
+            self._admit(req, hop_signature(parsed, store_ver or 0), key)
         except SchedOverloadError:
             # the queue-wait span opened above must land in the trace
             # with the shed verdict, not leak unfinished
             req.end_queue_wait("shed_overload")
             raise
-        result, stats = req.wait()
+        answer, stats = req.wait()
         if rc_key is not None:
-            # sharing the response dict is safe by the singleflight
-            # argument: handlers only encode results, never mutate them
-            with obs.stage(None, "result_cache_ms"):
-                rc.put(rc_key, rc_ver, result, stats)
-        return result, stats
+            # the cache takes the body where it is made: at the caller's
+            # socket write (stage http_write), or here for the tree's
+            # surfaces, which would otherwise never serialise it
+            def put(body: bytes) -> None:
+                rc.put(rc_key, rc_ver, body, stats if debug else None)
+
+            if tree:
+                with obs.stage(None, "result_cache_ms"):
+                    answer.keep(put)
+                    answer.body()
+            else:
+                answer.keep(put)
+        return answer, stats
 
     def _admit(self, req: SchedRequest, sig: tuple, key) -> None:
         with self._cond:
@@ -709,7 +740,7 @@ class CohortScheduler:
                         if req.result is not None or req.error is not None:
                             continue
                         if lead.error is None:
-                            # results are read-only from here on
+                            # answers are read-only from here on
                             # (handlers only encode them): sharing is safe
                             if req.ledger is not None:
                                 # dealt a twin's result: the follower's
@@ -902,7 +933,8 @@ class CohortScheduler:
                 # complete() fires, the handler thread owns the ledger
                 # again (the single-writer hand-off)
                 req.ledger.merge_engine_stats(eng.stats)
-            req.complete(out, dict(eng.stats))
+            # twins are dealt this one Answer: one tree, one encoding
+            req.complete(Answer(out), dict(eng.stats))
         except BaseException as e:  # noqa: BLE001 — delivered via req.fail
             if isinstance(e, (_qos.QueryCancelledError, SchedDeadlineError)):
                 # died at a checkpoint/seam: free the tenant's in-flight

@@ -14,16 +14,19 @@ through one ServeTask boundary per group (SURVEY.md §2c).
 
 from __future__ import annotations
 
+import copy
 import hashlib
 import itertools
 import json
 import threading
 import time
+from collections.abc import MutableMapping
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
 from dgraph_tpu import obs
+from dgraph_tpu.cache import Answer
 from dgraph_tpu.obs import device as _device
 from dgraph_tpu.obs import ledger as _ledger
 from dgraph_tpu.models.durability import ReadOnlyError, StorageFaultError
@@ -61,6 +64,54 @@ _CORS = {
     # per-request connections; idle keep-alive sockets fall to the
     # handler's 60s read timeout.
 }
+
+
+class Response(MutableMapping):
+    """``run_query(encoded=True)``'s return: the response — the answer's
+    blocks, then the per-request tail — FROZEN.  The ``/query`` handler,
+    which only sends it, ``encode()``s a frozen one by splicing the tail
+    round the answer's encoded body (``cache.Answer.reply``): one
+    serialisation for singleflight twins, cache and socket, and a
+    result-cache hit builds no tree at all.  Whatever sits between
+    ``run_query`` and the handler and treats the response as the dict
+    ``run_query`` otherwise returns (a wrapper, a test's hook) thaws it
+    into one, and what it leaves there is what gets sent."""
+
+    __slots__ = ("_answer", "_tail", "_dict")
+
+    def __init__(self, answer, tail: dict):
+        self._answer = answer
+        self._tail = tail
+        self._dict: Optional[dict] = None
+
+    def thaw(self) -> dict:
+        d = self._dict
+        if d is None:
+            d = self._dict = {**self._answer.tree(), **self._tail}
+        return d
+
+    def encode(self) -> bytes:
+        if self._dict is None:
+            return self._answer.reply(self._tail)
+        return json.dumps(self._dict).encode()
+
+    def __getitem__(self, key):
+        return self.thaw()[key]
+
+    def __setitem__(self, key, value) -> None:
+        self.thaw()[key] = value
+
+    def __delitem__(self, key) -> None:
+        del self.thaw()[key]
+
+    def __iter__(self):
+        return iter(self.thaw())
+
+    def __len__(self) -> int:
+        return len(self.thaw())
+
+    def __deepcopy__(self, memo) -> dict:
+        return copy.deepcopy(self.thaw(), memo)
 
 
 class DgraphServer:
@@ -310,9 +361,16 @@ class DgraphServer:
         tenant: str = "",
         cancel_probe=None,
         ledger_out: bool = False,
-    ) -> dict:
+        encoded: bool = False,
+    ):
         """The ParseQueryAndMutation → ProcessWithMutation → encode path
         with the reference's latency breakdown (query/query.go:102).
+
+        Returns the response dict.  ``encoded=True`` (the JSON-over-HTTP
+        handler, which only sends it) returns it as a frozen
+        ``Response``: the answer's blocks stay encoded — serialised once
+        for twins, cache and socket — and a result-cache hit never
+        builds a tree.
 
         ``timeout_s`` is the caller's remaining budget (gRPC deadline /
         X-Dgraph-Timeout header): a scheduled request past it sheds with
@@ -380,7 +438,6 @@ class DgraphServer:
                         "mutations shed until the re-arm probe clears",
                         retry_after=st.probe_interval_s,
                     )
-            out: dict = {}
             from dgraph_tpu.query import outputnode
 
             with obs.child("processing"):
@@ -408,18 +465,19 @@ class DgraphServer:
                         # are exactly the ones an operator can see (and
                         # therefore target) in /debug/traces.
                         _qos.REGISTRY.register(root.trace_id, token)
-                    result, stats = self.scheduler.run(
+                    answer, stats = self.scheduler.run_answer(
                         parsed, debug=debug, timeout_s=timeout_s,
                         key=(text, vkey, debug),
-                        tenant=tenant, cancel=token,
+                        tenant=tenant, cancel=token, tree=not encoded,
                     )
-                    out.update(result)
                 else:
+                    out: dict = {}
                     debug_token = outputnode.DEBUG_UIDS.set(debug)
                     try:
                         stats = self._run_locked(parsed, out)
                     finally:
                         outputnode.DEBUG_UIDS.reset(debug_token)
+                    answer = Answer(out)
                     if parsed.mutation is not None:
                         # group-commit durability barrier, OUTSIDE the
                         # write lock: the mutation is applied and
@@ -434,13 +492,15 @@ class DgraphServer:
             # json encode happens in the handler; pre-record here so the
             # latency map is complete before attaching it
             lat.record_json()
-            out["server_latency"] = lat.to_map()
+            # the per-request tail, appended after the answer's blocks
+            # (cache/result.py TAIL_KEYS)
+            tail = {"server_latency": lat.to_map()}
             if ledger_out and led is not None:
                 # explicit opt-in surface (?ledger=true): the account in
                 # the response extensions, the Dgraph convention for
                 # out-of-band response metadata.  Default responses (any
                 # gate state) never carry the key.
-                out.setdefault("extensions", {})["ledger"] = led.to_dict()
+                tail["extensions"] = {"ledger": led.to_dict()}
             if debug:
                 # per-stage engine breakdown (device vs host vs fused
                 # chain time + edges traversed) — the per-query profile
@@ -452,11 +512,13 @@ class DgraphServer:
                 # and device time to the member that led the dispatch —
                 # cohort-attributed, not per-request; DGRAPH_TPU_SCHED=0
                 # restores exact per-request accounting.
-                out["server_latency"]["engine"] = {
+                tail["server_latency"]["engine"] = {
                     k: (round(v, 3) if isinstance(v, float) else v)
                     for k, v in stats.items()
                 }
-            return out
+            if encoded:
+                return Response(answer, tail)
+            return {**answer.tree(), **tail}
         except BaseException as e:
             if root is not None:
                 root.set_attr("error", type(e).__name__)
@@ -1156,30 +1218,40 @@ def _make_handler(srv: DgraphServer):
                     tctx = obs.parse_traceparent(
                         self.headers.get("Traceparent")
                     )
+                    accept = self.headers.get("Accept", "")
+                    # binary client surface: protobuf wire-format
+                    # Response (graphresponse.proto), hand-encoded from
+                    # the tree — see serve/proto.py
+                    as_proto = (
+                        "application/protobuf" in accept
+                        or "application/x-protobuf" in accept
+                    )
                     out = srv.run_query(
                         body, variables, debug=debug, timeout_s=timeout_s,
                         trace_ctx=tctx,
                         tenant=self.headers.get("X-Dgraph-Tenant") or "",
                         cancel_probe=self._disconnect_probe(),
                         ledger_out=want_ledger,
+                        encoded=not as_proto,
                     )
                     # stage http_write: run_query's clock has stopped and
-                    # its ledger is drained; encoding the answer and the
-                    # socket write are the server's last share of the
-                    # client's latency
+                    # its ledger is drained; the answer's one
+                    # serialisation (a miss; twins wait for it, a
+                    # result-cache hit has the bytes), the cache's put of
+                    # those bytes, the splice of the tail and the socket
+                    # write are the server's last share of the client's
+                    # latency
                     with obs.stage(None, "http_write_ms"):
-                        accept = self.headers.get("Accept", "")
-                        if "application/protobuf" in accept or "application/x-protobuf" in accept:
-                            # binary client surface: protobuf wire-format
-                            # Response (graphresponse.proto), hand-encoded
-                            # — see serve/proto.py
+                        if as_proto:
                             from dgraph_tpu.serve import proto as _proto
 
                             self._reply(
                                 200, _proto.encode_response(out),
                                 "application/protobuf",
                             )
-                        else:
+                        elif isinstance(out, Response):
+                            self._reply(200, out.encode())
+                        else:  # a wrapper put a plain dict in its place
                             self._reply(200, json.dumps(out).encode())
                 except SchedQuotaError as e:
                     # per-TENANT quota shed: still a 429, but with a

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from dgraph_tpu.cache import (
+    Answer,
     HopCache,
     ResultCache,
     VersionedLFUCache,
@@ -290,6 +291,334 @@ def test_result_cache_gate_off_is_cacheless(monkeypatch):
         server.stop()
 
 
+# ------------------------------------- tier 2: the unit is the encoded body
+
+
+def _post_raw(addr, body, path="/query", headers=None, timeout=30):
+    req = urllib.request.Request(
+        addr + path, data=body.encode(), method="POST", headers=headers or {}
+    )
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return r.read()
+
+
+def _cut_tail(raw: bytes) -> bytes:
+    """The answer's blocks: all before the per-request tail."""
+    at = raw.rfind(b'"server_latency"')
+    assert at > 0, raw[:200]
+    return raw[:at]
+
+
+def _hits() -> int:
+    return QCACHE_RESULT_EVENTS.snapshot().get("hit", 0)
+
+
+@pytest.mark.parametrize("query", ["", "?ledger=true", "?debug=true"])
+def test_hit_body_equals_miss_body_byte_for_byte(srv, query):
+    """A hit splices the tail round the STORED bytes; the client must not
+    be able to tell it from the miss but for the tail's values — and the
+    whole is ``json.dumps`` of the response dict, as it always was."""
+    _post(srv.addr, 'mutation { set { <0x2> <name> "Zo\u00eb \\"B\\"" . } }')
+    q = "{ q(func: uid(0x1)) { name friend { name } } r(func: uid(0x2)) { name } }"
+    h0 = _hits()
+    miss = _post_raw(srv.addr, q, "/query" + query)
+    hit = _post_raw(srv.addr, q, "/query" + query)
+    assert _hits() == h0 + 1
+    assert _cut_tail(miss) == _cut_tail(hit)
+    for raw in (miss, hit):
+        d = json.loads(raw)
+        assert json.dumps(d).encode() == raw  # same separators, same escapes
+        assert list(d)[:2] == ["q", "r"] and d["r"][0]["name"] == 'Zo\u00eb "B"'
+        assert ("extensions" in d) == (query == "?ledger=true")
+        assert ("engine" in d["server_latency"]) == (query == "?debug=true")
+    assert set(json.loads(hit).get("extensions", {}).get("ledger", {"stages": {}})["stages"]) <= {
+        "parse", "result_cache"
+    }
+
+
+@pytest.mark.parametrize("tree,tail", [
+    ({}, {"server_latency": {"total": "1ms"}}),
+    ({"q": []}, {"server_latency": {"total": "1ms"}}),
+    ({"q": [{"name": "Zo\u00eb \"q\"", "n": 1.5, "ok": True, "none": None}]},
+     {"server_latency": {"total": "1ms"}, "extensions": {"ledger": {"edges": 3}}}),
+    # a block NAMED like a tail key: the tail's value takes its place
+    ({"server_latency": [{"name": "x"}], "q": [1]}, {"server_latency": {"total": "1ms"}}),
+    ({"q": [1], "extensions": [2]},
+     {"server_latency": {}, "extensions": {"ledger": {}}}),
+    ({"q": [1]}, {}),
+])
+def test_answer_reply_is_json_dumps_of_the_merged_dict(tree, tail):
+    want = json.dumps({**tree, **tail}).encode()
+    assert Answer(tree).reply(tail) == want
+    if tail.keys().isdisjoint(tree):  # what the cache may hold: bytes alone
+        assert Answer(body=json.dumps(tree).encode()).reply(tail) == want
+
+
+def test_response_is_sent_frozen_and_thaws_into_the_dict():
+    """``run_query(encoded=True)`` hands the handler a frozen Response:
+    sent by splice while untouched; read or changed as a dict, it IS the
+    dict ``run_query`` returns otherwise, and what is left is sent."""
+    import copy
+
+    from dgraph_tpu.serve.server import Response
+
+    body = b'{"q": [{"name": "Ann"}]}'
+    tail = {"server_latency": {"total": "1ms"}}
+    want = {"q": [{"name": "Ann"}], "server_latency": {"total": "1ms"}}
+    frozen = Response(Answer(body=body), tail)
+    assert frozen.encode() == json.dumps(want).encode()
+    assert frozen._dict is None                     # no tree was built
+    r = Response(Answer(body=body), tail)
+    assert r == want and dict(r) == want and list(r) == list(want)
+    assert r["q"][0]["name"] == "Ann" and "q" in r and len(r) == 2
+    clone = copy.deepcopy(Response(Answer(body=body), tail))
+    assert type(clone) is dict and clone == want    # a hook's deepcopy
+    r["q"] = []
+    del r["server_latency"]
+    assert r.encode() == b'{"q": []}'
+
+
+def test_a_wrapper_round_run_query_decides_what_is_sent(srv, monkeypatch):
+    """The benchmark plants its fault by wrapping ``run_query`` and
+    altering a deep copy of what it returns: hit or miss, the handler
+    sends the altered dict."""
+    import copy
+
+    plain = DgraphServer.run_query
+
+    def wrapped(self, text, *a, **kw):
+        out = plain(self, text, *a, **kw)
+        if "friend" in text:
+            out = copy.deepcopy(out)
+            out["q"][0]["friend"].pop()
+        return out
+
+    q = "{ q(func: uid(0x1)) { name friend { name } } }"
+    whole = _post(srv.addr, q)
+    monkeypatch.setattr(DgraphServer, "run_query", wrapped)
+    h0 = _hits()
+    cut = _post(srv.addr, q)
+    assert _hits() == h0 + 1                        # a hit, thawed by the hook
+    assert len(cut["q"][0]["friend"]) == len(whole["q"][0]["friend"]) - 1
+    assert "server_latency" in cut
+
+
+def test_reserved_block_names_are_never_cached(srv):
+    assert not cacheable(_parse("{ server_latency(func: uid(0x1)) { name } }"))
+    assert not cacheable(_parse("{ extensions(func: uid(0x1)) { name } }"))
+    q = "{ extensions(func: uid(0x1)) { name } }"
+    a = json.loads(_post_raw(srv.addr, q, "/query?ledger=true"))
+    assert "ledger" in a["extensions"] and "q" not in a
+
+
+def test_large_answer_admitted_at_len_of_body():
+    """48,000 objects — the head of the benchmark's Zipf law — are 1.2 MB
+    of JSON: under the default 32 MiB budget (4 MiB an entry) that is an
+    entry of exactly its length, not a refused 8.5 MB walk."""
+    body = json.dumps(
+        {"q": [{"name": "Actor %d" % n} for n in range(48_000)]}
+    ).encode()
+    assert 1_000_000 < len(body) < 1_300_000
+    rc = ResultCache()
+    assert rc._c.budget_bytes == 32 << 20 and rc._c.max_entry_bytes == 4 << 20
+    before = QCACHE_RESULT_EVENTS.snapshot()
+    key = ("{ big }", "", False)
+    rc.put(key, 7, body)
+    assert rc.occupancy_bytes == len(body) and len(rc) == 1
+    got, stats = rc.get(key, 7)
+    assert got is body and stats == {}
+    after = QCACHE_RESULT_EVENTS.snapshot()
+    assert after.get("rejected", 0) == before.get("rejected", 0)
+    # a debug entry keeps the engine's stats, encoded and counted too
+    dkey = ("{ big }", "", True)
+    rc.put(dkey, 7, body, {"edges": 5, "chain_ms": 1.25})
+    assert rc.get(dkey, 7)[1] == {"edges": 5, "chain_ms": 1.25}
+    assert rc.occupancy_bytes == 2 * len(body) + len(
+        json.dumps({"edges": 5, "chain_ms": 1.25})
+    )
+
+
+def test_no_recursive_footprint_walk_left():
+    import inspect
+
+    from dgraph_tpu.cache import result
+
+    assert not hasattr(result, "_approx_bytes")
+    assert "isinstance" not in inspect.getsource(result)
+
+
+def test_acknowledged_mutation_makes_the_twin_request_execute(srv, monkeypatch):
+    """Nothing is weakened: between two identical requests, an
+    acknowledged write to a predicate the request READS makes the second
+    execute; a write to one it does not read leaves it a hit."""
+    runs = []
+    orig = QueryEngine.run_parsed
+
+    def counting(self, parsed):
+        runs.append(1)
+        return orig(self, parsed)
+
+    q = "{ q(func: uid(0x1)) { friend { name } } }"
+    first = _post_raw(srv.addr, q)
+    monkeypatch.setattr(QueryEngine, "run_parsed", counting)
+    assert _cut_tail(_post_raw(srv.addr, q)) == _cut_tail(first)
+    assert runs == []                                   # a hit
+    _post(srv.addr, 'mutation { set { <0x9> <hobby> "chess" . } }')
+    n = len(runs)                                       # the mutation ran
+    assert _cut_tail(_post_raw(srv.addr, q)) == _cut_tail(first)
+    assert len(runs) == n                               # unread predicate: hit
+    _post(srv.addr, 'mutation { set { <0x3> <name> "Cleo" . } }')
+    n = len(runs)
+    fresh = json.loads(_post_raw(srv.addr, q))
+    assert len(runs) == n + 1                           # read predicate: executed
+    assert sorted(f["name"] for f in fresh["q"][0]["friend"]) == ["Ben", "Cleo"]
+
+
+def test_debug_and_variables_keep_separate_entries(srv):
+    q = "query q($n: string) { q(func: eq(name, $n)) { name friend { name } } }"
+    rc = srv.scheduler.result_cache
+
+    def run(vars_, debug=False):
+        return _post_raw(
+            srv.addr, q, "/query" + ("?debug=true" if debug else ""),
+            {"X-Dgraph-Vars": json.dumps(vars_)},
+        )
+
+    n0, h0 = len(rc), _hits()
+    ann = run({"$n": "Ann"})
+    ben = run({"$n": "Ben"})
+    dbg = run({"$n": "Ann"}, debug=True)
+    assert len(rc) == n0 + 3 and _hits() == h0          # three entries, no hit
+    assert _cut_tail(run({"$n": "Ann"})) == _cut_tail(ann)
+    assert _cut_tail(run({"$n": "Ben"})) == _cut_tail(ben)
+    dbg2 = run({"$n": "Ann"}, debug=True)
+    assert _cut_tail(dbg2) == _cut_tail(dbg) != _cut_tail(ann)
+    assert len(rc) == n0 + 3 and _hits() == h0 + 3
+    # the debug hit still carries the execution's engine breakdown
+    assert json.loads(dbg2)["server_latency"]["engine"]["edges"] == \
+        json.loads(dbg)["server_latency"]["engine"]["edges"]
+
+
+def test_singleflight_twins_get_one_encoding(srv, monkeypatch):
+    """K identical requests in flight together: one execution, ONE
+    serialisation of its blocks (each handler used to dump the shared
+    dict), and every twin is sent the same bytes."""
+    import time as _time
+
+    from dgraph_tpu.cache import result as result_mod
+
+    runs, encodes = [], []
+    orig_run = QueryEngine.run_parsed
+
+    def slow(self, parsed):
+        runs.append(1)
+        _time.sleep(0.4)  # twins attach while this executes
+        return orig_run(self, parsed)
+
+    class CountingJson:
+        loads = staticmethod(json.loads)
+
+        @staticmethod
+        def dumps(obj, **kw):
+            if isinstance(obj, dict) and "twin" in obj:
+                encodes.append(1)
+            return json.dumps(obj, **kw)
+
+    monkeypatch.setattr(QueryEngine, "run_parsed", slow)
+    monkeypatch.setattr(result_mod, "json", CountingJson)
+    q = "{ twin(func: uid(0x1)) { name friend { name } } }"
+    got = [None] * 6
+
+    def call(i):
+        got[i] = _post_raw(srv.addr, q)
+
+    ts = [threading.Thread(target=call, args=(i,)) for i in range(6)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(timeout=30)
+    assert all(g is not None for g in got)
+    assert len(runs) < 6                      # they did coalesce
+    assert len(encodes) == len(runs)          # one encoding an execution
+    assert len({_cut_tail(g) for g in got}) == 1
+    assert len(srv.scheduler.result_cache) >= 1   # and the cache got the bytes
+
+
+def _via_protobuf(srv, q):
+    from dgraph_tpu.serve.proto import decode_response
+
+    return decode_response(_post_raw(
+        srv.addr, q, headers={"Accept": "application/protobuf"}
+    ))
+
+
+def _via_grpc(srv, q):
+    grpc = pytest.importorskip("grpc")
+    from dgraph_tpu.serve.grpc_server import GrpcServer, encode_request
+    from dgraph_tpu.serve.proto import decode_response
+
+    gsrv = GrpcServer(srv, port=0)
+    gsrv.start()
+    try:
+        with grpc.insecure_channel(f"127.0.0.1:{gsrv.port}") as ch:
+            return decode_response(
+                ch.unary_unary("/protos.Dgraph/Run")(encode_request(q))
+            )
+    finally:
+        gsrv.stop()
+
+
+def _via_subscription(srv, q):
+    sub = srv.subs.register(q)
+    try:
+        ev = sub.next_event(timeout=10)
+        assert ev["kind"] == "snapshot"
+        return ev["data"]
+    finally:
+        srv.subs.cancel(sub.id)
+
+
+@pytest.mark.parametrize(
+    "surface", [_via_protobuf, _via_grpc, _via_subscription],
+    ids=["protobuf", "grpc", "subscription"],
+)
+def test_tree_surfaces_answer_alike_on_hit_and_miss(srv, surface):
+    """The surfaces that need the tree DECODE the stored body on a hit
+    (and store an encoding of their own miss): equal answers either way,
+    and equal to what the JSON surface says."""
+    q = (
+        "{ %s(func: uid(0x1)) { name friend { name friend { name } } } }"
+        % surface.__name__
+    )
+    n0, h0 = len(srv.scheduler.result_cache), _hits()
+    miss = surface(srv, q)
+    assert len(srv.scheduler.result_cache) == n0 + 1 and _hits() == h0
+    hit = surface(srv, q)
+    assert _hits() == h0 + 1
+    miss.pop("server_latency", None), hit.pop("server_latency", None)
+    assert miss == hit and surface.__name__ in miss
+    # the entry a tree surface stored serves the JSON surface, and back
+    over_json = _post(srv.addr, q)
+    assert _hits() == h0 + 2
+    over_json.pop("server_latency")
+    if surface is not _via_protobuf:  # (its decoder renders uids its own way)
+        assert miss == over_json
+
+
+def test_scheduler_run_returns_tree_run_answer_the_body(srv):
+    """``run`` (tree surfaces) and ``run_answer`` (the socket) over one
+    entry: the same blocks in both forms, whichever stored them."""
+    q = "{ both(func: uid(0x1)) { name friend { name } } }"
+    key = (q, "", False)
+    tree, _ = srv.scheduler.run(_parse(q), key=key)             # miss, stores
+    answer, stats = srv.scheduler.run_answer(_parse(q), key=key)  # hit
+    assert stats == {}
+    assert answer.body() == json.dumps(tree).encode()
+    assert answer.tree() == tree
+    again, _ = srv.scheduler.run(_parse(q), key=key)            # hit, decoded
+    assert again == tree and again is not tree
+
+
 # ------------------------------------------------- concurrency correctness
 
 
@@ -427,7 +756,7 @@ def test_hop_cache_drop_arena_is_selective():
 
 def test_result_cache_zero_budget_disables():
     rc = ResultCache(budget_bytes=0)
-    rc.put(("q", "", False), 1, {"q": []}, {})
+    rc.put(("q", "", False), 1, b'{"q": []}')
     assert rc.get(("q", "", False), 1) is None
 
 

@@ -429,7 +429,7 @@ def test_cohort_compiles_one_program_family(srv):
     srv.scheduler._flush(c1, "full")
     for r in c1.reqs:
         out, _ = r.wait()
-        assert "q" in out
+        assert "q" in out.tree()
     ce = arena._classed
     assert ce is not None, "fused classed path did not engage"
     n1 = len(ce._programs)
@@ -496,7 +496,7 @@ def test_merged_hops_ride_mesh_path(srv):
         c = Cohort(("mesh",))
         c.reqs = reqs
         srv.scheduler._flush(c, "full")
-        outs = [r.wait()[0] for r in reqs]
+        outs = [r.wait()[0].tree() for r in reqs]
         assert sorted(f["name"] for f in outs[0]["q"][0]["friend"]) == [
             "Ben", "Cara",
         ]
@@ -528,10 +528,11 @@ def test_singleflight_coalesces_identical_requests(srv, monkeypatch):
     c.reqs = reqs
     before = SCHED_COALESCED.value()
     srv.scheduler._flush(c, "full")
-    outs = [r.wait()[0] for r in reqs]
+    answers = [r.wait()[0] for r in reqs]
     assert len(runs) == 1  # one execution for four requests
     assert SCHED_COALESCED.value() == before + 3
-    assert all(o == outs[0] for o in outs)
+    assert all(a is answers[0] for a in answers)  # one Answer, one encoding
+    outs = [a.tree() for a in answers]
     assert outs[0]["q"][0]["name"] == "Ann"
 
 
