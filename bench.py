@@ -1,31 +1,9 @@
-"""Headline benchmark: edges traversed/sec on 2-hop fan-out queries.
+"""Serving-path A/B arms over in-process servers: serving, durability,
+QoS and IVM.  (The repo's benchmark is ``benchmark/run.py``; these arms
+are older records of single features, each against its own off switch.)
 
-Mirrors BASELINE.json's north-star metric: a Freebase-21M-scale synthetic
-graph (2M nodes, ~21M edges, skewed degrees), 2-hop traversal from random
-seed sets, measured against a fully-vectorized NumPy implementation of
-the same semantics (the stand-in for the reference's CPU posting-list
-walk).
-
-The device side runs the FUSED BATCHED HOP EXECUTOR (dgraph_tpu/ops/
-batch.py): one device program per hop for the whole query batch, in one
-of two dedup strategies:
-
-- ``host`` (default off-TPU): each hop is a degree-classed gather
-  program — scatter- and sort-free, because XLA-on-CPU's scatter
-  (~100ns/update) and sort (~10× numpy) would otherwise dominate — and
-  the inter-hop frontier dedup runs as numpy np.unique overlapped with
-  the device's async dispatch queue.  2 programs per query batch, not
-  one per set-op.
-- ``device`` (default on TPU): the whole 2-hop pipeline for a batch of
-  queries is ONE jitted program (inline-head expansion + skey-grouped
-  sort dedup, the round-5 TPU path); the frontier never leaves HBM.
-
-Every query's output materializes on device (per-query checksums, all
-verified against numpy), so the edges/s number cannot be faked by XLA
-dead-code elimination.
-
-Prints ONE json line: {"metric", "value", "unit", "vs_baseline",
-"fused_hop", "hop_dedup", "serving", ...}.  "serving" is the closed-loop
+Prints ONE json line: {"serving", "durability", "qos", "ivm", "planner",
+"platform", "device_kind", "device_count"}.  "serving" is the closed-loop
 multi-client A/B (run_serving_bench), three arms over one zipf workload:
 the cohort scheduler (DGRAPH_TPU_SCHED=1) vs the serial per-request
 path (=0), both cache-off, plus the two-tier query cache arm
@@ -34,11 +12,11 @@ path (=0), both cache-off, plus the two-tier query cache arm
 "tier2_hit_rate" (guarded nonzero) — with QPS, p50/p99 latency, mean
 cohort occupancy, flush-reason counts and a cross-arm response-parity
 check.
-Environment knobs: BENCH_NODES, BENCH_EDGES, BENCH_SEEDS, BENCH_ITERS,
-BENCH_SCALE (shrink everything by a factor: 0.1 -> 200k nodes / 2.1M
-edges), BENCH_DEDUP (host|device|auto), BENCH_SERVE (0 skips the
-serving A/B) / BENCH_CLIENTS / BENCH_SERVE_SECONDS / BENCH_SERVE_NODES /
-BENCH_SERVE_DEG.
+Environment knobs: BENCH_SERVE (0 skips the serving A/B) /
+BENCH_CLIENTS / BENCH_SERVE_SECONDS / BENCH_SERVE_NODES /
+BENCH_SERVE_DEG; BENCH_MUT, BENCH_QOS, BENCH_IVM (0 skips that arm) with
+their BENCH_MUT_* / BENCH_QOS_* / BENCH_IVM_* sizes; BENCH_ONLY=qos|ivm
+runs that arm alone.
 
 No fallback hides the device: the script uses the backend JAX gives it
 (``JAX_PLATFORMS=cpu`` for a rehearsal), names ``platform``,
@@ -70,258 +48,6 @@ def device_identity() -> dict:
         "device_kind": devs[0].device_kind,
         "device_count": len(devs),
     }
-
-
-def build_graph(n_nodes: int, n_edges: int, seed: int = 7):
-    """Skewed-degree random digraph (celebrity uids get most edges),
-    dense CSR layout: row i == uid i, so no row lookup on the hot path."""
-    rng = np.random.default_rng(seed)
-    # zipf-ish targets: mix uniform sources with popularity-weighted targets
-    src = rng.integers(1, n_nodes + 1, size=n_edges)
-    pop = (rng.pareto(1.2, size=n_edges).astype(np.float64) + 1.0)
-    dst = (np.clip(pop / pop.max(), 1e-9, 1.0) * (n_nodes - 1)).astype(np.int64) + 1
-    half = n_edges // 2
-    dst[:half] = rng.integers(1, n_nodes + 1, size=half)
-    from dgraph_tpu.models.arena import csr_dense_from_edges
-
-    return csr_dense_from_edges(src, dst, n_nodes)
-
-
-def np_expand(offsets, dst, rows):
-    """Vectorized numpy CSR expansion (the CPU baseline's hot op)."""
-    rows = rows[rows >= 0]
-    if not len(rows):
-        return np.empty(0, dtype=dst.dtype)
-    starts = offsets[rows]
-    degs = offsets[rows + 1] - starts
-    total = int(degs.sum())
-    if total == 0:
-        return np.empty(0, dtype=dst.dtype)
-    cum = np.cumsum(degs)
-    within = np.arange(total) - np.repeat(cum - degs, degs)
-    return dst[np.repeat(starts, degs) + within]
-
-
-def np_two_hop(a, h_dst, frontier):
-    # dense arena: rows are uids directly (same advantage the device gets)
-    out1 = np_expand(a.h_offsets, h_dst, frontier)
-    f1 = np.unique(out1)
-    out2 = np_expand(a.h_offsets, h_dst, f1)
-    chk = np.int32(out2.astype(np.int64).sum() & 0xFFFFFFFF)
-    return len(out1) + len(out2), np.unique(out2), chk
-
-
-def _run_host_dedup(a, h_dst, frontiers):
-    """Fused classed-hop pipeline: ONE device program per hop per
-    sub-batch, np.unique dedup between hops overlapped with the device's
-    async dispatch queue.  Returns (best seconds, edges, chks[int32],
-    last query's hop-2 unique set)."""
-    import jax
-    import jax.numpy as jnp
-    from dgraph_tpu import ops
-    from dgraph_tpu.ops.sets import SENT
-
-    ce = ops.ClassedExpander(a.offsets, a.dst, a.h_offsets)
-    iters = len(frontiers)
-
-    # --- capacity planning (untimed): worst per-class composition over
-    # the stream, bucket_fine'd so one compiled program per hop serves
-    # every sub-batch ---
-    n_cls = ce.n_cls
-    c1w = np.ones(n_cls, np.int64)
-    c2w = np.ones(n_cls, np.int64)
-    h1w = e1w = h2w = e2w = 0
-    uniq1 = []
-    for f in frontiers:
-        c1, h1, e1 = ce.class_counts(f)
-        c1w = np.maximum(c1w, c1)
-        h1w, e1w = max(h1w, h1), max(e1w, e1)
-        f1 = np.unique(np_expand(a.h_offsets, h_dst, f))
-        uniq1.append(f1)
-        c2, h2, e2 = ce.class_counts(f1)
-        c2w = np.maximum(c2w, c2)
-        h2w, e2w = max(h2w, h2), max(e2w, e2)
-    caps1 = ce.plan_caps(c1w, h1w, e1w)
-    caps2 = ce.plan_caps(c2w, h2w, e2w)
-    hop1 = ce.program(caps1, "materialize", batched=True)
-    hop2 = ce.program(caps2, "checksum", batched=True)
-
-    def stack_partitions(queries, caps):
-        """Class-sort each query's rows and write the per-class slices
-        straight into stacked [B, cap_c] mats (-1 pad) — the host side
-        of one batched hop dispatch."""
-        B = len(queries)
-        mats = [np.full((B, c), -1, np.int32) for c in caps[:n_cls]]
-        mats.append(np.full((B, max(caps[n_cls], 1)), -1, np.int32))
-        for j, f in enumerate(queries):
-            rs, starts, _deg, _pos = ce.class_sort(f)
-            for k in range(n_cls + 1):
-                lo, hi = int(starts[k]), int(starts[k + 1])
-                if hi > lo:
-                    mats[k][j, : hi - lo] = rs[lo:hi]
-        return tuple(jnp.asarray(m) for m in mats)
-
-    # --- seed partitions (untimed prep, like frontier padding was) ---
-    SB = int(os.environ.get("BENCH_SUBBATCH", 50))
-    nb = -(-iters // SB)
-    seed_batches = [
-        stack_partitions(frontiers[b * SB: (b + 1) * SB], caps1)
-        for b in range(nb)
-    ]
-
-    def one_pass():
-        # dispatch every hop-1 sub-batch up front: jax dispatch is
-        # async, so the host's unique+partition work below overlaps the
-        # device working through its queue
-        futs = [hop1(mb, ()) for mb in seed_batches]
-        chks = np.empty(iters, np.int32)
-        edges = 0
-        for b, (lanes, t1) in enumerate(futs):
-            lanes = np.asarray(lanes)  # blocks for THIS sub-batch only
-            edges += int(np.asarray(t1).astype(np.int64).sum())
-            B = lanes.shape[0]
-            uniq = []
-            for j in range(B):
-                u = np.unique(lanes[j])
-                if len(u) and u[-1] == SENT:
-                    u = u[:-1]
-                uniq.append(u)
-            c, t2 = hop2(stack_partitions(uniq, caps2), ())
-            chks[b * SB: b * SB + B] = np.asarray(c)
-            edges += int(np.asarray(t2).astype(np.int64).sum())
-        return edges, chks
-
-    edges, chks = one_pass()  # warmup/compile
-    best = float("inf")
-    for _ in range(4):  # best-of-4: the shared chip's load swings runs ~1.5×
-        t0 = time.time()
-        edges, chks = one_pass()
-        best = min(best, time.time() - t0)
-
-    # untimed correctness artifact: the last query's full hop-2 set
-    last_prog = ce.program(caps2, "materialize")
-    pm, _pos = ce.partition(uniq1[-1], caps2)
-    lanes, _t = last_prog(tuple(jnp.asarray(m) for m in pm), ())
-    lanes = np.asarray(lanes)
-    last_set = np.unique(lanes)
-    last_set = last_set[last_set != SENT]
-    return best, edges, chks, last_set
-
-
-def _run_device_dedup(a, frontiers, fcap):
-    """One jitted program for the WHOLE 2-hop pipeline per query batch
-    (inline-head expansion + skey-grouped sort dedup): the TPU path,
-    where the sort rides the VPU and the frontier never leaves HBM."""
-    import jax
-    import jax.numpy as jnp
-    from dgraph_tpu import ops
-    from dgraph_tpu.ops.sets import SENT
-
-    h_dst = np.asarray(a.dst)[: a.n_edges]
-    try:
-        metap, ov_chunks = a.inline_layout_grouped()
-        grouped = True
-        mask = int(ops.GROUP_MASK)
-    except ValueError:  # uid space >= 2^GROUP_BIT: plain inline layout
-        metap, ov_chunks = a.inline_layout()
-        grouped = False
-        mask = SENT  # identity decode
-    deg_of = (a.h_offsets[1:] - a.h_offsets[:-1]).astype(np.int64)
-    if grouped:
-        # group-order each seed frontier exactly like the device dedup
-        # orders hop-1 output: overflow-bearing rows first, ascending —
-        # hop 1 then shares the short-slot-map path (ops.skey_encode)
-        gfronts = []
-        for f in frontiers:
-            key = np.asarray(ops.skey_encode(f, deg_of[f] > ops.INLINE))
-            gfronts.append(f[np.argsort(key, kind="stable")])
-    else:
-        gfronts = frontiers
-
-    worst1 = worst2 = worstu = wp1 = wp2 = 1
-    for f in frontiers:
-        c1 = int(a.ov_chunk_degree_of_rows(f).sum())
-        f1 = np.unique(np_expand(a.h_offsets, h_dst, f))
-        c2 = int(a.ov_chunk_degree_of_rows(f1).sum())
-        worst1, worst2 = max(worst1, c1), max(worst2, c2)
-        worstu = max(worstu, len(f1))
-        wp1 = max(wp1, int((deg_of[f] > ops.INLINE).sum()))
-        wp2 = max(wp2, int((deg_of[f1] > ops.INLINE).sum()))
-    capo1, capo2 = ops.bucket_fine(worst1), ops.bucket_fine(worst2)
-    ucap = ops.bucket_fine(worstu)
-    if grouped:
-        pcap1, pcap2 = ops.bucket_fine(wp1), min(ops.bucket_fine(wp2), ucap)
-    else:  # ungrouped rows: the slot-map must span every row
-        pcap1, pcap2 = fcap, ucap
-
-    # slot-map backend: the sanctioned knob (DGRAPH_TPU_SLOTMAP=force,
-    # PR 16 promotion) or the legacy BENCH_PALLAS=1.  Selected OUTSIDE
-    # the jitted pipeline: the backend is baked into the compiled batch
-    # program.
-    expander = (
-        ops.expand_inline_grouped_pallas
-        if grouped
-        and (os.environ.get("BENCH_PALLAS") == "1" or ops.use_slotmap_pallas())
-        else ops.expand_inline_grouped
-    )
-
-    def one_query(frontier):
-        rows0 = ops.frontier_rows(frontier)
-        inl1, ov1, t1 = expander(metap, ov_chunks, rows0, capo1, pcap1)
-        f1 = ops.sort_unique(
-            jnp.concatenate([inl1.reshape(-1), ov1.reshape(-1)])
-        )[:ucap]
-        rows1 = jnp.where(f1 == SENT, -1, f1 & mask)
-        inl2, ov2, t2 = expander(metap, ov_chunks, rows1, capo2, pcap2)
-        # checksum over every produced uid (skey-decoded): forces each
-        # query's output to actually materialize
-        chk = jnp.sum(
-            jnp.where(inl2 == SENT, 0, inl2 & mask), dtype=jnp.int32
-        ) + jnp.sum(jnp.where(ov2 == SENT, 0, ov2 & mask), dtype=jnp.int32)
-        return chk, t1 + t2, (inl2, ov2)
-
-    CHUNK_Q = 200
-
-    @jax.jit
-    def run_batch(frontiers_mat):
-        def q(frontier):
-            chk, t, _out2 = one_query(frontier)
-            return chk, t
-
-        if frontiers_mat.shape[0] <= CHUNK_Q:
-            return jax.vmap(q)(frontiers_mat)
-        g = frontiers_mat.shape[0] // CHUNK_Q
-        sub = frontiers_mat[: g * CHUNK_Q].reshape(g, CHUNK_Q, -1)
-        chks, counts = jax.lax.map(jax.vmap(q), sub)
-        rest = frontiers_mat[g * CHUNK_Q:]
-        if rest.shape[0]:
-            ct, cc = jax.vmap(q)(rest)
-            return (
-                jnp.concatenate([chks.reshape(-1), ct]),
-                jnp.concatenate([counts.reshape(-1), cc]),
-            )
-        return chks.reshape(-1), counts.reshape(-1)
-
-    @jax.jit
-    def last_query_set(frontier):
-        _c, _t, (inl2, ov2) = one_query(frontier)
-        return ops.sort_unique(jnp.concatenate([inl2.reshape(-1), ov2.reshape(-1)]))
-
-    fmat = jnp.asarray(np.stack([ops.pad_to(f, fcap) for f in gfronts]))
-    chks, counts = run_batch(fmat)  # warmup/compile
-    np.asarray(counts)
-    best = float("inf")
-    for _ in range(4):
-        t0 = time.time()
-        chks, counts = run_batch(fmat)
-        counts = np.asarray(counts)  # sync
-        np.asarray(chks)
-        best = min(best, time.time() - t0)
-    edges = int(counts.sum())
-    got = np.asarray(last_query_set(fmat[-1]))
-    last_set = np.sort(got[got != SENT] & mask)
-    last_set = np.unique(last_set)
-    return best, edges, np.asarray(chks), last_set
 
 
 def _serving_store(n_nodes: int, deg: int, seed: int = 13):
@@ -1319,8 +1045,8 @@ def run_mutation_bench():
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def run_bench(scale: float, dev: dict) -> list:
-    """The headline run; returns the names of the arms that failed."""
+def run_arms(dev: dict) -> list:
+    """Every arm in turn; returns the names of the arms that failed."""
     # measured-cost planner: run (or load) the micro-calibration pass up
     # front so every route decision in this run prices from THIS host's
     # rates, and the calibration file is fresh for the next server boot
@@ -1331,66 +1057,6 @@ def run_bench(scale: float, dev: dict) -> list:
     # a DGRAPH_TPU_PLANNER=0 arm must not mutate planner state: no
     # measurement pass, no calibration-file overwrite — the operator
     # disabled the planner, the bench honors it
-
-    n_nodes = max(1024, int(int(os.environ.get("BENCH_NODES", 2_000_000)) * scale))
-    n_edges = max(4096, int(int(os.environ.get("BENCH_EDGES", 21_000_000)) * scale))
-    n_seeds = max(64, int(int(os.environ.get("BENCH_SEEDS", 4096)) * min(1.0, scale * 4)))
-    iters = int(os.environ.get("BENCH_ITERS", 1000))
-
-    t0 = time.time()
-    a = build_graph(n_nodes, n_edges)
-    h_dst = np.asarray(a.dst)[: a.n_edges]
-    build_s = time.time() - t0
-
-    rng = np.random.default_rng(3)
-    frontiers = [
-        np.unique(rng.integers(1, n_nodes + 1, size=n_seeds))
-        for _ in range(iters)
-    ]
-    from dgraph_tpu import ops
-
-    fcap = ops.bucket(max(len(f) for f in frontiers))
-
-    platform = dev["platform"]
-    dedup = os.environ.get("BENCH_DEDUP", "auto")
-    if dedup == "auto":
-        # host-side np.unique between hops wins wherever XLA's sort
-        # loses to numpy's (everywhere but TPU, measured ~10×); on TPU
-        # the sort rides the VPU and staying device-resident wins
-        dedup = "device" if platform == "tpu" else "host"
-
-    if dedup == "host":
-        dev_s, dev_edges, chks, last_set = _run_host_dedup(
-            a, h_dst, frontiers
-        )
-    else:
-        dev_s, dev_edges, chks, last_set = _run_device_dedup(
-            a, frontiers, fcap
-        )
-
-    # best-of-2 for the CPU baseline: the shared host's load swings numpy
-    # throughput ~2x between runs; compare against its fastest
-    cpu_s = float("inf")
-    for _ in range(2):
-        t0 = time.time()
-        cpu_edges = 0
-        cpu_chks = []
-        for f in frontiers:
-            n, _, c = np_two_hop(a, h_dst, f)
-            cpu_edges += n
-            cpu_chks.append(c)
-        cpu_s = min(cpu_s, time.time() - t0)
-
-    # correctness cross-check: per-query checksums + the last frontier set
-    _, want, _ = np_two_hop(a, h_dst, frontiers[-1])
-    assert np.array_equal(last_set, want), "device 2-hop != numpy reference"
-    assert dev_edges == cpu_edges, (dev_edges, cpu_edges)
-    assert np.array_equal(chks, np.array(cpu_chks, dtype=np.int32)), (
-        "per-query device checksums != numpy"
-    )
-
-    dev_eps = dev_edges / dev_s
-    cpu_eps = cpu_edges / cpu_s
 
     failed = []
 
@@ -1434,10 +1100,6 @@ def run_bench(scale: float, dev: dict) -> list:
     print(
         json.dumps(
             {
-                "metric": "edges_traversed_per_sec_2hop",
-                "value": round(dev_eps, 1),
-                "unit": "edges/s",
-                "vs_baseline": round(dev_eps / cpu_eps, 3),
                 # multi-client serving A/B (BENCH_SERVE=0 skips;
                 # BENCH_CLIENTS / BENCH_SERVE_SECONDS size it)
                 "serving": serving,
@@ -1460,20 +1122,8 @@ def run_bench(scale: float, dev: dict) -> list:
                 # self-describing record: the device every number here
                 # was taken on
                 **dev,
-                # the batched fused-hop executor (ops/batch.py) served
-                # every traversal: one device program per hop (host
-                # dedup) or per 2-hop batch (device dedup)
-                "fused_hop": True,
-                "hop_dedup": dedup,
-                "pallas_slotmap": os.environ.get("BENCH_PALLAS") == "1",
             }
         )
-    )
-    print(
-        f"# graph: {n_nodes} nodes / {a.n_edges} edges (build {build_s:.1f}s); "
-        f"{iters} queries x {n_seeds} seeds; device {dev_s:.2f}s "
-        f"({dev_eps/1e6:.1f}M e/s, {dedup} dedup) vs numpy {cpu_s:.2f}s "
-        f"({cpu_eps/1e6:.1f}M e/s) on {platform}; scale={scale:g}",
     )
     return failed
 
@@ -1483,8 +1133,8 @@ def main() -> int:
     print(f"# backend: {dev}", file=sys.stderr)
     if os.environ.get("BENCH_ONLY") == "qos":
         # standalone qos smoke (CI): the antagonist/victim harness runs
-        # without paying for the headline traversal bench — the job
-        # exists so the harness itself cannot rot
+        # without paying for the other arms — the job exists so the
+        # harness itself cannot rot
         print(json.dumps({"qos": run_qos_bench(), **dev}))
         return 0
     if os.environ.get("BENCH_ONLY") == "ivm":
@@ -1492,7 +1142,7 @@ def main() -> int:
         # push demo at tiny sizes — same rot-guard contract as qos
         print(json.dumps({"ivm": run_ivm_bench(), **dev}))
         return 0
-    failed = run_bench(float(os.environ.get("BENCH_SCALE", 1.0)), dev)
+    failed = run_arms(dev)
     if failed:
         print(f"# failed arms: {', '.join(failed)}", file=sys.stderr)
         return 1

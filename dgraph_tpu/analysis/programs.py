@@ -10,7 +10,7 @@ contains no serial ``scan``; ``multi_hop`` is cheap because its carry
 buffers are donated and aliased; the program cache is bounded because
 two frontiers in one capacity bucket trace byte-identical programs.
 Those invariants lived as scattered one-off asserts (``"scan[" not in
-…`` greps in bench_ops.py/test_spgemm.py) and one *suppressed* donation
+…`` greps in a bench script and test_spgemm.py) and one *suppressed* donation
 warning (ops/batch.py) — folklore, not contract.
 
 This module makes them enforced:
@@ -743,11 +743,6 @@ def _b_set_algebra() -> List[ProgramInstance]:
             "range_rows_C64", sets.range_rows,
             (jnp.int32(3), jnp.int32(9)), {"cap": 64},
         ),
-        ProgramInstance(
-            "unique_dense_U256", sets.unique_dense, (a,),
-            {"n_universe": 256, "cap": 64},
-        ),
-        ProgramInstance("unique_rows_L64", sets.unique_rows_sorted, (a,)),
     ]
 
 
@@ -773,7 +768,7 @@ def _b_expand_csr() -> List[ProgramInstance]:
 
 
 def _inline_layout():
-    """Small but real inline-head layout (ops/sets.py expand_inline
+    """Small but real inline-head layout (ops/sets.py expand_inline_seg
     docstring): 8 rows, three of them with overflow chunks."""
     jnp, np = _jnp()
     from dgraph_tpu.ops.sets import INLINE, SENT
@@ -803,35 +798,11 @@ def _b_expand_inline() -> List[ProgramInstance]:
     from dgraph_tpu.ops import sets
 
     metap, ovc = _inline_layout()
-    # grouped: overflow rows [1, 3, 6] form the ascending prefix
-    grouped = jnp.asarray(
-        np.array([1, 3, 6, -1, 0, 4, 7, -1], np.int32)
-    )
     anyorder = jnp.asarray(np.array([0, 1, 3, 4, 6, 7, -1, -1], np.int32))
-    # chunked layout twin: meta8 lanes (chunk_start, chunk_count, degree)
-    meta8 = np.zeros((8, 8), np.int32)
-    degs = np.asarray(metap)[:, 1]
-    cstart = 0
-    for i, d in enumerate(degs):
-        cc = -(-int(d) // sets.CHUNK)
-        meta8[i, :3] = (cstart, cc, int(d))
-        cstart += cc
-    chunk_dst = jnp.asarray(
-        np.full((max(cstart, 1), sets.CHUNK), sets.SENT, np.int32)
-    )
     return [
-        ProgramInstance(
-            "grouped_B8xP4xC8", sets.expand_inline_grouped,
-            (metap, ovc, grouped), {"capc": 8, "pcap": 4},
-        ),
         ProgramInstance(
             "seg_B8xC8", sets.expand_inline_seg,
             (metap, ovc, anyorder), {"capc": 8},
-        ),
-        ProgramInstance(
-            "chunked_B8xC8", sets.expand_chunked,
-            (jnp.asarray(meta8), chunk_dst, anyorder),
-            {"capc": 8, "with_seg": True},
         ),
     ]
 
@@ -1026,33 +997,6 @@ def _b_mesh_multi_hop() -> List[ProgramInstance]:
     ]
 
 
-def _classed() -> tuple:
-    jnp, np = _jnp()
-    from dgraph_tpu.ops import batch
-
-    h_src, h_offsets, h_dst, offsets, dst = _small_csr()
-    ce = batch.ClassedExpander(offsets, dst, h_offsets)
-    rows = np.arange(8, dtype=np.int64)
-    counts, n_heavy, heavy_edges = ce.class_counts(rows)
-    caps = ce.plan_caps(counts, n_heavy, heavy_edges, fine=False)
-    mats, _pos = ce.partition(rows, caps)
-    return ce, caps, tuple(jnp.asarray(m) for m in mats)
-
-
-def _b_classed_expander() -> List[ProgramInstance]:
-    ce, caps, mats = _classed()
-    return [
-        ProgramInstance(
-            f"materialize_{'x'.join(str(c) for c in caps)}",
-            ce.program(caps, mode="materialize"), (mats, ()),
-        ),
-        ProgramInstance(
-            f"frontier_{'x'.join(str(c) for c in caps)}",
-            ce.program(caps, mode="frontier"), (mats, ()),
-        ),
-    ]
-
-
 def _tiles():
     jnp, np = _jnp()
     from dgraph_tpu.ops import spgemm
@@ -1188,46 +1132,12 @@ def _b_packed_expand() -> List[ProgramInstance]:
 
     _, _, _, offsets, dst = _small_csr()
     rows = jnp.asarray(sets.pad_rows(np.arange(4, dtype=np.int64), 8))
-    metap, ovc = _inline_layout()
     return [
         ProgramInstance(
             "csr_R8xC32", qe._packed_expand_csr,
             (offsets, dst, rows), {"cap": 32},
         ),
-        ProgramInstance(
-            "inline_B8xC8", qe._packed_expand_inline,
-            (metap, ovc, rows), {"capc": 8},
-        ),
     ]
-
-
-def _b_pallas_slotmap() -> List[ProgramInstance]:
-    jnp, np = _jnp()
-    from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas
-
-    cs = jnp.asarray(np.zeros((1, 128), np.int32))
-    cd = jnp.asarray(np.zeros((1, 128), np.int32))
-    return [
-        ProgramInstance(
-            "Q1xP128xC128", slotmap_pallas, (cs, cd),
-            {"capc": 128, "interpret": True},
-        ),
-    ]
-
-
-def _slotmap_inst(raw_capc: int) -> ProgramInstance:
-    """The call _ov_slot_map_pallas (ops/sets.py) makes at a raw chunk
-    capacity: capc rounds to the kernel's 128-slot granule."""
-    jnp, np = _jnp()
-    from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas
-
-    cc = ((raw_capc + 127) >> 7) << 7
-    cs = jnp.asarray(np.zeros((1, 128), np.int32))
-    cd = jnp.asarray(np.zeros((1, 128), np.int32))
-    return ProgramInstance(
-        f"Q1xP128xC{cc}", slotmap_pallas, (cs, cd),
-        {"capc": cc, "interpret": True},
-    )
 
 
 def _resident_fixture():
@@ -1273,26 +1183,6 @@ def _b_pallas_gather() -> List[ProgramInstance]:
             "packed_R8xC32", gather_pallas_packed, (off, dst, rows),
             {"cap": 32, "interpret": True},
         ),
-    ]
-
-
-def _b_pallas_intersect() -> List[ProgramInstance]:
-    jnp, np = _jnp()
-    from dgraph_tpu.ops import sets
-    from dgraph_tpu.ops.pallas_intersect import intersect_pallas
-
-    m2 = jnp.asarray(np.stack([
-        sets.pad_to(np.arange(0, 20, 2), 64),
-        sets.pad_to(np.arange(0, 30, 3), 64),
-    ]))
-    m4 = jnp.asarray(np.stack([
-        sets.pad_to(np.arange(0, 24, k), 64) for k in (2, 3, 4, 6)
-    ]))
-    return [
-        ProgramInstance("K2xL64", intersect_pallas, (m2,),
-                        {"interpret": True}),
-        ProgramInstance("K4xL64", intersect_pallas, (m4,),
-                        {"interpret": True}),
     ]
 
 
@@ -1360,11 +1250,6 @@ def _gather_probe() -> BucketProbe:
     return BucketProbe(pairs=((10, 12), (5, 7)), make=_gather_inst)
 
 
-def _slotmap_probe() -> BucketProbe:
-    # 128-slot chunk granule: raw capacities 129 and 250 both pad to 256
-    return BucketProbe(pairs=((129, 250),), make=_slotmap_inst)
-
-
 REGISTRY: Dict[str, ProgramContract] = {
     c.name: c
     for c in (
@@ -1374,8 +1259,7 @@ REGISTRY: Dict[str, ProgramContract] = {
             build=_b_intersect_many,
             dtypes=_INT,
             notes="k-way intersection as a log-depth tree reduction; "
-                  "the scan-free declaration IS the perf contract "
-                  "(bench_ops.py kway grid).",
+                  "the scan-free declaration IS the perf contract.",
         ),
         ProgramContract(
             name="sets.union_many",
@@ -1395,9 +1279,6 @@ REGISTRY: Dict[str, ProgramContract] = {
                 f"{_OPS}/sets.py::difference",
                 f"{_OPS}/sets.py::union",
                 f"{_OPS}/sets.py::mask_to_set",
-                f"{_OPS}/sets.py::unique_dense",
-                f"{_OPS}/sets.py::unique_rows_sorted",
-                f"{_OPS}/sets.py::skey_uid",
                 f"{_OPS}/sets.py::frontier_rows",
                 f"{_OPS}/sets.py::rows_of",
                 f"{_OPS}/sets.py::range_rows",
@@ -1419,15 +1300,10 @@ REGISTRY: Dict[str, ProgramContract] = {
         ),
         ProgramContract(
             name="sets.expand_inline",
-            covers=(
-                f"{_OPS}/sets.py::expand_chunked",
-                f"{_OPS}/sets.py::expand_inline_grouped",
-                f"{_OPS}/sets.py::expand_inline_seg",
-            ),
+            covers=(f"{_OPS}/sets.py::expand_inline_seg",),
             build=_b_expand_inline,
             dtypes=_INT,
-            notes="chunked/inline-head posting gathers (round-4 fast "
-                  "path).",
+            notes="inline-head posting gather (the fused chain's).",
         ),
         ProgramContract(
             name="batch.set_ops",
@@ -1504,16 +1380,6 @@ REGISTRY: Dict[str, ProgramContract] = {
                   "segment (run_levels' instances declare it).",
         ),
         ProgramContract(
-            name="batch.classed_expander",
-            covers=(f"{_OPS}/batch.py::ClassedExpander._build",),
-            build=_b_classed_expander,
-            dtypes=_INT,
-            notes="degree-classed scatter/sort-free hop programs; "
-                  "capacity tuples ride bucket/bucket_fine so the "
-                  "family stays bounded "
-                  "(tests/test_batch_ops.py::test_program_cache_bound).",
-        ),
-        ProgramContract(
             name="spgemm.mask_algebra",
             covers=(
                 f"{_OPS}/spgemm.py::expand_counts",
@@ -1575,33 +1441,12 @@ REGISTRY: Dict[str, ProgramContract] = {
         ),
         ProgramContract(
             name="engine.packed_expand",
-            covers=(
-                "dgraph_tpu/query/engine.py::_make_packed_expand.run",
-                "dgraph_tpu/query/engine.py::_make_packed_inline.run",
-            ),
+            covers=("dgraph_tpu/query/engine.py::_make_packed_expand.run",),
             build=_b_packed_expand,
             dtypes=_INT,
-            notes="engine-boundary wrappers concatenating (out, seg) "
-                  "into one fetch; structurally they must stay thin "
-                  "shells over the registered expansion kernels.",
-        ),
-        ProgramContract(
-            name="pallas.slotmap",
-            covers=(
-                f"{_OPS}/pallas_slotmap.py::slotmap_pallas",
-                f"{_OPS}/sets.py::expand_inline_grouped_pallas",
-            ),
-            build=_b_pallas_slotmap,
-            scan_free=False,   # fori_loop over blocks inside the kernel
-            dtypes=_INT,
-            bucket_probe=_slotmap_probe(),
-            notes="PROMOTED (PR 16): wired into the grouped-expansion "
-                  "path behind DGRAPH_TPU_SLOTMAP=force (ops/sets.py "
-                  "expand_inline_grouped_auto), full checks — transfer, "
-                  "cost, bucket probe — in interpret mode.  The TPU "
-                  "v5e compiler refuses it (cumsum has no Pallas TPU "
-                  "lowering; tests/test_chip_compile.py strict xfail), "
-                  "so auto mode selects it nowhere.",
+            notes="engine-boundary wrapper concatenating (out, seg) "
+                  "into one fetch; structurally it must stay a thin "
+                  "shell over the registered expansion kernel.",
         ),
         ProgramContract(
             name="pallas.gather",
@@ -1622,19 +1467,6 @@ REGISTRY: Dict[str, ProgramContract] = {
                   "byte-identical to expand_csr; contract-checked in "
                   "interpret mode, compiled for TPU v5e in "
                   "tests/test_chip_compile.py.",
-        ),
-        ProgramContract(
-            name="pallas.intersect",
-            covers=(f"{_OPS}/pallas_intersect.py::intersect_pallas",),
-            build=_b_pallas_intersect,
-            scan_free=False,   # interpret-mode grid loop
-            dtypes=_INT,
-            notes="k-way (k<=8) sorted-set intersect over the stored "
-                  "layout (PR 16, EmptyHeaded-style probe + VPU "
-                  "membership tiles), byte-identical to intersect_many; "
-                  "checked in interpret mode.  The TPU v5e compiler "
-                  "refuses it (1-D vector_store alignment; "
-                  "tests/test_chip_compile.py strict xfail).",
         ),
         ProgramContract(
             name="resident.merge",

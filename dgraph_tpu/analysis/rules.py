@@ -371,8 +371,8 @@ class RecompileHazard(Rule):
                     self.id, node,
                     "jax.jit constructed inside a loop recompiles every "
                     "iteration; hoist it out or cache it keyed on the "
-                    "static arguments (see ops/batch.py ClassedExpander."
-                    "_program)",
+                    "static arguments (see mesh/programs.py "
+                    "mesh_multi_hop_step)",
                 )
 
 

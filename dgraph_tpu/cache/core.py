@@ -146,10 +146,18 @@ class VersionedLFUCache:
             e = self._m.get(key)
             return e is not None and e.version == version
 
-    def put(self, key, version: int, value, nbytes: int) -> bool:
+    def put(
+        self, key, version: int, value, nbytes: int,
+        admit: Optional[Callable[[], bool]] = None,
+    ) -> bool:
         """Admit ``value`` under the budget; returns False when refused
-        (over the per-entry cap, or a zero budget).  Also performs one
-        bounded generation sweep and, when needed, LFU-aging eviction."""
+        (over the per-entry cap, a zero budget, or ``admit`` said no).
+        Also performs one bounded generation sweep and, when needed,
+        LFU-aging eviction.
+
+        ``admit`` runs under the tier lock, so its answer is ordered
+        against ``drop_where``: an entry it lets in is there for a later
+        drop to find, and a drop that already ran is seen by it."""
         nbytes = int(nbytes)
         if self.budget_bytes <= 0 or nbytes > self.max_entry_bytes:
             hook = self._hook
@@ -158,6 +166,8 @@ class VersionedLFUCache:
             return False
         evicted = 0
         with self._lock:
+            if admit is not None and not admit():
+                return False
             self._sweep_locked(version)
             old = self._m.get(key)
             if old is not None:

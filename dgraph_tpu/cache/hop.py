@@ -180,7 +180,13 @@ class HopCache:
         nbytes = (
             int(out.nbytes) + int(seg_ptr.nbytes) + int(frontier.nbytes) + 64
         )
-        self._c.put(key, version, (out, seg_ptr, frontier), nbytes)
+        # an entry keyed at an epoch the arena has left can never be hit
+        # (every probe's key carries the current epoch), and the
+        # post-delta sweep that would drop it has already run
+        self._c.put(
+            key, version, (out, seg_ptr, frontier), nbytes,
+            admit=lambda: key[3] == getattr(arena, "epoch", 0),
+        )
         # admissions and sweeps change occupancy without a get-event
         QCACHE_HOP_BYTES.set(self._c.occupancy_bytes)
 

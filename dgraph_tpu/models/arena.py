@@ -41,8 +41,8 @@ from dgraph_tpu.models.types import TypeID, TypedValue, numeric
 
 
 # Shared lock for lazy per-arena derived-structure builds (ensure_device,
-# chunked, lut).  Struck once per build, never on warm reads — the warm
-# paths double-check their cached field before locking.  A single module
+# inline_layout, lut).  Struck once per build, never on warm reads — the
+# warm paths double-check their cached field before locking.  A single module
 # lock (vs per-arena) keeps CSRArena a plain dataclass; contention is
 # limited to cold-cache bursts.
 _BUILD_LOCK = threading.RLock()
@@ -68,7 +68,6 @@ class CSRArena:
     h_offsets: np.ndarray           # int64[S+1]
     n_rows: int
     n_edges: int
-    _chunked: Optional[tuple] = None  # lazy (meta8, chunk_dst)
 
     def degree_of_rows(self, rows: np.ndarray) -> np.ndarray:
         """Host-side degree lookup for capacity planning."""
@@ -89,7 +88,8 @@ class CSRArena:
 
     def host_dst(self) -> np.ndarray:
         """Host mirror of the packed dst column (lazy, cached; one device
-        fetch).  Serves the small-expansion numpy fast path and chunked()."""
+        fetch).  Serves the small-expansion numpy fast path and the lazy
+        layout builds."""
         if self._h_dst is None:
             self._h_dst = np.asarray(self.dst)[: self.n_edges]
         return self._h_dst
@@ -135,58 +135,6 @@ class CSRArena:
         out = self.host_dst()[np.repeat(starts, degs) + within].astype(np.int64)
         return out, seg_ptr
 
-    def chunked(self) -> tuple:
-        """Chunk-packed layout for ops.expand_chunked, built lazily.
-
-        Returns (meta8, chunk_dst): int32[Sb, 8] per-row
-        (chunk_start, chunk_count, degree) and int32[NCb, CHUNK]
-        chunk-packed dst with SENT pad lanes.  Rebuilt with the arena on
-        dirty refresh (the tuple dies with the CSRArena object); host
-        capacity planning uses chunk_degree_of_rows.
-        """
-        if self._chunked is not None:
-            return self._chunked
-        with _BUILD_LOCK:
-            return self._chunked_locked()
-
-    def _chunked_locked(self) -> tuple:
-        if self._chunked is not None:  # lost the build race: reuse
-            return self._chunked
-        C = ops.CHUNK
-        S = self.n_rows
-        E = self.n_edges
-        deg = self.h_offsets[1:] - self.h_offsets[:-1]
-        cdeg = (deg + C - 1) // C
-        coff = np.zeros(S + 1, dtype=np.int64)
-        np.cumsum(cdeg, out=coff[1:])
-        NC = int(coff[-1])
-        NCb = ops.bucket(max(1, NC))
-        chunk = np.full((NCb, C), SENT, dtype=np.int32)
-        if E:
-            h_dst = self.host_dst()
-            rowid = np.repeat(np.arange(S, dtype=np.int64), deg)
-            within = np.arange(E, dtype=np.int64) - np.repeat(
-                self.h_offsets[:-1], deg
-            )
-            chunk[coff[rowid] + within // C, within % C] = h_dst
-        # size from HOST state, not the device offsets tensor: after
-        # apply_delta the device tensors are stale until ensure_device(),
-        # but chunked() must serve fused chains immediately (a new source
-        # row crossing the power-of-two row bucket would otherwise break
-        # the meta[:S] broadcast below)
-        Sb = ops.bucket(max(1, self.n_rows))
-        meta = np.zeros((Sb, 8), dtype=np.int32)
-        meta[:S, 0] = coff[:-1]
-        meta[:S, 1] = cdeg
-        meta[:S, 2] = deg
-        self._chunked = (jnp.asarray(meta), jnp.asarray(chunk))
-        return self._chunked
-
-    def chunk_degree_of_rows(self, rows: np.ndarray) -> np.ndarray:
-        """Host chunk-count lookup (ceil(degree/CHUNK)) for planning."""
-        C = ops.CHUNK
-        return (self.degree_of_rows(rows) + C - 1) // C
-
     def device_bytes(self) -> int:
         """HBM footprint of this arena's device tensors (incl. built lazy
         layouts) — the residency manager's accounting unit."""
@@ -194,9 +142,8 @@ class CSRArena:
         for t in (self.src, self.offsets, self.dst, self._lut):
             if t is not None:
                 n += t.size * t.dtype.itemsize
-        for pair in (self._chunked, self._inline, self._inline_grouped):
-            if pair is not None:
-                n += sum(t.size * t.dtype.itemsize for t in pair)
+        if self._inline is not None:
+            n += sum(t.size * t.dtype.itemsize for t in self._inline)
         if self._tiles is not None:
             # MXU join tier (ops/spgemm.py): densified adjacency blocks
             # ride the same HBM budget/eviction as every other layout
@@ -211,15 +158,14 @@ class CSRArena:
     _inline: Optional[tuple] = None  # lazy (metap, ov_chunks)
 
     def inline_layout(self) -> tuple:
-        """Inline-head layout for ops.expand_inline, built lazily.
+        """Inline-head layout for ops.expand_inline_seg, built lazily.
 
         Returns (metap, ov_chunks): int32[Sb, 8] per-row rows with
         lane0 = overflow chunk start, lane1 = degree, lanes 2..7 = the
         first INLINE targets (SENT pad); int32[NCov, 8] overflow chunks
         (targets INLINE.. of each row), UNPADDED row count.  One row
-        gather serves metadata AND short posting lists — the gather-index
-        halving that lifted the 2-hop bench past the chunked layout
-        (docs/ROOFLINE.md round 4)."""
+        gather serves metadata AND short posting lists (docs/ROOFLINE.md
+        round 4)."""
         if self._inline is not None:
             return self._inline
         # stage h2d: built and put on first use — the request that meets
@@ -266,49 +212,6 @@ class CSRArena:
         """Host overflow-chunk-count lookup for inline_layout planning."""
         d = np.maximum(self.degree_of_rows(rows) - ops.INLINE, 0)
         return (d + 7) >> 3
-
-    _inline_grouped: Optional[tuple] = None
-
-    def inline_layout_grouped(self) -> tuple:
-        """inline_layout with skey-coded target lanes (ops.skey_encode):
-        stored targets carry the no-overflow group bit, so sorting an
-        expansion's output groups overflow-bearing rows into an ascending
-        prefix and ops.expand_inline_grouped can run its slot-map on that
-        prefix alone.  Dense arenas only (row i == uid i) with uids below
-        2^GROUP_BIT — raises ValueError beyond that; callers must catch
-        it and use inline_layout() (bench.py does)."""
-        if self._inline_grouped is not None:
-            return self._inline_grouped
-        from dgraph_tpu.ops.sets import GROUP_BIT, skey_encode
-
-        max_uid = self.n_rows
-        if self.n_edges:
-            max_uid = max(max_uid, int(self.host_dst().max()) + 1)
-        if max_uid >= (1 << GROUP_BIT):
-            raise ValueError(
-                f"uid space too large for grouped inline layout "
-                f"({max_uid} >= 2^{GROUP_BIT}); use inline_layout()"
-            )
-        with _BUILD_LOCK:
-            if self._inline_grouped is not None:
-                return self._inline_grouped
-            metap_j, ov_j = self.inline_layout()
-            metap = np.asarray(metap_j).copy()
-            ov = np.asarray(ov_j).copy()
-            S = self.n_rows
-            deg = self.h_offsets[1:] - self.h_offsets[:-1]
-            # overflow bit by TARGET uid; uids without a row have no edges,
-            # hence no overflow
-            has_ov_of_uid = np.zeros(max_uid + 1, bool)
-            has_ov_of_uid[:S] = deg > ops.INLINE
-            for tab in (metap[:, 2:], ov):
-                valid = tab != SENT
-                u = tab[valid]
-                tab[valid] = skey_encode(u, has_ov_of_uid[u])
-            with obs.stage(None, "h2d_ms"):
-                self._inline_grouped = (jnp.asarray(metap), jnp.asarray(ov))
-            _book_h2d(self._inline_grouped)
-            return self._inline_grouped
 
     # -- MXU join tier (ops/spgemm.py) --------------------------------------
 
@@ -460,7 +363,7 @@ class CSRArena:
         Runs under _BUILD_LOCK: in clustered mode refresh() applies
         deltas while readers run (ClusterStore drains dirty marks inside
         peek), so mirror mutation must be mutually exclusive with the
-        lazy derived-layout builds (inline_layout/chunked also take this
+        lazy derived-layout builds (inline_layout/lut also take this
         lock) — otherwise a build that sampled the mirrors pre-delta
         could cache a torn layout AFTER the invalidation below.
         """
@@ -483,6 +386,7 @@ class CSRArena:
             ])) if (len(adds) or len(dels)) else np.empty(0, np.int64)
             old_degs = self._degrees_of_uids(touched)
         h_dst = self.host_dst().astype(np.int64, copy=False)
+        h_src, h_offsets = self.h_src, self.h_offsets
         # absolute edge positions via the composite (row, dst) key — the
         # CSR flat dst IS sorted by it
         for arr, sign in ((dels, -1), (adds, +1)):
@@ -492,19 +396,16 @@ class CSRArena:
             dsts = arr[:, 1]
             if sign > 0:
                 # new source rows first (degree 0), keeping h_src sorted
-                newsrc = np.setdiff1d(srcs, self.h_src)
+                newsrc = np.setdiff1d(srcs, h_src)
                 if len(newsrc):
-                    at = np.searchsorted(self.h_src, newsrc)
-                    self.h_src = np.insert(self.h_src, at, newsrc)
-                    self.h_offsets = np.insert(
-                        self.h_offsets, at + 1, self.h_offsets[at]
-                    )
-                    self.n_rows = len(self.h_src)
-            rows = np.searchsorted(self.h_src, srcs)
+                    at = np.searchsorted(h_src, newsrc)
+                    h_src = np.insert(h_src, at, newsrc)
+                    h_offsets = np.insert(h_offsets, at + 1, h_offsets[at])
+            n_rows = len(h_src)
+            rows = np.searchsorted(h_src, srcs)
             keys = (rows.astype(np.int64) << 32) | dsts
             edge_rows = np.repeat(
-                np.arange(self.n_rows, dtype=np.int64),
-                np.diff(self.h_offsets),
+                np.arange(n_rows, dtype=np.int64), np.diff(h_offsets)
             )
             edge_keys = (edge_rows << 32) | h_dst
             order = np.argsort(keys, kind="stable")
@@ -514,22 +415,22 @@ class CSRArena:
                 h_dst = np.insert(h_dst, pos, dsts)
             else:
                 h_dst = np.delete(h_dst, pos)
-            cnt = np.bincount(rows, minlength=self.n_rows)
-            self.h_offsets = self.h_offsets.copy()
-            self.h_offsets[1:] += sign * np.cumsum(cnt)
-        self._h_dst = h_dst.astype(np.int32)
-        self.n_edges = len(h_dst)
+            cnt = np.bincount(rows, minlength=n_rows)
+            h_offsets = h_offsets.copy()
+            h_offsets[1:] += sign * np.cumsum(cnt)
+        h_dst = h_dst.astype(np.int32)
+        # the new mirrors are published back to back, whole: a reader
+        # that holds no lock against this writer (an embedded engine's
+        # host expansion, a clustered refresh) must not meet new offsets
+        # beside old targets while the arrays above are being built
+        self.h_src, self.h_offsets, self._h_dst = h_src, h_offsets, h_dst
+        self.n_rows, self.n_edges = len(h_src), len(h_dst)
         # derived device structures are stale until next device use
-        self._chunked = None
         self._inline = None
-        self._inline_grouped = None
         self._lut = None
         self._n_distinct_dst = None
         self._max_uid = None
-        for attr in (
-            "_topm_cdeg", "_topm_ovdeg", "_topm_deg", "_classed",
-            "_tile_blocks",
-        ):
+        for attr in ("_topm_ovdeg", "_topm_deg", "_tile_blocks"):
             if hasattr(self, attr):
                 delattr(self, attr)
         if hist is not None and touched is not None:
@@ -1196,7 +1097,7 @@ class ArenaManager:
 
     def _touch(self, lkey: tuple, obj) -> None:
         """LRU bookkeeping under _cache_lock: refresh recency + size (lazy
-        device layouts — lut/chunked/inline — built after caching grow the
+        device layouts — lut/inline/tiles — built after caching grow the
         footprint, so warm touches also re-check the budget)."""
         if lkey[0] == id(self._sharded):
             obj = obj[1]  # (_sharded caches (source arena, ShardedArena))
@@ -1292,17 +1193,11 @@ class ArenaManager:
             )
         tile_bytes = 0
         tile_sets = 0
-        classed = 0
-        classed_programs = 0
         for a in arenas:
             pt = getattr(a, "_tiles", None)
             if pt is not None:
                 tile_bytes += pt.device_bytes()
                 tile_sets += 1
-            ce = getattr(a, "_classed", None)
-            if ce is not None:
-                classed += 1
-                classed_programs += len(ce._programs)
         return {
             "resident_bytes": resident,
             "budget_bytes": self.budget_bytes,
@@ -1313,11 +1208,7 @@ class ArenaManager:
             "entries": entries,
             "evictions": evictions,
             "tile_bytes": tile_bytes,
-            "program_caches": {
-                "classed_expanders": classed,
-                "classed_programs": classed_programs,
-                "tile_sets": tile_sets,
-            },
+            "program_caches": {"tile_sets": tile_sets},
         }
 
     @_cache_locked

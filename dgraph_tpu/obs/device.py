@@ -1,17 +1,15 @@
 """Device/HBM telemetry: residency, program caches, compile events.
 
 The ArenaManager already *enforces* an HBM budget (models/arena.py LRU
-eviction) and the ops layer already *bounds* its program caches
-(ClassedExpander shape families, per-arena spgemm tile sets) — but none
-of that state was visible to an operator except by reading code.  This
-module turns the enforcement bookkeeping into gauges and one snapshot
-endpoint:
+eviction) and the ops layer already *bounds* its per-arena spgemm tile
+sets.  This module turns that enforcement bookkeeping into gauges and
+one snapshot endpoint:
 
 - **HBM residency** — resident bytes vs budget (headroom is the
   difference), dense join-tile bytes, cumulative arena evictions;
-- **program caches** — live ClassedExpander program counts and tile-set
-  counts per kind (`dgraph_program_cache_entries{kind}`), the occupancy
-  side of the compile-budget guards tests already enforce;
+- **program caches** — tile-set counts
+  (`dgraph_program_cache_entries{kind="tile_sets"}`), the occupancy side
+  of the compile-budget guards tests already enforce;
 - **XLA compile events** — every backend compilation via the same
   ``jax.monitoring`` event the per-test compile budgets count
   (`/jax/core/compile/backend_compile_duration`), as a process counter

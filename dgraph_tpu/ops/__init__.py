@@ -13,17 +13,7 @@ from dgraph_tpu.ops.sets import (  # noqa: F401
     SENT,
     bucket,
     bucket_fine,
-    expand_chunked,
-    expand_inline,
     expand_inline_seg,
-    expand_inline_grouped,
-    expand_inline_grouped_pallas,
-    expand_inline_grouped_auto,
-    use_slotmap_pallas,
-    skey_encode,
-    skey_uid,
-    GROUP_BIT,
-    GROUP_MASK,
     sort_desc_free,
     pad_to,
     pad_rows,
@@ -40,8 +30,6 @@ from dgraph_tpu.ops.sets import (  # noqa: F401
     count_valid,
     rows_of,
     range_rows,
-    unique_dense,
-    unique_rows_sorted,
     frontier_rows,
 )
 from dgraph_tpu.ops.pallas_gather import (  # noqa: F401
@@ -49,17 +37,11 @@ from dgraph_tpu.ops.pallas_gather import (  # noqa: F401
     gather_pallas_packed,
     gather_reference,
 )
-from dgraph_tpu.ops.pallas_intersect import (  # noqa: F401
-    intersect_pallas,
-    intersect_reference,
-)
 from dgraph_tpu.ops.order import (  # noqa: F401
     gather_ranks,
     segmented_sort_perm,
 )
 from dgraph_tpu.ops.batch import (  # noqa: F401
-    ClassedExpander,
-    classed_for_arena,
     difference_batch,
     expand_ascending,
     expand_filter_compact,

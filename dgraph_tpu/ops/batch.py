@@ -15,23 +15,13 @@ dgraph-tpu ops layer:
   — one dispatch for a whole batch of frontiers instead of B.
 - **`expand_ascending`**: dense CSR expansion for ASCENDING-DISTINCT row
   vectors via the telescoped slot map (one scatter + one prefix sum —
-  the scalar analog of ops.expand_chunked's chunk map).  Output is
+  the scalar analog of ops.expand_inline_seg's chunk map).  Output is
   densely packed (valid prefix, SENT tail), which makes the follow-up
   dedup sort as narrow as it can be.
 - **`expand_filter_compact`**: gather → k-way merge → multi-predicate
   intersect → compact in ONE jitted program (plus its vmapped batch
   form).  The per-op path for the same hop is ≥ (2 + n_predicates)
-  dispatches; bench_ops.py measures the ratio.
-- **Degree-classed hop programs** (`ClassedExpander`): a scatter- and
-  sort-free expansion for backends where XLA's scatter/sort lag far
-  behind its gathers (measured on XLA-on-CPU: scatter ≈ 100ns/update
-  and sort ≈ 10× numpy, while gathers run at memcpy-like rates).  Rows
-  are partitioned by ⌈log2(degree)⌉ into classes; class c expands as a
-  pure 2-D gather ``dst[o0[:, None] + iota(2^c)]`` masked by degree —
-  no slot map at all.  Degree > ``2^LOG_W_MAX`` rows fall into a dense
-  residual bucket served by `expand_ascending`.  Capacities reuse the
-  `bucket_fine` scheme so the jit cache stays bounded (one program per
-  bucketed capacity tuple — tests/test_batch_ops.py asserts the bound).
+  dispatches.
 - **`multi_hop`**: a `lax.scan` multi-hop driver that keeps the
   frontier (and optionally the visited set) device-resident across
   hops, with donated carry buffers — no host round trip between levels.
@@ -45,33 +35,18 @@ to the shared capacity L.
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from dgraph_tpu.ops.sets import (
     SENT,
-    bucket,
-    bucket_fine,
     frontier_rows,
     member_mask,
     sort_desc_free,
     sort_unique,
 )
-
-# widest per-row gather class: rows with degree above 2^LOG_W_MAX route
-# to the dense residual bucket (a handful of celebrity rows must not
-# force a megalane class matrix on everyone).  The class/residual split
-# is a route-selection knob like the rest — its read lives in
-# utils/planconfig.py (DGRAPH_TPU_CLASS_W_MAX) with the other gates —
-# but it is bound ONCE at import: the split shapes every compiled hop
-# program, so a per-call read would churn the jit cache (documented in
-# planconfig's module contract; set the env before first import).
-from dgraph_tpu.utils.planconfig import class_w_max
-
-LOG_W_MAX = class_w_max()
 
 
 # -- batched set ops ---------------------------------------------------------
@@ -108,7 +83,7 @@ def expand_ascending(
     """CSR expansion of an ASCENDING-DISTINCT row vector (-1 skips
     anywhere) into a densely packed target vector.
 
-    The slot→edge map telescopes exactly like ops.expand_chunked's
+    The slot→edge map telescopes exactly like ops.expand_inline_seg's
     chunk map: scatter ``o0_j - prev_end_j`` at each productive row's
     output start, prefix-sum, add the slot iota — one scatter + one
     O(cap) prefix sum, then a single dst gather per slot.  (Ascending
@@ -161,7 +136,7 @@ def expand_filter_compact(
     fused filter predicate), applied as member_mask's before the merge
     so masked lanes never survive into the dedup sort.  The per-op
     equivalent is (2 + len(keeps)) separate dispatches: expand, one
-    intersect per keep, then sort_unique — bench_ops.py measures both.
+    intersect per keep, then sort_unique.
 
     Returns (frontier int32[cap_out or cap] sorted-unique-padded,
     total int32 — raw edge count BEFORE filtering, the traversal work).
@@ -360,322 +335,3 @@ def _multi_hop_jit(
         body, (frontier, visited), None, length=n_hops
     )
     return fs, totals, vis
-
-
-# -- degree-classed hop programs --------------------------------------------
-
-
-class ClassedExpander:
-    """Scatter/sort-free batched hop programs over one CSR arena.
-
-    Host side, rows partition by degree class (`partition`); device
-    side, each class is a pure 2-D gather masked by degree.  Programs
-    cache per (mode, bucketed capacity tuple, batched) — capacities ride
-    the bucket_fine scheme, so a steady workload compiles a handful of
-    programs total, then reuses them (the jit-cache bound that
-    tests/test_batch_ops.py::test_program_cache_bound locks in).
-
-    Construct once per arena from its device tensors + host offsets
-    mirror; the object is cheap, the cached programs are the asset.
-    """
-
-    def __init__(
-        self,
-        offsets: jnp.ndarray,
-        dst: jnp.ndarray,
-        h_offsets: np.ndarray,
-    ):
-        self.offsets = offsets
-        self.dst = dst
-        self.h_deg = np.asarray(
-            h_offsets[1:] - h_offsets[:-1], dtype=np.int64
-        )
-        maxdeg = int(self.h_deg.max()) if len(self.h_deg) else 0
-        self.n_cls = min(
-            max(1, int(np.ceil(np.log2(max(2, maxdeg)))) + 1), LOG_W_MAX + 1
-        )
-        self.widths = [1 << c for c in range(self.n_cls)]
-        self._programs: Dict[tuple, object] = {}
-
-    # -- host planning ------------------------------------------------------
-
-    def cls_of(self, deg: np.ndarray) -> np.ndarray:
-        """Class index per degree: ⌈log2(deg)⌉ clamped to the class
-        count; degree > 2^LOG_W_MAX means class n_cls (heavy).  Loop of
-        vector compares, not a [n, n_cls] broadcast — this runs per
-        query on the bench's hot host path."""
-        deg = np.asarray(deg)
-        c = np.zeros(deg.shape, np.int64)
-        for t in range(self.n_cls - 1):
-            c += deg > (1 << t)
-        if self.n_cls == LOG_W_MAX + 1:  # heavy rows possible
-            c = np.where(deg > (1 << (self.n_cls - 1)), self.n_cls, c)
-        return c
-
-    def class_sort(
-        self, rows: np.ndarray
-    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """Stable class-partition of a row vector: returns (rows sorted
-        class-major — ascending within each class —, starts int64[
-        n_cls+2] class boundaries, degrees aligned with the sorted rows,
-        original positions aligned with the sorted rows).  Negative and
-        degree-0 rows drop (they contribute no edges)."""
-        rows = np.asarray(rows)
-        pos0 = np.arange(len(rows))
-        keep = rows >= 0
-        rows, pos0 = rows[keep], pos0[keep]
-        deg = self.h_deg[rows]
-        keep = deg > 0
-        rows, pos0, deg = rows[keep], pos0[keep], deg[keep]
-        c = self.cls_of(deg)
-        order = np.argsort(c, kind="stable")
-        counts = np.bincount(c, minlength=self.n_cls + 1)
-        starts = np.zeros(self.n_cls + 2, dtype=np.int64)
-        np.cumsum(counts, out=starts[1:])
-        return rows[order], starts, deg[order], pos0[order]
-
-    def class_counts(self, rows: np.ndarray) -> Tuple[np.ndarray, int, int]:
-        """(per-class row counts, heavy row count, heavy edge total) for
-        one frontier — the inputs to `plan_caps`.  Negative rows skip."""
-        rows = np.asarray(rows)
-        rows = rows[rows >= 0]
-        deg = self.h_deg[rows]
-        deg = deg[deg > 0]
-        c = self.cls_of(deg)
-        counts = np.bincount(c, minlength=self.n_cls + 1)
-        heavy = counts[self.n_cls]
-        n_heavy = int(heavy)
-        heavy_edges = int(deg[c == self.n_cls].sum()) if n_heavy else 0
-        return counts[: self.n_cls], n_heavy, heavy_edges
-
-    def plan_caps(
-        self, counts: np.ndarray, n_heavy: int, heavy_edges: int,
-        fine: bool = True,
-    ) -> tuple:
-        """Bucket worst-case per-class row counts (+ heavy bucket) into
-        the static capacity tuple that keys the compiled program.
-
-        ``fine`` uses 1/8-step buckets — right when ONE plan serves a
-        long batch (bench.py plans the worst composition over the whole
-        stream once).  Per-query planning (engine per-level path) MUST
-        use fine=False: pow2 buckets, or the per-class combinatorics
-        compile a fresh program for every frontier wiggle."""
-        b = bucket_fine if fine else bucket
-        caps = tuple(int(b(max(1, int(c)), floor=8)) for c in counts)
-        hr = int(bucket(max(1, n_heavy), floor=8)) if n_heavy else 0
-        he = int(b(max(1, heavy_edges))) if n_heavy else 0
-        return caps + (hr, he)
-
-    def partition(
-        self, rows: np.ndarray, caps: tuple
-    ) -> Tuple[tuple, List[np.ndarray]]:
-        """Split an ascending-distinct row vector into per-class padded
-        mats (-1 pad) + the heavy-row mat.  Returns (mats, positions):
-        positions[c] = each class row's index in the INPUT vector, for
-        matrix reassembly.  Rows with degree 0 (or negative) are
-        dropped — they contribute no edges."""
-        rs, starts, _deg, pos = self.class_sort(rows)
-        mats = []
-        positions = []
-        for k in range(self.n_cls):
-            m = np.full(caps[k], -1, dtype=np.int32)
-            lo, hi = int(starts[k]), int(starts[k + 1])
-            m[: hi - lo] = rs[lo:hi]
-            mats.append(m)
-            positions.append(pos[lo:hi])
-        lo, hi = int(starts[self.n_cls]), int(starts[self.n_cls + 1])
-        hm = np.full(max(caps[self.n_cls], 1), -1, dtype=np.int32)
-        hm[: hi - lo] = rs[lo:hi]
-        mats.append(hm)
-        positions.append(pos[lo:hi])
-        return tuple(mats), positions
-
-    # -- device programs ----------------------------------------------------
-
-    def _build(self, caps: tuple, mode: str, batched: bool):
-        offsets, dst = self.offsets, self.dst
-        widths = self.widths
-        n_cls = self.n_cls
-        he_cap = caps[n_cls + 1]
-
-        def one(mats, keeps):
-            chk = jnp.int32(0)
-            total = jnp.int32(0)
-            parts = []
-            for k in range(n_cls):
-                w = widths[k]
-                r = mats[k]
-                lv = r >= 0
-                uc = jnp.where(lv, r, 0)
-                o0 = offsets[uc]
-                dg = jnp.where(lv, offsets[uc + 1] - o0, 0)
-                iot = jnp.arange(w, dtype=jnp.int32)
-                m = iot[None, :] < dg[:, None]
-                vals = dst[
-                    jnp.clip(o0[:, None] + iot[None, :], 0, dst.shape[0] - 1)
-                ]
-                total += jnp.sum(dg, dtype=jnp.int32)
-                vals = jnp.where(m, vals, SENT)
-                for s in keeps:
-                    vals = jnp.where(member_mask(vals, s), vals, SENT)
-                if mode == "checksum":
-                    chk += jnp.sum(
-                        jnp.where(vals == SENT, 0, vals), dtype=jnp.int32
-                    )
-                else:
-                    parts.append(vals.reshape(-1))
-            if he_cap:
-                hout, htot = expand_ascending(
-                    offsets, dst, mats[n_cls], he_cap
-                )
-                total += htot
-                for s in keeps:
-                    hout = jnp.where(member_mask(hout, s), hout, SENT)
-                if mode == "checksum":
-                    chk += jnp.sum(
-                        jnp.where(hout == SENT, 0, hout), dtype=jnp.int32
-                    )
-                else:
-                    parts.append(hout)
-            if mode == "checksum":
-                return chk, total
-            lanes = jnp.concatenate(parts)
-            if mode == "frontier":
-                return sort_unique(lanes), total
-            return lanes, total
-
-        if batched:
-            def run(mats, keeps):
-                return jax.vmap(lambda mm: one(mm, keeps))(mats)
-        else:
-            run = one
-        return jax.jit(run)
-
-    def program(
-        self, caps: tuple, mode: str = "materialize", batched: bool = False
-    ):
-        """Fetch-or-build the jitted hop program for a capacity tuple.
-
-        mode: "materialize" (flat SENT-masked lanes + edge total — the
-        engine's matrix source), "frontier" (sorted-unique next frontier
-        + total), or "checksum" (int32 wraparound sum of produced uids +
-        total; forces every edge to materialize without shipping lanes).
-        """
-        key = (caps, mode, batched)
-        p = self._programs.get(key)
-        if p is None:
-            p = self._build(caps, mode, batched)
-            self._programs[key] = p
-        return p
-
-    def lanes_of(self, caps: tuple) -> int:
-        """Flat lane count of a materialize-mode output for ``caps``."""
-        return sum(
-            caps[c] * self.widths[c] for c in range(self.n_cls)
-        ) + caps[self.n_cls + 1]
-
-    # -- single-frontier convenience (engine per-level path) ----------------
-
-    def expand_rows(
-        self, rows: np.ndarray, degs: np.ndarray
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """One-program expansion of an ascending-distinct row vector into
-        the engine's (out_flat int64, seg_ptr int64) uid-matrix layout.
-
-        One device dispatch + one fetch; reassembly into frontier order
-        happens host-side from the known per-row degrees (the same
-        O(edges) numpy accounting the packed CSR path already pays).
-        """
-        from dgraph_tpu import obs
-
-        sp = obs.current_span()
-        if sp is not None:
-            # sampled: the classed hop program is the device-program
-            # granularity below the engine's `hop` span — class shape +
-            # heavy-bucket size explain which compiled program family ran
-            with sp.child("hop.program") as hs:
-                out_flat, seg_ptr = self._expand_rows(rows, degs, hs)
-            return out_flat, seg_ptr
-        return self._expand_rows(rows, degs, None)
-
-    def _expand_rows(
-        self, rows: np.ndarray, degs: np.ndarray, span
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        # ONE classification pass serves counts, caps and the mats —
-        # this runs per level on the hot path, so no re-derivation
-        rs, starts, deg_s, pos = self.class_sort(rows)
-        counts = np.diff(starts)[: self.n_cls]
-        hlo, hhi = int(starts[self.n_cls]), int(starts[self.n_cls + 1])
-        n_heavy = hhi - hlo
-        heavy_edges = int(deg_s[hlo:hhi].sum()) if n_heavy else 0
-        caps = self.plan_caps(counts, n_heavy, heavy_edges, fine=False)
-        mats = []
-        positions = []
-        for k in range(self.n_cls + 1):
-            lo, hi = int(starts[k]), int(starts[k + 1])
-            m = np.full(
-                max(caps[k], 1) if k == self.n_cls else caps[k],
-                -1, dtype=np.int32,
-            )
-            m[: hi - lo] = rs[lo:hi]
-            mats.append(m)
-            positions.append(pos[lo:hi])
-        prog = self.program(caps, mode="materialize")
-        lanes_dev, _total = prog(
-            tuple(jnp.asarray(m) for m in mats), ()
-        )
-        if span is not None:
-            span.set_attr("rows", int(len(rows)))
-            span.set_attr("heavy_rows", int(n_heavy))
-            span.set_attr("caps", list(int(c) for c in caps))
-            from dgraph_tpu import obs
-
-            span.set_attr(
-                "device_sync_ms", round(obs.block_ready_ms(lanes_dev), 3)
-            )
-        lanes = np.asarray(lanes_dev)
-        degs = np.asarray(degs)
-        n = len(rows)
-        seg_ptr = np.zeros(n + 1, dtype=np.int64)
-        np.cumsum(np.where(degs > 0, degs, 0), out=seg_ptr[1:])
-        out_flat = np.empty(int(seg_ptr[-1]), dtype=np.int64)
-        off = 0
-        for k in range(self.n_cls + 1):
-            w = self.widths[k] if k < self.n_cls else 0
-            pos = positions[k]
-            if k < self.n_cls:
-                blk = lanes[off: off + caps[k] * w].reshape(caps[k], w)
-                off += caps[k] * w
-                if not len(pos):
-                    continue
-                d = degs[pos]
-                m = np.arange(w)[None, :] < d[:, None]
-                vals = blk[: len(pos)][m]
-            else:
-                he_cap = caps[self.n_cls + 1]
-                blk = lanes[off: off + he_cap]
-                off += he_cap
-                if not len(pos):
-                    continue
-                d = degs[pos]
-                vals = blk[: int(d.sum())].astype(np.int64)
-            # scatter this class's per-row runs to their frontier slots
-            starts = seg_ptr[pos]
-            within = np.arange(int(d.sum())) - np.repeat(
-                np.cumsum(d) - d, d
-            )
-            out_flat[np.repeat(starts, d) + within] = vals
-        return out_flat, seg_ptr
-
-
-def classed_for_arena(arena) -> ClassedExpander:
-    """Lazily build (and cache on the arena object) the ClassedExpander
-    for a CSRArena — same lifetime pattern as arena.chunked()."""
-    arena.ensure_device()
-    ce = getattr(arena, "_classed", None)
-    if ce is None or ce.offsets is not arena.offsets:
-        # (re)build: apply_delta invalidates, and ensure_device swaps the
-        # device tensors — either way the cached programs are stale
-        ce = ClassedExpander(arena.offsets, arena.dst, arena.h_offsets)
-        arena._classed = ce
-    return ce

@@ -30,7 +30,7 @@ is that tier for dgraph-tpu:
   the stack, == k where all agree); padded uid SETS intersect in ONE
   program via k-1 parallel membership probes against the first set plus
   a single compacting sort — the per-op path dispatches k-1 separate
-  sort+probe programs (bench_ops.py measures both).
+  sort+probe programs.
 - **`run_mask_chain`**: the generic-join driver — a whole multi-level
   uid chain (each level optionally intersected with a keep mask, e.g. a
   fused ``@filter`` or a cycle-closing set) as ONE jitted program; masks

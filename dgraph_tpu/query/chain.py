@@ -6,7 +6,7 @@ the reference pays as per-key badger lookups (worker/task.go:287-440) and
 that VERDICT r2 flagged as the engine's bottleneck.  This module fuses a
 maximal chain of uid expansions into ONE jitted program: the frontier
 stays device-resident between levels (rows via a dense uid→row LUT,
-expansion via ops.expand_chunked, dedup via sort), and only the final
+expansion via ops.expand_inline_seg, dedup via sort), and only the final
 per-level result matrices transfer to the host for filtering-free levels'
 JSON encoding.
 
@@ -158,8 +158,8 @@ def _run_fused(
     Round 4: levels expand through the INLINE-HEAD layout
     (ops.expand_inline_seg) — one 32B row gather serves metadata and the
     first INLINE targets; only degree>INLINE rows touch overflow chunks.
-    Gather-index count per level roughly halves vs the chunked layout
-    (docs/ROOFLINE.md).
+    Gather-index count per level is roughly half that of a layout whose
+    rows hold metadata only (docs/ROOFLINE.md).
 
     root_vec: int32[B0] sorted-unique uids, SENT-padded.
     metas/ovs/luts: tuples of per-level inline-layout arrays.
@@ -344,7 +344,7 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
             return reject("device sick: per-level host execution (devguard)")
         src = np.asarray(src)
         if not np.all(src[1:] > src[:-1]):
-            # expand_chunked's slot mapping requires an ascending-distinct
+            # expand_inline_seg's slot map requires an ascending-distinct
             # frontier; an order-by at the root permutes dest_uids, so
             # fusing would corrupt the matrices — fall back
             return reject("frontier not ascending-distinct")
@@ -489,9 +489,6 @@ def try_run_chain(engine, child, src: np.ndarray, resolver=None) -> bool:
         light
         and undecorated
         and all(a is arenas[0] for a in arenas)
-        # honor the fused-executor kill switch (DGRAPH_TPU_FUSED_HOP=0):
-        # the scan driver is part of ops/batch.py's fused machinery
-        and getattr(engine.expander, "fused_hop", "0") != "0"
         and _try_chain_scan(engine, levels, arenas[0], src, est_edges, universe)
     ):
         # the chain RAN: record the decision and hand it to the engine's
@@ -831,11 +828,9 @@ def _try_mesh_chain(engine, levels, src, reject):
         for sg in levels
     ):
         return None
-    # var blocks only (result matrices never leave the device) + the
-    # fused-executor kill switch, exactly like the unsharded scan gate
+    # var blocks only (result matrices never leave the device), exactly
+    # like the unsharded scan gate
     if not getattr(engine, "_cur_block_internal", False):
-        return None
-    if getattr(engine.expander, "fused_hop", "0") == "0":
         return None
     a = engine.arenas.reverse(attr) if rev else engine.arenas.data(attr)
     if a.n_edges == 0 or not engine.arenas.use_mesh_for(a):

@@ -53,30 +53,10 @@ def _make_packed_expand():
     return run
 
 
-def _make_packed_inline():
-    from functools import partial
-
-    import jax
-    import jax.numpy as jnp
-
-    @partial(jax.jit, static_argnames=("capc",))
-    def run(metap, ov_chunks, rows, capc):
-        inline, ov, _total, ovseg = ops.expand_inline_seg(
-            metap, ov_chunks, rows, capc
-        )
-        return jnp.concatenate([inline.reshape(-1), ov.reshape(-1), ovseg])
-
-    return run
-
-
-# device expansions with everything concatenated on device: one host fetch
-# instead of several (each fetch pays a full transport round trip).
-# Module-level so the jit cache persists across queries.  The CSR form
-# stays live as the fallback for NON-ASCENDING frontiers (ordered roots,
-# recurse orderings): expand_inline_seg's slot map requires
-# ascending-distinct rows, expand_csr accepts any order.
+# the non-TPU per-level program with out|seg concatenated on device: one
+# host fetch instead of two (each fetch pays a full transport round trip).
+# Module-level so the jit cache persists across queries.
 _packed_expand_csr = _make_packed_expand()
-_packed_expand_inline = _make_packed_inline()
 
 
 def _unpack_out_seg(packed: np.ndarray, cap: int, total: int, n: int):
@@ -91,13 +71,44 @@ def _unpack_out_seg(packed: np.ndarray, cap: int, total: int, n: int):
 
 
 def _pallas_interpret() -> bool:
-    """Interpret-mode flag for the resident Pallas tier: only the CPU
-    backend (tests, ``DGRAPH_TPU_RESIDENT=force`` rehearsals) runs the
-    kernels under the interpreter.  On a TPU the kernel compiles through
-    Mosaic or the query fails — never the interpreter on the chip."""
+    """Interpret-mode flag for the resident Pallas program: only the CPU
+    backend (the tests that pick the resident program by constructor
+    argument) runs the kernel under the interpreter.  On a TPU the kernel
+    compiles through Mosaic or the query fails — never the interpreter
+    on the chip."""
     import jax
 
     return jax.default_backend() == "cpu"
+
+
+def _resident_program(arena):
+    """The TPU's per-level program: walk the CSR pinned in HBM
+    (ops/pallas_gather.py over ResidentArena's epoch buffers).  No
+    ``ensure_device`` restage rides the dispatch; only the frontier
+    crosses h2d and only the packed result crosses d2h."""
+    ra = arena.resident()
+    interp = _pallas_interpret()
+    return lambda rows_d, cap: ra.expand_packed(rows_d, cap, interpret=interp)
+
+
+def _csr_program(arena):
+    """Every other backend's per-level program: ops.expand_csr over the
+    staged arena tensors."""
+    arena.ensure_device()  # re-upload after host deltas
+    return lambda rows_d, cap: _packed_expand_csr(
+        arena.offsets, arena.dst, rows_d, cap
+    )
+
+
+# program name (also the hop's route label) → arena → (rows_d, cap) →
+# packed out|seg device buffer.  Both take any frontier order.
+_PROGRAMS = {"resident": _resident_program, "csr": _csr_program}
+
+
+def _platform_program() -> str:
+    import jax
+
+    return "resident" if jax.default_backend() == "tpu" else "csr"
 
 
 def _fresh_stats() -> dict:
@@ -142,39 +153,28 @@ def _fresh_stats() -> dict:
 
 class DeviceExpander:
     """Per-level expansion routing: ONE device program (or one host
-    numpy pass) per (level × predicate), whatever the backend.
+    numpy pass) per (level × predicate).
 
     Routing order per call: mesh-sharded (big multi-device predicates) →
-    host numpy (below expand_device_min, transport-bound) → fused
-    classed-gather hop (ops/batch.py — scatter/sort-free, the win on
-    backends where XLA scatter+sort lag its gathers; requires an
-    ascending-distinct frontier) → inline-head device path (the TPU
-    gather-rate layout) → order-agnostic packed CSR (any frontier
-    order).  A sixth route lives ABOVE this per-level entry: the
-    ``mxu`` join tier (query/joinplan.py + ops/spgemm.py) takes whole
-    light chains — cyclic/triangle patterns included — as one blocked
-    boolean-matmul program before the per-level machinery ever runs;
-    its hop spans carry ``route:mxu`` with the tile-build vs matmul
-    time split.  The fused path is gated by ``fused_hop``:
+    host numpy (below the planner's break-even, transport-bound) → the
+    platform's device program: ``resident`` on a TPU backend (the Pallas
+    gather over the CSR pinned in HBM), ``csr`` anywhere else
+    (ops.expand_csr over the staged arena).  Both accept any frontier
+    order.  Two routes live ABOVE this per-level entry and take whole
+    chains before it runs: ``chain`` (query/chain.py — consecutive
+    levels fused into one device program) and the ``mxu`` join tier
+    (query/joinplan.py + ops/spgemm.py — light chains, cyclic/triangle
+    patterns included, as one blocked boolean-matmul program; its hop
+    spans carry ``route:mxu`` with the tile-build vs matmul time split).
 
-      "0"    — never (legacy per-op routing only)
-      "1"/"" — auto: on where the default backend is cpu (measured: XLA
-               CPU scatter ≈ 100ns/update and sort ≈ 10× numpy, so the
-               gather-only classed program wins), off on tpu where the
-               inline-head layout is tuned to the gather engine
-      "force" — always (tests force cross-backend coverage with this)
-
-    Env: DGRAPH_TPU_FUSED_HOP.
+    ``program`` names the device program; the default is the platform's.
+    Tests pass the other name to run the resident kernel (interpret
+    mode) on the CPU backend.
     """
 
-    def __init__(self, engine: "QueryEngine"):
+    def __init__(self, engine: "QueryEngine", program: Optional[str] = None):
         self.engine = engine
-        self.fused_hop = planconfig.fused_hop()
-        # device-resident Pallas tier (PR 16, ops/pallas_gather.py):
-        # "0" never / "1" auto (TPU backend only — default CPU serving
-        # stays byte-identical to the staged routes) / "force" (any
-        # backend, interpret kernels on CPU; the parity-test mode)
-        self.resident_mode = planconfig.resident()
+        self.program = program or _platform_program()
         # cross-session hop coalescing: the cohort scheduler
         # (sched/scheduler.py) installs one HopMerger per cohort so
         # same-(arena, predicate, direction) expansions from different
@@ -193,31 +193,13 @@ class DeviceExpander:
         # rate refinement)
         self._expand_dec = None
 
-    def _use_classed(self) -> bool:
-        if self.fused_hop == "0":
-            return False
-        if self.fused_hop == "force":
-            return True
-        import jax
-
-        return jax.default_backend() == "cpu"
-
-    def _use_resident(self) -> bool:
-        if self.resident_mode == "0":
-            return False
-        if self.resident_mode == "force":
-            return True
-        import jax
-
-        return jax.default_backend() == "tpu"
-
     def expand(
         self, arena, src: np.ndarray, attr: str = "", reverse: bool = False
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Per-level expansion entry.  When the request is SAMPLED
         (obs/spans.py), each call records one ``hop`` span carrying the
         predicate, frontier size, edges traversed, the route the
-        expansion took (cache/merged/mesh/host/classed/inline/csr; the
+        expansion took (cache/merged/mesh/host/resident/csr; the
         chain-level ``mxu`` route emits its own hop span upstream) and
         the device-time split; the unsampled path branches away before
         any span object exists.
@@ -480,9 +462,9 @@ class DeviceExpander:
         # algo/uidlist.go:56-64, priced from MEASURED rates instead of a
         # magic number); static expand_device_min compare when the
         # planner is off or the knob is pinned
-        use_resident = self._use_resident() and hasattr(arena, "resident")
         use_device, dec = planner.expand_route(
-            total, eng.expand_device_min, resident=use_resident
+            total, eng.expand_device_min,
+            resident=self.program == "resident",
         )
         if dec is not None:
             planner.record(eng.stats, dec)
@@ -497,157 +479,25 @@ class DeviceExpander:
             # a device dispatch costs a transport round trip that dwarfs
             # the work
             return self._host_fallback(arena, rows)
-        if use_resident:
-            # device-resident Pallas tier (PR 16): walk the CSR pinned
-            # in HBM (ops/pallas_gather.py over ResidentArena's epoch
-            # buffers) — no ``ensure_device`` restage rides this
-            # dispatch; only the frontier crosses h2d and only the
-            # packed result crosses d2h, which is exactly what the
-            # ledger charges below (the tier's transfer contract).
-            # Order-agnostic like the CSR route, so it sits above the
-            # ascending-only ladder.  Devguard brackets it as a
-            # device-domain route: a classified fault lands on the
-            # byte-identical host fallback.
-            self._route = "resident"
-            interp = _pallas_interpret()
+        self._route = self.program
 
-            def _dispatch_resident():
-                fail.point("device.hop")
-                # plain-data return: ledger/span writes stay on the
-                # caller thread (see _dispatch_inline's note)
-                st = eng.stats
-                with obs.stage(st, "device_expand_ms"):
-                    ra = arena.resident()
-                    with obs.stage(st, "h2d_ms"):
-                        rows_d = jnp.asarray(
-                            ops.pad_rows(rows, ops.bucket(n)).astype(np.int32)
-                        )
-                    with obs.stage(st, "dispatch_ms"):
-                        dev = ra.expand_packed(rows_d, cap, interpret=interp)
-                    sync_ms = (
-                        obs.block_ready_ms(dev)
-                        if self._span is not None else None
-                    )
-                    # one fetch: out|seg concatenated on device
-                    with obs.stage(st, "fetch_ms"):
-                        return np.asarray(dev), sync_ms
-
-            got = self._run_guarded("device.hop", _dispatch_resident)
-            if got is None:
-                return self._host_fallback(arena, rows)
-            packed, sync_ms = got
-            led = _ledger.current()
-            if sync_ms is not None and self._span is not None:
-                self._span.set_attr("device_sync_ms", round(sync_ms, 3))
-                if led is not None:
-                    led.device_sync_ms += sync_ms
-            if led is not None:
-                led.bytes_h2d += int(rows.nbytes)
-                led.bytes_d2h += int(packed.nbytes)
-            with obs.stage(eng.stats, "convert_ms"):
-                out, seg_ptr = _unpack_out_seg(packed, cap, total, n)
-            eng.stats["edges"] += len(out)
-            return out, seg_ptr
-        # big single-device expansion.  The inline-head fast path (one
-        # 32B row gather serves metadata + the first INLINE targets;
-        # docs/ROOFLINE.md round 4) and the classed-gather path both
-        # require ASCENDING-distinct rows — an ordered root permutes the
-        # frontier, so those fall back to the order-agnostic CSR gather.
-        valid_rows = rows[rows >= 0]
-        ascending = bool(np.all(valid_rows[1:] > valid_rows[:-1]))
-        if ascending and self._use_classed():
-            self._route = "classed"
-
-            def _dispatch_classed():
-                fail.point("device.hop")
-                with obs.stage(eng.stats, "device_expand_ms"):
-                    arena.ensure_device()  # re-upload after deltas
-                    ce = ops.classed_for_arena(arena)
-                    # the classed program dispatches, fetches and unpacks
-                    # per degree class inside ops/batch.py: one bracket
-                    with obs.stage(eng.stats, "dispatch_ms"):
-                        return ce.expand_rows(
-                            rows, arena.degree_of_rows(rows)
-                        )
-
-            got = self._run_guarded("device.hop", _dispatch_classed)
-            if got is None:
-                return self._host_fallback(arena, rows)
-            out, seg_ptr = got
-            eng.stats["edges"] += len(out)
-            led = _ledger.current()
-            if led is not None:
-                led.bytes_h2d += int(rows.nbytes)
-                led.bytes_d2h += int(out.nbytes + seg_ptr.nbytes)
-            return out, seg_ptr
-        if ascending:
-            self._route = "inline"
-            B = ops.bucket(n)
-            capov = ops.bucket(
-                max(1, int(arena.ov_chunk_degree_of_rows(rows).sum()))
-            )
-
-            def _dispatch_inline():
-                fail.point("device.hop")
-                # staging inside the bracket: an HBM OOM uploading the
-                # inline layout classifies like a dispatch OOM.  The
-                # closure returns plain data — ledger/span writes happen
-                # on the CALLER thread below, so an abandoned (wedged)
-                # worker waking up later can never scribble on a pooled
-                # struct a newer request now owns
-                metap, ov_chunks = arena.inline_layout()
-                st = eng.stats
-                with obs.stage(st, "device_expand_ms"):
-                    with obs.stage(st, "h2d_ms"):
-                        rows_d = jnp.asarray(ops.pad_rows(rows, B))
-                    with obs.stage(st, "dispatch_ms"):
-                        dev = _packed_expand_inline(
-                            metap, ov_chunks, rows_d, capov
-                        )
-                    # sampled: split pure device time from the host
-                    # fetch (the unsampled path stays dispatch-async —
-                    # asarray overlaps compute with bookkeeping)
-                    sync_ms = (
-                        obs.block_ready_ms(dev)
-                        if self._span is not None else None
-                    )
-                    # one fetch: inline|ov|ovseg concatenated on device
-                    with obs.stage(st, "fetch_ms"):
-                        return np.asarray(dev), sync_ms
-
-            got = self._run_guarded("device.hop", _dispatch_inline)
-            if got is None:
-                return self._host_fallback(arena, rows)
-            packed, sync_ms = got
-            led = _ledger.current()
-            if sync_ms is not None and self._span is not None:
-                self._span.set_attr("device_sync_ms", round(sync_ms, 3))
-                if led is not None:
-                    led.device_sync_ms += sync_ms
-            if led is not None:
-                led.bytes_h2d += int(rows.nbytes)
-                led.bytes_d2h += int(packed.nbytes)
-            from dgraph_tpu.query.chain import packed_inline_to_matrix
-
-            with obs.stage(eng.stats, "convert_ms"):
-                out, seg_ptr = packed_inline_to_matrix(packed, B, capov, n)
-            eng.stats["edges"] += len(out)
-            return out, seg_ptr
-        self._route = "csr"
-
-        def _dispatch_csr():
+        def _dispatch():
             fail.point("device.hop")
-            # plain-data return: ledger/span writes stay on the caller
-            # thread (see _dispatch_inline's abandoned-worker note)
+            # staging inside the bracket: an HBM OOM uploading the arena
+            # classifies like a dispatch OOM.  The closure returns plain
+            # data — ledger/span writes happen on the CALLER thread
+            # below, so an abandoned (wedged) worker waking up later can
+            # never scribble on a pooled struct a newer request now owns
             st = eng.stats
             with obs.stage(st, "device_expand_ms"):
-                arena.ensure_device()  # re-upload after host deltas
+                run = _PROGRAMS[self.program](arena)
                 with obs.stage(st, "h2d_ms"):
                     rows_d = jnp.asarray(ops.pad_rows(rows, ops.bucket(n)))
                 with obs.stage(st, "dispatch_ms"):
-                    dev = _packed_expand_csr(
-                        arena.offsets, arena.dst, rows_d, cap
-                    )
+                    dev = run(rows_d, cap)
+                # sampled: split pure device time from the host fetch
+                # (the unsampled path stays dispatch-async — asarray
+                # overlaps compute with bookkeeping)
                 sync_ms = (
                     obs.block_ready_ms(dev)
                     if self._span is not None else None
@@ -656,7 +506,7 @@ class DeviceExpander:
                 with obs.stage(st, "fetch_ms"):
                     return np.asarray(dev), sync_ms
 
-        got = self._run_guarded("device.hop", _dispatch_csr)
+        got = self._run_guarded("device.hop", _dispatch)
         if got is None:
             return self._host_fallback(arena, rows)
         packed, sync_ms = got
@@ -713,8 +563,7 @@ class QueryEngine:
         # chain decision awaiting its post-hoc latency check (see
         # _exec_child's chain_ms bracket)
         self._pending_chain_dec = None
-        # per-level expansion routing, incl. the fused batched hop path
-        # (ops/batch.py) — see DeviceExpander
+        # per-level expansion routing — see DeviceExpander
         self.expander = DeviceExpander(self)
         # below this fan-out an expansion runs as vectorized numpy on the
         # host CSR mirror: a device dispatch pays a fixed round trip
@@ -750,8 +599,8 @@ class QueryEngine:
         Segmented dataflow (PR 18): a checkpoint is also a scheduler
         yield point — after the token probe it offers the seam to a
         queued higher-priority cohort (sched/segments.py), so per-level
-        hop loops (ClassedExpander chains) preempt at hop boundaries
-        exactly like the fused drivers preempt at segment seams."""
+        hop loops preempt at hop boundaries exactly like the fused
+        drivers preempt at segment seams."""
         tok = self.cancel
         if tok is not None:
             tok.check()

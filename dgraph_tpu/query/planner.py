@@ -1,11 +1,11 @@
 """Measured-cost adaptive planner: ONE calibrated model for every route.
 
-The engine has five execution routes (serial per-op, fused classed,
-chain-scan, fused recurse, MXU tile join) plus the host-vs-device k-way
-intersection.  Until PR 10 each was gated by its own magic number — two
-independently-grown ``262144`` twins among them — and BENCH21M showed
-the cost: ``chain_reject: "fan-out estimate 168342 below threshold
-262144"`` kept the chain scan out of hot 3-hop queries it measurably
+The engine chooses between host and device at every level, and above
+the level between the per-level loop and the fused routes (fused chain,
+chain-scan, fused recurse, MXU tile join), plus the host-vs-device k-way
+intersection.  A static threshold per gate costs real queries: BENCH21M
+showed ``chain_reject: "fan-out estimate 168342 below threshold
+262144"`` keeping the chain scan out of hot 3-hop queries it measurably
 wins.  Banyan (PAPERS.md) frames graph serving as scoped dataflow with
 per-scope scheduling choices; EmptyHeaded's cost-based plan choice
 already drives PR 9's join tier.  This module generalizes that: every

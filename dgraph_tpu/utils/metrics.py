@@ -745,7 +745,7 @@ SUBS_SHED = metrics.labeled(
 # the BASELINE north-star metric (edges traversed per second) a live
 # per-tenant series instead of a bench artifact; LEDGER_HOPS{route}
 # counts hop dispatches by the route the expander took
-# (cache/merged/mesh/host/resident/classed/inline/csr/chain/mxu) and
+# (cache/merged/mesh/host/resident/csr/chain/path/mxu/empty) and
 # LEDGER_HOP_EDGES{route} the edges those hops traversed — which route
 # actually carries a deployment's traffic, in the unit users pay for;
 # LEDGER_STAGE_US{stage} accumulates, in integer microseconds, the
@@ -795,7 +795,7 @@ ENCODE_OBJECTS = metrics.labeled("dgraph_encode_objects_total", label="path")
 # under the ArenaManager budget (resident/budget gauges — headroom is
 # the difference, computed in PromQL, not stored), dense join-tile
 # residency, arena LRU evictions, bounded program-cache occupancy per
-# kind (classed-expander programs, tile sets), and XLA compile events
+# kind (tile sets), and XLA compile events
 # via jax.monitoring (count + seconds as a histogram, so compile storms
 # show up as a rate AND a duration distribution).
 HBM_RESIDENT_BYTES = metrics.gauge("dgraph_hbm_resident_bytes")

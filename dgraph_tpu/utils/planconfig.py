@@ -1,15 +1,14 @@
 """Planner configuration: every execution-route gate knob in ONE module.
 
-Before PR 10 the engine's five execution routes (serial per-op, fused
-classed, chain-scan, fused recurse, MXU tile join) plus the host-vs-
-device k-way intersection were each gated by their own magic number,
-read from the environment at four different sites — two of them the
-SAME ``262144`` grown independently (``query/chain.py`` and
-``query/joinplan.py``).  This module is the deduplication: one table of
+The routes above the per-level hop (fused chain, chain-scan, fused
+recurse, MXU tile join), the per-level host-vs-device choice and the
+host-vs-device k-way intersection read their gates here: one table of
 documented defaults, one read path, and one override-detection helper
 (the adaptive planner in ``query/planner.py`` only substitutes its
 calibrated decision when the operator has NOT pinned the knob — an
-explicit env value or runtime assignment always wins).
+explicit env value or runtime assignment always wins).  Which device
+program expands a level is not a knob: ``query/engine.py``
+``DeviceExpander`` resolves it from the platform.
 
 The graftlint rule ``naked-route-threshold`` (analysis/rules.py) forbids
 raw ``DGRAPH_TPU_*`` env reads and naked numeric route-gate comparisons
@@ -45,33 +44,12 @@ DGRAPH_TPU_MXU_MASK_MAX     1<<22    largest frontier-mask lane count the
 DGRAPH_TPU_TILE               128    adjacency tile edge length (MXU-
                                      native 128; tests shrink it)
 DGRAPH_TPU_TILE_BUDGET      1<<28    per-arena densified-tile byte budget
-DGRAPH_TPU_FUSED_HOP          "1"    classed-gather hop programs: 0 never
-                                     / 1 auto (cpu backend) / force
-DGRAPH_TPU_EXPAND_IMPL      "scan"   expand_csr owner-computation kernel
-                                     strategy (see ops/sets.py)
-DGRAPH_TPU_CLASS_W_MAX         10    widest classed-gather degree class
-                                     (log2); heavier rows take the dense
-                                     residual route (ops/batch.py)
 DGRAPH_TPU_CALIBRATION_FILE  scratch/planner_calib.json
                                      persisted micro-calibration (warm
                                      boots skip the measurement pass)
 DGRAPH_TPU_CALIBRATE          "0"    "1" re-measures at server boot and
                                      re-persists (stale-calibration
                                      remedy); default boots load the file
-DGRAPH_TPU_RESIDENT           "1"    device-resident Pallas hop tier
-                                     (query/engine.py route:resident):
-                                     0 never / 1 auto (TPU backend only
-                                     — CPU serving stays byte-identical
-                                     to the staged routes) / force
-                                     (any backend, interpret kernels on
-                                     CPU; the parity-test mode)
-DGRAPH_TPU_SLOTMAP            "1"    Pallas slot-map kernel in grouped
-                                     inline expansions (ops/sets.py
-                                     expand_inline_grouped_auto): 0 / 1
-                                     XLA scan/scatter (auto selects the
-                                     kernel nowhere while the TPU
-                                     compiler refuses it) / force (any
-                                     backend, interpret mode on CPU)
 DGRAPH_TPU_IVM_REPAIR         "1"    IVM delta repair of cached hop
                                      entries / tile blocks: 0 drop-only /
                                      1 cost-gated / force (skip the
@@ -98,13 +76,7 @@ DGRAPH_TPU_SEGMENT_K           4     steps (hop levels / scan iterations /
 
 Reads happen per call (not at import) so tests can flip knobs with
 monkeypatch and a long-lived process picks up operator edits on the
-next decision — EXCEPT the program-shape constants, which are bound
-once when their kernel module imports and are documented as such at
-the binding site: ``DGRAPH_TPU_CLASS_W_MAX`` (ops/batch.py LOG_W_MAX —
-the degree-class split is baked into every compiled hop program; a
-per-call read would churn the jit cache) and ``DGRAPH_TPU_EXPAND_IMPL``
-(ops/sets.py — same property, pre-existing behavior).  Set those in the
-environment before the first dgraph_tpu.ops import.
+next decision.
 """
 
 from __future__ import annotations
@@ -121,7 +93,6 @@ CHAIN_MAX_CAPC_LIGHT_DEFAULT = 1 << 23
 MXU_MASK_MAX_DEFAULT = 1 << 22
 TILE_DEFAULT = 128
 TILE_BUDGET_DEFAULT = 1 << 28
-CLASS_W_MAX_DEFAULT = 10
 CALIBRATION_FILE_DEFAULT = "scratch/planner_calib.json"
 IVM_REPAIR_MAX_DELTA_DEFAULT = 512
 SEGMENT_K_DEFAULT = 4
@@ -203,39 +174,6 @@ def tile_size() -> int:
 def tile_budget() -> int:
     """Per-arena densified-tile byte budget."""
     return _int("DGRAPH_TPU_TILE_BUDGET", TILE_BUDGET_DEFAULT)
-
-
-def fused_hop() -> str:
-    """DGRAPH_TPU_FUSED_HOP: classed-gather hop gate ('0'/'1'/'force')."""
-    return os.environ.get("DGRAPH_TPU_FUSED_HOP", "1")
-
-
-def resident() -> str:
-    """DGRAPH_TPU_RESIDENT: device-resident hop tier gate ('0' never /
-    '1' auto: TPU backend only, so default CPU serving never diverges
-    from the staged routes / 'force': any backend — Pallas interpret
-    mode on CPU, the mode the parity tests pin)."""
-    return os.environ.get("DGRAPH_TPU_RESIDENT", "1")
-
-
-def slotmap_pallas() -> str:
-    """DGRAPH_TPU_SLOTMAP: grouped-expansion slot-map backend ('0' and
-    the default '1': the XLA scan/scatter chain — auto selects the
-    Pallas kernel nowhere while the TPU compiler refuses it / 'force':
-    the Pallas kernel on any backend, interpret mode on CPU — the mode
-    the parity tests pin)."""
-    return os.environ.get("DGRAPH_TPU_SLOTMAP", "1")
-
-
-def expand_impl() -> str:
-    """expand_csr owner-computation strategy (ops/sets.py)."""
-    return os.environ.get("DGRAPH_TPU_EXPAND_IMPL", "scan")
-
-
-def class_w_max() -> int:
-    """Widest classed-gather degree class (log2 width); rows above it
-    route to the dense residual bucket."""
-    return _int("DGRAPH_TPU_CLASS_W_MAX", CLASS_W_MAX_DEFAULT)
 
 
 def calibration_file() -> str:

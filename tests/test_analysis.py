@@ -1517,7 +1517,7 @@ def test_program_factory_live_coverage_names_real_sites():
         "dgraph_tpu/ops/sets.py::intersect_many",
         "dgraph_tpu/ops/batch.py::_multi_hop_jit",
         "dgraph_tpu/ops/spgemm.py::run_mask_chain",
-        "dgraph_tpu/ops/pallas_slotmap.py::slotmap_pallas",
+        "dgraph_tpu/ops/pallas_gather.py::gather_pallas_packed",
         "dgraph_tpu/query/chain.py::_run_fused",
         "dgraph_tpu/utils/calibrate.py::measure.gather",
     ):

@@ -2,11 +2,9 @@
 
 Seeded-random agreement tests between the batched [B, L] kernels and the
 scalar sets.py ops across ragged valid-lengths, empty sets, and all-SENT
-rows; classed-gather expansion vs the host CSR reference (including
-heavy rows beyond the widest gather class); the lax.scan multi-hop
-driver vs a host BFS; goldens with the fused engine path forced on and
-off; and the jit-cache bound of the classed hop programs (one compiled
-program per bucketed capacity tuple).
+rows; the lax.scan multi-hop driver vs a host BFS; and goldens with
+every level on the device route and chains fused against every level on
+the host route and no chain.
 """
 
 import numpy as np
@@ -15,9 +13,8 @@ import pytest
 import jax.numpy as jnp
 
 from dgraph_tpu import ops
-from dgraph_tpu.ops import batch as bops
 from dgraph_tpu.ops.sets import SENT
-from dgraph_tpu.models.arena import csr_dense_from_edges, csr_from_edges
+from dgraph_tpu.models.arena import csr_dense_from_edges
 
 
 def _rand_set(rng, lo, hi, max_n, L):
@@ -146,65 +143,6 @@ def test_expand_filter_compact_batch_matches_scalar(graph):
         assert int(tb[i]) == int(ts)
 
 
-def test_classed_expand_rows_vs_host(graph):
-    a = graph
-    ce = ops.classed_for_arena(a)
-    assert ce.n_cls == bops.LOG_W_MAX + 1  # heavy row present
-    rng = np.random.default_rng(4)
-    for trial in range(12):
-        f = np.unique(rng.integers(1, 601, size=int(rng.integers(1, 200))))
-        if trial == 0:
-            f = np.array([17], dtype=np.int64)  # the heavy row alone
-        if trial == 1:
-            f = np.empty(0, dtype=np.int64)
-        rows = f
-        want, want_ptr = a.expand_host(rows)
-        got, got_ptr = ce.expand_rows(rows, a.degree_of_rows(rows))
-        assert np.array_equal(got_ptr, want_ptr), trial
-        assert np.array_equal(got, want), trial
-
-
-def test_classed_expand_rows_sparse_arena():
-    """Non-dense arena (searchsorted rows, missing uids → -1 rows)."""
-    rng = np.random.default_rng(8)
-    src = rng.integers(1, 1000, size=2000)
-    dst = rng.integers(1, 1000, size=2000)
-    a = csr_from_edges(src, dst)
-    ce = ops.classed_for_arena(a)
-    for _ in range(6):
-        uids = np.unique(rng.integers(1, 1000, size=80))
-        rows = a.rows_for_uids_host(uids)  # ascending with -1 misses
-        want, want_ptr = a.expand_host(rows)
-        got, got_ptr = ce.expand_rows(rows, a.degree_of_rows(rows))
-        assert np.array_equal(got_ptr, want_ptr)
-        assert np.array_equal(got, want)
-
-
-def test_program_cache_bound(graph):
-    """The fused 2-hop path compiles at most one program per bucketed
-    capacity tuple per mode — a steady shape family reuses its programs
-    instead of blowing the jit cache (ISSUE acceptance guard)."""
-    a = graph
-    a._classed = None  # fresh expander, empty program cache
-    ce = ops.classed_for_arena(a)
-    rng = np.random.default_rng(6)
-    cap_keys = set()
-    for _ in range(20):  # one shape family: same seed-count regime
-        f = np.unique(rng.integers(1, 601, size=64))
-        f1_out, _ = a.expand_host(f)
-        f1 = np.unique(f1_out)
-        for frontier in (f, f1):
-            counts, nh, he = ce.class_counts(frontier)
-            caps = ce.plan_caps(counts, nh, he, fine=False)
-            cap_keys.add(caps)
-            prog = ce.program(caps, "materialize")
-            mats, _pos = ce.partition(frontier, caps)
-            prog(tuple(jnp.asarray(m) for m in mats), ())
-    # ≤ one compiled program per distinct bucketed capacity tuple
-    assert len(ce._programs) <= len(cap_keys)
-    assert len(cap_keys) <= 8, cap_keys  # bucketing really is coarse
-
-
 # ------------------------------------------------------------- multi-hop
 
 
@@ -277,7 +215,7 @@ def test_mesh_batched_frontiers(graph):
         assert totals[i, 0] == len(o1) and totals[i, 1] == len(o2)
 
 
-# ------------------------------------------- engine: fused on/off goldens
+# ------------------------------------------- engine: device/host goldens
 
 
 @pytest.fixture(scope="module")
@@ -324,21 +262,19 @@ GOLDEN_QUERIES = [
 
 
 @pytest.mark.parametrize("qi", range(len(GOLDEN_QUERIES)))
-def test_goldens_fused_on_off(store, qi):
-    """The fused batched path (forced on, chains enabled) and the legacy
-    per-op path (forced off, chains disabled) must produce identical
-    responses."""
+def test_goldens_device_vs_host(store, qi):
+    """Every level on the device route with chains fused, and every level
+    on the host route with no chain, must produce identical responses."""
     from dgraph_tpu.query import QueryEngine
 
     q = GOLDEN_QUERIES[qi]
-    on = QueryEngine(store)
-    on.expander.fused_hop = "force"
-    on.expand_device_min = 0
-    on.chain_threshold = 0
-    off = QueryEngine(store)
-    off.expander.fused_hop = "0"
-    off.chain_threshold = 1 << 62
-    assert on.run(q) == off.run(q)
+    dev = QueryEngine(store)
+    dev.expand_device_min = 0
+    dev.chain_threshold = 0
+    host = QueryEngine(store)
+    host.expand_device_min = 1 << 62
+    host.chain_threshold = 1 << 62
+    assert dev.run(q) == host.run(q)
 
 
 def test_cascade_prune_vectorized(store):

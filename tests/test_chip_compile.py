@@ -122,14 +122,15 @@ def test_packed_expand_csr_compiles(one_chip, no_compile_cache):
     assert c.memory_analysis().output_size_in_bytes == 2 * CAP * 4
 
 
-def test_packed_expand_inline_compiles(one_chip, no_compile_cache):
+def test_expand_inline_seg_compiles(one_chip, no_compile_cache):
     from dgraph_tpu import ops
-    from dgraph_tpu.query import engine as qe
+    from dgraph_tpu.ops import sets
 
     s = _shape(one_chip)
-    # the arena at film width; frontier and overflow capacity cut 16x —
-    # at 2^16 rows / 2^19 chunks the same program takes the compiler 15 s
-    qe._packed_expand_inline.lower(
+    # the fused chain's posting gather, the arena at film width; frontier
+    # and overflow capacity cut 16x — at 2^16 rows / 2^19 chunks the same
+    # program takes the compiler 15 s
+    sets.expand_inline_seg.lower(
         s(ops.bucket(ROWS), 8), s(1 << 20, 8), s(FRONTIER // 16), capc=1 << 15
     ).compile()
 
@@ -175,36 +176,3 @@ def test_mesh_multi_hop_compiles_on_four_chips(mesh4, no_compile_cache):
     per_chip = c.memory_analysis().argument_size_in_bytes
     # a quarter of the arena plus the replicated frontier, give or take padding
     assert abs(per_chip - (arena_bytes // 4 + 4 * cap)) < 1 << 16, (per_chip, arena_bytes)
-
-
-# -- kernels the chip's compiler refuses (off every served path) ---------------
-#
-# strict xfail, carrying the compiler's message: the PR that repairs one
-# (ROADMAP S5) starts from it, and an unexpected pass fails the suite until
-# the marker — and the kernel's Status paragraph — are brought up to date.
-
-
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError,
-    reason="Unimplemented primitive in Pallas TPU lowering for "
-           "KernelType.TC: cumsum (ops/pallas_slotmap.py Status)",
-)
-def test_slotmap_pallas_refused(one_chip, no_compile_cache):
-    from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas
-
-    s = _shape(one_chip)
-    slotmap_pallas.lower(s(1, 4096), s(1, 4096), 1 << 16).compile()
-
-
-@pytest.mark.xfail(
-    strict=True,
-    reason="MosaicError: INTERNAL: Mosaic failed to compile TPU kernel: "
-           "cannot statically prove that index in dimension 0 is a "
-           "multiple of 1024, on the tpu.vector_store of the (128,) "
-           "survivor block (ops/pallas_intersect.py Status)",
-)
-def test_intersect_pallas_refused(one_chip, no_compile_cache):
-    from dgraph_tpu.ops.pallas_intersect import intersect_pallas
-
-    s = _shape(one_chip)
-    intersect_pallas.lower(s(4, 4096)).compile()

@@ -602,7 +602,12 @@ def test_delta_epoch_flip_races_inflight_expand_never_serves_stale():
     stop = threading.Event()
     errs = []
 
-    seen = {}  # epoch -> edge count served at that epoch
+    # (id(arena), epoch) -> edge count served at that epoch.  An epoch is
+    # one arena's: a full rebuild (a journal window lost to the storm)
+    # hands out a NEW arena that counts from 0 again.  ``alive`` keeps
+    # every arena met, so that no id is handed out twice
+    seen = {}
+    alive = {}
 
     def expander():
         while not stop.is_set():
@@ -613,12 +618,16 @@ def test_delta_epoch_flip_races_inflight_expand_never_serves_stale():
                 if arena.epoch != e0:
                     continue  # flip mid-read: no epoch to pin it to
                 n = len(out)
-                want = seen.setdefault(e0, n)
+                alive[id(arena)] = arena
+                want = seen.setdefault((id(arena), e0), n)
                 if n != want:
                     errs.append(
                         f"epoch {e0} served {n} edges, previously {want}"
                     )
-                prior = [v for k, v in seen.items() if k < e0]
+                prior = [
+                    v for (a, k), v in seen.items()
+                    if a == id(arena) and k < e0
+                ]
                 if prior and n < max(prior):
                     errs.append(
                         f"epoch {e0} served {n} < earlier epoch's "

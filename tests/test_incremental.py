@@ -105,28 +105,25 @@ def test_has_excludes_emptied_rows():
     assert [x["_uid_"] for x in got["q"]] == ["0x1"]
 
 
-def test_chunked_after_row_bucket_growth():
-    """ADVICE r3 (high): chunked() must size its meta from HOST state.
-    After apply_delta adds a new source row that crosses the power-of-two
-    row bucket, a fused chain calls a.chunked() without ensure_device() —
-    this used to crash broadcasting meta[:S] into a stale-bucket array."""
+def test_inline_layout_after_row_bucket_growth():
+    """inline_layout() must size its meta from HOST state.  After
+    apply_delta adds a new source row that crosses the power-of-two row
+    bucket, a fused chain calls a.inline_layout() without
+    ensure_device() — sizing from the stale device tensors would break
+    the metap[:S] broadcast."""
     st = PostingStore()
     am = ArenaManager(st)
     # exactly 8 rows -> row bucket 8
     st.bulk_set_uid_edges("e", np.arange(1, 9), np.arange(11, 19))
     a = am.data("e")
     assert a.n_rows == 8
-    a.chunked()  # build once at the old bucket
+    a.inline_layout()  # build once at the old bucket
     st.set_edge("e", 9, 19)  # 9th source row crosses the bucket
     a = am.data("e")
     assert a.n_rows == 9
-    meta8, chunk_dst = a.chunked()  # must not raise
-    assert meta8.shape[0] >= 9
-    # row 8 (uid 9) must be queryable through the chunked layout
-    import numpy as _np
-
-    m = _np.asarray(meta8)
-    row = int(_np.searchsorted(a.h_src, 9))
-    cs, cd, deg = m[row, 0], m[row, 1], m[row, 2]
-    assert (cd, deg) == (1, 1)
-    assert int(_np.asarray(chunk_dst)[cs, 0]) == 19
+    metap, _ov = a.inline_layout()  # must not raise
+    assert metap.shape[0] >= 9
+    # row 8 (uid 9) must be queryable through the inline layout
+    m = np.asarray(metap)
+    row = int(np.searchsorted(a.h_src, 9))
+    assert (int(m[row, 1]), int(m[row, 2])) == (1, 19)

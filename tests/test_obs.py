@@ -201,9 +201,7 @@ def test_single_node_trace_covers_scheduler_cache_engine(srv):
     hop = by_name["hop"]
     assert hop["attrs"]["pred"] == "follows"
     assert hop["attrs"]["edges"] == 1
-    assert hop["attrs"]["route"] in (
-        "host", "classed", "inline", "csr", "cache", "merged", "mesh"
-    )
+    assert hop["attrs"]["route"] in ("host", "csr", "cache", "merged", "mesh")
     # the engine span links to the shared cohort-flush span
     eng = by_name["engine"]
     flush = by_name["sched.flush"]

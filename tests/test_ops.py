@@ -149,113 +149,11 @@ def test_range_rows():
     np.testing.assert_array_equal(np.asarray(rows), [0, 1, 2, 3])
 
 
-def test_unique_rows_sorted():
-    import numpy as np
-    from dgraph_tpu import ops
-    from dgraph_tpu.ops.sets import SENT
-
-    rng = np.random.default_rng(11)
-    for n, cap in ((0, 8), (5, 8), (100, 128), (1000, 1024)):
-        vals = rng.integers(0, 50, size=n)
-        x = ops.pad_to(vals, cap)
-        got = np.asarray(ops.unique_rows_sorted(x))
-        kept = got[got >= 0]
-        assert np.array_equal(kept, np.unique(vals))
-        # valid entries ascend in place; everything else is the skip row
-        assert set(got.tolist()) - set(kept.tolist()) == ({-1} if (cap > n or len(kept) < n) else set())
-
-
-def test_expand_chunked(rng):
-    """Chunked expansion == element-level reference, incl. seg owners.
-
-    Rows must be ascending-distinct with -1 skips (the contract the
-    kernel's telescoping construction relies on; see ops/sets.py).
-    """
-    from dgraph_tpu.models.arena import csr_from_edges
-
-    for trial in range(15):
-        n_src = int(rng.integers(1, 40))
-        n_edges = int(rng.integers(0, 300))
-        src = rng.integers(0, n_src, size=n_edges)
-        dst = rng.integers(0, 500, size=n_edges)
-        a = csr_from_edges(src, dst)
-        meta8, chunk_dst = a.chunked()
-        # ascending distinct rows with -1 skips sprinkled in
-        nrows = a.n_rows
-        pick = np.unique(rng.integers(0, max(1, nrows), size=rng.integers(0, 8)))
-        pick = pick[pick < nrows]
-        rows = []
-        for r in pick:
-            if rng.random() < 0.3:
-                rows.append(-1)
-            rows.append(r)
-        rows = np.array(rows + [-1] * int(rng.integers(0, 3)), dtype=np.int32)
-        B = ops.bucket(max(1, len(rows)))
-        rows_p = np.full(B, -1, dtype=np.int32)
-        rows_p[: len(rows)] = rows
-        want = ref.expand_csr(
-            a.h_offsets.astype(np.int32),
-            np.asarray(a.dst)[: a.n_edges],
-            rows,
-        )
-        capc = ops.bucket(int(a.chunk_degree_of_rows(rows).sum()) or 1)
-        out, total, seg = ops.expand_chunked(meta8, chunk_dst, rows_p, capc, with_seg=True)
-        out, seg = np.asarray(out), np.asarray(seg)
-        assert int(total) == len(want)
-        flat = out.reshape(-1)
-        np.testing.assert_array_equal(np.sort(flat[flat != SENT]), np.sort(want))
-        # per-slot owners: expand each chunk-slot owner to its valid lanes
-        lane_owner = np.repeat(seg, ops.CHUNK)
-        valid = flat != SENT
-        want_seg = np.concatenate(
-            [
-                np.full(int(a.h_offsets[r + 1] - a.h_offsets[r]), i)
-                for i, r in enumerate(rows_p)
-                if r >= 0
-            ]
-            or [np.empty(0, dtype=np.int64)]
-        )
-        # group uids by owner and compare as multisets per owner
-        got_pairs = sorted(zip(lane_owner[valid].tolist(), flat[valid].tolist()))
-        want_pairs = sorted(zip(want_seg.tolist(), want.tolist()))
-        assert got_pairs == want_pairs
-
-
-def test_expand_chunked_two_hop_matches_scalar(rng):
-    """Whole 2-hop chunked pipeline == numpy unique/expand semantics."""
-    from dgraph_tpu.models.arena import csr_dense_from_edges
-
-    n_nodes = 200
-    src = rng.integers(1, n_nodes + 1, size=2000)
-    dst = rng.integers(1, n_nodes + 1, size=2000)
-    a = csr_dense_from_edges(src, dst, n_nodes)
-    meta8, chunk_dst = a.chunked()
-    h_dst = np.asarray(a.dst)[: a.n_edges]
-    frontier = np.unique(rng.integers(1, n_nodes + 1, size=30))
-
-    out1 = ref.expand_csr(a.h_offsets.astype(np.int32), h_dst, frontier)
-    f1 = np.unique(out1)
-    out2 = ref.expand_csr(a.h_offsets.astype(np.int32), h_dst, f1)
-    want_edges = len(out1) + len(out2)
-
-    fcap = ops.bucket(len(frontier))
-    capc1 = ops.bucket(int(a.chunk_degree_of_rows(frontier).sum()) or 1)
-    capc2 = ops.bucket(int(a.chunk_degree_of_rows(f1).sum()) or 1)
-    rows0 = ops.frontier_rows(ops.pad_to(frontier, fcap))
-    o1, t1, _ = ops.expand_chunked(meta8, chunk_dst, rows0, capc1)
-    rows1 = ops.unique_rows_sorted(o1.reshape(-1))
-    o2, t2, _ = ops.expand_chunked(meta8, chunk_dst, rows1, capc2)
-    assert int(t1) + int(t2) == want_edges
-    flat = np.asarray(ops.sort_unique(o2.reshape(-1)))
-    got = flat[flat != SENT]
-    np.testing.assert_array_equal(got, np.unique(out2))
-
-
-def test_expand_inline_matches_reference():
-    """expand_inline (inline-head layout) reproduces the reference CSR
+def test_expand_inline_seg_degree_boundaries():
+    """expand_inline_seg (inline-head layout) reproduces the reference CSR
     expansion exactly: inline ∪ overflow lanes = the row's full target
     multiset, totals exact, -1 skips honored, across degree edge cases
-    (0, 1, INLINE, INLINE+1, INLINE+8, big)."""
+    (1, INLINE, INLINE+1, INLINE+8, big)."""
     import numpy as np
     import jax
     from dgraph_tpu import ops
@@ -278,7 +176,9 @@ def test_expand_inline_matches_reference():
     rows = np.array([0, -1, 1, 2, 3, -1, 4, 5, 6, 7, 8, -1], np.int32)
     capc = int(a.ov_chunk_degree_of_rows(rows).sum()) or 1
     capc = ops.bucket_fine(capc)
-    inline, ovout, total = ops.expand_inline(metap, ov, jax.device_put(rows), capc)
+    inline, ovout, total, _ovseg = ops.expand_inline_seg(
+        metap, ov, jax.device_put(rows), capc
+    )
     inline, ovout = np.asarray(inline), np.asarray(ovout)
     got = np.concatenate([inline.reshape(-1), ovout.reshape(-1)])
     got = np.sort(got[got != SENT])
@@ -308,87 +208,6 @@ def test_bucket_fine_steps():
         assert n <= b <= bucket(n)
         assert b - n <= max(1, b >> 3)
     assert bucket_fine(3) == 8  # floor
-
-
-def test_expand_inline_grouped_matches_reference():
-    """Grouped (skey) expansion == plain expansion after decode: the
-    group bit only reorders work, never changes the produced multiset."""
-    import numpy as np
-    import jax
-    from dgraph_tpu import ops
-    from dgraph_tpu.models.arena import csr_dense_from_edges
-    from dgraph_tpu.ops.sets import SENT, GROUP_MASK
-
-    rng = np.random.default_rng(5)
-    n = 500
-    src = rng.integers(1, n, size=4000)
-    dst = rng.integers(1, n, size=4000)
-    a = csr_dense_from_edges(src, dst, n)
-    metap, ov = a.inline_layout_grouped()
-    deg = (a.h_offsets[1:] - a.h_offsets[:-1])
-    f = np.unique(rng.integers(1, n, size=64))
-    key = np.asarray(ops.skey_encode(f, deg[f] > ops.INLINE))
-    f = f[np.argsort(key)]
-    pcap = ops.bucket_fine(int((deg[f] > ops.INLINE).sum()))
-    capc = ops.bucket_fine(int(a.ov_chunk_degree_of_rows(f).sum()) or 1)
-    rows = jax.device_put(np.asarray(f, np.int32))
-    inline, ovout, total = ops.expand_inline_grouped(metap, ov, rows, capc, pcap)
-    got = np.concatenate([np.asarray(inline).reshape(-1), np.asarray(ovout).reshape(-1)])
-    got = got[got != SENT] & int(GROUP_MASK)
-    want, _ = a.expand_host(f)
-    assert int(total) == len(want)
-    assert np.array_equal(np.sort(got), np.sort(want.astype(np.int32)))
-
-
-def test_grouped_layout_above_4m_uids():
-    """The grouped fast path must survive uid spaces beyond the OLD
-    2^22 (~4.2M) ceiling — full-Freebase-scale predicates hit that on day
-    one.  GROUP_BIT is now 29 (536M uids); this pins the cliff fix by
-    exercising uids straddling 2^22, including overflow rows up there."""
-    import numpy as np
-    import jax
-    from dgraph_tpu import ops
-    from dgraph_tpu.models.arena import csr_dense_from_edges
-    from dgraph_tpu.ops.sets import SENT, GROUP_MASK, GROUP_BIT
-
-    assert (1 << GROUP_BIT) > 4_500_000  # the cliff itself is gone
-    rng = np.random.default_rng(11)
-    n = 4_500_000  # > old 2^22 cap
-    lo, hi = (1 << 22) - 64, n  # cluster activity around/above the old cliff
-    src = rng.integers(lo, hi, size=6000)
-    src[:1500] = (1 << 22) + 17  # a fat overflow row ABOVE the old cap
-    dst = rng.integers(lo, hi, size=6000)
-    a = csr_dense_from_edges(src, dst, n)
-    metap, ov = a.inline_layout_grouped()  # must NOT raise ValueError
-    deg = a.h_offsets[1:] - a.h_offsets[:-1]
-    f = np.unique(rng.integers(lo, hi, size=128))
-    f = np.append(f, (1 << 22) + 17)
-    key = np.asarray(ops.skey_encode(f, deg[f] > ops.INLINE))
-    f = f[np.argsort(key)]
-    pcap = ops.bucket_fine(int((deg[f] > ops.INLINE).sum()) or 1)
-    capc = ops.bucket_fine(int(a.ov_chunk_degree_of_rows(f).sum()) or 1)
-    rows = jax.device_put(np.asarray(f, np.int32))
-    inline, ovout, total = ops.expand_inline_grouped(metap, ov, rows, capc, pcap)
-    got = np.concatenate(
-        [np.asarray(inline).reshape(-1), np.asarray(ovout).reshape(-1)]
-    )
-    got = got[got != SENT] & int(GROUP_MASK)
-    want, _ = a.expand_host(f)
-    assert int(total) == len(want)
-    assert np.array_equal(np.sort(got), np.sort(want.astype(np.int32)))
-
-
-def test_skey_encode_no_sent_collision():
-    """Max-uid no-overflow skey must stay strictly below SENT (the bit
-    budget documented at GROUP_BIT: 2^30 - 1 < 2^31 - 1)."""
-    import numpy as np
-    from dgraph_tpu import ops
-    from dgraph_tpu.ops.sets import SENT, GROUP_BIT
-
-    top = np.array([(1 << GROUP_BIT) - 1], np.int64)
-    enc = ops.skey_encode(top, np.array([False]))
-    assert 0 < int(enc[0]) < SENT
-    assert int(np.asarray(ops.skey_uid(enc))[0]) == (1 << GROUP_BIT) - 1
 
 
 def test_expand_inline_seg_owners():

@@ -1,24 +1,18 @@
 """Pallas kernel tier parity suite (`pallas-interpret` CI job).
 
-Every kernel is pinned byte-identical against TWO references — a pure
-numpy/python oracle AND the XLA program it replaces:
-
-- slot-map (ops/pallas_slotmap.py) vs slotmap_reference + _ov_slot_map,
-  promoted behind DGRAPH_TPU_SLOTMAP (expand_inline_grouped_auto);
-- segment-gather (ops/pallas_gather.py) vs gather_reference +
-  expand_csr, over the real ResidentArena slack-padded layout;
-- k-way intersect (ops/pallas_intersect.py) vs intersect_reference +
-  intersect_many, k in {2, 4, 8}.
+The segment-gather kernel (ops/pallas_gather.py) is pinned byte-identical
+against TWO references — the pure numpy oracle gather_reference AND
+expand_csr, the XLA program every other backend runs — over the real
+ResidentArena slack-padded layout.
 
 Runs in Pallas interpret mode (CPU backend, like the rest of the suite).
-Interpret mode skips Mosaic lowering: TPU compilation is intended but
-unverified until the next real-chip session (see the kernel docstrings).
+Interpret mode skips Mosaic lowering: tests/test_chip_compile.py compiles
+the kernel for a described v5e.
 """
 
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 # the pallas-interpret CI job re-runs this module on its own (these
@@ -26,193 +20,6 @@ import jax.numpy as jnp
 pytestmark = pytest.mark.pallas_interpret
 
 
-def _grouped_case(rng, n_rows, pcap):
-    """Random grouped-prefix inputs: strictly-ascending chunk starts for
-    n_rows productive rows (cd >= 1), zero-padded to pcap."""
-    cd = rng.integers(1, 6, size=n_rows).astype(np.int32)
-    gaps = rng.integers(0, 3, size=n_rows).astype(np.int64)
-    cs = np.zeros(n_rows, dtype=np.int32)
-    nxt = 0
-    for i in range(n_rows):
-        nxt += int(gaps[i])
-        cs[i] = nxt
-        nxt += int(cd[i])
-    csp = np.zeros(pcap, np.int32)
-    cdp = np.zeros(pcap, np.int32)
-    csp[:n_rows] = cs
-    cdp[:n_rows] = cd
-    return csp, cdp
-
-
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_slotmap_pallas_matches_reference(seed):
-    from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas, slotmap_reference
-
-    rng = np.random.default_rng(seed)
-    pcap, capc, q = 256, 512, 3
-    css, cds, want = [], [], []
-    for i in range(q):
-        n = int(rng.integers(1, pcap // 2))
-        cs, cd = _grouped_case(rng, n, pcap)
-        css.append(cs)
-        cds.append(cd)
-        want.append(slotmap_reference(cs[:n], cd[:n], capc))
-    got = np.asarray(
-        slotmap_pallas(
-            jnp.asarray(np.stack(css)), jnp.asarray(np.stack(cds)), capc,
-            interpret=True,
-        )
-    )
-    for i in range(q):
-        assert np.array_equal(got[i], want[i]), i
-
-
-def test_slotmap_pallas_matches_xla_slotmap():
-    """The kernel and the production XLA scatter/scan construction agree
-    on the same inputs (chunkid equality on the valid span)."""
-    from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas
-    from dgraph_tpu.ops.sets import _ov_slot_map
-
-    rng = np.random.default_rng(7)
-    pcap, capc = 128, 256
-    cs, cd = _grouped_case(rng, 50, pcap)
-    chunkid, ok, _cstart, _prod = jax.jit(
-        lambda c, d: _ov_slot_map(c, d, capc), static_argnums=()
-    )(jnp.asarray(cs), jnp.asarray(cd))
-    xla = np.where(np.asarray(ok), np.asarray(chunkid), -1)
-    pal = np.asarray(
-        slotmap_pallas(
-            jnp.asarray(cs[None, :]), jnp.asarray(cd[None, :]), capc,
-            interpret=True,
-        )
-    )[0]
-    assert np.array_equal(pal, xla)
-
-
-def test_expand_inline_grouped_pallas_matches_xla():
-    """The integrated Pallas-backed grouped expansion (what BENCH_PALLAS=1
-    runs) produces exactly the XLA path's outputs on real arena data."""
-    from dgraph_tpu import ops
-    from dgraph_tpu.models.arena import csr_dense_from_edges
-    from dgraph_tpu.ops.sets import SENT
-
-    rng = np.random.default_rng(9)
-    n = 800
-    src = rng.integers(1, n, size=9000)
-    dst = rng.integers(1, n, size=9000)
-    a = csr_dense_from_edges(src, dst, n)
-    metap, ov = a.inline_layout_grouped()
-    deg = a.h_offsets[1:] - a.h_offsets[:-1]
-    f = np.unique(rng.integers(1, n, size=96))
-    key = np.asarray(ops.skey_encode(f, deg[f] > ops.INLINE))
-    f = f[np.argsort(key)]
-    pcap = ops.bucket_fine(int((deg[f] > ops.INLINE).sum()) or 1)
-    capc = ops.bucket_fine(int(a.ov_chunk_degree_of_rows(f).sum()) or 1)
-    rows = jax.device_put(np.asarray(f, np.int32))
-    want = ops.expand_inline_grouped(metap, ov, rows, capc, pcap)
-    got = ops.expand_inline_grouped_pallas(metap, ov, rows, capc, pcap)
-    for w, g in zip(want, got):
-        assert np.array_equal(np.asarray(w), np.asarray(g))
-
-
-def test_expand_inline_grouped_pallas_under_vmap():
-    """bench.py vmaps the expansion over a query batch: the Pallas path
-    must survive the batching rule with unchanged outputs."""
-    from dgraph_tpu import ops
-    from dgraph_tpu.models.arena import csr_dense_from_edges
-
-    rng = np.random.default_rng(13)
-    n = 400
-    src = rng.integers(1, n, size=4000)
-    dst = rng.integers(1, n, size=4000)
-    a = csr_dense_from_edges(src, dst, n)
-    metap, ov = a.inline_layout_grouped()
-    deg = a.h_offsets[1:] - a.h_offsets[:-1]
-    B = 4
-    frontiers = []
-    for _ in range(B):
-        f = np.unique(rng.integers(1, n, size=48))
-        key = np.asarray(ops.skey_encode(f, deg[f] > ops.INLINE))
-        frontiers.append(ops.pad_to(f[np.argsort(key)].astype(np.int32), 64))
-    rowsb = jnp.asarray(np.stack(frontiers))
-    rowsb = jnp.where(rowsb == ops.SENT, -1, rowsb)
-    pcap, capc = 64, 512
-
-    xla = jax.vmap(
-        lambda r: ops.expand_inline_grouped(metap, ov, r, capc, pcap)
-    )(rowsb)
-    pal = jax.vmap(
-        lambda r: ops.expand_inline_grouped_pallas(metap, ov, r, capc, pcap)
-    )(rowsb)
-    for w, g in zip(xla, pal):
-        assert np.array_equal(np.asarray(w), np.asarray(g))
-
-
-@pytest.mark.parametrize("total_target", [127, 128, 129, 255, 256, 257, 383])
-def test_slotmap_pallas_block_boundaries(total_target):
-    """Totals straddling the 128-slot block boundary: the per-block
-    prefix/window logic must hand off exactly at multiples of 128."""
-    from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas, slotmap_reference
-
-    rng = np.random.default_rng(total_target)
-    pcap, capc = 256, 512
-    cs = []
-    cd = []
-    nxt = 0
-    total = 0
-    while total < total_target:
-        d = int(rng.integers(1, 5))
-        d = min(d, total_target - total)
-        gap = int(rng.integers(0, 2))
-        nxt += gap
-        cs.append(nxt)
-        cd.append(d)
-        nxt += d
-        total += d
-    csp = np.zeros(pcap, np.int32)
-    cdp = np.zeros(pcap, np.int32)
-    csp[: len(cs)] = cs
-    cdp[: len(cd)] = cd
-    got = np.asarray(
-        slotmap_pallas(jnp.asarray(csp[None]), jnp.asarray(cdp[None]), capc,
-                       interpret=True)
-    )[0]
-    want = slotmap_reference(csp[: len(cs)], cdp[: len(cd)], capc)
-    assert np.array_equal(got, want)
-
-
-def test_slotmap_pallas_dense_and_edge_cases():
-    from dgraph_tpu.ops.pallas_slotmap import slotmap_pallas, slotmap_reference
-
-    pcap, capc = 128, 256
-    # dense: no gaps, all cd=1 (identity mapping)
-    cs = np.arange(pcap, dtype=np.int32)
-    cd = np.ones(pcap, np.int32)
-    got = np.asarray(
-        slotmap_pallas(jnp.asarray(cs[None]), jnp.asarray(cd[None]), capc,
-                       interpret=True)
-    )[0]
-    assert np.array_equal(got, slotmap_reference(cs, cd, capc))
-    # single giant row spanning several blocks
-    cs2 = np.zeros(pcap, np.int32)
-    cd2 = np.zeros(pcap, np.int32)
-    cs2[0], cd2[0] = 17, 200
-    got = np.asarray(
-        slotmap_pallas(jnp.asarray(cs2[None]), jnp.asarray(cd2[None]), capc,
-                       interpret=True)
-    )[0]
-    assert np.array_equal(got, slotmap_reference(cs2[:1], cd2[:1], capc))
-    # empty prefix: everything -1
-    z = np.zeros(pcap, np.int32)
-    got = np.asarray(
-        slotmap_pallas(jnp.asarray(z[None]), jnp.asarray(z[None]), capc,
-                       interpret=True)
-    )[0]
-    assert (got == -1).all()
-
-
-# ----------------------------------------------------- segment-gather kernel
-#
 # gather_pallas walks a ResidentArena-layout CSR (SENT slack-padded dst,
 # bucketed offsets) — every case below runs the kernel over the REAL
 # seeded layout and byte-compares against BOTH the pure-numpy oracle
@@ -345,72 +152,6 @@ def test_gather_pallas_packed_layout():
     assert np.array_equal(packed[cap:], np.asarray(seg))
 
 
-# ------------------------------------------------------ k-way intersect
-
-
-def _sets_case(rng, k, L, universe, density):
-    """k sorted-unique SENT-padded rows with a controllable overlap."""
-    from dgraph_tpu import ops
-
-    rows = []
-    for _ in range(k):
-        m = int(rng.integers(1, max(2, int(L * density))))
-        rows.append(ops.pad_to(
-            np.unique(rng.integers(0, universe, size=m)).astype(np.int32), L
-        ))
-    return np.stack([np.asarray(r) for r in rows])
-
-
-@pytest.mark.parametrize("k", [2, 4, 8])
-@pytest.mark.parametrize("seed", [0, 1])
-def test_intersect_pallas_matches_reference_and_xla(k, seed):
-    from dgraph_tpu import ops
-
-    rng = np.random.default_rng(10 * k + seed)
-    # small universe → dense overlap; large → sparse/empty results
-    for universe in (40, 5000):
-        mat = _sets_case(rng, k, 192, universe, 0.8)
-        got = np.asarray(ops.intersect_pallas(jnp.asarray(mat),
-                                              interpret=True))
-        want = ops.intersect_reference(mat)
-        valid = got[got != ops.SENT]
-        assert valid.tolist() == list(want)
-        assert (got[len(valid):] == ops.SENT).all()
-        xla = np.asarray(ops.intersect_many(jnp.asarray(mat)))
-        assert np.array_equal(got, xla)
-
-
-def test_intersect_pallas_empty_set_annihilates():
-    """One all-SENT row forces an empty intersection regardless of the
-    other lanes — and an ALL-empty stack stays empty."""
-    from dgraph_tpu import ops
-
-    rng = np.random.default_rng(11)
-    mat = _sets_case(rng, 4, 128, 30, 0.9)
-    mat[2, :] = ops.SENT
-    got = np.asarray(ops.intersect_pallas(jnp.asarray(mat), interpret=True))
-    assert (got == ops.SENT).all()
-    assert np.array_equal(
-        got, np.asarray(ops.intersect_many(jnp.asarray(mat)))
-    )
-    allempty = np.full((8, 256), ops.SENT, np.int32)
-    got = np.asarray(
-        ops.intersect_pallas(jnp.asarray(allempty), interpret=True)
-    )
-    assert (got == ops.SENT).all()
-
-
-def test_intersect_pallas_identical_rows_roundtrip():
-    from dgraph_tpu import ops
-
-    s = np.unique(np.arange(0, 500, 7, dtype=np.int32))
-    row = np.asarray(ops.pad_to(s, 128))
-    mat = np.stack([row] * 8)
-    got = np.asarray(ops.intersect_pallas(jnp.asarray(mat), interpret=True))
-    assert got[: len(s)].tolist() == s.tolist()
-    assert (got[len(s):] == ops.SENT).all()
-
-
 # -------------------------------------------- program-count discipline
 
 
@@ -427,50 +168,9 @@ def test_repeat_shapes_compile_zero_new_programs():
     a, ra = _seeded_csr(rng, 300, 4000)
     f = np.unique(rng.integers(0, a.n_rows, size=40)).astype(np.int64)
     rows = jnp.asarray(ops.pad_rows(f, 64).astype(np.int32))
-    mat = jnp.asarray(_sets_case(rng, 4, 128, 60, 0.8))
-    # warm every program once (compiles allowed here)
+    # warm the program once (compiles allowed here)
     ops.gather_pallas_packed(ra.off, ra.dst, rows, 4096, interpret=True)
-    ops.intersect_pallas(mat, interpret=True)
     c0 = compile_count()
     for _ in range(3):
         ops.gather_pallas_packed(ra.off, ra.dst, rows, 4096, interpret=True)
-        ops.intersect_pallas(mat, interpret=True)
     assert compile_count() == c0, "repeat shapes recompiled"
-
-
-# ------------------------------------- slot-map promotion (DGRAPH_TPU_SLOTMAP)
-
-
-def test_grouped_auto_force_matches_xla(monkeypatch):
-    """expand_inline_grouped_auto under DGRAPH_TPU_SLOTMAP=force (the
-    parity-test mode) is byte-identical to the XLA grouped path on real
-    arena data; '0' pins the XLA path; '1' on CPU stays XLA (the
-    backend gate)."""
-    from dgraph_tpu import ops
-    from dgraph_tpu.models.arena import csr_dense_from_edges
-
-    rng = np.random.default_rng(21)
-    n = 600
-    src = rng.integers(1, n, size=7000)
-    dst = rng.integers(1, n, size=7000)
-    a = csr_dense_from_edges(src, dst, n)
-    metap, ov = a.inline_layout_grouped()
-    deg = a.h_offsets[1:] - a.h_offsets[:-1]
-    f = np.unique(rng.integers(1, n, size=80))
-    key = np.asarray(ops.skey_encode(f, deg[f] > ops.INLINE))
-    f = f[np.argsort(key)]
-    pcap = ops.bucket_fine(int((deg[f] > ops.INLINE).sum()) or 1)
-    capc = ops.bucket_fine(int(a.ov_chunk_degree_of_rows(f).sum()) or 1)
-    rows = jax.device_put(np.asarray(f, np.int32))
-    want = ops.expand_inline_grouped(metap, ov, rows, capc, pcap)
-
-    monkeypatch.setenv("DGRAPH_TPU_SLOTMAP", "force")
-    assert ops.use_slotmap_pallas() is True
-    got = ops.expand_inline_grouped_auto(metap, ov, rows, capc, pcap)
-    for w, g in zip(want, got):
-        assert np.array_equal(np.asarray(w), np.asarray(g))
-
-    monkeypatch.setenv("DGRAPH_TPU_SLOTMAP", "0")
-    assert ops.use_slotmap_pallas() is False
-    monkeypatch.setenv("DGRAPH_TPU_SLOTMAP", "1")
-    assert ops.use_slotmap_pallas() is False  # CPU backend: auto = off

@@ -57,12 +57,8 @@ def _jnp():
 def test_registry_has_ten_plus_full_contracts():
     full = [c for c in programs.REGISTRY.values() if not c.experimental]
     assert len(full) >= 10
-    # the PR-16 kernel tier: slotmap PROMOTED to a full contract, and
-    # the resident data plane's programs all under full contracts too
-    for name in (
-        "pallas.slotmap", "pallas.gather", "pallas.intersect",
-        "resident.merge",
-    ):
+    # the resident data plane's programs are under full contracts
+    for name in ("pallas.gather", "resident.merge"):
         assert not programs.REGISTRY[name].experimental, name
     # every contract's covers + exemptions feed the lint acceptance set
     cov = programs.covered_sites()
@@ -391,9 +387,9 @@ def test_update_refuses_to_bless_violating_program(monkeypatch, tmp_path):
 
 
 def test_assert_contract_is_the_bench_seam(monkeypatch):
-    """bench_ops.py / test_spgemm.py migrated their hand-rolled
-    `"scan[" not in jaxpr` greps onto assert_contract — prove the seam
-    raises on the bug class they used to catch."""
+    """test_spgemm.py's `"scan[" not in jaxpr` greps live on
+    assert_contract — prove the seam raises on the bug class they used
+    to catch."""
     programs.assert_contract("sets.intersect_many")  # shipped: passes
     monkeypatch.setitem(
         programs.REGISTRY, "seed.bad", SEEDED_BADS["scan"]

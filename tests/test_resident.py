@@ -1,16 +1,16 @@
 """Device-resident data plane (PR 16): ResidentArena epoch buffers, the
-``route:resident`` engine tier behind DGRAPH_TPU_RESIDENT, hop-cache
-epoch keys, and the HBM accounting of double-buffered flips.
+``route:resident`` engine program (the TPU backend's; picked here by
+DeviceExpander's constructor argument), hop-cache epoch keys, and the
+HBM accounting of double-buffered flips.
 
 The acceptance pins from ISSUE 16:
 
 - a warm resident hop is TRANSFER-FREE: the kernel runs device-in,
   device-out under ``jax.transfer_guard("disallow")`` with zero ledger
   h2d/d2h bytes;
-- ``DGRAPH_TPU_RESIDENT=0`` is byte-identical through the full serving
-  path (DgraphServer with scheduler + cache + planner armed), and the
-  engine's force-mode resident route is byte-identical to the host
-  route on the same store;
+- the resident program is byte-identical to the ``csr`` program through
+  the full serving path (DgraphServer with scheduler + cache + planner
+  armed), and to the host route on the same store;
 - deltas cross the host→device boundary as (row, dst) pairs only: the
   on-device merge produces the next epoch's buffers, the flip is
   atomic, and the previous epoch stays pinned as the shadow;
@@ -32,7 +32,8 @@ from dgraph_tpu import ops
 from dgraph_tpu.models import PostingStore
 from dgraph_tpu.models.arena import ResidentArena, csr_dense_from_edges
 from dgraph_tpu.obs import ledger as ledgermod
-from dgraph_tpu.query.engine import QueryEngine
+from dgraph_tpu.query import engine as engine_mod
+from dgraph_tpu.query.engine import DeviceExpander, QueryEngine
 
 # the pallas-interpret CI job re-runs this module on its own (these
 # tests also run inside tier-1 — the marker adds a name, not an excuse)
@@ -284,28 +285,33 @@ def _seed_big(st, rows=100, fanout=64, seed=7):
             st.set_edge("friend", s, int(d))
 
 
-def test_resident_route_byte_identical_to_knob_off(monkeypatch):
-    """force-mode routes the big hop through route:resident and the
-    bytes are identical to a knob-off engine on the same store.  The
-    device threshold is PINNED (static fallback) so the decision can't
-    drift with the planner's online rate refinement — interpret-mode
-    kernel timings on CPU are meaningless as routing signal."""
+def _resident_engine(st) -> QueryEngine:
+    eng = QueryEngine(st)
+    eng.expander = DeviceExpander(eng, program="resident")
+    return eng
+
+
+def test_resident_route_byte_identical_to_csr(monkeypatch):
+    """The resident program takes the big hop as route:resident and the
+    bytes are identical to the platform's program (csr on the CPU
+    backend) on the same store.  The device threshold is PINNED (static
+    fallback) so the decision can't drift with the planner's online
+    rate refinement — interpret-mode kernel timings on CPU are
+    meaningless as routing signal."""
     monkeypatch.setenv("DGRAPH_TPU_EXPAND_DEVICE_MIN", "1000")
     st = PostingStore()
     _seed_big(st)
     src = np.arange(1, 101, dtype=np.int64)
 
-    monkeypatch.setenv("DGRAPH_TPU_RESIDENT", "force")
-    eng_r = QueryEngine(st)
+    eng_r = _resident_engine(st)
     a = eng_r.arenas.data("friend")
     out_r, seg_r = eng_r.expander.expand(a, src, attr="friend")
     assert eng_r.expander._route == "resident"
 
-    monkeypatch.setenv("DGRAPH_TPU_RESIDENT", "0")
     eng_h = QueryEngine(st)
     ah = eng_h.arenas.data("friend")
     out_h, seg_h = eng_h.expander.expand(ah, src, attr="friend")
-    assert eng_h.expander._route != "resident"
+    assert eng_h.expander._route == "csr"
 
     assert np.array_equal(np.asarray(out_r), np.asarray(out_h))
     assert np.array_equal(np.asarray(seg_r), np.asarray(seg_h))
@@ -313,10 +319,8 @@ def test_resident_route_byte_identical_to_knob_off(monkeypatch):
     w_out, w_seg = ah.expand_host(ah.rows_for_uids_host(src))
     assert np.array_equal(np.asarray(out_r), np.asarray(w_out))
     assert np.array_equal(np.asarray(seg_r), np.asarray(w_seg))
-    # auto mode on the CPU backend keeps the default serving path
-    monkeypatch.setenv("DGRAPH_TPU_RESIDENT", "1")
-    eng_a = QueryEngine(st)
-    assert eng_a.expander._use_resident() is False
+    # the program is the platform's: csr on the CPU backend
+    assert eng_h.expander.program == "csr"
 
 
 def test_resident_route_ledger_attribution(monkeypatch):
@@ -325,10 +329,9 @@ def test_resident_route_ledger_attribution(monkeypatch):
     else: no staged-arena bytes (the staging term the planner prices at
     zero for this route)."""
     monkeypatch.setenv("DGRAPH_TPU_EXPAND_DEVICE_MIN", "1000")
-    monkeypatch.setenv("DGRAPH_TPU_RESIDENT", "force")
     st = PostingStore()
     _seed_big(st)
-    eng = QueryEngine(st)
+    eng = _resident_engine(st)
     a = eng.arenas.data("friend")
     a.resident()  # seed OUTSIDE the measured window
     src = np.arange(1, 101, dtype=np.int64)
@@ -353,13 +356,12 @@ def test_resident_faulted_dispatch_falls_back_to_host(monkeypatch):
     from dgraph_tpu.utils.failpoints import fail
 
     monkeypatch.setenv("DGRAPH_TPU_EXPAND_DEVICE_MIN", "1000")
-    monkeypatch.setenv("DGRAPH_TPU_RESIDENT", "force")
     fail.reset()
     devguard.reset_for_tests()
     try:
         st = PostingStore()
         _seed_big(st)
-        eng = QueryEngine(st)
+        eng = _resident_engine(st)
         a = eng.arenas.data("friend")
         src = np.arange(1, 101, dtype=np.int64)
         want_out, want_seg = a.expand_host(a.rows_for_uids_host(src))
@@ -380,13 +382,16 @@ SEED_ROWS, SEED_FAN = 4, 1600  # hub rows: 6400 edges > the resident
 #                                break-even at prior rates (~5.3k)
 
 
-def _serve_once(monkeypatch, resident_mode):
+def _serve_once(monkeypatch, program):
+    """One query through a server whose every engine (the scheduler makes
+    one a request) resolves its device program to ``program``, as if the
+    platform were the one that runs it."""
     from dgraph_tpu.serve.server import DgraphServer
 
     monkeypatch.setenv("DGRAPH_TPU_SCHED", "1")
     monkeypatch.setenv("DGRAPH_TPU_CACHE", "1")
     monkeypatch.setenv("DGRAPH_TPU_EXPAND_DEVICE_MIN", "1000")
-    monkeypatch.setenv("DGRAPH_TPU_RESIDENT", resident_mode)
+    monkeypatch.setattr(engine_mod, "_platform_program", lambda: program)
     st = PostingStore()
     st.apply_schema("follows: uid .")
     for s in range(1, SEED_ROWS + 1):
@@ -408,13 +413,13 @@ def _serve_once(monkeypatch, resident_mode):
         server.stop()
 
 
-def test_serving_path_byte_identical_with_knob_off(monkeypatch):
-    """ISSUE 16 acceptance: DGRAPH_TPU_RESIDENT=0 is byte-identical to
-    force mode through the FULL serving path — DgraphServer with the
-    scheduler, result/hop caches and planner armed — while the ledger
-    proves force mode actually took route:resident."""
-    off = _serve_once(monkeypatch, "0")
-    frc = _serve_once(monkeypatch, "force")
+def test_serving_path_byte_identical_across_programs(monkeypatch):
+    """ISSUE 16 acceptance: the csr program is byte-identical to the
+    resident program through the FULL serving path — DgraphServer with
+    the scheduler, result/hop caches and planner armed — while the
+    ledger proves the resident server actually took route:resident."""
+    off = _serve_once(monkeypatch, "csr")
+    frc = _serve_once(monkeypatch, "resident")
     hops_off = off.pop("extensions")["ledger"].get("hops", {})
     hops_frc = frc.pop("extensions")["ledger"].get("hops", {})
     off.pop("server_latency", None)  # debug timings, not data

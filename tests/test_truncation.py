@@ -3,7 +3,6 @@
 Every fixed-capacity op documents what happens past ``cap``:
 - expand_csr silently truncates its output but returns the TRUE total —
   callers must compare and re-bucket;
-- unique_dense truncates past cap by design;
 - range_rows returns (rows, n) where n > cap signals the caller chose
   too small a cap.
 
@@ -37,16 +36,6 @@ def test_expand_csr_truncation_signals_true_total():
     got = np.asarray(out2)
     assert int(total2) == 32
     assert np.array_equal(got[got != SENT], np.arange(32))
-
-
-def test_unique_dense_overflow_truncates_ascending_prefix():
-    x = jnp.asarray(np.arange(1, 65, dtype=np.int32))  # 64 distinct
-    got = np.asarray(ops.unique_dense(x, 128, 32))
-    kept = got[got != SENT]
-    assert len(kept) == 32, "silently truncates past cap"
-    assert np.array_equal(kept, np.arange(1, 33)), "ascending prefix kept"
-    full = np.asarray(ops.unique_dense(x, 128, 64))
-    assert np.array_equal(full[full != SENT], np.arange(1, 65))
 
 
 def test_range_rows_reports_n_over_cap():
