@@ -934,7 +934,7 @@ def _b_path_search() -> List[ProgramInstance]:
     deg = np.diff(h_offsets)
     off = np.zeros(17, np.int32)
     np.cumsum(np.bincount(h_src, weights=deg, minlength=16), out=off[1:])
-    off = jnp.asarray(off)
+    off = jnp.asarray(np.stack([off[:-1], off[1:]], axis=1))
     dst = jnp.asarray(sets.pad_to(h_dst, 32))
     esrc = jnp.asarray(sets.pad_to(np.repeat(h_src, deg), 32, fill=0))
     cap, chunk = bfs.capacities(32, int(deg.max()))

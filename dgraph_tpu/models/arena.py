@@ -718,8 +718,10 @@ class PathLayout:
     over the uid space (``ops/bfs.py``): row = uid, so a frontier uid is
     its own row and a level table is indexed by what the edges hold; a
     uid's edges lie in the order the predicates were listed, each
-    predicate's ascending.  ``esrc`` is the source uid of every edge slot
-    (0 on padding: no uid), for the level done as a sweep.  Built on the
+    predicate's ascending.  ``off`` is int32[ub, 2]: a uid's first and
+    past-the-last edge slot side by side, so that one row gather reads both.
+    ``esrc`` is the source uid of every edge slot (0 on padding: no uid),
+    for the level done as a sweep.  Built on the
     host from the arenas' mirrors on first use and kept by the
     ``ArenaManager`` until one of its arenas changes.  Its tables are DENSE
     over the uid space, as a search's level and parent tables are:
@@ -740,7 +742,7 @@ class PathLayout:
         off = np.zeros(self.ub + 1, dtype=np.int32)
         np.cumsum(counts, out=off[1:])
         eb = ops.bucket(max(1, self.n_edges))
-        self.off = jnp.asarray(off)
+        self.off = jnp.asarray(np.stack([off[:-1], off[1:]], axis=1))
         self.dst = jnp.asarray(ops.pad_to(dst[order], eb))
         self.esrc = jnp.asarray(ops.pad_to(src[order], eb, fill=0))
         self.key = tuple((id(a), a.epoch) for a in arenas)

@@ -3,40 +3,49 @@ of ``shortest(from:, to:)`` at unit cost (query/shortest.py).
 
 The listed predicates' arenas are merged into ONE CSR over the uid space
 (``models/arena.py`` ``PathLayout``: row = uid, a uid's edges under the
-first listed predicate first).  The frontier, the level of every reached
-uid and its parent stay on the device from the first level to the one
-that holds the target; the host gets back the distance, the path
-(parents walked back on the device) and the sums the ledger books, never
-a frontier.
+first listed predicate first; ``off[u]`` = the (first, past-the-last) edge
+slots of uid ``u``, side by side: one row gather reads both).  The
+frontier, the parent of every reached uid and its level stay on the device
+from the first level to the one that holds the target; the host gets back
+the distance, the path (parents walked back on the device) and the sums
+the ledger books, never a frontier.
 
     L0 = {from};  L(i+1) = every uid reached from Li under ANY listed
     predicate and not reached before;  parent(v) = the LEAST uid of Li
     with an edge to v.  The search ends after the first level that holds
     the target, or with an empty level.
 
-State over the uid space: ``lvl`` (-1 = not reached) and ``par``.  A
-level is done one of two ways, chosen per level from the frontier's size
-and the layout's size:
+State over the uid space: ``par`` (SENT = not reached; the source is its
+own parent) is the visited set AND the parent, ``lvl`` (-1 = not reached)
+what the sweep reads.  A level is done one of two ways, chosen per level
+from the frontier's size and the layout's size:
 
-- **gather**: the frontier as a LIST, ``chunk`` slots of its edges at a
-  time: the slot -> edge map telescoped from one scatter a row (as
-  ``batch.expand_ascending``), one ``dst`` gather a slot, each target's
-  level read, its parent scatter-min'ed, its level set, the new uids
-  sorted into the next list with their degrees.  About nine random
-  accesses a slot and none over the uid space: the cost follows the
-  frontier's edges.
+- **gather**: the frontier as a LIST — ascending, duplicate-free, each
+  uid beside its first edge slot and the running sum of the degrees —
+  ``chunk`` slots of its edges at a time: the slot -> edge and slot ->
+  source maps telescoped from one scatter a row (as
+  ``batch.expand_ascending``), one ``dst`` gather a slot, the target's
+  parent read and, where it has none, scatter-min'ed.  Chunks run in
+  ascending source order, so the first chunk that reaches a uid holds its
+  least parent and a later one finds it taken.  What the chunks found is
+  sorted ONCE after the loop, in a size class of the level's edges, into
+  the next list; one pass over that list reads each new uid's offsets
+  (its slot and degree, exact: the search books them) and writes its
+  level.  No access over the uid space: the cost follows the frontier's
+  edges.
 - **sweep**: every edge of the layout reads its source's level and
   scatter-mins its source into a candidate table over the uid space:
   two random accesses an edge of the LAYOUT, whatever the frontier
   holds; no list, no sort.
 
-On the chip a random access costs about the same gathered or scattered
-(9-10 ns an element on a v5e; PERF.md, PR 28) and a streaming pass is
-nearly free beside it.  So a level goes to the sweep when the frontier's
-out-degree sum times ``_ACCESS_PER_SLOT`` passes the layout's edges times
-``_ACCESS_PER_EDGE`` — or when the frontier outgrew its list.  Both
-numbers are on the device when the level starts: a level's size and
-out-degree sum are counted as its uids are found.
+On the chip a random access costs 9-10 ns an element on a v5e, gathered
+or scattered, a dropped index as much as a live one (PERF.md, PRs 28 and
+31; ``_take`` reads a word for 2.5) and a streaming pass or a sort is
+nearly free beside it.  So a level goes to the sweep when the
+frontier's out-degree sum times ``_ACCESS_PER_SLOT`` passes the layout's
+edges times ``_ACCESS_PER_EDGE`` — or when the frontier outgrew its list.
+Both numbers are on the device when the level starts: a level's size and
+out-degree sum are counted as its uids are listed.
 """
 
 from __future__ import annotations
@@ -50,23 +59,30 @@ import jax.numpy as jnp
 from dgraph_tpu.ops.sets import SENT, bucket, sort_unique
 
 # Random accesses the two ways of doing a level make, per unit of work,
-# COUNTED from the code below, not fitted to a run: a gather's slot reads
-# dst and the target's level, mins the parent, sets the level, reads the
-# new uid's two offsets, and shares its row's three (two offsets, the
-# previous row's source) and two scatters with one other slot (a chunk
-# holds half as many rows as slots); a sweep's edge reads its source's
-# level and mins the candidate.  They are this module's priors in the sense
-# of utils/calibrate.py's: the calibration measures no rate for either way
+# COUNTED from the code below, not fitted to a run.  A gather's slot reads
+# dst and the target's parent and mins it (``_gather_chunk``: three), shares
+# its row's telescoping scatter with one other slot (a chunk holds half as
+# many rows as slots: a half), and finds one new uid at most, whose offsets
+# are read and whose level is written once, from the sorted list
+# (``_enlist``: two).  A sweep's edge reads its source's level and mins the
+# candidate.  They are this module's priors in the sense of
+# utils/calibrate.py's: the calibration measures no rate for either way
 # (PERF.md Open questions 15e), and a change to either level's code has to
-# recount them (tests/test_path_search.py holds the list to the count)
-_ACCESS_PER_SLOT = 9
+# recount them (tests/test_path_search.py counts the jaxprs' gathers and
+# scatters and holds the constant to the sum)
+_ACCESS_PER_SLOT = 5.5
 _ACCESS_PER_EDGE = 2
+_LANES = 128         # a vector register's lanes: the row ``_take`` gathers
 PATH_CAP = 64        # the path comes back this many uids a walk
 HEAD = 5             # result header: found, levels, rows, edges, sweeps
 
 
-def _pow2_floor(n: int) -> int:
-    return 1 << (max(1, int(n)).bit_length() - 1)
+def _fine_floor(n: int) -> int:
+    """``n`` rounded DOWN to a 1/8-step of a power of two (the steps of
+    ``ops.bucket_fine``, which rounds up)."""
+    base = 1 << (max(1, int(n)).bit_length() - 1)
+    step = max(1, base >> 3)
+    return base + (int(n) - base) // step * step
 
 
 def capacities(n_edge_slots: int, max_degree: int) -> Tuple[int, int]:
@@ -75,11 +91,12 @@ def capacities(n_edge_slots: int, max_degree: int) -> Tuple[int, int]:
 
     The list holds the largest frontier a gather can still win with: past
     ``_ACCESS_PER_EDGE * slots / _ACCESS_PER_SLOT`` edges the sweep is
-    cheaper.  A chunk is a sixteenth of that — a level then overshoots its
-    edges by a few chunks at most — and never under the widest uid, which
-    has to fit one chunk whole."""
-    top = max(8, _pow2_floor(_ACCESS_PER_EDGE * max(1, n_edge_slots) // _ACCESS_PER_SLOT))
-    chunk = max(8, top >> 4, bucket(max(1, max_degree)))
+    cheaper (a bucketed size under it: the shapes depend on the layout's
+    bucket alone).  A chunk is a power of two about a twentieth of that — a
+    level then overshoots its edges by a few chunks at most — and never
+    under the widest uid, which has to fit one chunk whole."""
+    top = max(8, _fine_floor(int(_ACCESS_PER_EDGE * max(1, n_edge_slots) / _ACCESS_PER_SLOT)))
+    chunk = bucket(max(8, top >> 5, max_degree))
     return max(top, chunk), chunk
 
 
@@ -88,56 +105,95 @@ def small_chunk(chunk: int) -> int:
     return max(8, chunk >> 4)
 
 
-def _gather_chunk(off, dst, lvl, par, uids, n_valid, cur, chunk):
-    """Expand the first ``n_valid`` of ``uids`` (int32[chunk // 2]), whose
-    edges fit ``chunk`` slots.  Returns (lvl, par, the new uids
-    sorted-unique SENT-padded [chunk], how many, their degrees)."""
+def _sort_sizes(chunk: int, top: int) -> Tuple[int, ...]:
+    """The static sizes a level's finds are sorted at: ``chunk`` and its
+    multiples by four under ``top``, then ``top``."""
+    sizes = []
+    while chunk < top:
+        sizes.append(chunk)
+        chunk *= 4
+    return (*sizes, top)
+
+
+def _take(table, idx):
+    """``table[idx]`` for in-bounds ``idx`` into a 1-D table: its rows of
+    ``_LANES`` gathered whole and the lane picked.  On the chip a gather of
+    scalars costs 8.8 ns an element and this 2.5 (PERF.md, PR 31); a table
+    whose size ``_LANES`` does not divide (a toy layout) is read plainly."""
+    if table.shape[0] % _LANES:
+        return table[idx]
+    rows = table.reshape(-1, _LANES).at[idx // _LANES].get(mode="promise_in_bounds")
+    lane = jnp.arange(_LANES, dtype=idx.dtype)
+    return jnp.sum(jnp.where(lane == (idx % _LANES)[:, None], rows, 0), axis=1)
+
+
+def _gather_chunk(dst, par, uids, starts, cum, n_valid, chunk):
+    """Expand the first ``n_valid`` of ``uids`` (int32[chunk // 2],
+    ascending), whose edges fit ``chunk`` slots: ``starts`` their first edge
+    slots, ``cum`` the running sum of their degrees from the chunk's start.
+    Returns (par, the targets that had no parent — SENT elsewhere, a uid
+    once for every slot that reached it — int32[chunk], the live slots)."""
     C, R = chunk, uids.shape[0]
-    ub = lvl.shape[0]
+    ub = par.shape[0]
     i = jnp.arange(C, dtype=jnp.int32)
-    # ascending within the chunk: the telescoping below needs it
-    u = jnp.sort(jnp.where(jnp.arange(R, dtype=jnp.int32) < n_valid, uids, SENT))
-    valid = u != SENT
-    uc = jnp.where(valid, u, 0)
-    o0 = off[uc]
-    deg = jnp.where(valid, off[uc + 1] - o0, 0)
-    cum = jnp.cumsum(deg)
+    valid = jnp.arange(R, dtype=jnp.int32) < n_valid
+    cum = jnp.where(valid, cum, 0)
+    total = jnp.max(cum)
+    deg = jnp.where(valid, cum - jnp.concatenate([jnp.zeros((1,), cum.dtype), cum[:-1]]), 0)
     productive = deg > 0
     slot = jnp.where(productive, cum - deg, C)     # C = dropped
-    # slot -> edge: each productive row's first slot holds the jump from
-    # the previous productive row's end; a prefix sum plus the slot's own
-    # index is the edge (rows ascend, so do their starts)
-    end = jnp.where(productive, o0 + deg, 0)
-    prev_end = jnp.concatenate([jnp.zeros((1,), end.dtype), jax.lax.cummax(end)[:-1]])
-    jump = jnp.zeros((C,), jnp.int32).at[slot].set(
-        jnp.where(productive, o0 - prev_end, 0), mode="drop")
-    edge = jnp.cumsum(jump) + i
-    # slot -> source uid, telescoped the same way
-    idx = jnp.where(productive, jnp.arange(R, dtype=jnp.int32), -1)
-    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), jax.lax.cummax(idx)[:-1]])
-    prev_src = jnp.where(prev >= 0, uc[jnp.maximum(prev, 0)], 0)
-    step = jnp.zeros((C,), jnp.int32).at[slot].set(
-        jnp.where(productive, uc - prev_src, 0), mode="drop")
-    src = jnp.cumsum(step)
-    live = i < cum[-1]
-    out = jnp.where(live, dst[jnp.clip(edge, 0, dst.shape[0] - 1)], SENT)
-    at = lvl[jnp.clip(out, 0, ub - 1)]
-    fresh = live & (at < 0)
-    # a uid another chunk of this level reached first still takes a lesser parent
-    again = live & (at == cur + 1)
-    par = par.at[jnp.where(fresh | again, out, ub)].min(src, mode="drop")
-    lvl = lvl.at[jnp.where(fresh, out, ub)].set(cur + 1, mode="drop")
-    new = sort_unique(jnp.where(fresh, out, SENT))
-    is_new = new != SENT
-    nc = jnp.where(is_new, new, 0)
-    new_deg = jnp.where(is_new, off[nc + 1] - off[nc], 0)
-    return lvl, par, new, jnp.sum(is_new).astype(jnp.int32), new_deg
+    # slot -> edge and slot -> source uid, telescoped: each productive row's
+    # first slot holds the jump from the previous productive row's end (its
+    # uid), a prefix sum — plus the slot's own index — is the edge (the
+    # source).  Rows ascend, so do their starts: the previous row's are a
+    # running maximum.  Both maps ride ONE scatter, two values a row
+    here = jnp.stack([jnp.where(productive, starts + deg, 0), jnp.where(productive, uids, 0)])
+    prev = jnp.concatenate(
+        [jnp.zeros((2, 1), here.dtype), jax.lax.cummax(here, axis=1)[:, :-1]], axis=1)
+    first = jnp.stack([starts, uids]) - prev
+    run = jnp.cumsum(jnp.zeros((2, C), jnp.int32).at[:, slot].set(
+        jnp.where(productive, first, 0), mode="drop"), axis=1)
+    edge, src = run[0] + i, run[1]
+    live = i < total
+    out = jnp.where(live, _take(dst, jnp.clip(edge, 0, dst.shape[0] - 1)), SENT)
+    fresh = live & (_take(par, jnp.clip(out, 0, ub - 1)) == SENT)
+    par = par.at[jnp.where(fresh, out, ub)].min(src, mode="drop")
+    return par, jnp.where(fresh, out, SENT), total
 
 
-def _gather_level(off, dst, chunk, cap, st):
-    """One level from the frontier list (capacity ``cap``), ``chunk`` slots
-    of its edges at a time."""
-    fl, cd, f, cur = st["fl"], st["cd"], st["f"], st["cur"]
+def _enlist(off, piece, st, n, level=None):
+    """The first ``n`` of ``st["fl"]`` (ascending uids) made a frontier
+    list, ``piece`` uids at a time: each uid's first edge slot and the
+    running sum of the degrees beside it, its level written where ``level``
+    is given.  Returns (st, the degrees' sum)."""
+    ub = st["lvl"].shape[0]
+    k = jnp.arange(piece, dtype=jnp.int32)
+
+    def cond(c):
+        return c[0] < n
+
+    def body(c):
+        b, lvl, fo, cd, m = c
+        u = jax.lax.dynamic_slice(st["fl"], (b,), (piece,))
+        ok = b + k < n
+        span = off[jnp.where(ok, u, 0)]
+        d = jnp.where(ok, span[:, 1] - span[:, 0], 0)
+        if level is not None:
+            lvl = lvl.at[jnp.where(ok, u, ub)].set(level, mode="drop")
+        fo = jax.lax.dynamic_update_slice(fo, span[:, 0], (b,))
+        cd = jax.lax.dynamic_update_slice(cd, m + jnp.cumsum(d), (b,))
+        return b + piece, lvl, fo, cd, m + jnp.sum(d).astype(jnp.int32)
+
+    zero = jnp.int32(0)
+    _, lvl, fo, cd, m = jax.lax.while_loop(cond, body, (zero, st["lvl"], st["fo"], st["cd"], zero))
+    return dict(st, lvl=lvl, fo=fo, cd=cd), m
+
+
+def _gather_level(off, dst, chunk, top, st):
+    """One level of at most ``top`` edges from the frontier list, ``chunk``
+    slots of them at a time.  The next list holds at most the level's edges:
+    it never outgrows a list this level fitted."""
+    fl, fo, cd, f, m = st["fl"], st["fo"], st["cd"], st["f"], st["m"]
     rows = chunk // 2
     j = jnp.arange(rows, dtype=jnp.int32)
 
@@ -145,27 +201,34 @@ def _gather_level(off, dst, chunk, cap, st):
         return c[0] < f
 
     def body(c):
-        a, lvl, par, nfl, ncd, n_next, m_next = c
+        a, w, par, raw = c
         # the chunk [a, a + nb): as many uids as fit ``chunk`` slots
         below = jnp.where(a > 0, cd[jnp.maximum(a - 1, 0)], 0)
-        upto = jax.lax.dynamic_slice(cd, (a,), (rows,))
-        nb = jnp.sum(((upto - below) <= chunk) & (a + j < f)).astype(jnp.int32)
+        cum = jax.lax.dynamic_slice(cd, (a,), (rows,)) - below
+        nb = jnp.sum((cum <= chunk) & (a + j < f)).astype(jnp.int32)
         nb = jnp.maximum(nb, 1)
-        uids = jax.lax.dynamic_slice(fl, (a,), (rows,))
-        lvl, par, new, n, new_deg = _gather_chunk(off, dst, lvl, par, uids, nb, cur, chunk)
-        # append at n_next; past ``cap`` the list is lost (n_next says so)
-        at = jnp.minimum(n_next, cap)
-        nfl = jax.lax.dynamic_update_slice(nfl, new, (at,))
-        ncd = jax.lax.dynamic_update_slice(ncd, m_next + jnp.cumsum(new_deg), (at,))
-        return (a + nb, lvl, par, nfl, ncd, n_next + n,
-                m_next + jnp.sum(new_deg).astype(jnp.int32))
+        par, new, total = _gather_chunk(
+            dst, par, jax.lax.dynamic_slice(fl, (a,), (rows,)),
+            jax.lax.dynamic_slice(fo, (a,), (rows,)), cum, nb, chunk)
+        # one find a live slot, end to end: the level's finds take ``m`` slots
+        return a + nb, w + total, par, jax.lax.dynamic_update_slice(raw, new, (w,))
 
     zero = jnp.int32(0)
-    _, lvl, par, nfl, ncd, n_next, m_next = jax.lax.while_loop(
-        cond, body, (zero, st["lvl"], st["par"], st["nfl"], st["ncd"], zero, zero))
-    # the lists swap: the next level reads what this one wrote
-    return dict(st, lvl=lvl, par=par, fl=nfl, cd=ncd, nfl=fl, ncd=cd,
-                f=n_next, m=m_next, listed=n_next <= cap)
+    _, _, par, raw = jax.lax.while_loop(cond, body, (zero, zero, st["par"], st["raw"]))
+
+    # the finds sorted once, duplicates out, at the least size that holds
+    # them, over the list this level has done with
+    def sort_at(size, fl, raw):
+        new = sort_unique(jnp.where(jnp.arange(size, dtype=jnp.int32) < m, raw[:size], SENT))
+        return (jax.lax.dynamic_update_slice(fl, new, (0,)),
+                jnp.sum(new != SENT).astype(jnp.int32))
+
+    sizes = _sort_sizes(chunk, top)
+    at = sum((m > s).astype(jnp.int32) for s in sizes[:-1])
+    fl, f_next = jax.lax.switch(at, [partial(sort_at, s) for s in sizes], fl, raw)
+    st, m_next = _enlist(off, chunk, dict(st, par=par, fl=fl, raw=raw), f_next,
+                         level=st["cur"] + 1)
+    return dict(st, f=f_next, m=m_next, listed=jnp.bool_(True))
 
 
 def _sweep_level(off, dst, esrc, chunk, st):
@@ -178,26 +241,23 @@ def _sweep_level(off, dst, esrc, chunk, st):
     new = (cand != SENT) & (lvl < 0)
     par = jnp.where(new, cand, par)
     lvl = jnp.where(new, cur + 1, lvl)
-    deg = off[1:] - off[:-1]
+    deg = off[:, 1] - off[:, 0]
     f = jnp.sum(new).astype(jnp.int32)
     m = jnp.sum(jnp.where(new, deg, 0)).astype(jnp.int32)
-    fl, cd = st["fl"], st["cd"]
-    cap = fl.shape[0] - chunk
+    n = st["fl"].shape[0]
+    cap = n - chunk
+    lists = {"fl": st["fl"], "fo": st["fo"], "cd": st["cd"], "lvl": lvl}
 
-    def relist(_):
+    def relist(lists):
         # the frontier is back under the list's capacity: one sort of the
         # uid space puts it there again
         uids = jnp.sort(jnp.where(new, jnp.arange(ub, dtype=jnp.int32), SENT))
-        uids = jnp.concatenate([uids, jnp.full((max(0, cap - ub),), SENT, jnp.int32)])[:cap]
-        ok = uids != SENT
-        uc = jnp.where(ok, uids, 0)
-        d = jnp.where(ok, off[uc + 1] - off[uc], 0)
-        return (jnp.concatenate([uids, jnp.full((chunk,), SENT, jnp.int32)]),
-                jnp.concatenate([jnp.cumsum(d), jnp.zeros((chunk,), jnp.int32)]))
+        uids = jnp.concatenate([uids, jnp.full((max(0, n - ub),), SENT, jnp.int32)])[:n]
+        return _enlist(off, chunk, dict(lists, fl=uids), f)[0]
 
     listed = (f <= cap) & (m <= cap)
-    fl, cd = jax.lax.cond(listed, relist, lambda _: (fl, cd), None)
-    return dict(st, lvl=lvl, par=par, fl=fl, cd=cd, f=f, m=m, listed=listed)
+    lists = jax.lax.cond(listed, relist, lambda lists: lists, lists)
+    return dict(st, **lists, par=par, f=f, m=m, listed=listed)
 
 
 @partial(jax.jit, static_argnames=("chunk",), donate_argnums=(3,))
@@ -222,12 +282,12 @@ def run_levels(off, dst, esrc, st, to, steps, chunk):
         tiny = gather & (st["m"] <= small) & (st["f"] <= small // 2)
         st = jax.lax.switch(
             jnp.where(tiny, 0, jnp.where(gather, 1, 2)),
-            [lambda s: _gather_level(off, dst, small, cap, s),
-             lambda s: _gather_level(off, dst, chunk, cap, s),
+            [lambda s: _gather_level(off, dst, small, small, s),
+             lambda s: _gather_level(off, dst, chunk, cap + chunk, s),
              lambda s: _sweep_level(off, dst, esrc, chunk, s)],
             st,
         )
-        st = dict(st, cur=st["cur"] + 1, found=st["lvl"][to] >= 0,
+        st = dict(st, cur=st["cur"] + 1, found=st["par"][to] != SENT,
                   sweeps=st["sweeps"] + jnp.where(gather, 0, 1).astype(jnp.int32))
         return st, left - 1
 
@@ -237,18 +297,21 @@ def run_levels(off, dst, esrc, st, to, steps, chunk):
 
 @partial(jax.jit, static_argnames=("cap", "chunk"))
 def start(off, src, cap, chunk):
-    """The state before level 0: the source alone, at level 0."""
-    ub = off.shape[0] - 1
+    """The state before level 0: the source alone, at level 0, its own
+    parent."""
+    ub = off.shape[0]
     n = cap + chunk
-    d0 = (off[src + 1] - off[src]).astype(jnp.int32)
+    d0 = (off[src, 1] - off[src, 0]).astype(jnp.int32)
     zero = jnp.int32(0)
     return {
         "lvl": jnp.full((ub,), -1, jnp.int32).at[src].set(0),
-        "par": jnp.full((ub,), SENT, jnp.int32),
+        "par": jnp.full((ub,), SENT, jnp.int32).at[src].set(src),
+        # the frontier list: uids, their first edge slots, their degrees'
+        # running sum; ``raw``: what a level's chunks found, before the sort
         "fl": jnp.full((n,), SENT, jnp.int32).at[0].set(src),
+        "fo": jnp.zeros((n,), jnp.int32).at[0].set(off[src, 0]),
         "cd": jnp.zeros((n,), jnp.int32).at[0].set(d0),
-        "nfl": jnp.full((n,), SENT, jnp.int32),
-        "ncd": jnp.zeros((n,), jnp.int32),
+        "raw": jnp.full((n,), SENT, jnp.int32),
         "f": jnp.int32(1), "m": d0, "cur": zero,
         "rows": zero, "edges": zero, "sweeps": zero,
         "found": jnp.bool_(False), "listed": jnp.bool_(True),
@@ -257,12 +320,14 @@ def start(off, src, cap, chunk):
 
 @jax.jit
 def walk_back(par, at):
-    """``at`` and its PATH_CAP - 1 ancestors (SENT past the source)."""
+    """``at`` and its PATH_CAP - 1 ancestors (SENT past the source, which is
+    its own parent)."""
     ub = par.shape[0]
 
     def body(i, c):
         buf, u = c
-        return buf.at[i].set(u), jnp.where(u == SENT, SENT, par[jnp.clip(u, 0, ub - 1)])
+        p = par[jnp.clip(u, 0, ub - 1)]
+        return buf.at[i].set(u), jnp.where((u == SENT) | (p == u), SENT, p)
 
     buf, _ = jax.lax.fori_loop(
         0, PATH_CAP, body, (jnp.full((PATH_CAP,), SENT, jnp.int32), jnp.int32(at)))

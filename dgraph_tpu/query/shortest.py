@@ -41,7 +41,8 @@ from dgraph_tpu.models.types import TypedValue, numeric
 from dgraph_tpu.obs import ledger as _ledger
 from dgraph_tpu.query.functions import QueryError
 from dgraph_tpu.query.subgraph import SubGraph
-from dgraph_tpu.utils.metrics import PATH_FRONTIER_ROWS, PATH_LEVELS, PATH_SEARCHES
+from dgraph_tpu.utils.metrics import (
+    PATH_FRONTIER_ROWS, PATH_LEVEL_WAYS, PATH_LEVELS, PATH_SEARCHES)
 
 MAX_EDGES = 10_000_000
 
@@ -130,7 +131,7 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
         tuple((c.attr, bool(c.reverse)) for c in preds))
     if max(src, dst) >= lay.ub:
         # an endpoint no edge knows of: level 0 alone
-        _book(engine, 1, 1, 0)
+        _book(engine, 1, 1, 0, 0)
         _set_paths(engine, sg, preds, None)
         return True
     with obs.stage(st, "plan_ms"):
@@ -178,7 +179,7 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
             segments.seam("path")
         got = guard.run("device.path", lambda: _fetch(state))
         d2h = int(got.nbytes)
-        found, levels, rows, edges = (int(x) for x in got[:4])
+        found, levels, rows, edges, sweeps = (int(x) for x in got[:bfs.HEAD])
         path: Optional[List[int]] = None
         if found:
             with obs.stage(st, "convert_ms"):
@@ -199,20 +200,23 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
     if led is not None:
         led.bytes_h2d += 8          # the two endpoints
         led.bytes_d2h += d2h
-    st["path_sweeps"] = st.get("path_sweeps", 0) + int(got[4])
-    _book(engine, levels, rows, edges)
+    _book(engine, levels, rows, edges, sweeps)
     with obs.stage(st, "convert_ms"):
         _set_paths(engine, sg, preds, path)
     return True
 
 
-def _book(engine, levels: int, rows: int, edges: int) -> None:
+def _book(engine, levels: int, rows: int, edges: int, sweeps: int) -> None:
     """A device search's work, by the module's definition, to the request's
     stats (hence the ledger's ``edges``), the ``path`` route and the path
-    counters."""
-    engine.stats["edges"] = engine.stats.get("edges", 0) + edges
+    counters; ``sweeps`` of its levels were swept, the others gathered."""
+    st = engine.stats
+    st["edges"] = st.get("edges", 0) + edges
+    st["path_sweeps"] = st.get("path_sweeps", 0) + sweeps
     PATH_LEVELS.add(levels)
     PATH_FRONTIER_ROWS.add(rows)
+    PATH_LEVEL_WAYS.add("gather", levels - sweeps)
+    PATH_LEVEL_WAYS.add("sweep", sweeps)
     led = _ledger.current()
     if led is not None:
         led.note_hop("path", edges)

@@ -775,13 +775,18 @@ LEDGERS_CREATED = metrics.counter("dgraph_ledger_structs_total")
 # cost, one path) or "host" (the Dijkstra: numpaths > 1, a weight facet,
 # a decorated child); PATH_LEVELS the levels the device route expanded
 # and PATH_FRONTIER_ROWS the uids in them, so rows / levels is a level's
-# mean width.  Their edges ride LEDGER_HOP_EDGES{route="path"}.  Every
-# label is there at zero from boot.
+# mean width; PATH_LEVEL_WAYS{way} the same levels by what did them —
+# "gather" (from the frontier list) or "sweep" (every edge of the layout;
+# ops/bfs.py chooses per level).  Their edges ride
+# LEDGER_HOP_EDGES{route="path"}.  Every label is there at zero from boot.
 PATH_SEARCHES = metrics.labeled("dgraph_path_searches_total", label="route")
 PATH_LEVELS = metrics.counter("dgraph_path_levels_total")
 PATH_FRONTIER_ROWS = metrics.counter("dgraph_path_frontier_rows_total")
+PATH_LEVEL_WAYS = metrics.labeled("dgraph_path_level_ways_total", label="way")
 for _r in ("device", "host"):
     PATH_SEARCHES.add(_r, 0)
+for _w in ("gather", "sweep"):
+    PATH_LEVEL_WAYS.add(_w, 0)
 LEDGER_HOP_EDGES.add("path", 0)
 
 # result encoder (query/outputnode.py): result objects emitted, by the

@@ -18,7 +18,8 @@ from dgraph_tpu.ops import bfs
 from dgraph_tpu.query import planner
 from dgraph_tpu.query.engine import QueryEngine
 from dgraph_tpu.sched import CancelToken, QueryCancelledError
-from dgraph_tpu.utils.metrics import PATH_FRONTIER_ROWS, PATH_LEVELS, PATH_SEARCHES
+from dgraph_tpu.utils.metrics import (
+    PATH_FRONTIER_ROWS, PATH_LEVEL_WAYS, PATH_LEVELS, PATH_SEARCHES)
 
 PREDS = ("a", "b", "c")
 LISTED = "a ~a b ~c"          # c is walked backwards only
@@ -198,11 +199,15 @@ def test_both_ways_of_doing_a_level_run_and_agree():
     gathers = sweeps = 0
     for dst in range(2, 60):
         want, edges, _, levels = numpy_bfs(g, 1, dst)
+        ways = PATH_LEVEL_WAYS.snapshot()
         got = e.run("{ shortest(from: 0x1, to: 0x%x) { %s } }" % (dst, LISTED))
         assert (len(path_of(got)) - 1 if want else None) == (len(want) - 1 if want else None)
         assert e.stats["edges"] == edges
-        sweeps += e.stats.get("path_sweeps", 0)
-        gathers += levels - e.stats.get("path_sweeps", 0)
+        swept = e.stats.get("path_sweeps", 0)
+        grown = {w: n - ways[w] for w, n in PATH_LEVEL_WAYS.snapshot().items()}
+        assert grown == {"gather": levels - swept, "sweep": swept}
+        sweeps += swept
+        gathers += levels - swept
     assert sweeps > 0 and gathers > 0
 
 
@@ -218,12 +223,124 @@ def test_a_path_longer_than_one_walk_back():
     assert e.stats["edges"] == n - 1
 
 
-def test_capacities_follow_the_layouts_size():
-    cap, chunk = bfs.capacities(8 * 1024 * 1024, 97_734)
-    assert cap * bfs._ACCESS_PER_SLOT <= 8 * 1024 * 1024 * bfs._ACCESS_PER_EDGE
-    assert chunk >= 97_734 and chunk <= cap
-    cap, chunk = bfs.capacities(16, 40)       # a uid wider than the list: the chunk holds it
+@pytest.mark.parametrize("slots,widest", [
+    pytest.param(8 * 1024 * 1024, 97_734, id="film-q4"),
+    pytest.param(3 * 1024 * 1024, 12, id="narrow"),
+    pytest.param(1024, 200, id="one-wide-uid"),
+])
+def test_capacities_follow_the_layouts_size(slots, widest):
+    cap, chunk = bfs.capacities(slots, widest)
+    break_even = slots * bfs._ACCESS_PER_EDGE / bfs._ACCESS_PER_SLOT
+    # the list ends where the sweep gets cheaper, and nothing is floored away
+    assert break_even / 2 < cap <= break_even
+    assert widest <= chunk <= cap             # the list holds the widest uid
+    assert chunk & (chunk - 1) == 0
+
+
+def test_a_uid_wider_than_the_break_even_still_fits_the_chunk():
+    cap, chunk = bfs.capacities(16, 40)
     assert chunk >= 40 and cap >= chunk
+
+
+def _accesses(jaxpr, into=None):
+    """{index rows: count} of the ``gather`` / ``scatter*`` equations of a
+    jaxpr, those of its sub-jaxprs (loops, branches, calls) included."""
+    into = {} if into is None else into
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            rows = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+            into[rows] = into.get(rows, 0) + 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _accesses(sub, into)
+    return into
+
+
+def test_the_access_counts_are_what_the_code_does():
+    """``_ACCESS_PER_SLOT`` / ``_ACCESS_PER_EDGE`` price a level's two ways
+    (``capacities``, ``run_levels``): they have to be the random accesses the
+    code makes — per slot of a chunk, per row (half as many), per uid found
+    (one a slot at most), per edge of the layout."""
+    import jax
+    import jax.numpy as jnp
+
+    C, E, ub = 64, 4096, 1024        # sizes no two of which coincide
+    off = jnp.zeros((ub, 2), jnp.int32)
+    dst = jnp.zeros((E,), jnp.int32)
+    i32 = lambda n: jnp.zeros((n,), jnp.int32)  # noqa: E731
+    chunk = _accesses(jax.make_jaxpr(
+        lambda par, uids, starts, cum: bfs._gather_chunk(dst, par, uids, starts, cum, 3, C)
+    )(i32(ub), i32(C // 2), i32(C // 2), i32(C // 2)).jaxpr)
+    assert set(chunk) == {C, C // 2}, chunk
+    st = bfs.start(off, jnp.int32(1), 8 * C, C)
+    found = _accesses(jax.make_jaxpr(
+        lambda st: bfs._enlist(off, C, st, jnp.int32(5), level=jnp.int32(1)))(st).jaxpr)
+    assert set(found) == {C}, found
+    assert bfs._ACCESS_PER_SLOT == chunk[C] + chunk[C // 2] / 2 + found[C]
+    sweep = _accesses(jax.make_jaxpr(
+        lambda st: bfs._sweep_level(off, dst, dst, C, st))(st).jaxpr)
+    assert bfs._ACCESS_PER_EDGE == sweep[E]
+
+
+def hand_layout(order):
+    """A layout by hand, three levels below uid 1, each uid of degree 4 so a
+    chunk of 8 slots holds two: level 1 = 10..15 (three chunks), level 2 =
+    twelve uids of 30..52 (six chunks) which level 1 finds in ``order`` —
+    "asc": the first chunk finds the least, "desc": the last does — and
+    two of which every neighbouring pair of level 1 finds twice; level 3 =
+    100 (reached from level 2's least AND greatest uid, the first and the
+    last chunk), 101 (from the greatest and a middle one), 102."""
+    adj = {1: [15, 12, 10, 13, 11, 14, 1, 1]}          # any order within a row
+    two = [30 + 2 * k for k in range(12)]
+    two = two if order == "asc" else two[::-1]
+    for i, u in enumerate(range(10, 16)):
+        shared = two[2 * ((i + 1) % 6)]                # the next uid's first find
+        adj[u] = [two[2 * i + 1], 1, shared, two[2 * i]]
+    lo, mid, hi = 30, 40, 52
+    for v in range(30, 54, 2):
+        adj[v] = [10, 1, v, 102]
+    adj[lo], adj[hi], adj[mid] = [100, 10, 1, 102], [11, 100, 101, 1], [101, 1, 12, 102]
+    ub = 128
+    deg = np.array([len(adj.get(u, ())) for u in range(ub)])
+    off = np.zeros(ub + 1, np.int32)
+    np.cumsum(deg, out=off[1:])
+    dst = np.concatenate([adj.get(u, []) for u in range(ub)]).astype(np.int32)
+    return adj, off, dst
+
+
+@pytest.mark.parametrize("order", ["asc", "desc"])
+def test_a_gathered_level_of_several_chunks_keeps_the_least_parent(order):
+    """``_gather_level`` at a chunk of 8 slots over levels of one, three and
+    six chunks: the parent of a uid two chunks reach is the least uid of the
+    level, whichever chunk found that parent; the next list is ascending,
+    duplicate-free, with its offsets and exact degree sums."""
+    import jax.numpy as jnp
+
+    adj, off, dst = hand_layout(order)
+    chunk, cap = 8, 56
+    d_off = jnp.asarray(np.stack([off[:-1], off[1:]], axis=1))
+    d_dst = jnp.asarray(np.concatenate([dst, np.full(128 - len(dst), bfs.SENT, np.int32)]))
+    st = bfs.start(d_off, jnp.int32(1), cap, chunk)
+    parent, level = {1: 1}, [1]
+    for cur in range(3):
+        st = dict(bfs._gather_level(d_off, d_dst, chunk, cap + chunk, st), cur=jnp.int32(cur + 1))
+        nxt = sorted({v for u in level for v in adj[u]} - set(parent))
+        for v in nxt:
+            parent[v] = min(u for u in level if v in adj[u])
+        f = int(st["f"])
+        assert np.asarray(st["fl"])[:f].tolist() == nxt, cur      # ascending, once each
+        assert np.asarray(st["fo"])[:f].tolist() == [int(off[v]) for v in nxt]
+        degs = [len(adj.get(v, ())) for v in nxt]
+        assert np.asarray(st["cd"])[:f].tolist() == np.cumsum(degs).tolist()
+        assert int(st["m"]) == sum(degs)
+        par = np.asarray(st["par"])
+        assert {v: int(par[v]) for v in np.flatnonzero(par != bfs.SENT).tolist()} == parent
+        assert np.asarray(st["lvl"])[nxt].tolist() == [cur + 1] * len(nxt)
+        level = nxt
+    assert [len(level), parent[100], parent[101]] == [3, 30, 40]
 
 
 ROUTES = [
