@@ -18,7 +18,12 @@ import urllib.request
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(BENCH_DIR)
-DEVICE_ROUTES = ("resident", "inline", "csr", "chain", "classed")
+# the labels of dgraph_ledger_hop_edges_total that are the device's work in the
+# program as it stands (utils/metrics.py lists all ten): the per-level program
+# (`resident` on a TPU, `csr` elsewhere), hops merged into one (`merged`), the
+# fused chain, the MXU tier, the path search's BFS and the mesh executor.
+# `cache`, `host` and `empty` are the host's.
+DEVICE_ROUTES = ("resident", "csr", "merged", "chain", "mxu", "path", "mesh")
 HTTP_TIMEOUT_S = 1100.0
 
 
@@ -106,11 +111,13 @@ class Server:
             f.seek(max(0, f.tell() - n))
             return f.read().decode(errors="replace")
 
-    def stop(self) -> None:
+    def stop(self, grace_s: float = 120.0) -> None:
+        """Asks the server to shut down and waits ``grace_s`` for it; one
+        that is still there (a profiler stop that does not return) is killed."""
         if self.proc.poll() is None:
             try:
                 http(self.addr, "/admin/shutdown", timeout=10.0)
-                self.proc.wait(timeout=120)
+                self.proc.wait(timeout=grace_s)
             except (urllib.error.URLError, OSError, subprocess.TimeoutExpired):
                 pass
         if self.proc.poll() is None:

@@ -3,7 +3,9 @@ least time the chip's memory could move the bytes of the traversal work the
 device routes carried in the traced window (``work.traversal_bytes`` — a
 function of edges and frontier rows, whatever kernel does it) over the time
 its operations ran in the trace.  Memory-bound by construction: a traversal
-has no arithmetic to speak of.
+has no arithmetic to speak of.  On a mesh the bytes are held against ALL the
+traced chips' bandwidth (``trace["devices"]`` x one chip's peak) and the
+busy time is the mean a chip.
 
 Edges on device routes: ``dgraph_ledger_hop_edges_total{route}`` over the
 window (only the program knows the route).  Frontier rows: the reference's
@@ -26,4 +28,4 @@ def read(obs):
         if e is not None and ((tail.get("extensions") or {}).get("ledger") or {}).get("edges")
     )
     return work.roofline_share(on_device, rows * on_device / total, t["busy_s"],
-                               obs.peaks["hbm_bytes_per_s"])
+                               obs.peaks["hbm_bytes_per_s"], devices=t["devices"])

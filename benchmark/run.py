@@ -46,7 +46,53 @@ import stats  # noqa: E402
 import tracered  # noqa: E402
 import trafficgen  # noqa: E402
 
-TRACE_WINDOW_S = 15.0    # a traced run's window: the trace comes back whole
+TRACE_WINDOW_S = 15.0    # a one-chip traced run's window: the trace comes back whole
+KILL_GRACE_S = 10.0      # a server whose profiler does not return is not waited for
+
+
+def trace_rule(chips: int) -> dict:
+    """What a traced run does with the profiler, from the cell's ``chips``
+    and nothing else (PERF.md section 2 has the measured seconds behind it).
+
+    ``window_s``: the profiler's stop takes about 120 us a device event of
+    the trace, and the events are chips x seconds x what the cell's programs
+    run a second — so the window shrinks as the chips grow, and a one-chip
+    cell keeps its 15 s.  ``budget_s``: the seconds the profiler's start and
+    stop may take TOGETHER; the paths cell's 15 s on one chip need 57-60 of
+    them, four chips' 3.75 s of the traverse mix 64.  Set-up has no budget
+    (a first run in a checkout compiles for minutes), and the reading of the
+    file none either: it is 3-7 s of this process's own, timed beside them."""
+    return {"window_s": TRACE_WINDOW_S / max(1, int(chips)), "budget_s": 120.0}
+
+
+class ProfilerOverBudget(RuntimeError):
+    pass
+
+
+class TraceClock:
+    """The profiler's phases of a traced run: ``split`` holds the seconds of
+    each, and start and stop share ONE budget — a wait that outlasts what
+    the earlier one left ends the run with the seconds of each phase; it is
+    not left for the driver to kill."""
+
+    def __init__(self, budget_s: float):
+        self.budget_s = float(budget_s)
+        self.split = {}
+
+    def wait(self, name: str, path: str) -> dict:
+        """The server child's acknowledgement at ``path`` of the phase
+        ``name`` (``start``, ``stop``)."""
+        t0 = time.monotonic()
+        try:
+            return _wait_for(path, max(0.0, self.budget_s - sum(self.split.values())))
+        except TimeoutError:
+            self.split[name] = time.monotonic() - t0
+            raise ProfilerOverBudget(
+                f"the profiler's {name} took {self.split[name]:.1f} s of {self.budget_s:.0f} "
+                f"for start and stop together; trace_split_s {json.dumps(self.split)}"
+            ) from None
+        finally:
+            self.split.setdefault(name, time.monotonic() - t0)
 
 
 def say(*a) -> None:
@@ -188,7 +234,7 @@ def set_up(server, workdir: str, quads: int, seed: int, mix: dict, chips: int,
     return Ready(identity, world, classes, plan, gen, warm, warm_answers)
 
 
-def measure(server, control_dir: str, traced: bool, ready, seconds, mix) -> dict:
+def measure(server, control_dir: str, clock, ready, seconds, mix) -> dict:
     """The window, with the program's counters read on either side of it and
     the profiler started before and stopped after it in a traced run.
 
@@ -199,12 +245,13 @@ def measure(server, control_dir: str, traced: bool, ready, seconds, mix) -> dict
     its own, so the result cache is as cold as before; after
     ``window_retries`` of those the run gives no result.  A traced run's
     window is profiled once and is not run again: ``compile_s_in_window`` is
-    one of its metrics."""
+    one of its metrics.  ``clock`` is a traced run's ``TraceClock``, else None."""
     w = mix["warm"]
+    traced = clock is not None
     attempts = 1 if traced else 1 + int(w["window_retries"])
     if traced:
         open(os.path.join(control_dir, "trace.start"), "w").close()
-        _wait_for(os.path.join(control_dir, "trace.started"), 120.0)
+        clock.wait("start", os.path.join(control_dir, "trace.started"))
     for attempt in range(attempts):
         tag = f"r{attempt}" if attempt else ""
         memo = {}
@@ -228,10 +275,10 @@ def measure(server, control_dir: str, traced: bool, ready, seconds, mix) -> dict
     trace_ack = None
     if traced:
         open(os.path.join(control_dir, "trace.stop"), "w").close()
-        trace_ack = _wait_for(os.path.join(control_dir, "trace.stopped"), 240.0)
+        trace_ack = clock.wait("stop", os.path.join(control_dir, "trace.stopped"))
     return {"win": win, "before": before, "after": after, "setup_s": setup_s,
             "tag": tag, "windows": attempt + 1, "window_compile_s": compile_s,
-            "trace_ack": trace_ack,
+            "trace_ack": trace_ack, "clock": clock,
             "dev": harness.http_json(server.addr, "/debug/device"),
             "planner": harness.http_json(server.addr, "/debug/planner").get("counts")}
 
@@ -255,7 +302,9 @@ def main(argv=None) -> int:
     quads = args.quads or int(config["scale"]["quads"])
     chips = int(cell["chips"])
     traced = bool(args.trace)
-    seconds = min(args.seconds, TRACE_WINDOW_S) if traced else args.seconds
+    rule = trace_rule(chips)
+    seconds = min(args.seconds, rule["window_s"]) if traced else args.seconds
+    clock = TraceClock(rule["budget_s"]) if traced else None
 
     workdir = os.path.join(harness.CHECKOUT, ".bench_work", f"run-{os.getpid()}")
     shutil.rmtree(workdir, ignore_errors=True)
@@ -275,7 +324,7 @@ def main(argv=None) -> int:
             return 1
         why = harness.unfit(server.addr, chips)
         if rehearsal or not why:
-            m = measure(server, control_dir, traced, ready, seconds, mix)
+            m = measure(server, control_dir, clock, ready, seconds, mix)
             why = harness.unfit(server.addr, chips)   # a failover inside the window
         server.stop()               # the program's state is freed before the reference runs
         server = None
@@ -287,6 +336,12 @@ def main(argv=None) -> int:
             say("no result: " + "; ".join(why))
             return 1
         return report(args, bench, cell, quads, control_dir, ready, split, m)
+    except ProfilerOverBudget as e:
+        say(f"no result: {e}; setup_split_s {json.dumps(split)}")
+        if server is not None:
+            server.stop(grace_s=KILL_GRACE_S)
+            server = None
+        return 1
     except Exception as e:  # noqa: BLE001 — report, clean up, fail with no result line
         say(traceback.format_exc()[-3000:])
         say(f"no result: {type(e).__name__}: {e}"[:4000])
@@ -309,10 +364,12 @@ def report(args, bench, cell, quads, control_dir, ready, split, m) -> int:
     window_s = win["t_close"] - win["t_open"]
     reduced = None
     if traced:
+        t0 = time.monotonic()
         reduced = tracered.reduce(
             tracered.load_xplane(tracered.find_xplane(os.path.join(control_dir, "trace"))),
             window_s=m["trace_ack"]["traced_s"], rehearsal=rehearsal,
         )
+        m["clock"].split["read"] = time.monotonic() - t0
     t0 = time.monotonic()
     cmp_ = compare.compare(records, classes, tag=m["tag"])
     cmp_["numbers"]["unanswered"] += len(win["never_answered"])
@@ -362,6 +419,7 @@ def report(args, bench, cell, quads, control_dir, ready, split, m) -> int:
         "latency_ms": {f"p{q}": 1e3 * stats.percentile(lat, q) for q in (50, 90, 95, 99)}
         if lat else {},
         "setup_split_s": split, "warm_up": warm, "check_s": check_s,
+        **({"trace_split_s": m["clock"].split} if traced else {}),
         "bytes_answered": sum(len(r[6]) for r in answered),
         "by_class": {k: {"n": len(v), "sum_s": sum(v),
                          **{f"p{q}_ms": 1e3 * stats.percentile(v, q) for q in (50, 90, 99)},
@@ -405,13 +463,18 @@ def report(args, bench, cell, quads, control_dir, ready, split, m) -> int:
 
 
 def _wait_for(path: str, timeout_s: float) -> dict:
+    """The server child's acknowledgement of a profiler step, or
+    ``TimeoutError`` where it does not come in ``timeout_s``."""
     t0 = time.monotonic()
     while not os.path.exists(path):
         if time.monotonic() - t0 > timeout_s:
-            raise RuntimeError(f"the server child did not write {os.path.basename(path)}")
+            raise TimeoutError(os.path.basename(path))
         time.sleep(0.02)
     with open(path) as f:
-        return json.load(f)
+        ack = json.load(f)
+    if ack.get("error"):
+        raise RuntimeError(f"the profiler in the server child: {ack['error']}")
+    return ack
 
 
 def _peaks(kind: str, rehearsal: bool) -> dict | None:

@@ -5,11 +5,14 @@ No server, no chip, no JAX.
 1. The reduction from a trace to ``busy_s``, ``device_idle_share``,
    ``traversal_roofline`` and ``breakdown``, on a trace recorded on the chip
    and kept in ``fixtures/trace_v5e.json.gz`` (a cut of one traced run of
-   ``film-q4.traverse``), against numbers worked out here the slow way; and
-   on a hand-made trace whose answer is known by construction.
+   ``film-q4.traverse``), against numbers worked out here the slow way; on
+   a hand-made trace whose answer is known by construction; and on one of
+   four device planes (a mesh): busy, operations and gaps are a chip's mean,
+   the roofline is held against four chips' bandwidth, and the mesh's and
+   the path search's edges count as the device's.
 2. The bytes function.
 3. The percentile and rate arithmetic on a made-up window that holds a
-   stall: the stall has to move ``query_p95_ms`` and ``edges_per_s``.
+   stall: the stall has to move the 95th percentile (``latency_p95_ms``) and ``edges_per_s``.
 4. The traffic generator: every stretch of a seed's sequence holds the same
    shares of classes and sizes.
 """
@@ -24,6 +27,7 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
+import harness  # noqa: E402
 import stats  # noqa: E402
 import tracered  # noqa: E402
 import trafficgen  # noqa: E402
@@ -120,6 +124,39 @@ def check_reduction() -> None:
           f"top op {r['device_ops'][0]}; longest gap {r['idle_gaps'][0]}")
 
 
+def four_plane_trace() -> dict:
+    """Four chips over a 20 ms extent.  Chip n runs 'exchange' at [n, n+2)
+    ms and 'expand' at [10, 10+n+1) ms: busy 3, 4, 5, 6 ms.  The host:
+    'dgraph.plan' [0, 10) ms, 'dgraph.encode' [10, 20) ms."""
+    ms = 1e6
+    planes = [{"name": f"/device:TPU:{n}", "lines": [
+        {"name": "XLA Ops", "events": [["exchange", n * ms, 2 * ms], ["expand", 10 * ms, (n + 1) * ms]]},
+        {"name": "XLA Modules", "events": [["jit_run", 0.0, 20 * ms]]},
+    ]} for n in range(4)]
+    planes.append({"name": "/host:CPU", "lines": [{"name": "worker", "events": [
+        ["dgraph.plan", 0.0, 10 * ms], ["dgraph.encode", 10 * ms, 10 * ms]]}]})
+    return {"planes": planes}
+
+
+def check_mesh_reduction() -> None:
+    r = tracered.reduce(four_plane_trace(), window_s=0.020)
+    check(r["devices"] == 4 and close(r["busy_s"], (3 + 4 + 5 + 6) / 4 / 1e3),
+          "four-plane trace: busy is the mean a chip", got=r)
+    ops, gaps = dict(r["device_ops"]), dict(r["idle_gaps"])
+    check(close(ops["exchange"], 0.002) and close(ops["expand"], (1 + 2 + 3 + 4) / 4 / 1e3),
+          "four-plane trace: device_ops are a chip's seconds", got=ops)
+    check(close(gaps["dgraph.plan"], 0.008) and close(gaps["dgraph.encode"], (9 + 8 + 7 + 6) / 4 / 1e3),
+          "four-plane trace: idle gaps are a chip's seconds, by the host event over them", got=gaps)
+    check(close(sum(gaps.values()) + r["busy_s"], 0.020), "four-plane trace: busy + idle gaps = the window")
+    one = work.roofline_share(1e6, 1e5, r["busy_s"], 819e9)
+    four = work.roofline_share(1e6, 1e5, r["busy_s"], 819e9, devices=r["devices"])
+    check(close(four, one / 4) and close(four, 100 * (8.8e6 / (4 * 819e9)) / 0.0045),
+          "traversal_roofline on four planes: a quarter of the one-plane share for the same bytes and busy time",
+          got=(one, four))
+    split = harness.route_split({"mesh": 900.0, "path": 50.0, "chain": 30.0, "host": 15.0, "cache": 5.0})
+    check(split == (980.0, 1000.0), "route_split: mesh, path and chain edges are the device's", got=split)
+
+
 def check_bytes() -> None:
     check(work.traversal_bytes(10, 3) == 8 * 10 + 8 * 3, "traversal_bytes: 8 B an edge, 8 B a row")
 
@@ -134,7 +171,7 @@ def check_window_arithmetic() -> None:
     p95_s, rate_s = stats.percentile(lat_s, 95), stats.rate(2000 * len(lat_s), 10.0)
     check(close(p95, 0.5) and close(stats.percentile(lat, 50), 0.5),
           "steady window: p50 = p95 = 500 ms")
-    check(close(p95_s, 2.5) and close(stats.percentile(lat_s, 50), 0.5), "a stall moves query_p95_ms (no trimming, no chunking)",
+    check(close(p95_s, 2.5) and close(stats.percentile(lat_s, 50), 0.5), "a stall moves the 95th percentile (no trimming, no chunking)",
           steady=p95, stalled=p95_s)
     check(close(rate_s, 0.8 * rate), "a stall moves edges_per_s (all the window's seconds count)",
           steady=rate, stalled=rate_s)
@@ -177,6 +214,7 @@ def check_deal() -> None:
 
 def main() -> int:
     check_reduction()
+    check_mesh_reduction()
     check_bytes()
     check_window_arithmetic()
     check_deal()

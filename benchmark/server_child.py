@@ -38,24 +38,34 @@ def _ack(path: str, obj: dict) -> None:
 
 
 def _watch(control: str) -> None:
-    import jax
-
     def wait_for(name: str) -> None:
         p = os.path.join(control, name)
         while not os.path.exists(p):
             time.sleep(POLL_S)
 
     wait_for("trace.start")
+    # not before: imported at the thread's start it raced the main thread's own
+    # imports for numpy's module lock, and one boot in some dozens died of it
+    import jax
+
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0   # the host's Python frames would swamp the file
-    opts.host_tracer_level = 2
+    opts.host_tracer_level = 2     # 1 holds as many events (measured, PR 33): the runtime's stay
     t0 = time.time_ns()
-    jax.profiler.start_trace(os.path.join(control, "trace"), profiler_options=opts)
+    try:
+        jax.profiler.start_trace(os.path.join(control, "trace"), profiler_options=opts)
+    except Exception as e:  # noqa: BLE001 — the parent reads why and gives no result
+        _ack(os.path.join(control, "trace.started"), {"error": repr(e)})
+        return
     t1 = time.time_ns()
     _ack(os.path.join(control, "trace.started"), {"call_ns": t0, "return_ns": t1})
     wait_for("trace.stop")
     t2 = time.time_ns()
-    jax.profiler.stop_trace()
+    try:
+        jax.profiler.stop_trace()
+    except Exception as e:  # noqa: BLE001
+        _ack(os.path.join(control, "trace.stopped"), {"error": repr(e)})
+        return
     t3 = time.time_ns()
     _ack(os.path.join(control, "trace.stopped"),
          {"call_ns": t2, "return_ns": t3, "traced_s": (t2 - t1) / 1e9})

@@ -11,8 +11,9 @@ hold).  None of them is part of a benchmark run.
   come out NOT correct, and the same run without the fault correct.
 
 The other faults the contract lists (a state returned unchanged, half a
-batch left out, the exchange between chips left out) are a training step's
-or a mesh's: a one-chip cell that serves reads has none of them.
+batch left out) are a training step's: a cell that serves reads has neither.
+The exchange between chips left out is a mesh's: no cell runs one yet, and
+``test_yardstick.py`` plants it on four virtual devices (``faults/no_exchange.py``).
 """
 
 import json
