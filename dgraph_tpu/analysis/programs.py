@@ -1206,6 +1206,24 @@ def _b_resident_merge() -> List[ProgramInstance]:
     ]
 
 
+def _b_arena_scatter() -> List[ProgramInstance]:
+    jnp, np = _jnp()
+    from dgraph_tpu.models import arena as marena
+
+    # as CSRArena._layouts_take_delta calls it: a layout table with its
+    # (index, row) pairs padded past the end, and the 1-D LUT with entries
+    table = jnp.asarray(np.zeros((16, 8), np.int32))
+    idx = jnp.asarray(np.array([3, 15, 16, 16], np.int32))
+    rows = jnp.asarray(np.ones((4, 8), np.int32))
+    lut = jnp.asarray(np.full(32, -1, np.int32))
+    return [
+        ProgramInstance("T16x8xK4", marena._scatter_rows, (table, idx, rows)),
+        ProgramInstance(
+            "L32xK4", marena._scatter_rows, (lut, idx, rows[:, 0])
+        ),
+    ]
+
+
 _INT = frozenset({"int32", "bool"})
 # searchsorted-bearing kernels: jnp.searchsorted lowers to a log-depth
 # lax.scan whose index carry is uint32 (documented at ops/sets.py
@@ -1479,6 +1497,18 @@ REGISTRY: Dict[str, ProgramContract] = {
                   "pairs into the NEXT epoch's (offsets, dst) — the "
                   "device twin of CSRArena._apply_delta_locked.  Only "
                   "the padded delta pairs ever cross h2d." + _SS_NOTE,
+        ),
+        ProgramContract(
+            name="arena.scatter_rows",
+            covers=("dgraph_tpu/models/arena.py::_scatter_rows",),
+            build=_b_arena_scatter,
+            notes="PR 34: a write's delta scattered into a device inline "
+                  "layout or LUT that is there (touched metap rows, moved "
+                  "or new overflow chunks, new uid->row entries); indices "
+                  "past the end are the padding and are dropped.  NOT "
+                  "donated by design: a holder of the old table keeps a "
+                  "whole snapshot.  Held to a build from the host mirrors "
+                  "by tests/test_write_under_read.py.",
         ),
         ProgramContract(
             name="mesh.multi_hop",

@@ -33,7 +33,12 @@ import jax.numpy as jnp
 
 from dgraph_tpu import obs, ops
 from dgraph_tpu.obs import ledger as _ledger
-from dgraph_tpu.utils.metrics import ARENA_EVICTIONS, RESIDENT_EPOCHS
+from dgraph_tpu.utils.metrics import (
+    ARENA_EVICTIONS,
+    ARENA_LAYOUT_UPDATES,
+    ARENA_REFRESH_H2D_BYTES,
+    RESIDENT_EPOCHS,
+)
 from dgraph_tpu.ops.sets import SENT
 from dgraph_tpu import tok as tokmod
 from dgraph_tpu.models.store import PostingStore
@@ -55,6 +60,134 @@ def _book_h2d(arrays) -> None:
     led = _ledger.current()
     if led is not None:
         led.bytes_h2d += sum(int(a.nbytes) for a in arrays)
+
+
+# What an in-place layout update may rewrite: rows of ``metap`` and chunks of
+# ``ov``.  Past either the layout is built anew — derived from sizes, as the
+# IVM gate is: a rewrite of this many 32-byte rows is 2 MB put on the device,
+# a fortieth of the smallest chain arena's layout.
+_LAYOUT_ROWS_MAX = 4096
+_LAYOUT_CHUNKS_MAX = 65536
+_SCATTER_MIN = 64   # index vectors are padded to this (one program a table)
+
+
+def _chunks_of(deg: np.ndarray) -> np.ndarray:
+    """Overflow chunks a row of each degree holds: its targets past the
+    first INLINE, ``ops.CHUNK`` a chunk."""
+    return (np.maximum(deg - ops.INLINE, 0) + ops.CHUNK - 1) // ops.CHUNK
+
+
+def _ov_capacity(n_chunks: int) -> int:
+    """Rows of an overflow-chunk table that holds ``n_chunks`` and can take
+    writes: a sixteenth of room (at least 1,024 chunks), rounded up to an
+    eighth-step of a power of two.  The table's length is a static shape of
+    every compiled chain program, so it must not follow the graph chunk by
+    chunk (``ops.expand_inline_seg`` reads it in a ``clip`` only)."""
+    return ops.bucket_fine(n_chunks + max(1024, n_chunks >> 4))
+
+
+@jax.jit
+def _scatter_rows(table, idx, rows):
+    """``table`` with ``rows`` written at ``idx`` (an index past the end is
+    dropped: the padding).  NOT donated: a reader that holds the old table —
+    an embedded engine, a clustered refresh, a dispatch in flight — keeps a
+    whole snapshot, as ``ResidentArena``'s shadow epoch does; the price is a
+    device-side copy of the table (67 MB at the film graph's largest: 0.2 ms
+    of the chip's bandwidth)."""
+    return table.at[idx].set(rows, mode="drop")
+
+
+def _put_scatter(table, idx: np.ndarray, rows: np.ndarray):
+    """Pads (idx, rows) to a bucketed length and scatters them into the
+    device ``table``; books the bytes that crossed."""
+    k = ops.bucket(max(_SCATTER_MIN, len(idx)))
+    pad_idx = np.full(k, table.shape[0], dtype=np.int32)
+    pad_idx[: len(idx)] = idx
+    pad_rows = np.zeros((k,) + tuple(table.shape[1:]), dtype=np.int32)
+    pad_rows[: len(idx)] = rows
+    di, dr = jnp.asarray(pad_idx), jnp.asarray(pad_rows)
+    _book_refresh_h2d((di, dr))
+    return _scatter_rows(table, di, dr)
+
+
+def _book_refresh_h2d(arrays) -> None:
+    """``_book_h2d`` for what a write put on the device (a layout's delta or
+    its rebuild): the writer's account and the refresh counter."""
+    _book_h2d(arrays)
+    ARENA_REFRESH_H2D_BYTES.add(sum(int(a.nbytes) for a in arrays))
+
+
+def _topm_replace(cs: np.ndarray, old: np.ndarray, new: np.ndarray) -> np.ndarray:
+    """``cs`` — [0, cumsum of the descending-sorted positive values] — after
+    the values ``old`` left the multiset and ``new`` joined it (zeros are
+    not held).  Exact: a bound that drifted by one a write would walk a
+    chain program's capacity across a power of two."""
+    asc = np.diff(cs)[::-1]
+    old, new = np.sort(old[old > 0]), np.sort(new[new > 0])
+    if len(old):
+        # the k-th of several equal values leaves the k-th place of its run
+        nth = np.arange(len(old)) - np.searchsorted(old, old, side="left")
+        asc = np.delete(asc, np.searchsorted(asc, old, side="left") + nth)
+    if len(new):
+        asc = np.insert(asc, np.searchsorted(asc, new), new)
+    return np.concatenate([[0], np.cumsum(asc[::-1])])
+
+
+def _rows_of_uids(h_src: np.ndarray, uids: np.ndarray) -> np.ndarray:
+    """Row of each uid in the sorted ``h_src``, -1 where it has none."""
+    if not len(h_src):
+        return np.full(len(uids), -1, dtype=np.int64)
+    pos = np.minimum(np.searchsorted(h_src, uids), len(h_src) - 1)
+    return np.where(h_src[pos] == uids, pos, -1)
+
+
+def _with_new_rows(h_src, h_offsets, srcs):
+    """(h_src, h_offsets) with a degree-0 row for every uid of ``srcs``
+    that has none, h_src kept sorted."""
+    u = np.unique(srcs)
+    newsrc = u[_rows_of_uids(h_src, u) < 0]
+    if len(newsrc):
+        at = np.searchsorted(h_src, newsrc)
+        h_src = np.insert(h_src, at, newsrc)
+        h_offsets = np.insert(h_offsets, at + 1, h_offsets[at])
+    return h_src, h_offsets
+
+
+def _shift_offsets(h_offsets: np.ndarray, rows: np.ndarray, sign: int) -> None:
+    """``h_offsets`` (the caller's own copy) after one edge joined
+    (``sign`` +1) or left (-1) each row of ``rows``: only the offsets past
+    the first touched row move, so rows appended at the end cost nothing."""
+    lo = int(rows.min())
+    cnt = np.bincount(rows - lo, minlength=len(h_offsets) - 1 - lo)
+    h_offsets[lo + 1:] += sign * np.cumsum(cnt)
+
+
+def _merge(h_src, h_offsets, h_dst, adds, dels):
+    """The host mirrors after edges left and joined: each edge's place is
+    a search in its own row (a journal window holds 65,536 at most), and
+    every array is copied once — no pass over the arena's edges but the
+    copies themselves."""
+    theirs = h_offsets          # the published array: never written in place
+    for arr, sign in ((dels, -1), (adds, +1)):
+        if not len(arr):
+            continue
+        if sign > 0:
+            h_src, h_offsets = _with_new_rows(h_src, h_offsets, arr[:, 0])
+        order = np.lexsort((arr[:, 1], arr[:, 0]))
+        srcs, dsts = arr[order, 0], arr[order, 1]
+        rows = np.searchsorted(h_src, srcs)
+        lo, hi = h_offsets[rows], h_offsets[rows + 1]
+        pos = np.fromiter(
+            (a + np.searchsorted(h_dst[a:b], d)
+             for a, b, d in zip(lo.tolist(), hi.tolist(), dsts.tolist())),
+            dtype=np.int64, count=len(rows),
+        )
+        h_dst = (np.insert(h_dst, pos, dsts.astype(np.int32)) if sign > 0
+                 else np.delete(h_dst, pos))
+        if h_offsets is theirs:
+            h_offsets = h_offsets.copy()
+        _shift_offsets(h_offsets, rows, sign)
+    return h_src, h_offsets, h_dst
 
 
 @dataclass
@@ -156,62 +289,83 @@ class CSRArena:
         return n
 
     _inline: Optional[tuple] = None  # lazy (metap, ov_chunks)
+    _ov_coff: Optional[np.ndarray] = None  # int64[S+1]: a row's first chunk
 
     def inline_layout(self) -> tuple:
         """Inline-head layout for ops.expand_inline_seg, built lazily.
 
         Returns (metap, ov_chunks): int32[Sb, 8] per-row rows with
         lane0 = overflow chunk start, lane1 = degree, lanes 2..7 = the
-        first INLINE targets (SENT pad); int32[NCov, 8] overflow chunks
-        (targets INLINE.. of each row), UNPADDED row count.  One row
-        gather serves metadata AND short posting lists (docs/ROOFLINE.md
-        round 4)."""
+        first INLINE targets (SENT pad); int32[cap, 8] overflow chunks
+        (targets INLINE.. of each row, a row's chunks side by side, rows
+        in order), ``cap`` = ``_ov_capacity`` of the chunks in use: SENT
+        rows past them, which a write fills.  One row gather serves
+        metadata AND short posting lists (docs/ROOFLINE.md round 4).
+        Kept true by ``_apply_delta_locked``: always what a build from the
+        host mirrors would give, up to the capacity."""
         if self._inline is not None:
             return self._inline
         # stage h2d: built and put on first use — the request that meets
         # the layout missing pays for it (or waits out another's build)
         with obs.stage(None, "h2d_ms"), _BUILD_LOCK:
-            if self._inline is not None:
-                return self._inline
-            INL = ops.INLINE
-            S = self.n_rows
-            deg = self.h_offsets[1:] - self.h_offsets[:-1]
-            ovdeg = np.maximum(deg - INL, 0)
-            cdeg = (ovdeg + 7) >> 3
-            coff = np.zeros(S + 1, dtype=np.int64)
-            np.cumsum(cdeg, out=coff[1:])
-            NCov = int(coff[-1])
-            Sb = ops.bucket(max(1, S))
-            metap = np.full((Sb, 8), SENT, dtype=np.int32)
-            metap[:, :2] = 0
-            metap[:S, 0] = coff[:-1]
-            metap[:S, 1] = deg
-            h_dst = self.host_dst() if self.n_edges else np.zeros(0, np.int32)
-            starts = self.h_offsets[:-1]
-            for j in range(INL):
-                sel = deg > j
-                metap[:S][sel, 2 + j] = h_dst[starts[sel] + j]
-            ov = np.full((max(1, NCov), 8), SENT, dtype=np.int32)
-            rows = np.nonzero(deg > INL)[0]
-            if len(rows):
-                # vectorized tail-edge index set (no per-row arange loop):
-                # within = 0..ovdeg-1 per row via the repeat/cumsum trick
-                od = ovdeg[rows]
-                rowid = np.repeat(rows, od)
-                ends = np.cumsum(od)
-                within = np.arange(int(ends[-1]), dtype=np.int64) - np.repeat(
-                    ends - od, od
-                )
-                e = starts[rowid] + INL + within
-                ov[coff[rowid] + (within >> 3), within & 7] = h_dst[e]
-            self._inline = (jnp.asarray(metap), jnp.asarray(ov))
-            _book_h2d(self._inline)
+            if self._inline is None:
+                self._build_inline()
+                _book_h2d(self._inline)
             return self._inline
+
+    def _inline_rows(self, rows: np.ndarray, coff_rows: np.ndarray):
+        """(metap rows, chunk positions, chunk rows) of ``rows`` (ascending
+        row indices) from the host mirrors, ``coff_rows`` being each row's
+        first chunk: the layout's arithmetic, for a build and for a delta."""
+        INL = ops.INLINE
+        starts = self.h_offsets[rows]
+        deg = self.h_offsets[rows + 1] - starts
+        h_dst = self.host_dst() if self.n_edges else np.zeros(0, np.int32)
+        mrows = np.full((len(rows), 8), SENT, dtype=np.int32)
+        mrows[:, 0] = coff_rows
+        mrows[:, 1] = deg
+        for j in range(INL):
+            sel = deg > j
+            mrows[sel, 2 + j] = h_dst[starts[sel] + j]
+        od = np.maximum(deg - INL, 0)
+        cd = _chunks_of(deg)
+        n_chunks = int(cd.sum())
+        cpos = np.repeat(coff_rows, cd) + (
+            np.arange(n_chunks, dtype=np.int64) - np.repeat(np.cumsum(cd) - cd, cd)
+        )
+        crows = np.full((n_chunks, 8), SENT, dtype=np.int32)
+        if n_chunks:
+            # vectorized tail-edge index set (no per-row arange loop):
+            # within = 0..od-1 per row via the repeat/cumsum trick
+            big = np.nonzero(od)[0]
+            odb = od[big]
+            ends = np.cumsum(odb)
+            within = np.arange(int(ends[-1]), dtype=np.int64) - np.repeat(ends - odb, odb)
+            which = np.repeat(big, odb)
+            first = (np.cumsum(cd) - cd)[which]      # the row's first chunk, here
+            crows[first + (within >> 3), within & 7] = h_dst[
+                starts[which] + INL + within
+            ]
+        return mrows, cpos, crows
+
+    def _build_inline(self) -> None:
+        """The whole layout from the host mirrors, put on the device (the
+        caller holds ``_BUILD_LOCK`` and books the bytes)."""
+        S = self.n_rows
+        coff = np.zeros(S + 1, dtype=np.int64)
+        np.cumsum(_chunks_of(np.diff(self.h_offsets)), out=coff[1:])
+        mrows, cpos, crows = self._inline_rows(np.arange(S, dtype=np.int64), coff[:-1])
+        metap = np.full((ops.bucket(max(1, S)), 8), SENT, dtype=np.int32)
+        metap[:, :2] = 0
+        metap[:S] = mrows
+        ov = np.full((_ov_capacity(int(coff[-1])), 8), SENT, dtype=np.int32)
+        ov[cpos] = crows
+        self._ov_coff = coff
+        self._inline = (jnp.asarray(metap), jnp.asarray(ov))
 
     def ov_chunk_degree_of_rows(self, rows: np.ndarray) -> np.ndarray:
         """Host overflow-chunk-count lookup for inline_layout planning."""
-        d = np.maximum(self.degree_of_rows(rows) - ops.INLINE, 0)
-        return (d + 7) >> 3
+        return _chunks_of(self.degree_of_rows(rows))
 
     # -- MXU join tier (ops/spgemm.py) --------------------------------------
 
@@ -300,23 +454,20 @@ class CSRArena:
             return self._lut
         with obs.stage(None, "h2d_ms"), _BUILD_LOCK:  # as inline_layout
             cur = self._lut
-            if cur is not None and cur.shape[0] >= need:
-                return cur
-            t = np.full(need, -1, dtype=np.int32)
-            if self.n_rows:
-                keys = self.h_src[self.h_src <= universe]
-                t[keys] = np.arange(len(keys), dtype=np.int32)
-            self._lut = jnp.asarray(t)
-            _book_h2d((self._lut,))
+            if cur is None or cur.shape[0] < need:
+                self._build_lut(need)
+                _book_h2d((self._lut,))
             return self._lut
 
+    def _build_lut(self, size: int) -> None:
+        t = np.full(size, -1, dtype=np.int32)
+        if self.n_rows:
+            keys = self.h_src[self.h_src < size]
+            t[keys] = np.arange(len(keys), dtype=np.int32)
+        self._lut = jnp.asarray(t)
+
     def rows_for_uids_host(self, uids: np.ndarray) -> np.ndarray:
-        pos = np.searchsorted(self.h_src, uids)
-        pos = np.clip(pos, 0, max(0, self.n_rows - 1))
-        if self.n_rows == 0:
-            return np.full(len(uids), -1, dtype=np.int64)
-        hit = self.h_src[pos] == uids
-        return np.where(hit, pos, -1)
+        return _rows_of_uids(self.h_src, np.asarray(uids))
 
     # -- device-resident tier (PR 16: ops/pallas_gather.py) -----------------
 
@@ -371,74 +522,52 @@ class CSRArena:
             self._apply_delta_locked(adds, dels)
 
     def _apply_delta_locked(self, adds: np.ndarray, dels: np.ndarray) -> None:
-        # degree-histogram repair (IVM satellite): capture the affected
-        # rows' PRE-delta degrees so the log2 buckets can be adjusted
-        # instead of dropped — the planner's skew inputs (joinplan's
-        # heavy-tail pad) otherwise cold-start on every point write
-        pre_rows = self.n_rows  # resident reseed probe: new source rows
-        #                         shift every row index (see tail below)
-        hist = getattr(self, "_deg_hist", None)
-        touched = None
-        if hist is not None:
-            touched = np.unique(np.concatenate([
-                np.asarray(a[:, 0], dtype=np.int64)
-                for a in (adds, dels) if len(a)
-            ])) if (len(adds) or len(dels)) else np.empty(0, np.int64)
-            old_degs = self._degrees_of_uids(touched)
-        h_dst = self.host_dst().astype(np.int64, copy=False)
-        h_src, h_offsets = self.h_src, self.h_offsets
-        # absolute edge positions via the composite (row, dst) key — the
-        # CSR flat dst IS sorted by it
-        for arr, sign in ((dels, -1), (adds, +1)):
-            if not len(arr):
-                continue
-            srcs = arr[:, 0]
-            dsts = arr[:, 1]
-            if sign > 0:
-                # new source rows first (degree 0), keeping h_src sorted
-                newsrc = np.setdiff1d(srcs, h_src)
-                if len(newsrc):
-                    at = np.searchsorted(h_src, newsrc)
-                    h_src = np.insert(h_src, at, newsrc)
-                    h_offsets = np.insert(h_offsets, at + 1, h_offsets[at])
-            n_rows = len(h_src)
-            rows = np.searchsorted(h_src, srcs)
-            keys = (rows.astype(np.int64) << 32) | dsts
-            edge_rows = np.repeat(
-                np.arange(n_rows, dtype=np.int64), np.diff(h_offsets)
-            )
-            edge_keys = (edge_rows << 32) | h_dst
-            order = np.argsort(keys, kind="stable")
-            keys, rows, dsts = keys[order], rows[order], dsts[order]
-            pos = np.searchsorted(edge_keys, keys)
-            if sign > 0:
-                h_dst = np.insert(h_dst, pos, dsts)
-            else:
-                h_dst = np.delete(h_dst, pos)
-            cnt = np.bincount(rows, minlength=n_rows)
-            h_offsets = h_offsets.copy()
-            h_offsets[1:] += sign * np.cumsum(cnt)
-        h_dst = h_dst.astype(np.int32)
+        pre_rows = self.n_rows
+        old_last = int(self.h_src[-1]) if pre_rows else -1
+        n_delta = len(adds) + len(dels)
+        # the rows a delta touches, by uid, with their degrees before it:
+        # the degree histogram, the top-m chunk sums and the device layout
+        # are REPAIRED from them below, not dropped — a point write must
+        # not cost the next reader a pass over the arena
+        touched = np.unique(np.concatenate([
+            np.asarray(a[:, 0], dtype=np.int64) for a in (adds, dels)
+        ])) if n_delta else np.empty(0, np.int64)
+        old_degs = self._degrees_of_uids(touched)
+        h_src, h_offsets, h_dst = _merge(
+            self.h_src, self.h_offsets, self.host_dst(), adds, dels)
         # the new mirrors are published back to back, whole: a reader
         # that holds no lock against this writer (an embedded engine's
         # host expansion, a clustered refresh) must not meet new offsets
         # beside old targets while the arrays above are being built
         self.h_src, self.h_offsets, self._h_dst = h_src, h_offsets, h_dst
         self.n_rows, self.n_edges = len(h_src), len(h_dst)
-        # derived device structures are stale until next device use
-        self._inline = None
-        self._lut = None
-        self._n_distinct_dst = None
-        self._max_uid = None
-        for attr in ("_topm_ovdeg", "_topm_deg", "_tile_blocks"):
-            if hasattr(self, attr):
-                delattr(self, attr)
-        if hist is not None and touched is not None:
+        new_degs = self._degrees_of_uids(touched)
+        # bounds, kept as bounds: every added target may be new, every
+        # deleted one may have twins (an exact count is one np.unique over
+        # the arena, which the next whole build takes)
+        if self._n_distinct_dst is not None and len(adds):
+            self._n_distinct_dst += len(np.unique(adds[:, 1]))
+        if self._max_uid is not None and len(adds):
+            self._max_uid = max(self._max_uid, int(adds.max()))
+        tb = getattr(self, "_tile_blocks", None)
+        if tb is not None and len(adds):
+            self._tile_blocks = (tb[0], tb[1] + len(adds),
+                                 max(tb[2], int(adds.max()) + 1))
+        if hasattr(self, "_topm_deg"):
+            del self._topm_deg   # read by the scan chain, recurse, the mesh
+        cs = getattr(self, "_topm_ovdeg", None)
+        if cs is not None:
+            ocd, ncd = _chunks_of(old_degs), _chunks_of(new_degs)
+            moved = ocd != ncd
+            if moved.any():
+                self._topm_ovdeg = _topm_replace(cs, ocd[moved], ncd[moved])
+        if getattr(self, "_deg_hist", None) is not None:
             # move each affected row between its old and new log2 bucket
-            new_degs = self._degrees_of_uids(touched)
             for od, nd in zip(old_degs.tolist(), new_degs.tolist()):
                 if od != nd:
                     self._hist_move(od, nd)
+        if n_delta and (self._inline is not None or self._lut is not None):
+            self._layouts_take_delta(pre_rows, old_last, touched)
         # MXU tile repair (dgraph_tpu/ivm/): a small delta scatters onto
         # the stored T×T blocks instead of dropping the densified layout
         # wholesale — structurally-impossible repairs (new block, grown
@@ -509,18 +638,87 @@ class CSRArena:
                     RESIDENT_EPOCHS.add("merge")
         self._device_stale = True
 
+    def _layouts_take_delta(self, pre_rows: int, old_last: int,
+                            touched: np.ndarray) -> None:
+        """The device inline layout and LUT after a delta the host mirrors
+        have taken: the touched rows of ``metap``, the chunks that moved or
+        are new and the new rows' LUT entries are scattered into the tables
+        that are there, so that they read as a build from the mirrors would
+        (tests hold them to it).  Built anew — here, on the writer's
+        account, never by the next reader — where rows were renumbered, a
+        table's capacity is outgrown or the rewrite would pass
+        ``_LAYOUT_ROWS_MAX`` / ``_LAYOUT_CHUNKS_MAX``; a LUT the uid space
+        has outgrown is dropped (its size is the caller's to say)."""
+        S = self.n_rows
+        # new rows all lie past the old ones: no old row was renumbered
+        appended = S == pre_rows or pre_rows == 0 or int(
+            self.h_src[pre_rows - 1]) == old_last
+        how = "delta"
+        if self._lut is not None:
+            size = int(self._lut.shape[0])
+            if S and int(self.h_src[-1]) >= size:
+                self._lut = None
+                how = "rebuild"
+            elif not appended:
+                self._build_lut(size)
+                _book_refresh_h2d((self._lut,))
+                how = "rebuild"
+            elif S > pre_rows:
+                self._lut = _put_scatter(
+                    self._lut, self.h_src[pre_rows:].astype(np.int32),
+                    np.arange(pre_rows, S, dtype=np.int32))
+        if self._inline is not None and not (
+            appended and self._inline_take_delta(pre_rows, touched)
+        ):
+            self._build_inline()
+            _book_refresh_h2d(self._inline)
+            how = "rebuild"
+        ARENA_LAYOUT_UPDATES.add(how)
+
+    def _inline_take_delta(self, pre_rows: int, touched: np.ndarray) -> bool:
+        """False where the inline layout cannot take the delta in place.
+        New rows lie past the old ones (the caller saw to it).  A row whose
+        chunk count changed moves the chunks of every row after it, so the
+        layout is rewritten from the first such row on — which is cheap
+        where that row is near the end (a new film's cast), and a rebuild
+        where it is not."""
+        metap, ov = self._inline
+        S, coff = self.n_rows, self._ov_coff
+        if S > metap.shape[0]:
+            return False
+        t_rows = self.rows_for_uids_host(touched)
+        t_rows = t_rows[(t_rows >= 0) & (t_rows < pre_rows)]
+        cd = _chunks_of(self.h_offsets[t_rows + 1] - self.h_offsets[t_rows])
+        moved = t_rows[cd != coff[t_rows + 1] - coff[t_rows]]
+        r0 = int(moved.min()) if len(moved) else pre_rows
+        tail = np.arange(r0, S, dtype=np.int64)
+        tcoff = coff[r0] + np.concatenate([[0], np.cumsum(_chunks_of(
+            self.h_offsets[tail + 1] - self.h_offsets[tail]))])
+        used, was = int(tcoff[-1]), int(coff[-1])
+        head = t_rows[t_rows < r0]
+        if len(tail) + len(head) > _LAYOUT_ROWS_MAX or used > ov.shape[0]:
+            return False
+        rows = np.concatenate([head, tail])
+        mrows, cpos, crows = self._inline_rows(
+            rows, np.concatenate([coff[head], tcoff[:-1]]))
+        if len(cpos) + max(0, was - used) > _LAYOUT_CHUNKS_MAX:
+            return False
+        if was > used:      # chunks given up read as a build leaves them
+            cpos = np.concatenate([cpos, np.arange(used, was)])
+            crows = np.concatenate(
+                [crows, np.full((was - used, 8), SENT, dtype=np.int32)])
+        if len(rows):
+            metap = _put_scatter(metap, rows.astype(np.int32), mrows)
+        if len(cpos):
+            ov = _put_scatter(ov, cpos.astype(np.int32), crows)
+        self._ov_coff = np.concatenate([coff[: r0 + 1], tcoff[1:]])
+        self._inline = (metap, ov)
+        return True
+
     def _degrees_of_uids(self, uids: np.ndarray) -> np.ndarray:
-        """Out-degree per ROW-KEY uid (0 where the uid has no row) —
-        the histogram repair's before/after probe."""
-        if not len(uids):
-            return np.zeros(0, dtype=np.int64)
-        pos = np.searchsorted(self.h_src, uids)
-        pos = np.clip(pos, 0, max(0, self.n_rows - 1))
-        if self.n_rows == 0:
-            return np.zeros(len(uids), dtype=np.int64)
-        hit = self.h_src[pos] == uids
-        deg = self.h_offsets[pos + 1] - self.h_offsets[pos]
-        return np.where(hit, deg, 0).astype(np.int64)
+        """Out-degree per ROW-KEY uid (0 where the uid has no row) — a
+        delta's before/after probe."""
+        return self.degree_of_rows(self.rows_for_uids_host(uids)).astype(np.int64)
 
     def _hist_move(self, old_deg: int, new_deg: int) -> None:
         """Shift one row between log2 degree buckets (bucket definition
@@ -536,6 +734,22 @@ class CSRArena:
                     [h, np.zeros(b + 1 - len(h), dtype=h.dtype)]
                 )
             h[b] += step
+
+    def insert_empty_rows(self, at: np.ndarray) -> None:
+        """Degree-0 rows before the rows ``at`` (ascending, positions in the
+        rows as they are) of an arena whose row keys are the row numbers —
+        an index arena taking new tokens.  Every later row is renumbered,
+        so what was derived from the rows is dropped."""
+        with _BUILD_LOCK:
+            h_offsets = np.insert(self.h_offsets, at + 1, self.h_offsets[at])
+            self.h_src = np.arange(len(h_offsets) - 1, dtype=np.int64)
+            self.h_offsets, self.n_rows = h_offsets, len(h_offsets) - 1
+            self._inline = self._ov_coff = self._lut = None
+            self._resident = self._tiles = None
+            for attr in ("_topm_ovdeg", "_topm_deg", "_tile_blocks", "_deg_hist"):
+                if hasattr(self, attr):
+                    delattr(self, attr)
+            self._device_stale = True
 
     def ensure_device(self) -> None:
         """Re-upload device tensors from the host mirrors if a delta made
@@ -865,6 +1079,34 @@ class IndexArena:
 
     def device_bytes(self) -> int:
         return self.csr.device_bytes()
+
+    def take_values(self, items) -> None:
+        """The index after (uid, value) pairs were set on uids that held no
+        value (``PostingStore.value_delta``): a token that is new gets a
+        row at its place, each uid joins its tokens' rows — what a build
+        from the store would give, without the walk over every value."""
+        pairs = set()
+        for uid, val in items:
+            try:
+                toks = tokmod.tokens_for_value_lang(self.tokenizer, val, "")
+            except (ValueError, TypeError, OverflowError):
+                continue  # unindexable value, as at the build
+            pairs.update((t, int(uid)) for t in toks)
+        if not pairs:
+            return
+        with _BUILD_LOCK:
+            new = sorted({t for t, _ in pairs if self.row_of(t) < 0})
+            if new:
+                at = np.array([bisect.bisect_left(self.tokens, t) for t in new],
+                              dtype=np.int64)
+                tokens = list(self.tokens)   # published whole, as the mirrors are
+                for t in new:
+                    bisect.insort(tokens, t)
+                self.csr.insert_empty_rows(at)
+                self.tokens = tokens
+            adds = np.array(sorted((self.row_of(t), u) for t, u in pairs),
+                            dtype=np.int64).reshape(-1, 2)
+            self.csr.apply_delta(adds, np.zeros((0, 2), dtype=np.int64))
 
     def row_range(self, lo=None, hi=None, lo_open=False, hi_open=False) -> Tuple[int, int]:
         """Token rows t with lo <=(<) t <=(<) hi, as [start, end)."""
@@ -1218,9 +1460,12 @@ class ArenaManager:
         """Drop or incrementally update cached arenas for predicates
         mutated since last refresh.  Small uid-edge deltas (the store's
         bounded journal) update cached data/reverse arenas in place —
-        the gentle-commit amortization (posting/lists.go:109-215) — while
-        value mutations, bulk loads and journal overflow fall back to the
-        full rebuild."""
+        the gentle-commit amortization (posting/lists.go:109-215) — and
+        with them their device layouts (``_layouts_take_delta``); values
+        set on uids that held none go into the predicate's index arenas
+        in place (``IndexArena.take_values``).  A value overwritten or
+        deleted, a bulk load and a journal overflow fall back to the full
+        rebuild."""
         dirty = self.store.dirty
         if not dirty:
             return
@@ -1244,9 +1489,11 @@ class ArenaManager:
             # remaining per-predicate marks fall through to the loop:
             # their caches are already gone, so it just consumes deltas
         deltas = getattr(self.store, "delta", {})
+        vdeltas = getattr(self.store, "value_delta", {})
         bases = getattr(self.store, "delta_base", {})
         for p in list(dirty):
             delta = deltas.pop(p, None)
+            vdelta = vdeltas.pop(p, None)
             # the journal window's repair base (models/store.py) is
             # consumed WITH the journal — a stale base must never
             # re-key a later window's entries
@@ -1255,7 +1502,7 @@ class ArenaManager:
             # the predicate: the delta can't reach an arena that isn't
             # cached yet, so the builder must re-peek (_get_or_build)
             self._inval_gen[p] = self._inval_gen.get(p, 0) + 1
-            if delta is not None and self._try_apply_delta(p, delta, base):
+            if delta is not None and self._take_journal(p, delta, vdelta, base):
                 dirty.discard(p)
                 continue
             for key in [k for k in self._data if k == p or k.startswith(p + "\x00")]:
@@ -1276,6 +1523,25 @@ class ArenaManager:
                 self._index.pop(key, None)
                 self._lru_drop(self._index, key)
             dirty.discard(p)
+
+    def _take_journal(self, pred: str, delta: list, vdelta, base) -> bool:
+        """The cached arenas of ``pred`` after its journal window: uid
+        edges into the data and reverse arenas (``_try_apply_delta``),
+        values set on uids that held none into its index arenas.  False
+        where something cached cannot take it, and the caller drops all."""
+        if not vdelta:
+            return self._try_apply_delta(pred, delta, base)
+        # a value write.  A predicate that carries uid edges as well, or
+        # whose has() rows are cached, is dropped whole, as it always was
+        if (delta or pred in self._data or pred in self._reverse
+                or (pred + "\x00has") in self._data):
+            return False
+        for key in [k for k in self._index if k[0] == pred]:
+            self._index[key].take_values(vdelta)
+            self._touch((id(self._index), key), self._index[key])
+        self._values.pop(pred, None)     # numeric ranks shift: rebuilt on use
+        self._lru_drop(self._values, pred)
+        return True
 
     def _try_apply_delta(self, pred: str, delta: list, base=None) -> bool:
         """Incrementally update the cached data (and reverse) arena for
@@ -1303,14 +1569,16 @@ class ArenaManager:
             # the host store, never in (out, seg_ptr))
             self._repair_hop_entries(pred, a, _E, _E, base, gate=True)
             return True
-        # row-garbage bound: repeated delete churn leaves degree-0 rows
-        # that only a full rebuild reclaims; rebuild once they dominate
-        zero_rows = int(np.count_nonzero(np.diff(a.h_offsets) == 0))
-        if zero_rows > max(4096, a.n_rows // 4):
-            return False
         net: Dict[Tuple[int, int], int] = {}
         for s, d, sign in delta:
             net[(s, d)] = net.get((s, d), 0) + sign
+        # row-garbage bound: repeated delete churn leaves degree-0 rows
+        # that only a full rebuild reclaims; rebuild once they dominate
+        # (a window without a delete adds none: no pass over the rows)
+        if any(v < 0 for v in net.values()) and int(
+            np.count_nonzero(np.diff(a.h_offsets) == 0)
+        ) > max(4096, a.n_rows // 4):
+            return False
         adds = np.array(
             [k for k, v in net.items() if v > 0], dtype=np.int64
         ).reshape(-1, 2)

@@ -74,6 +74,30 @@ class PredicateData:
             m = self._untagged = (arr, vals)
         return m
 
+    def _note_untagged(self, items, fresh: bool) -> None:
+        """A write of untagged values, ``items`` = [(uid, value)]: NEW uids
+        are inserted into the built mirror at their places, all at once (an
+        append for freshly assigned uids) — a 17-quad ingest must not cost
+        every later value leaf a walk over half a million values; an
+        overwrite drops the mirror, as before.  The pair is published
+        whole: a reader holds the old one or the new one."""
+        m = self._untagged
+        if m is None:
+            return
+        if not fresh:
+            self._untagged = None
+            return
+        import numpy as _np
+
+        arr, vals = m
+        items = sorted(items, key=lambda it: it[0])
+        uids = _np.fromiter((u for u, _ in items), dtype=_np.int64, count=len(items))
+        objs = _np.empty(len(items), dtype=object)
+        for i, (_, v) in enumerate(items):
+            objs[i] = v
+        at = _np.searchsorted(arr, uids)
+        self._untagged = (_np.insert(arr, at, uids), _np.insert(vals, at, objs))
+
     def untagged_lookup(self, uids):
         """Vectorized untagged-value probe: (hit_mask, positions) into the
         mirror's value array for ``uids`` (int64 ndarray).  Shared by the
@@ -187,6 +211,13 @@ class PostingStore:
         # journal's first delta — the version every live cache entry for
         # that predicate carries, which the delta-repair path
         # (models/arena.py) needs to re-key repaired entries safely.
+        # pred -> [(uid, TypedValue), ...]: untagged, facet-less values set
+        # on uids that held none, since the last arena refresh — what an
+        # index arena can take in place (a new token row, a uid in a row);
+        # None = a value was overwritten, deleted, tagged or faceted: the
+        # predicate's index and value arenas rebuild.  Lives and dies
+        # with ``delta``: ArenaManager.refresh pops both.
+        self.value_delta: Dict[str, Optional[List[tuple]]] = {}
         self.pred_versions: Dict[str, int] = {}
         self.pred_floor = 0
         self.delta_base: Dict[str, int] = {}
@@ -272,6 +303,21 @@ class PostingStore:
 
     def _delta_overflow(self, pred: str) -> None:
         self.delta[pred] = None
+        self.value_delta[pred] = None
+
+    def _journal_value(self, pred: str, uid: int, value) -> None:
+        """Journal a value set on a uid that held none (see
+        ``value_delta``); the edge journal gets an empty touch, so the
+        predicate's uid arenas are left alone."""
+        vd = self.value_delta.get(pred, [])
+        if vd is None or self.delta.get(pred, []) is None:
+            return  # already overflowed
+        if len(vd) >= self.DELTA_MAX:
+            self._delta_overflow(pred)
+            return
+        self._journal_touch(pred)
+        vd.append((uid, value))
+        self.value_delta[pred] = vd
 
     def _note_pred_mutation(self, pred: str, stream_kind: str = "",
                             src: int = 0, dst: int = 0, sign: int = 0) -> None:
@@ -301,10 +347,17 @@ class PostingStore:
         kind, sign = "pred", 0
         if e.op == "set":
             if e.value is not None:
+                # a plain value on a uid that held none: indexes take it
+                # in place (value_delta); anything else rebuilds them
+                fresh = (not e.lang and not e.facets
+                         and (e.src, "") not in p.values)
                 p.values[(e.src, e.lang)] = e.value
                 if not e.lang:  # the mirror indexes untagged values only
-                    p._untagged = None
-                self._delta_overflow(e.pred)  # value/index arenas rebuild
+                    p._note_untagged([(e.src, e.value)], fresh)
+                if fresh:
+                    self._journal_value(e.pred, e.src, e.value)
+                else:
+                    self._delta_overflow(e.pred)  # value/index arenas rebuild
                 if e.lang:
                     # invalidate the lazy lang-presence flag (functions.py
                     # caches it on this live object)
@@ -430,8 +483,15 @@ class PostingStore:
         self.dirty.add(pred)
         self.version += 1
         p._wdmirror = None
-        self._delta_overflow(pred)  # value/index arenas rebuild
         vals = p.values
+        # point-write shape, as bulk_set_uid_edges': plain values on uids
+        # that held none are journaled so index arenas take them in place;
+        # a load, an overwrite or a tagged value rebuilds them
+        fresh = len(items) <= self.BULK_JOURNAL_MAX and all(
+            not lang and (src, "") not in vals for src, lang, _ in items
+        ) and len({src for src, _, _ in items}) == len(items)
+        if not fresh:
+            self._delta_overflow(pred)  # value/index arenas rebuild
         any_untagged = any_lang = False
         for src, lang, v in items:
             vals[(src, lang)] = v
@@ -439,8 +499,10 @@ class PostingStore:
                 any_lang = True
             else:
                 any_untagged = True
+            if fresh:
+                self._journal_value(pred, src, v)
         if any_untagged:
-            p._untagged = None
+            p._note_untagged([(src, v) for src, _, v in items], fresh)
         if any_lang:
             try:
                 del p._has_langs

@@ -556,20 +556,24 @@ class DurableStore(PostingStore):
         ) from exc
 
     def _append_guarded(self, payload: bytes) -> None:
-        try:
-            self.wal.append(payload)
-        except StorageFaultError:
-            raise
-        except OSError as e:
-            self._storage_fault("wal.append", e)
+        # stage write_wal (with _flush_guarded and the server's barrier):
+        # inside a mutation's write_apply bracket, out of which it is carved
+        with obs.stage(None, "write_wal_ms"):
+            try:
+                self.wal.append(payload)
+            except StorageFaultError:
+                raise
+            except OSError as e:
+                self._storage_fault("wal.append", e)
 
     def _flush_guarded(self) -> None:
-        try:
-            self.wal.flush()
-        except StorageFaultError:
-            raise
-        except OSError as e:
-            self._storage_fault("wal.flush", e)
+        with obs.stage(None, "write_wal_ms"):
+            try:
+                self.wal.flush()
+            except StorageFaultError:
+                raise
+            except OSError as e:
+                self._storage_fault("wal.flush", e)
 
     def _journal(self, payload: bytes) -> None:
         if not self._replaying:

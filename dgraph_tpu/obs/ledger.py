@@ -79,6 +79,8 @@ STAGES = (
     "parse", "result_cache", "queue", "merge_wait", "plan", "host_expand",
     "h2d", "dispatch", "fetch", "convert", "assemble", "encode", "handoff",
     "http_write",
+    # the write path (serve/server.py, query/engine.py, models/wal.py)
+    "write_lock", "write_apply", "write_wal", "refresh",
 )
 _STAGE_KEYS = tuple((s, s + "_ms") for s in STAGES)
 # every label a scraper may diff is there at zero from boot: a family

@@ -327,6 +327,12 @@ def _annotation(name: str):
     return _TraceAnnotation("dgraph." + name)
 
 
+_open_stage: "contextvars.ContextVar[Optional[_Stage]]" = contextvars.ContextVar(
+    "dgraph_tpu_open_stage", default=None
+)
+_CATALOGUE_KEYS = frozenset(s + "_ms" for s in _ledger.STAGES)
+
+
 class _Stage:
     """Accumulating stage timer: ONE bracket, three sinks.  This is the
     ONE sanctioned home of perf_counter stage bracketing outside obs
@@ -349,11 +355,14 @@ class _Stage:
       — no request active — straight to
       ``dgraph_ledger_stage_us_total{stage}``.
 
-    Catalogue brackets never nest in one another, so a request's stages
-    add up to no more than its wall time (docs/deploy.md).  ``key`` is
-    the stage's name with ``_ms``: the stats key it accumulates under."""
+    A request's stages add up to no more than its wall time
+    (docs/deploy.md): a bracket opened inside another on the same thread —
+    the WAL's appends inside ``write_apply``, a layout built on first use
+    inside ``plan`` — takes its milliseconds OUT of the outer one, whose
+    start is moved forward by them.  ``key`` is the stage's name with
+    ``_ms``: the stats key it accumulates under."""
 
-    __slots__ = ("stats", "key", "t0", "_ann")
+    __slots__ = ("stats", "key", "t0", "_ann", "_outer", "_tok")
 
     def __init__(self, stats: Optional[dict], key: str):
         self.stats = stats
@@ -362,11 +371,22 @@ class _Stage:
     def __enter__(self) -> "_Stage":
         self._ann = _annotation(self.key[:-3])
         self._ann.__enter__()
+        # the coarse route keys (``chain_ms``, ...) are parents by design
+        # and keep their whole time; only catalogue stages carve
+        self._tok = None
+        if self.key in _CATALOGUE_KEYS:
+            self._outer = _open_stage.get()
+            self._tok = _open_stage.set(self)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb) -> None:
-        ms = (time.perf_counter() - self.t0) * 1e3
+        dt = time.perf_counter() - self.t0
+        ms = dt * 1e3
+        if self._tok is not None:
+            _open_stage.reset(self._tok)
+            if self._outer is not None:
+                self._outer.t0 += dt
         self._ann.__exit__(et, ev, tb)
         stats = self.stats
         if stats is not None:

@@ -323,9 +323,16 @@ def expand_inline_seg(
                  degree (overflow chunk count derives on device:
                  ceil(max(0, deg-INLINE)/8)), lanes 2..7 = first INLINE
                  targets ascending, SENT-padded.
-      ov_chunks: int32[NCov, 8] - targets INLINE.. of each row, 8 per
-                 chunk, ascending, SENT pad lanes; UNPADDED row count
-                 (pow2-padding the table costs gather rate, not just HBM).
+      ov_chunks: int32[cap, 8] - targets INLINE.. of each row, 8 per
+                 chunk, ascending, SENT pad lanes; a row's chunks side by
+                 side, rows in order (``_ov_slot_map`` leans on it); SENT
+                 rows past the chunks in use, up to the arena's bucketed
+                 capacity (models/arena.py ``_ov_capacity``: an eighth-step,
+                 so a write moves no static shape).  The table's length is
+                 read in a ``clip`` only: ``capc`` slots are gathered
+                 whatever it is (that padding the table "costs gather rate, not
+                 just HBM" was held here from before the chip; PR 34's
+                 pairs on the traverse cell are in PERF.md section 6).
 
     Args:
       rows: int32[B] row ids, ascending over valid entries, DISTINCT;

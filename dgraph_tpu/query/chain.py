@@ -905,9 +905,10 @@ def _topm_ov_chunk_sum(arena, m: int) -> int:
     metadata row, so only degree>INLINE rows have chunks)."""
     cs = getattr(arena, "_topm_ovdeg", None)
     if cs is None:
-        deg = arena.h_offsets[1:] - arena.h_offsets[:-1]
-        ovdeg = np.maximum(deg - ops.INLINE, 0)
-        cdeg = np.sort((ovdeg + ops.CHUNK - 1) // ops.CHUNK)[::-1]
+        cdeg = arena.ov_chunk_degree_of_rows(np.arange(arena.n_rows))
+        # the rows that have chunks alone: the others add nothing to a sum,
+        # and a write repairs this array (models/arena.py _topm_replace)
+        cdeg = np.sort(cdeg[cdeg > 0])[::-1]
         cs = np.concatenate([[0], np.cumsum(cdeg)])
         arena._topm_ovdeg = cs
     return int(cs[min(m, len(cs) - 1)])

@@ -658,7 +658,11 @@ class QueryEngine:
                 format_assigned_uids,
             )
 
-            blanks = apply_mutation(self.store, parsed.mutation)
+            # stage write_apply: quads to edges, blank nodes to uids, the
+            # store, its journals and dirty marks (the WAL's appends inside
+            # it are stage write_wal, carved out: models/wal.py)
+            with obs.stage(None, "write_apply_ms"):
+                blanks = apply_mutation(self.store, parsed.mutation)
             if blanks:
                 # assigned blank-node uids, as the reference's mutation
                 # response carries (protos AssignedUids)

@@ -17,6 +17,7 @@ from dgraph_tpu.models.password import hash_password
 from dgraph_tpu.models.store import Edge, PostingStore
 from dgraph_tpu.models.types import TypeID, TypedValue, convert
 from dgraph_tpu.rdf import NQuad, parse_nquads
+from dgraph_tpu.utils.metrics import WRITE_QUADS
 
 
 def resolve_uid(store: PostingStore, ref: str, blanks: Dict[str, int]) -> int:
@@ -121,6 +122,7 @@ def apply_mutation(store: PostingStore, mu: Mutation) -> Dict[str, int]:
             edges.extend(nquad_to_edge(store, nq, blanks, "set"))
     edges.extend(del_edges)
     store.apply_many(edges)
+    WRITE_QUADS.add((applied or 0) + len(edges))
     return blanks
 
 

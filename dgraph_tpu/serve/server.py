@@ -41,6 +41,7 @@ from dgraph_tpu.utils.metrics import (
     QUERY_CANCELLED,
     QUERY_LATENCY,
     TENANT_LATENCY,
+    WRITES,
     metrics,
 )
 from dgraph_tpu.cluster.peerclient import StaleUnavailableError
@@ -421,6 +422,7 @@ class DgraphServer:
         # None-check — the byte-identical off switch
         led = _ledger.start(tenant)
         ltoken = _ledger.activate(led) if led is not None else None
+        parsed = None
         try:
             with obs.child("parsing"), obs.stage(None, "parse_ms"):
                 parsed = gql.parse(text, variables)
@@ -484,10 +486,12 @@ class DgraphServer:
                         # journaled; the ack (this response) waits for a
                         # shared fsync that concurrent writers amortize
                         # (no-op unless enable_group_commit ran — see
-                        # __init__)
+                        # __init__).  Stage write_wal, with the appends
+                        # and the flush (models/wal.py)
                         barrier = getattr(self.store, "sync_barrier", None)
                         if barrier is not None:
-                            barrier()
+                            with obs.stage(None, "write_wal_ms"):
+                                barrier()
             lat.record_processing()
             # json encode happens in the handler; pre-record here so the
             # latency map is complete before attaching it
@@ -516,10 +520,14 @@ class DgraphServer:
                     k: (round(v, 3) if isinstance(v, float) else v)
                     for k, v in stats.items()
                 }
+            if parsed.mutation is not None:
+                WRITES.add("ok")   # past the barrier: this return is the ack
             if encoded:
                 return Response(answer, tail)
             return {**answer.tree(), **tail}
         except BaseException as e:
+            if parsed is not None and parsed.mutation is not None:
+                WRITES.add("error")
             if root is not None:
                 root.set_attr("error", type(e).__name__)
                 if isinstance(e, QueryCancelledError):
@@ -591,10 +599,16 @@ class DgraphServer:
         # arena cache (query/query.go:1684-1714 runs per-request
         # goroutines the same way).
         is_write = parsed.mutation is not None or self._profiler is not None
-        lock = (
-            self._engine_lock.write() if is_write else self._engine_lock.read()
-        )
-        with lock:
+        if is_write:
+            # stage write_lock: a mutation's wait for the exclusive side —
+            # every reader in flight runs out first
+            with obs.stage(None, "write_lock_ms"):
+                self._engine_lock.acquire_write()
+            release = self._engine_lock.release_write
+        else:
+            self._engine_lock.acquire_read()
+            release = self._engine_lock.release_read
+        try:
             if self._profiler is not None:
                 self._profiler.enable()
             try:
@@ -605,6 +619,16 @@ class DgraphServer:
                     eng.chain_threshold = self.engine.chain_threshold
                 eng.dump_shapes = bool(self.dumpsg_path)
                 out.update(eng.run_parsed(parsed))
+                if parsed.mutation is not None:
+                    # stage refresh: the writer, which holds the exclusive
+                    # side, takes its own journal into the cached arenas and
+                    # their device layouts — once, while no reader runs; the
+                    # reader that comes next finds nothing dirty and pays
+                    # for no layout (an embedded engine or a clustered
+                    # apply, which have no such lock, still refresh on the
+                    # next read, inside that read's own stages)
+                    with obs.stage(None, "refresh_ms"):
+                        self.engine.arenas.refresh()
                 led = _ledger.current()
                 if led is not None:
                     led.merge_engine_stats(eng.stats)
@@ -614,6 +638,8 @@ class DgraphServer:
                 if self._profiler is not None:
                     self._profiler.disable()
             return dict(eng.stats)
+        finally:
+            release()
 
 
 def _auto_mesh():

@@ -808,6 +808,24 @@ HBM_RESIDENT_BYTES = metrics.gauge("dgraph_hbm_resident_bytes")
 # the pinned buffers — "merge" = the on-device delta merge (only the delta
 # pairs crossed h2d), "reseed" = a structural change re-uploaded the CSR
 RESIDENT_EPOCHS = metrics.labeled("dgraph_resident_epochs_total", label="how")
+# a write reaching an arena whose inline layout or LUT is on the device:
+# "delta" = the touched rows, new chunks and new LUT entries were scattered
+# into the tables that are there, "rebuild" = the layout was built anew from
+# the host mirrors (a bucket outgrown, rows renumbered, a large delta)
+ARENA_LAYOUT_UPDATES = metrics.labeled(
+    "dgraph_arena_layout_updates_total", label="how"
+)
+for _how in ("delta", "rebuild"):
+    ARENA_LAYOUT_UPDATES.add(_how, 0)
+# bytes those updates and rebuilds put on the device (they are in
+# dgraph_ledger_bytes_total{dir="h2d"} too, on the writer's account)
+ARENA_REFRESH_H2D_BYTES = metrics.counter("dgraph_arena_refresh_h2d_bytes_total")
+# the write path (serve/server.py run_query): mutations by outcome, and the
+# N-Quads the acknowledged ones set or deleted
+WRITES = metrics.labeled("dgraph_writes_total", label="result")
+for _r in ("ok", "error"):
+    WRITES.add(_r, 0)
+WRITE_QUADS = metrics.counter("dgraph_write_quads_total")
 HBM_BUDGET_BYTES = metrics.gauge("dgraph_hbm_budget_bytes")
 HBM_TILE_BYTES = metrics.gauge("dgraph_hbm_tile_bytes")
 ARENA_EVICTIONS = metrics.counter("dgraph_arena_evictions_total")
