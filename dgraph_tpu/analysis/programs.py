@@ -1392,8 +1392,10 @@ REGISTRY: Dict[str, ProgramContract] = {
                   "over the listed predicates' merged layout, levels driven "
                   "by lax.while_loop, each done as a gather (chunks of the "
                   "frontier list) or a sweep (every edge) by the frontier's "
-                  "out-degree sum against the layout's size; parents are "
-                  "scatter-min'ed (least uid), the path is walked back on "
+                  "out-degree sum against the layout's size; a gathered "
+                  "level's parents (least uid) are decided by its one sort "
+                  "of (target, source) pairs and written once a uid found, "
+                  "a swept one's scatter-min'ed; the path is walked back on "
                   "the device.  The state is donated from segment to "
                   "segment (run_levels' instances declare it).",
         ),

@@ -930,7 +930,7 @@ class ResidentArena:
 class PathLayout:
     """The arenas of a path search's listed predicates merged into ONE CSR
     over the uid space (``ops/bfs.py``): row = uid, so a frontier uid is
-    its own row and a level table is indexed by what the edges hold; a
+    its own row and a table over the uids is indexed by what the edges hold; a
     uid's edges lie in the order the predicates were listed, each
     predicate's ascending.  ``off`` is int32[ub, 2]: a uid's first and
     past-the-last edge slot side by side, so that one row gather reads both.

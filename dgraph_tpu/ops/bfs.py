@@ -16,32 +16,39 @@ the ledger books, never a frontier.
     the target, or with an empty level.
 
 State over the uid space: ``par`` (SENT = not reached; the source is its
-own parent) is the visited set AND the parent, ``lvl`` (-1 = not reached)
-what the sweep reads.  A level is done one of two ways, chosen per level
-from the frontier's size and the layout's size:
+own parent) is the visited set AND the parent; ``mark``, a flag a uid, is
+the frontier of a level that was swept, written and read by the sweep
+alone.  A level is done one of two ways, chosen per level from the
+frontier's size and the layout's size:
 
 - **gather**: the frontier as a LIST — ascending, duplicate-free, each
   uid beside its first edge slot and the running sum of the degrees —
   ``chunk`` slots of its edges at a time: the slot -> edge and slot ->
   source maps telescoped from one scatter a row (as
   ``batch.expand_ascending``), one ``dst`` gather a slot, the target's
-  parent read and, where it has none, scatter-min'ed.  Chunks run in
-  ascending source order, so the first chunk that reaches a uid holds its
-  least parent and a later one finds it taken.  What the chunks found is
-  sorted ONCE after the loop, in a size class of the level's edges, into
-  the next list; one pass over that list reads each new uid's offsets
-  (its slot and degree, exact: the search books them) and writes its
-  level.  No access over the uid space: the cost follows the frontier's
-  edges.
-- **sweep**: every edge of the layout reads its source's level and
+  parent read.  A chunk WRITES nothing over the uid space: a target that
+  had no parent when the level started goes to the level's finds, once a
+  slot that reached it, its source beside it.  The finds are sorted ONCE
+  after the loop, as (target, source) pairs, in a size class of the
+  level's edges: the first of a target's run is its least source — the
+  sort that makes the next list also decides every parent.  One pass
+  over that list reads each new uid's offsets (its slot and degree, exact:
+  the search books them) and writes its parent.  No access over the uid
+  space that is not a frontier's: the cost follows the frontier's edges.
+- **sweep**: every edge of the layout reads its source's mark and
   scatter-mins its source into a candidate table over the uid space:
   two random accesses an edge of the LAYOUT, whatever the frontier
-  holds; no list, no sort.
+  holds; no list, no sort.  The mark is the sweep's own: after a listed
+  level it is written from the list (one access a list slot), after a
+  sweep it is what that sweep found.
 
 On the chip a random access costs 9-10 ns an element on a v5e, gathered
 or scattered, a dropped index as much as a live one (PERF.md, PRs 28 and
-31; ``_take`` reads a word for 2.5) and a streaming pass or a sort is
-nearly free beside it.  So a level goes to the sweep when the
+31: a scatter-min 10.0, a scatter-set 6.45; ``_take`` reads a word for
+2.5) and a streaming pass or a sort is nearly free beside it: the two
+sorts that make 131,072 (target, source) pairs a list with its parents
+take 0.39 ms, what forty thousand scattered words cost (PERF.md, PR 35).
+So a level goes to the sweep when the
 frontier's out-degree sum times ``_ACCESS_PER_SLOT`` passes the layout's
 edges times ``_ACCESS_PER_EDGE`` — or when the frontier outgrew its list.
 Both numbers are on the device when the level starts: a level's size and
@@ -56,21 +63,24 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from dgraph_tpu.ops.sets import SENT, bucket, sort_unique
+from dgraph_tpu.ops.sets import SENT, bucket
 
 # Random accesses the two ways of doing a level make, per unit of work,
 # COUNTED from the code below, not fitted to a run.  A gather's slot reads
-# dst and the target's parent and mins it (``_gather_chunk``: three), shares
-# its row's telescoping scatter with one other slot (a chunk holds half as
-# many rows as slots: a half), and finds one new uid at most, whose offsets
-# are read and whose level is written once, from the sorted list
-# (``_enlist``: two).  A sweep's edge reads its source's level and mins the
-# candidate.  They are this module's priors in the sense of
-# utils/calibrate.py's: the calibration measures no rate for either way
+# dst and the target's parent (``_gather_chunk``: two), shares its row's
+# telescoping scatter with one other slot (a chunk holds half as many rows
+# as slots: a half), and finds one new uid at most, whose offsets are read
+# and whose parent is written once, from the sorted list (``_enlist``: two).
+# A sweep's edge reads its source's mark and mins the candidate.  The mark a
+# sweep writes from the list, when the level before was listed, is one
+# access a list SLOT and not an edge's: it is left out of the constant, so
+# the break-even leans to the sweep by at most the list's own length, a
+# fifth of what that sweep does.  They are this module's priors in the sense
+# of utils/calibrate.py's: the calibration measures no rate for either way
 # (PERF.md Open questions 15e), and a change to either level's code has to
 # recount them (tests/test_path_search.py counts the jaxprs' gathers and
-# scatters and holds the constant to the sum)
-_ACCESS_PER_SLOT = 5.5
+# scatters and holds the constants to the sum)
+_ACCESS_PER_SLOT = 4.5
 _ACCESS_PER_EDGE = 2
 _LANES = 128         # a vector register's lanes: the row ``_take`` gathers
 PATH_CAP = 64        # the path comes back this many uids a walk
@@ -107,11 +117,12 @@ def small_chunk(chunk: int) -> int:
 
 def _sort_sizes(chunk: int, top: int) -> Tuple[int, ...]:
     """The static sizes a level's finds are sorted at: ``chunk`` and its
-    multiples by four under ``top``, then ``top``."""
+    doublings under ``top``, then ``top`` (pairs cost over twice what keys
+    do to sort: a level pays for at most twice its edges, not four times)."""
     sizes = []
     while chunk < top:
         sizes.append(chunk)
-        chunk *= 4
+        chunk *= 2
     return (*sizes, top)
 
 
@@ -131,8 +142,9 @@ def _gather_chunk(dst, par, uids, starts, cum, n_valid, chunk):
     """Expand the first ``n_valid`` of ``uids`` (int32[chunk // 2],
     ascending), whose edges fit ``chunk`` slots: ``starts`` their first edge
     slots, ``cum`` the running sum of their degrees from the chunk's start.
-    Returns (par, the targets that had no parent — SENT elsewhere, a uid
-    once for every slot that reached it — int32[chunk], the live slots)."""
+    Returns (the targets that had no parent when the level started — SENT
+    elsewhere, a uid once for every slot that reached it — int32[chunk],
+    each slot's source, the live slots).  ``par`` is read, never written."""
     C, R = chunk, uids.shape[0]
     ub = par.shape[0]
     i = jnp.arange(C, dtype=jnp.int32)
@@ -157,36 +169,48 @@ def _gather_chunk(dst, par, uids, starts, cum, n_valid, chunk):
     live = i < total
     out = jnp.where(live, _take(dst, jnp.clip(edge, 0, dst.shape[0] - 1)), SENT)
     fresh = live & (_take(par, jnp.clip(out, 0, ub - 1)) == SENT)
-    par = par.at[jnp.where(fresh, out, ub)].min(src, mode="drop")
-    return par, jnp.where(fresh, out, SENT), total
+    return jnp.where(fresh, out, SENT), src, total
 
 
-def _enlist(off, piece, st, n, level=None):
+def _sort_unique_pairs(keys, vals):
+    """``sets.sort_unique`` with a payload: the distinct ``keys`` ascending
+    (SENT after them) and, beside each, the LEAST of the ``vals`` that stood
+    beside it.  Two keys, no stability asked for: on the chip that is the
+    cheaper sort of a pair (PERF.md, PR 35)."""
+    keys, vals = jax.lax.sort((keys, vals), num_keys=2, is_stable=False)
+    dup = jnp.concatenate([jnp.zeros((1,), dtype=bool), keys[1:] == keys[:-1]])
+    return jax.lax.sort((jnp.where(dup, SENT, keys), vals), num_keys=1, is_stable=False)
+
+
+def _enlist(off, piece, st, n, parents=None):
     """The first ``n`` of ``st["fl"]`` (ascending uids) made a frontier
     list, ``piece`` uids at a time: each uid's first edge slot and the
-    running sum of the degrees beside it, its level written where ``level``
-    is given.  Returns (st, the degrees' sum)."""
-    ub = st["lvl"].shape[0]
+    running sum of the degrees beside it, and its parent written where
+    ``parents`` (aligned with the list) is given.  Returns (st, the degrees'
+    sum)."""
     k = jnp.arange(piece, dtype=jnp.int32)
+    write = parents is not None
 
     def cond(c):
         return c[0] < n
 
     def body(c):
-        b, lvl, fo, cd, m = c
+        b, fo, cd, m, par = c
         u = jax.lax.dynamic_slice(st["fl"], (b,), (piece,))
         ok = b + k < n
         span = off[jnp.where(ok, u, 0)]
         d = jnp.where(ok, span[:, 1] - span[:, 0], 0)
-        if level is not None:
-            lvl = lvl.at[jnp.where(ok, u, ub)].set(level, mode="drop")
+        if write:
+            par = par.at[jnp.where(ok, u, par.shape[0])].set(
+                jax.lax.dynamic_slice(parents, (b,), (piece,)), mode="drop")
         fo = jax.lax.dynamic_update_slice(fo, span[:, 0], (b,))
         cd = jax.lax.dynamic_update_slice(cd, m + jnp.cumsum(d), (b,))
-        return b + piece, lvl, fo, cd, m + jnp.sum(d).astype(jnp.int32)
+        return b + piece, fo, cd, m + jnp.sum(d).astype(jnp.int32), par
 
     zero = jnp.int32(0)
-    _, lvl, fo, cd, m = jax.lax.while_loop(cond, body, (zero, st["lvl"], st["fo"], st["cd"], zero))
-    return dict(st, lvl=lvl, fo=fo, cd=cd), m
+    _, fo, cd, m, par = jax.lax.while_loop(
+        cond, body, (zero, st["fo"], st["cd"], zero, st["par"] if write else ()))
+    return dict(st, fo=fo, cd=cd, **({"par": par} if write else {})), m
 
 
 def _gather_level(off, dst, chunk, top, st):
@@ -201,63 +225,71 @@ def _gather_level(off, dst, chunk, top, st):
         return c[0] < f
 
     def body(c):
-        a, w, par, raw = c
+        a, w, raw, via = c
         # the chunk [a, a + nb): as many uids as fit ``chunk`` slots
         below = jnp.where(a > 0, cd[jnp.maximum(a - 1, 0)], 0)
         cum = jax.lax.dynamic_slice(cd, (a,), (rows,)) - below
         nb = jnp.sum((cum <= chunk) & (a + j < f)).astype(jnp.int32)
         nb = jnp.maximum(nb, 1)
-        par, new, total = _gather_chunk(
-            dst, par, jax.lax.dynamic_slice(fl, (a,), (rows,)),
+        new, src, total = _gather_chunk(
+            dst, st["par"], jax.lax.dynamic_slice(fl, (a,), (rows,)),
             jax.lax.dynamic_slice(fo, (a,), (rows,)), cum, nb, chunk)
         # one find a live slot, end to end: the level's finds take ``m`` slots
-        return a + nb, w + total, par, jax.lax.dynamic_update_slice(raw, new, (w,))
+        return (a + nb, w + total, jax.lax.dynamic_update_slice(raw, new, (w,)),
+                jax.lax.dynamic_update_slice(via, src, (w,)))
 
     zero = jnp.int32(0)
-    _, _, par, raw = jax.lax.while_loop(cond, body, (zero, zero, st["par"], st["raw"]))
+    _, _, raw, via = jax.lax.while_loop(cond, body, (zero, zero, st["raw"], st["via"]))
 
-    # the finds sorted once, duplicates out, at the least size that holds
-    # them, over the list this level has done with
-    def sort_at(size, fl, raw):
-        new = sort_unique(jnp.where(jnp.arange(size, dtype=jnp.int32) < m, raw[:size], SENT))
+    # the finds sorted once beside their sources, duplicates out and the
+    # least source kept, at the least size that holds them: the next list
+    # over the one this level has done with, its parents over the sources
+    def sort_at(size, fl, raw, via):
+        new, parents = _sort_unique_pairs(
+            jnp.where(jnp.arange(size, dtype=jnp.int32) < m, raw[:size], SENT), via[:size])
         return (jax.lax.dynamic_update_slice(fl, new, (0,)),
+                jax.lax.dynamic_update_slice(via, parents, (0,)),
                 jnp.sum(new != SENT).astype(jnp.int32))
 
     sizes = _sort_sizes(chunk, top)
     at = sum((m > s).astype(jnp.int32) for s in sizes[:-1])
-    fl, f_next = jax.lax.switch(at, [partial(sort_at, s) for s in sizes], fl, raw)
-    st, m_next = _enlist(off, chunk, dict(st, par=par, fl=fl, raw=raw), f_next,
-                         level=st["cur"] + 1)
+    fl, via, f_next = jax.lax.switch(at, [partial(sort_at, s) for s in sizes], fl, raw, via)
+    st, m_next = _enlist(off, chunk, dict(st, fl=fl, raw=raw, via=via), f_next, parents=via)
     return dict(st, f=f_next, m=m_next, listed=jnp.bool_(True))
 
 
 def _sweep_level(off, dst, esrc, chunk, st):
-    """One level from the level table: every edge of the layout."""
-    lvl, par, cur = st["lvl"], st["par"], st["cur"]
-    ub = lvl.shape[0]
-    active = lvl[esrc] == cur
-    cand = jnp.full((ub,), SENT, jnp.int32).at[jnp.where(active, dst, ub)].min(
+    """One level from a mark of the frontier over the uid space: every edge
+    of the layout."""
+    par, fl = st["par"], st["fl"]
+    ub, n = par.shape[0], fl.shape[0]
+
+    def from_list(_):
+        # the level before left a list (only a sweep leaves a mark)
+        at = jnp.where(jnp.arange(n, dtype=jnp.int32) < st["f"], fl, ub)
+        return jnp.zeros((ub,), dtype=bool).at[at].set(True, mode="drop")
+
+    mark = jax.lax.cond(st["listed"], from_list, lambda _: st["mark"], None)
+    cand = jnp.full((ub,), SENT, jnp.int32).at[jnp.where(mark[esrc], dst, ub)].min(
         esrc, mode="drop")
-    new = (cand != SENT) & (lvl < 0)
+    new = (cand != SENT) & (par == SENT)
     par = jnp.where(new, cand, par)
-    lvl = jnp.where(new, cur + 1, lvl)
     deg = off[:, 1] - off[:, 0]
     f = jnp.sum(new).astype(jnp.int32)
     m = jnp.sum(jnp.where(new, deg, 0)).astype(jnp.int32)
-    n = st["fl"].shape[0]
     cap = n - chunk
-    lists = {"fl": st["fl"], "fo": st["fo"], "cd": st["cd"], "lvl": lvl}
+    lists = {"fl": fl, "fo": st["fo"], "cd": st["cd"]}
 
     def relist(lists):
         # the frontier is back under the list's capacity: one sort of the
-        # uid space puts it there again
+        # uid space puts it there again (its parents are written already)
         uids = jnp.sort(jnp.where(new, jnp.arange(ub, dtype=jnp.int32), SENT))
         uids = jnp.concatenate([uids, jnp.full((max(0, n - ub),), SENT, jnp.int32)])[:n]
         return _enlist(off, chunk, dict(lists, fl=uids), f)[0]
 
     listed = (f <= cap) & (m <= cap)
     lists = jax.lax.cond(listed, relist, lambda lists: lists, lists)
-    return dict(st, **lists, par=par, f=f, m=m, listed=listed)
+    return dict(st, **lists, par=par, mark=new, f=f, m=m, listed=listed)
 
 
 @partial(jax.jit, static_argnames=("chunk",), donate_argnums=(3,))
@@ -297,21 +329,25 @@ def run_levels(off, dst, esrc, st, to, steps, chunk):
 
 @partial(jax.jit, static_argnames=("cap", "chunk"))
 def start(off, src, cap, chunk):
-    """The state before level 0: the source alone, at level 0, its own
-    parent."""
+    """The state before level 0: the source alone, its own parent."""
     ub = off.shape[0]
     n = cap + chunk
     d0 = (off[src, 1] - off[src, 0]).astype(jnp.int32)
     zero = jnp.int32(0)
     return {
-        "lvl": jnp.full((ub,), -1, jnp.int32).at[src].set(0),
         "par": jnp.full((ub,), SENT, jnp.int32).at[src].set(src),
+        # the frontier of a level that was swept (``_sweep_level`` alone
+        # writes and reads it)
+        "mark": jnp.zeros((ub,), dtype=bool),
         # the frontier list: uids, their first edge slots, their degrees'
-        # running sum; ``raw``: what a level's chunks found, before the sort
+        # running sum; ``raw``: what a level's chunks found, before the
+        # sort, ``via``: the sources they were found from (after the sort:
+        # the new list's parents)
         "fl": jnp.full((n,), SENT, jnp.int32).at[0].set(src),
         "fo": jnp.zeros((n,), jnp.int32).at[0].set(off[src, 0]),
         "cd": jnp.zeros((n,), jnp.int32).at[0].set(d0),
         "raw": jnp.full((n,), SENT, jnp.int32),
+        "via": jnp.zeros((n,), jnp.int32),
         "f": jnp.int32(1), "m": d0, "cur": zero,
         "rows": zero, "edges": zero, "sweeps": zero,
         "found": jnp.bool_(False), "listed": jnp.bool_(True),

@@ -565,7 +565,7 @@ def path_route(
     predicates; k paths, a facet on a listed predicate (a weight, or one
     the hop has to render) and a decorated child (filter, pagination,
     order, count, ...) are the Dijkstra's.  So is a store whose uid space
-    is wider than its arenas: the BFS keeps a level and a parent for EVERY
+    is wider than its arenas: the BFS keeps a parent and a frontier mark for EVERY
     uid up to the largest (``universe``), for each search in flight, and
     where that outgrows the rows and edges the listed arenas hold
     (``held``) — one uid near 2^30 in a store of a thousand edges — the

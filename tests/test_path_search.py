@@ -121,7 +121,7 @@ CASES = [
     pytest.param(7, 40, 30, 0, 10, id="chain"),
     pytest.param(8, 40, 30, 30, 9, id="hub-chain"),
     pytest.param(9, 500, 300, 0, 0, id="large"),
-    pytest.param(10, 500, 300, 450, 0, id="large-hub"),
+    pytest.param(10, 500, 400, 450, 0, id="large-hub"),
 ]
 
 
@@ -242,20 +242,23 @@ def test_a_uid_wider_than_the_break_even_still_fits_the_chunk():
     assert chunk >= 40 and cap >= chunk
 
 
-def _accesses(jaxpr, into=None):
+def _accesses(jaxpr, into=None, scattered=None):
     """{index rows: count} of the ``gather`` / ``scatter*`` equations of a
-    jaxpr, those of its sub-jaxprs (loops, branches, calls) included."""
+    jaxpr, those of its sub-jaxprs (loops, branches, calls) included;
+    ``scattered`` collects the size of every scatter's operand."""
     into = {} if into is None else into
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name == "gather" or name.startswith("scatter"):
             rows = int(np.prod(eqn.invars[1].aval.shape[:-1]))
             into[rows] = into.get(rows, 0) + 1
+            if scattered is not None and name != "gather":
+                scattered.append(int(np.prod(eqn.invars[0].aval.shape)))
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _accesses(sub, into)
+                    _accesses(sub, into, scattered)
     return into
 
 
@@ -263,7 +266,10 @@ def test_the_access_counts_are_what_the_code_does():
     """``_ACCESS_PER_SLOT`` / ``_ACCESS_PER_EDGE`` price a level's two ways
     (``capacities``, ``run_levels``): they have to be the random accesses the
     code makes — per slot of a chunk, per row (half as many), per uid found
-    (one a slot at most), per edge of the layout."""
+    (one a slot at most), per edge of the layout.  And what a gathered level
+    writes over the uid space is ONE table, once a uid found: no scatter of
+    ``_gather_chunk`` has an operand of the uid space's size, ``_enlist``'s
+    one has (``par``), and the level as a whole has no other."""
     import jax
     import jax.numpy as jnp
 
@@ -271,18 +277,46 @@ def test_the_access_counts_are_what_the_code_does():
     off = jnp.zeros((ub, 2), jnp.int32)
     dst = jnp.zeros((E,), jnp.int32)
     i32 = lambda n: jnp.zeros((n,), jnp.int32)  # noqa: E731
+    into = []
     chunk = _accesses(jax.make_jaxpr(
         lambda par, uids, starts, cum: bfs._gather_chunk(dst, par, uids, starts, cum, 3, C)
-    )(i32(ub), i32(C // 2), i32(C // 2), i32(C // 2)).jaxpr)
-    assert set(chunk) == {C, C // 2}, chunk
+    )(i32(ub), i32(C // 2), i32(C // 2), i32(C // 2)).jaxpr, scattered=into)
+    assert chunk == {C: 2, C // 2: 1}, chunk    # dst and par read; the rows' one scatter
+    assert into == [2 * C], into                # and that one not over the uid space
     st = bfs.start(off, jnp.int32(1), 8 * C, C)
+    n = st["fl"].shape[0]
+    assert "lvl" not in st and st["mark"].dtype == bool
+    into = []
     found = _accesses(jax.make_jaxpr(
-        lambda st: bfs._enlist(off, C, st, jnp.int32(5), level=jnp.int32(1)))(st).jaxpr)
-    assert set(found) == {C}, found
-    assert bfs._ACCESS_PER_SLOT == chunk[C] + chunk[C // 2] / 2 + found[C]
+        lambda st: bfs._enlist(off, C, st, jnp.int32(5), parents=st["via"]))(st).jaxpr,
+        scattered=into)
+    assert found == {C: 2} and into == [ub], (found, into)   # off read, par written
+    assert bfs._ACCESS_PER_SLOT == chunk[C] + chunk[C // 2] / 2 + found[C] == 4.5
+    into = []
+    _accesses(jax.make_jaxpr(
+        lambda st: bfs._gather_level(off, dst, C, n, st))(st).jaxpr, scattered=into)
+    assert into.count(ub) == 1, into
+    # a sweep: two an edge; after a listed level it first marks the list,
+    # once a list slot (``_ACCESS_PER_EDGE``'s comment: not an edge's cost);
+    # a list made again reads its uids' offsets and writes no table
+    into = []
     sweep = _accesses(jax.make_jaxpr(
-        lambda st: bfs._sweep_level(off, dst, dst, C, st))(st).jaxpr)
+        lambda st: bfs._sweep_level(off, dst, dst, C, st))(st).jaxpr, scattered=into)
+    assert sweep == {E: 2, n: 1, C: 1}, sweep
     assert bfs._ACCESS_PER_EDGE == sweep[E]
+    assert sorted(into) == [ub, ub], into       # the mark and the candidates
+
+
+def test_the_film_layout_lists_what_the_count_allows():
+    # 8,388,608 edge slots: twice the slots over 4.5 accesses a slot, floored
+    assert bfs.capacities(8 * 1024 * 1024, 97_734) == (3_670_016, 131_072)
+
+
+def _csr(adj, ub):
+    """(off int32[ub + 1], dst) of ``adj`` over uids 0..ub-1, rows in order."""
+    off = np.zeros(ub + 1, np.int32)
+    np.cumsum([len(adj.get(u, ())) for u in range(ub)], out=off[1:])
+    return off, np.concatenate([adj.get(u, []) for u in range(ub)]).astype(np.int32)
 
 
 def hand_layout(order):
@@ -303,12 +337,7 @@ def hand_layout(order):
     for v in range(30, 54, 2):
         adj[v] = [10, 1, v, 102]
     adj[lo], adj[hi], adj[mid] = [100, 10, 1, 102], [11, 100, 101, 1], [101, 1, 12, 102]
-    ub = 128
-    deg = np.array([len(adj.get(u, ())) for u in range(ub)])
-    off = np.zeros(ub + 1, np.int32)
-    np.cumsum(deg, out=off[1:])
-    dst = np.concatenate([adj.get(u, []) for u in range(ub)]).astype(np.int32)
-    return adj, off, dst
+    return (adj, *_csr(adj, 128))
 
 
 @pytest.mark.parametrize("order", ["asc", "desc"])
@@ -324,7 +353,7 @@ def test_a_gathered_level_of_several_chunks_keeps_the_least_parent(order):
     d_off = jnp.asarray(np.stack([off[:-1], off[1:]], axis=1))
     d_dst = jnp.asarray(np.concatenate([dst, np.full(128 - len(dst), bfs.SENT, np.int32)]))
     st = bfs.start(d_off, jnp.int32(1), cap, chunk)
-    parent, level = {1: 1}, [1]
+    parent, level, par = {1: 1}, [1], np.asarray(st["par"])
     for cur in range(3):
         st = dict(bfs._gather_level(d_off, d_dst, chunk, cap + chunk, st), cur=jnp.int32(cur + 1))
         nxt = sorted({v for u in level for v in adj[u]} - set(parent))
@@ -336,11 +365,102 @@ def test_a_gathered_level_of_several_chunks_keeps_the_least_parent(order):
         degs = [len(adj.get(v, ())) for v in nxt]
         assert np.asarray(st["cd"])[:f].tolist() == np.cumsum(degs).tolist()
         assert int(st["m"]) == sum(degs)
-        par = np.asarray(st["par"])
+        # the parents of exactly the uids found, and no other entry moved
+        before, par = par, np.asarray(st["par"])
+        assert np.flatnonzero(par != before).tolist() == nxt, cur
         assert {v: int(par[v]) for v in np.flatnonzero(par != bfs.SENT).tolist()} == parent
-        assert np.asarray(st["lvl"])[nxt].tolist() == [cur + 1] * len(nxt)
         level = nxt
     assert [len(level), parent[100], parent[101]] == [3, 30, 40]
+
+
+def layered(seed, widths, degs):
+    """A layout by layers: layer i holds ``widths[i]`` uids of ``degs[i]``
+    edges each.  A layer's first edges reach every uid of the next (so the
+    levels are the layers, level i of ``widths[i] * degs[i]`` edges); of the
+    others seven in ten go to the next layer and the rest anywhere at or
+    above their own (back edges, loops and repeats: a merged layout has
+    them).  The uids are dealt at random, so a layer's order is not its
+    uids'.  Returns (adj, off, dst, the source)."""
+    rng = np.random.default_rng(seed)
+    uids = (1 + rng.permutation(sum(widths))).tolist()
+    layers, at = [], 0
+    for w in widths:
+        layers.append(uids[at:at + w])
+        at += w
+    adj = {}
+    for i, (layer, d) in enumerate(zip(layers, degs)):
+        ahead = layers[min(i + 1, len(layers) - 1)]
+        behind = [u for lay in layers[:i + 1] for u in lay]
+        assert len(layer) * d >= len(ahead) or i + 1 == len(layers)
+        for k, u in enumerate(layer):
+            adj[u] = [ahead[e] if e < len(ahead)
+                      else int(rng.choice(ahead if rng.random() < 0.7 else behind))
+                      for e in range(k * d, k * d + d)]
+            rng.shuffle(adj[u])
+    return (adj, *_csr(adj, 128 * -(-(len(uids) + 1) // 128)), layers[0][0])
+
+
+WAYS = [
+    # layout, chunk, list capacity, the run of ways that has to occur
+    pytest.param(lambda: (*hand_layout("asc"), 1), 8, 16, "gssg", id="hand-asc"),
+    pytest.param(lambda: (*hand_layout("desc"), 1), 8, 16, "gssg", id="hand-desc"),
+    pytest.param(lambda: layered(1, [1, 3, 10, 20, 20], [3, 30, 2, 2, 1]), 32, 64, "gsg",
+                 id="layers-gather-sweep-gather"),
+    pytest.param(lambda: layered(2, [1, 4, 60, 10, 15, 5], [4, 20, 3, 2, 1, 0]), 32, 64, "gssg",
+                 id="layers-sweep-sweep-gather"),
+    pytest.param(lambda: layered(3, [1, 6, 50, 70, 12, 9], [6, 16, 4, 1, 2, 1]), 16, 48, "gsssg",
+                 id="layers-three-sweeps"),
+    pytest.param(lambda: layered(4, [1, 5, 8, 6, 10, 6], [5, 12, 2, 14, 1, 1]), 16, 32, "gsgsg",
+                 id="layers-in-and-out"),
+]
+
+
+@pytest.mark.parametrize("layout,chunk,cap,ways", WAYS)
+def test_levels_swept_between_gathered_ones_keep_distance_parent_and_list(
+        layout, chunk, cap, ways):
+    """A list of a few chunks, so that levels go gather -> sweep -> gather
+    and sweep -> sweep -> gather: after EVERY level the parent table is the
+    reference's (the least uid of the level before, nothing else touched),
+    the level's size and degree sum are exact and, where the level left a
+    list, so are its uids, offsets and running degrees.  What a sweep reads
+    — a mark written from the list, or from the sweep before — and what a
+    gather reads after a sweep have no other judge."""
+    import jax.numpy as jnp
+
+    adj, off, dst, src = layout()
+    ub, e = len(off) - 1, 128 * -(-len(dst) // 128)
+    deg = np.diff(off)
+    assert deg.max() <= chunk <= cap
+    d_off = jnp.asarray(np.stack([off[:-1], off[1:]], axis=1))
+    d_dst = jnp.asarray(np.concatenate([dst, np.full(e - len(dst), bfs.SENT, np.int32)]))
+    d_esrc = jnp.asarray(np.concatenate(
+        [np.repeat(np.arange(ub), deg), np.zeros(e - len(dst), np.int64)]).astype(np.int32))
+    st = bfs.start(d_off, jnp.int32(src), cap, chunk)
+    parent, level, went, par = {src: src}, [src], "", np.asarray(st["par"])
+    rows = edges = 0
+    while level:
+        rows, edges = rows + len(level), edges + int(deg[level].sum())
+        swept = int(st["sweeps"])
+        st = bfs.run_levels(d_off, d_dst, d_esrc, st, jnp.int32(0), jnp.int32(1), chunk)
+        went += "s" if int(st["sweeps"]) > swept else "g"
+        nxt = sorted({v for u in level for v in adj.get(u, ())} - set(parent))
+        for v in nxt:
+            parent[v] = min(u for u in level if v in adj[u])
+        # distance: the uids that got a parent at this level are this level's
+        before, par = par, np.asarray(st["par"])
+        assert np.flatnonzero(par != before).tolist() == nxt, went
+        assert {v: int(par[v]) for v in np.flatnonzero(par != bfs.SENT).tolist()} == parent, went
+        assert (int(st["f"]), int(st["m"])) == (len(nxt), int(deg[nxt].sum())), went
+        assert (int(st["rows"]), int(st["edges"]), int(st["cur"])) == (rows, edges, len(went))
+        if bool(st["listed"]):
+            f = len(nxt)
+            assert np.asarray(st["fl"])[:f].tolist() == nxt, went
+            assert np.asarray(st["fo"])[:f].tolist() == off[nxt].tolist(), went
+            assert np.asarray(st["cd"])[:f].tolist() == np.cumsum(deg[nxt]).tolist(), went
+        else:
+            assert len(nxt) > cap or deg[nxt].sum() > cap, went
+        level = nxt
+    assert ways in went, went
 
 
 ROUTES = [
