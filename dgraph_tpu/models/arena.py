@@ -37,6 +37,8 @@ from dgraph_tpu.utils.metrics import (
     ARENA_EVICTIONS,
     ARENA_LAYOUT_UPDATES,
     ARENA_REFRESH_H2D_BYTES,
+    PATH_LAYOUT_H2D_BYTES,
+    PATH_LAYOUT_UPDATES,
     RESIDENT_EPOCHS,
 )
 from dgraph_tpu.ops.sets import SENT
@@ -97,24 +99,31 @@ def _scatter_rows(table, idx, rows):
     return table.at[idx].set(rows, mode="drop")
 
 
-def _put_scatter(table, idx: np.ndarray, rows: np.ndarray):
+def _book_refresh_h2d(arrays) -> None:
+    """``_book_h2d`` for what a write put on the device (a layout's delta or
+    its rebuild): the writer's account and the refresh counter."""
+    _book_h2d(arrays)
+    ARENA_REFRESH_H2D_BYTES.add(sum(int(a.nbytes) for a in arrays))
+
+
+def _book_path_h2d(arrays) -> None:
+    """``_book_h2d`` for a path search's merged layout (``PathLayout``), built
+    or given a write's delta: the request's account and the layout's counter."""
+    _book_h2d(arrays)
+    PATH_LAYOUT_H2D_BYTES.add(sum(int(a.nbytes) for a in arrays))
+
+
+def _put_scatter(table, idx: np.ndarray, rows: np.ndarray, book=_book_refresh_h2d):
     """Pads (idx, rows) to a bucketed length and scatters them into the
-    device ``table``; books the bytes that crossed."""
+    device ``table``; ``book`` books the bytes that crossed."""
     k = ops.bucket(max(_SCATTER_MIN, len(idx)))
     pad_idx = np.full(k, table.shape[0], dtype=np.int32)
     pad_idx[: len(idx)] = idx
     pad_rows = np.zeros((k,) + tuple(table.shape[1:]), dtype=np.int32)
     pad_rows[: len(idx)] = rows
     di, dr = jnp.asarray(pad_idx), jnp.asarray(pad_rows)
-    _book_refresh_h2d((di, dr))
+    book((di, dr))
     return _scatter_rows(table, di, dr)
-
-
-def _book_refresh_h2d(arrays) -> None:
-    """``_book_h2d`` for what a write put on the device (a layout's delta or
-    its rebuild): the writer's account and the refresh counter."""
-    _book_h2d(arrays)
-    ARENA_REFRESH_H2D_BYTES.add(sum(int(a.nbytes) for a in arrays))
 
 
 def _topm_replace(cs: np.ndarray, old: np.ndarray, new: np.ndarray) -> np.ndarray:
@@ -933,11 +942,14 @@ class PathLayout:
     its own row and a table over the uids is indexed by what the edges hold; a
     uid's edges lie in the order the predicates were listed, each
     predicate's ascending.  ``off`` is int32[ub, 2]: a uid's first and
-    past-the-last edge slot side by side, so that one row gather reads both.
+    past-the-last edge slot side by side, so that one row gather reads both
+    (a uid that holds no edge reads two equal slots, WHICH is not said: a
+    build leaves the running count there, a delta what was there).
     ``esrc`` is the source uid of every edge slot (0 on padding: no uid),
     for the level done as a sweep.  Built on the
     host from the arenas' mirrors on first use and kept by the
-    ``ArenaManager`` until one of its arenas changes.  Its tables are DENSE
+    ``ArenaManager``, which gives it every delta its arenas take
+    (``take_delta``).  Its tables are DENSE
     over the uid space, as a search's level and parent tables are:
     ``planner.path_route`` sends a block to the host where that space is
     wider than the arenas hold (``path_extent``)."""
@@ -948,7 +960,8 @@ class PathLayout:
         src = np.concatenate(srcs) if srcs else np.empty(0, np.int64)
         dst = np.concatenate(dsts) if dsts else np.empty(0, np.int64)
         self.n_edges = int(len(src))
-        self.universe = int(max(src.max(), dst.max())) if self.n_edges else 0
+        self.top = int(src.max()) if self.n_edges else 0   # the last uid that holds an edge
+        self.universe = int(max(self.top, dst.max())) if self.n_edges else 0
         self.ub = ops.bucket_fine(self.universe + 2)
         order = np.argsort(src, kind="stable")
         counts = np.bincount(src, minlength=self.ub)
@@ -960,7 +973,52 @@ class PathLayout:
         self.dst = jnp.asarray(ops.pad_to(dst[order], eb))
         self.esrc = jnp.asarray(ops.pad_to(src[order], eb, fill=0))
         self.key = tuple((id(a), a.epoch) for a in arenas)
-        _book_h2d((self.off, self.dst, self.esrc))
+        _book_path_h2d((self.off, self.dst, self.esrc))
+
+    def take_delta(self, arenas: List[CSRArena], took: list) -> Optional["PathLayout"]:
+        """The layout after ``arenas`` (the ones it was built from, in the
+        order listed) took ``took`` — an (adds, dels) pair of (src, dst)
+        arrays an arena, None where it took nothing: a NEW layout whose tables
+        are this one's with the new uids' ``off`` rows and edge slots
+        scattered in, array for array what a build from the arenas gives
+        (tests hold it to that), at the same shapes, so that ``ops/bfs.py``
+        compiles nothing.  None where it cannot be had that way and the
+        caller builds anew: an edge deleted, a source that holds an edge
+        already or lies under one that does (its slots are in the MIDDLE of
+        ``dst``: every later uid's would move), the uid space or the edge
+        slots outgrown, a degree past the widest there is (``bfs.capacities``
+        sizes a chunk by it), or an arena that is not one epoch on from the
+        one merged.  What a new film brings — itself, its performances, its
+        newcomers, uids handed out past all others — passes."""
+        adds = []
+        for a, (was_id, was_epoch), t in zip(arenas, self.key, took):
+            if id(a) != was_id or a.epoch != was_epoch + (t is not None):
+                return None
+            if t is not None:
+                if len(t[1]):
+                    return None
+                adds.append(t[0][np.lexsort((t[0][:, 1], t[0][:, 0]))])
+        new = np.concatenate(adds) if adds else np.zeros((0, 2), np.int64)
+        new = new[np.argsort(new[:, 0], kind="stable")]   # listed order within a uid
+        n = self.n_edges + len(new)
+        if not len(new) or int(new[:, 0].min()) <= self.top \
+                or int(new.max()) + 2 > self.ub or n > self.dst.shape[0]:
+            return None
+        uids, counts = np.unique(new[:, 0], return_counts=True)
+        if int(counts.max()) > self.max_degree:
+            return None
+        ends = self.n_edges + np.cumsum(counts)
+        slots = np.arange(self.n_edges, n, dtype=np.int32)
+        lay = object.__new__(PathLayout)
+        lay.off = _put_scatter(self.off, uids.astype(np.int32),
+                               np.stack([ends - counts, ends], axis=1), _book_path_h2d)
+        lay.dst = _put_scatter(self.dst, slots, new[:, 1], _book_path_h2d)
+        lay.esrc = _put_scatter(self.esrc, slots, new[:, 0], _book_path_h2d)
+        lay.n_edges, lay.top = n, int(uids[-1])
+        lay.universe = max(self.universe, int(new.max()))
+        lay.ub, lay.max_degree = self.ub, self.max_degree
+        lay.key = tuple((id(a), a.epoch) for a in arenas)
+        return lay
 
 
 def _build_csr(rows_to_dsts: Dict[int, np.ndarray]) -> CSRArena:
@@ -1238,6 +1296,8 @@ class ArenaManager:
         # merged layouts of path searches (PathLayout), by the listed
         # predicates: a handful at most, dropped whole under HBM pressure
         self._path_layouts: Dict[tuple, PathLayout] = {}
+        # id(arena) -> the (adds, dels) it took in the refresh under way
+        self._took: Dict[int, tuple] = {}
         # protects the cache dicts + refresh bookkeeping ONLY — heavy
         # arena builds run outside it under per-key build locks
         # (_get_or_build), so one cold predicate never stalls readers of
@@ -1465,10 +1525,17 @@ class ArenaManager:
         set on uids that held none go into the predicate's index arenas
         in place (``IndexArena.take_values``).  A value overwritten or
         deleted, a bulk load and a journal overflow fall back to the full
-        rebuild."""
+        rebuild.  Every cached ``PathLayout`` one of whose arenas was written
+        is then brought up to them (``_path_layouts_take``): by whoever
+        refreshes — in a server the writer, under the exclusive side."""
         dirty = self.store.dirty
         if not dirty:
             return
+        self._refresh_dirty(dirty)
+        took, self._took = self._took, {}
+        self._path_layouts_take(took)
+
+    def _refresh_dirty(self, dirty) -> None:
         # Never blanket-clear the dirty set: concurrent readers (admitted
         # by the server's RW lock) may add marks between our snapshot and
         # the clear (ClusterStore._drain_dirty runs inside peek); only
@@ -1523,6 +1590,27 @@ class ArenaManager:
                 self._index.pop(key, None)
                 self._lru_drop(self._index, key)
             dirty.discard(p)
+
+    def _path_layouts_take(self, took: Dict[int, tuple]) -> None:
+        """Every cached ``PathLayout`` one of whose arenas this refresh wrote
+        or dropped, brought up to them: the delta scattered into its tables
+        (``PathLayout.take_delta``) or — counted, never silent — the layout
+        built anew from the arenas here, on the refresher's account, so that
+        the next search pays for nothing; one whose arena left the cache goes
+        with it (the arena is the next reader's to build, and the layout
+        after it).  Stage ``path_layout``, inside ``refresh``."""
+        for preds, lay in list(self._path_layouts.items()):
+            arenas = [(self._reverse if rev else self._data).get(a) for a, rev in preds]
+            if lay.key == tuple(None if a is None else (id(a), a.epoch) for a in arenas):
+                continue
+            with obs.stage(None, "path_layout_ms"), _BUILD_LOCK:
+                cached = all(a is not None for a in arenas)
+                new = lay.take_delta(arenas, [took.get(id(a)) for a in arenas]) if cached else None
+                PATH_LAYOUT_UPDATES.add("rebuild" if new is None else "delta")
+                if cached:
+                    self._path_layouts[preds] = new or PathLayout(arenas)
+                else:
+                    del self._path_layouts[preds]
 
     def _take_journal(self, pred: str, delta: list, vdelta, base) -> bool:
         """The cached arenas of ``pred`` after its journal window: uid
@@ -1590,6 +1678,10 @@ class ArenaManager:
         if r is not None:
             r.apply_delta(adds[:, ::-1], dels[:, ::-1])
         n_delta = len(adds) + len(dels)
+        if n_delta:     # what the merged path layouts over them have to take
+            self._took[id(a)] = (adds, dels)
+            if r is not None:
+                self._took[id(r)] = (adds[:, ::-1], dels[:, ::-1])
         self._repair_hop_entries(
             pred, a, adds, dels, base,
             # the cost prior prices a typical warm entry as a ~32-row
@@ -1890,7 +1982,9 @@ class ArenaManager:
         """The merged layout of ``preds`` — ((attr, reverse), ...) in the
         order listed — for ``ops/bfs.py``.  Valid while every arena it
         was built from is the cached one at the same epoch (a delta bumps
-        the epoch, a rebuild replaces the object)."""
+        the epoch, a rebuild replaces the object): ``refresh`` keeps it so
+        through writes, and what is built here is what was never built,
+        was evicted (``evict_for_oom``) or went with a dropped arena."""
         arenas = [self.reverse(a) if rev else self.data(a) for a, rev in preds]
         key = tuple((id(a), a.epoch) for a in arenas)
         with self._cache_lock:

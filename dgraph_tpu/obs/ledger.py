@@ -81,6 +81,8 @@ STAGES = (
     "http_write",
     # the write path (serve/server.py, query/engine.py, models/wal.py)
     "write_lock", "write_apply", "write_wal", "refresh",
+    # a path search's merged layout given a write (models/arena.py; inside refresh)
+    "path_layout",
 )
 _STAGE_KEYS = tuple((s, s + "_ms") for s in STAGES)
 # every label a scraper may diff is there at zero from boot: a family

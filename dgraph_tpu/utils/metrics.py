@@ -820,6 +820,19 @@ for _how in ("delta", "rebuild"):
 # bytes those updates and rebuilds put on the device (they are in
 # dgraph_ledger_bytes_total{dir="h2d"} too, on the writer's account)
 ARENA_REFRESH_H2D_BYTES = metrics.counter("dgraph_arena_refresh_h2d_bytes_total")
+# a write reaching a cached PathLayout (a path search's merged layout,
+# models/arena.py): "delta" = the new uids' offset rows and edge slots were
+# scattered into the tables that are there (``PathLayout.take_delta``),
+# "rebuild" = the layout was built anew from its arenas by the writer, or
+# dropped with an arena that was, for the next search to build
+PATH_LAYOUT_UPDATES = metrics.labeled(
+    "dgraph_path_layout_updates_total", label="how"
+)
+for _how in ("delta", "rebuild"):
+    PATH_LAYOUT_UPDATES.add(_how, 0)
+# bytes a PathLayout put on the device: a build's three tables, a delta's
+# index vectors, rows and slots (in dgraph_ledger_bytes_total{dir="h2d"} too)
+PATH_LAYOUT_H2D_BYTES = metrics.counter("dgraph_path_layout_h2d_bytes_total")
 # the write path (serve/server.py run_query): mutations by outcome, and the
 # N-Quads the acknowledged ones set or deleted
 WRITES = metrics.labeled("dgraph_writes_total", label="result")

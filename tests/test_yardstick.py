@@ -403,3 +403,214 @@ def test_readwrite_roofline_adds_the_writes_least_bytes(cell):
     assert read(obs) == pytest.approx(
         100 * ((work.traversal_bytes(1e6, rows) + wrote) / 819e9) / 0.004)
     assert read(_obs(classes)) is None                        # no trace: nothing, never 0
+
+
+# == film-q4-paths-rw.searchwrite (PR 36): the cell against its files ===============================
+
+import reference_paths_rw  # noqa: E402
+import work_path_writes  # noqa: E402
+import work_paths  # noqa: E402
+
+SW_CELL = "film-q4-paths-rw.searchwrite"
+SW_LISTED = ["~performance.actor", "~starring", "starring", "performance.actor"]
+SW_READERS = ["path_layout_rebuild_share", "path_layout_ms", "h2d_bytes_per_path_write",
+              "searchwrite_device_share", "searchwrite_roofline"]
+
+
+@pytest.fixture(scope="module")
+def swcell():
+    bench, w, config = run.find_cell(SW_CELL)
+    mix = trafficgen.load_json("traffic", w["traffic"] + ".json")
+    world = run.World(filmgen.generate(20_000, 3))
+    classes = trafficgen.load_classes(mix, world)
+    return bench, w, config, mix, world, classes
+
+
+def test_the_searchwrite_cell_is_one_chip_one_configuration_five_readers(swcell):
+    bench, w, config, mix, _, _ = swcell
+    assert (w["config"], w["traffic"], w["chips"]) == ("film21m-q4-paths-rw", "searchwrite", 1)
+    assert [m["name"] for m in run.metrics_of(bench, "end_to_end", SW_CELL)] == \
+        ["query_p50_ms", "setup_s"]                # not edges_per_s: its list stays as it was
+    assert next(m for m in bench["end_to_end"] if m["name"] == "edges_per_s")["workloads"] == \
+        ["film-q4-paths.shortest"]
+    mine = [m for m in bench["per_layer"] if m.get("workloads") == [SW_CELL]]
+    assert [m["name"] for m in mine] == SW_READERS and bench["per_layer"][-5:] == mine
+    assert all(m["moves"] == "query_p50_ms" for m in mine)
+    assert [m["layer"] for m in mine] == ["arenas", "arenas", "arenas", "planner", "kernels"]
+    assert not any(SW_CELL in m.get("workloads", []) for m in bench["per_layer"][:-5])
+    base = trafficgen.load_json("configs", "film21m-q4.json")
+    for key in ("scale", "upstream_scale", "reduced", "schema", "shapes"):
+        assert config[key] == base[key]            # the same graph, cut the same way
+    entry = next(c for c in bench["configs"] if c["name"] == "film21m-q4-paths-rw")
+    assert entry["reduced"] == ["quads", "actors"] and entry["source"] == config["source"]
+    assert len(entry["source"]) <= 200 and len(w["why"]) <= 200
+    assert set(config["guarantees"]) >= {"server", "writes", "read_your_write", "atomicity",
+                                         "path", "reads", "caches"}
+    assert "--sync is off" in config["guarantees"]["writes"]
+    assert "~starring" in config["guarantees"]["read_your_write"]
+
+
+def test_the_mix_is_the_paths_mix_and_a_tenth_of_writes(swcell):
+    *_, mix, _, _ = swcell
+    paths = trafficgen.load_json("traffic", "paths.json")
+    weights = {c["class"]: c["weight"] for c in mix["classes"]}
+    assert [c["class"] for c in mix["classes"]] == \
+        ["path_to_star", "path_pair", "path_costar", "add_film", "path_back"]
+    assert weights["add_film"] == 0.10 and weights["path_back"] == 0
+    for c in paths["classes"]:                     # the searches keep their proportions
+        assert weights[c["class"]] == pytest.approx(0.9 * c["weight"])
+    assert mix["follow"] == {"add_film": "path_back"} and mix["generator"] == "closed_follow"
+    assert mix["warm"] == paths["warm"] and mix["clients"] == 8 and mix["deck"] == 16384
+    assert mix["needs"] == ["dgraph_path_layout_updates_total", "dgraph_writes_total"]
+
+
+@pytest.mark.parametrize("seed", [1, 3600000011])
+def test_the_deck_writes_every_film_once_and_never_deals_a_path_back(swcell, seed):
+    *_, mix, _, classes = swcell
+    plan = trafficgen.deal(mix, classes, seed)
+    films = [r for c, r in plan if c == "add_film"]
+    assert len(films) == len(set(films)) == pytest.approx(0.1 * len(plan), abs=1)
+    assert not any(c == "path_back" for c, _ in plan)
+    assert list(classes["path_back"].pool()) == list(classes["add_film"].pool())
+    by = {c: sum(1 for x, _ in plan[:1100] if x == c) for c in ("path_to_star", "path_pair",
+                                                                 "path_costar", "add_film")}
+    for cls, share in {"path_to_star": 594, "path_pair": 297, "path_costar": 99, "add_film": 110}.items():
+        assert by[cls] == pytest.approx(share, abs=1)
+
+
+@pytest.mark.parametrize("c", range(1, 9))
+def test_a_path_back_is_what_the_issue_says(swcell, c):
+    *_, world, classes = swcell
+    # a kind of its own whose every film has cast c (the law's own casts are 3 to 8)
+    kind = type(classes["path_back"])("path_back", classes["path_back"].spec, world)
+    kind.written.cast = np.full(reference_rw.POOL, c)
+    k = 40 + c
+    text, exp = kind.text(k, "w"), kind.expect(k)
+    paths_text = trafficgen.load_json("queries", "path_to_star.json")["text"]
+    assert text == paths_text.replace("Actor $FROM", f"Newcomer w-{k}-{c}").replace(
+        "Actor $TO", f"Newcomer w-{k}-1" if c > 1 else f"Film w-{k}").replace(
+        "hops(func:", "hopsw(func:")
+    if c > 1:      # newcomer, its performance, the film, the first performance, the first newcomer
+        assert exp["want"] == {"d": 4, "keys": ["performance.actor", "starring", "starring",
+                                               "performance.actor"]}
+        assert (exp["edges"], exp["rows"], exp["levels"]) == (3 * c + 1, c + 2, [1, 1, 1, c - 1])
+    else:
+        assert exp["want"] == {"d": 2, "keys": ["performance.actor", "starring"]}
+        assert (exp["edges"], exp["rows"], exp["levels"]) == (3, 2, [1, 1])
+    assert exp["path_touch"] == {"rows": 1 + 2 * c, "slots": 4 * c}
+    true = kind.render(k, None)
+    assert kind.check(true, exp) is None
+    assert sorted(h["name"] for h in true["hops"]) == sorted(
+        [f"Film -{k}", f"Newcomer -{k}-{c}"] + ([f"Newcomer -{k}-1"] if c > 1 else []))
+    for wrong, word in [({}, "0 paths"), ({**true, "hops": true["hops"][:-1]}, "second block"),
+                        ({**true, "_path_": [true["_path_"][0]["performance.actor"][0]]}, "hops, the"),
+                        ({**true, "_path_": true["_path_"] * 2}, "2 paths")]:
+        assert word in kind.check(wrong, exp)
+
+
+def test_no_listed_predicate_leads_out_of_a_written_film_or_into_one(swcell):
+    *_, world, classes = swcell
+    w = classes["add_film"].written
+    assert reference_paths_rw.closed(world.g, w, range(0, reference_rw.POOL, 997), SW_LISTED)
+
+    class Hot(reference_rw.Written):
+        def quads(self, k, tag):       # a new role for a generated actor: a walked node
+            return super().quads(k, tag) + [f"_:p1 <performance.actor> <0x{self.g.actor_base + 1:x}> ."]
+
+    class Sequel(reference_rw.Written):
+        def quads(self, k, tag):       # a generated film gets a written performance
+            return super().quads(k, tag) + [f"<0x{int(self.g.film[0]):x}> <starring> _:p1 ."]
+
+    for planted in (Hot, Sequel):
+        with pytest.raises(AssertionError, match="ties the film to the walked graph"):
+            reference_paths_rw.closed(world.g, planted(world.g), [3], SW_LISTED)
+    # the director and the genre are existing uids, under predicates no search walks
+    assert any("<director.film>" in q for q in w.quads(3, "x"))
+    with pytest.raises(AssertionError):
+        reference_paths_rw.closed(world.g, w, [3], SW_LISTED + ["~director.film"])
+
+
+def _sw_records(classes, walker):
+    recs = []
+    for i, (cls, root) in enumerate([("add_film", 7), ("path_back", 7), ("add_film", 9),
+                                     ("path_back", 9), ("path_costar", 5)]):
+        body = json.dumps({**classes[cls].render(root, walker), "server_latency": {"total": "1ms"}})
+        recs.append((0, cls, root, float(i), i + 0.5, 200, body.encode()))
+    return recs
+
+
+def test_the_true_reference_is_correct_and_lost_path_write_is_not(swcell):
+    *_, world, classes = swcell
+    true = compare.compare(_sw_records(classes, None), classes)
+    assert compare.verdict(true["numbers"])[0] and true["numbers"]["compared"] == 5
+    broken = trafficgen.load_module("controls", "lost_path_write").walker(world)
+    lost = compare.compare(_sw_records(classes, broken), classes)
+    ok, shown = compare.verdict(lost["numbers"])
+    assert not ok and shown["wrong"]["value"] == 2           # both path-backs, and nothing else
+    assert [c for c, good in zip(["w", "b", "w", "b", "s"], lost["ok"]) if not good] == ["b", "b"]
+
+
+def _sw_obs(classes, **kw):
+    led = {"extensions": {"ledger": {"edges": 1, "hop_edges": {"path": 1}}}}
+    recs = [(0, "add_film", 7, 0.0, 0.040, 200, b""), (0, "path_back", 7, 0.04, 0.05, 200, b""),
+            (1, "add_film", 9, 0.0, 0.100, 200, b""), (1, "add_film", 11, 0.2, 0.9, 500, b""),
+            (2, "path_costar", 5, 0.0, 0.02, 200, b"")]
+    base = dict(
+        records=recs, answered=[r for r in recs if r[5] == 200],
+        counters_before={"dgraph_num_queries_total": {"": 10.0}},
+        counters_after={"dgraph_num_queries_total": {"": 15.0}},
+        expect=[classes[c].expect(r) if s == 200 else None for _, c, r, _, _, s, _ in recs],
+        ok=[True, True, True, False, True], tails=[{}, led, {}, {}, led],
+        trace=None, peaks={"hbm_bytes_per_s": 819e9})
+    base.update(kw)
+    return run.Observed(**base)
+
+
+@pytest.mark.parametrize("name", SW_READERS)
+def test_a_searchwrite_reader_returns_none_on_a_parent_without_the_family(swcell, name):
+    *_, classes = swcell
+    read = trafficgen.load_module("metrics", name).read
+    parent = _sw_obs(classes, trace={"busy_s": 0.5, "devices": 1, "window_s": 1.0})
+    _grow(parent, dgraph_ledger_hop_edges_total={"path": 1e6},
+          dgraph_ledger_stage_us_total={"parse": 5.0, "refresh": 7.0},
+          dgraph_writes_total={"ok": 2.0, "error": 0.0},
+          **({} if name == "searchwrite_device_share"
+             else {"dgraph_path_searches_total": {"device": 3.0, "host": 0.0}}))
+    assert read(parent) is None
+
+
+def test_the_searchwrite_counter_and_stage_readers(swcell):
+    classes = swcell[-1]
+    obs = _grow(_sw_obs(classes), dgraph_path_layout_updates_total={"delta": 19.0, "rebuild": 1.0},
+                dgraph_path_layout_h2d_bytes_total={"": 3_584.0},
+                dgraph_writes_total={"ok": 2.0, "error": 1.0},
+                dgraph_path_searches_total={"device": 9.0, "host": 1.0},
+                dgraph_ledger_stage_us_total={"refresh": 50_000.0, "path_layout": 4_000.0})
+    read = lambda name: trafficgen.load_module("metrics", name).read(obs)  # noqa: E731
+    assert read("path_layout_rebuild_share") == pytest.approx(5.0)
+    assert read("h2d_bytes_per_path_write") == pytest.approx(1_792.0)
+    assert read("searchwrite_device_share") == pytest.approx(90.0)
+    assert read("path_layout_ms") == pytest.approx(0.8)      # 4 ms over the window's five requests
+    still = _grow(_sw_obs(classes), dgraph_path_layout_updates_total={"delta": 0.0, "rebuild": 0.0},
+                  dgraph_path_layout_h2d_bytes_total={"": 0.0},
+                  dgraph_writes_total={"ok": 0.0, "error": 0.0})
+    for name in ("path_layout_rebuild_share", "h2d_bytes_per_path_write"):
+        assert trafficgen.load_module("metrics", name).read(still) is None
+
+
+def test_searchwrite_roofline_adds_the_writes_least_bytes(swcell):
+    classes = swcell[-1]
+    read = trafficgen.load_module("metrics", "searchwrite_roofline").read
+    obs = _grow(_sw_obs(classes, trace={"busy_s": 0.004, "devices": 1, "window_s": 1.0}),
+                dgraph_path_layout_updates_total={"delta": 2.0, "rebuild": 0.0})
+    back, star = classes["path_back"].expect(7), classes["path_costar"].expect(5)
+    c = classes["add_film"].written.cast_size(7)
+    # film 7's path-back came back: its write counts; film 9's did not, film 11 was refused
+    wrote = work_path_writes.write_bytes(1 + 2 * c, 4 * c)
+    assert wrote == 8 * (1 + 2 * c) + 8 * 4 * c
+    searched = work_paths.path_bytes(back["edges"] + star["edges"], back["rows"] + star["rows"])
+    assert read(obs) == pytest.approx(100 * ((searched + wrote) / 819e9) / 0.004)
+    assert read(_sw_obs(classes)) is None                     # no trace: nothing, never 0
+    alone = work_paths.roofline_share(back["edges"] + star["edges"], back["rows"] + star["rows"],
+                                      0.004, 819e9)
+    assert read(obs) > alone
