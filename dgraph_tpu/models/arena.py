@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import bisect
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -36,6 +36,7 @@ from dgraph_tpu.obs import ledger as _ledger
 from dgraph_tpu.utils.metrics import (
     ARENA_EVICTIONS,
     ARENA_LAYOUT_UPDATES,
+    ARENA_MIRROR_UPDATES,
     ARENA_REFRESH_H2D_BYTES,
     PATH_LAYOUT_H2D_BYTES,
     PATH_LAYOUT_UPDATES,
@@ -150,6 +151,37 @@ def _rows_of_uids(h_src: np.ndarray, uids: np.ndarray) -> np.ndarray:
     return np.where(h_src[pos] == uids, pos, -1)
 
 
+def _capacity(n: int) -> int:
+    """Entries of the buffer behind a host mirror of ``n``: a sixteenth of
+    room, at least 1,024 (``_ov_capacity``'s rule; a buffer outgrown is
+    copied once into one a seventeenth longer, so an entry appended is
+    copied sixteen times over the arena's life at most)."""
+    return n + max(1024, n >> 4)
+
+
+def _roomy(arr: np.ndarray, n: int) -> np.ndarray:
+    """A copy of ``arr`` at the start of a NEW buffer that holds ``n``
+    entries and room (``_capacity``): the view of the copy, the buffer its
+    ``base``."""
+    buf = np.empty(_capacity(n), dtype=arr.dtype)
+    buf[: len(arr)] = arr
+    return buf[: len(arr)]
+
+
+def _spliced(arr: np.ndarray, pos: np.ndarray, vals) -> np.ndarray:
+    """``np.insert(arr, pos, vals)`` (``pos`` non-decreasing) as the first
+    entries of a NEW buffer with room (``_capacity``): the view returned has
+    the buffer as its ``base``.  ``arr`` is not written."""
+    n = len(arr) + len(pos)
+    out = np.empty(_capacity(n), dtype=arr.dtype)[:n]
+    at = pos + np.arange(len(pos))
+    old = np.ones(n, dtype=bool)
+    old[at] = False
+    out[at] = vals
+    out[old] = arr
+    return out
+
+
 def _with_new_rows(h_src, h_offsets, srcs):
     """(h_src, h_offsets) with a degree-0 row for every uid of ``srcs``
     that has none, h_src kept sorted."""
@@ -157,8 +189,8 @@ def _with_new_rows(h_src, h_offsets, srcs):
     newsrc = u[_rows_of_uids(h_src, u) < 0]
     if len(newsrc):
         at = np.searchsorted(h_src, newsrc)
-        h_src = np.insert(h_src, at, newsrc)
-        h_offsets = np.insert(h_offsets, at + 1, h_offsets[at])
+        h_src = _spliced(h_src, at, newsrc)
+        h_offsets = _spliced(h_offsets, at + 1, h_offsets[at])
     return h_src, h_offsets
 
 
@@ -172,10 +204,12 @@ def _shift_offsets(h_offsets: np.ndarray, rows: np.ndarray, sign: int) -> None:
 
 
 def _merge(h_src, h_offsets, h_dst, adds, dels):
-    """The host mirrors after edges left and joined: each edge's place is
-    a search in its own row (a journal window holds 65,536 at most), and
-    every array is copied once — no pass over the arena's edges but the
-    copies themselves."""
+    """The host mirrors after edges left and joined anywhere: each edge's
+    place is a search in its own row (a journal window holds 65,536 at
+    most), and every array that changes is copied once, into a new buffer
+    with room at its end (``_spliced``) — O(rows + edges), no pass over the
+    arena's edges but the copies themselves.  The arrays given are not
+    written: whoever holds them keeps a whole snapshot."""
     theirs = h_offsets          # the published array: never written in place
     for arr, sign in ((dels, -1), (adds, +1)):
         if not len(arr):
@@ -191,10 +225,15 @@ def _merge(h_src, h_offsets, h_dst, adds, dels):
              for a, b, d in zip(lo.tolist(), hi.tolist(), dsts.tolist())),
             dtype=np.int64, count=len(rows),
         )
-        h_dst = (np.insert(h_dst, pos, dsts.astype(np.int32)) if sign > 0
-                 else np.delete(h_dst, pos))
+        if sign > 0:
+            h_dst = _spliced(h_dst, pos, dsts)
+        else:
+            stay = np.ones(len(h_dst), dtype=bool)
+            stay[pos] = False
+            n = len(h_dst) - len(pos)
+            h_dst = np.compress(stay, h_dst, out=np.empty(_capacity(n), h_dst.dtype)[:n])
         if h_offsets is theirs:
-            h_offsets = h_offsets.copy()
+            h_offsets = _roomy(h_offsets, len(h_offsets))
         _shift_offsets(h_offsets, rows, sign)
     return h_src, h_offsets, h_dst
 
@@ -370,6 +409,7 @@ class CSRArena:
         ov = np.full((_ov_capacity(int(coff[-1])), 8), SENT, dtype=np.int32)
         ov[cpos] = crows
         self._ov_coff = coff
+        self._bufs.pop("_ov_coff", None)
         self._inline = (jnp.asarray(metap), jnp.asarray(ov))
 
     def ov_chunk_degree_of_rows(self, rows: np.ndarray) -> np.ndarray:
@@ -510,10 +550,15 @@ class CSRArena:
 
     def apply_delta(self, adds: np.ndarray, dels: np.ndarray) -> None:
         """Apply a small mutation batch to the HOST mirrors in place of a
-        full rebuild: O(E) memcpy via np.insert/np.delete instead of the
-        O(E log E) lexsort + dict flatten of csr_from_edges — the
-        incremental counterpart of the reference's mutation layer merge
-        (posting/list.go:321-410).  Device tensors go stale and re-upload
+        full rebuild (the O(E log E) lexsort + dict flatten of
+        csr_from_edges) — the incremental counterpart of the reference's
+        mutation layer merge (posting/list.go:321-410).  What the delta
+        costs follows where it lies (``_take_delta_host``): rows and edges
+        past everything the arena holds — a new film, its performances, its
+        newcomers, whose uids are freshly assigned — are written into the
+        room at the end of the mirrors' buffers, O(delta); a delete, or an
+        edge of a row that is there, copies the mirrors once (``_merge``),
+        O(rows + edges) memcpy.  Device tensors go stale and re-upload
         lazily on the next device-path use (ensure_device) — host-routed
         queries after a point mutation never touch the device at all.
 
@@ -542,14 +587,8 @@ class CSRArena:
             np.asarray(a[:, 0], dtype=np.int64) for a in (adds, dels)
         ])) if n_delta else np.empty(0, np.int64)
         old_degs = self._degrees_of_uids(touched)
-        h_src, h_offsets, h_dst = _merge(
-            self.h_src, self.h_offsets, self.host_dst(), adds, dels)
-        # the new mirrors are published back to back, whole: a reader
-        # that holds no lock against this writer (an embedded engine's
-        # host expansion, a clustered refresh) must not meet new offsets
-        # beside old targets while the arrays above are being built
-        self.h_src, self.h_offsets, self._h_dst = h_src, h_offsets, h_dst
-        self.n_rows, self.n_edges = len(h_src), len(h_dst)
+        if n_delta:
+            ARENA_MIRROR_UPDATES.add(self._take_delta_host(adds, dels))
         new_degs = self._degrees_of_uids(touched)
         # bounds, kept as bounds: every added target may be new, every
         # deleted one may have twins (an exact count is one np.unique over
@@ -647,6 +686,66 @@ class CSRArena:
                     RESIDENT_EPOCHS.add("merge")
         self._device_stale = True
 
+    # the buffers behind the host mirrors, by mirror name, once a delta has
+    # needed room: the mirror is then ``buf[:n]``.  Empty on an arena that
+    # took no delta: its mirrors are the arrays the build made
+    _bufs: dict = field(default_factory=dict)
+
+    def _extended(self, name: str, arr: np.ndarray, tail) -> Tuple[np.ndarray, bool]:
+        """The mirror ``name``, ``arr`` followed by ``tail``, and whether a
+        buffer was made for it.  Where ``arr`` is the start of its buffer
+        and the buffer has the room, ``tail`` is written there and the view
+        returned is longer: nothing ``arr`` covers is written, so whoever
+        holds ``arr`` holds what it held.  Else ``arr`` is copied once to
+        the start of a new buffer with room (``_capacity``)."""
+        if not len(tail):
+            return arr, False
+        n = len(arr) + len(tail)
+        buf = self._bufs.get(name)
+        made = buf is None or arr.base is not buf or len(buf) < n
+        if made:
+            buf = self._bufs[name] = _roomy(arr, n).base
+        buf[len(arr): n] = tail
+        return buf[:n], made
+
+    def _take_delta_host(self, adds: np.ndarray, dels: np.ndarray) -> str:
+        """The host mirrors after a delta, and how they took it (the label of
+        ``dgraph_arena_mirror_updates_total``).  The delta is split at the
+        arena's last row: what lies at or before it — a delete, an edge of a
+        row that is there (the last one too: its end is ``h_offsets[-1]``,
+        which the published view holds), a row in the middle — goes through
+        ``_merge``, which copies each mirror it changes into a new buffer
+        with room (``copy``); rows past it with their edges are then written
+        into the room at the end (``_extended``: ``append``, or ``grow``
+        where a buffer had to be made or had run out).  Nothing a published
+        view covers is ever written, so a reader that holds the old views —
+        an embedded engine's host expansion, a clustered refresh, which hold
+        no lock against this writer — keeps a whole snapshot; the new views
+        are published back to back, so that it does not meet new offsets
+        beside old targets while they are made."""
+        last = int(self.h_src[-1]) if self.n_rows else -1
+        past = adds[:, 0] > last
+        mid, end = adds[~past], adds[past]
+        src, off, dst = self.h_src, self.h_offsets, self.host_dst()
+        how = "append"
+        if len(mid) or len(dels):
+            how = "copy"
+            merged = _merge(src, off, dst, mid, dels)
+            for name, was, now in zip(("h_src", "h_offsets", "_h_dst"), (src, off, dst), merged):
+                if now is not was:
+                    self._bufs[name] = now.base
+            src, off, dst = merged
+        end = end[np.lexsort((end[:, 1], end[:, 0]))]
+        uids, counts = np.unique(end[:, 0], return_counts=True)
+        src, made_s = self._extended("h_src", src, uids)
+        off, made_o = self._extended("h_offsets", off, len(dst) + np.cumsum(counts))
+        dst, made_d = self._extended("_h_dst", dst, end[:, 1])
+        if how == "append" and (made_s or made_o or made_d):
+            how = "grow"
+        self.h_src, self.h_offsets, self._h_dst = src, off, dst
+        self.n_rows, self.n_edges = len(src), len(dst)
+        return how
+
     def _layouts_take_delta(self, pre_rows: int, old_last: int,
                             touched: np.ndarray) -> None:
         """The device inline layout and LUT after a delta the host mirrors
@@ -720,7 +819,9 @@ class CSRArena:
             metap = _put_scatter(metap, rows.astype(np.int32), mrows)
         if len(cpos):
             ov = _put_scatter(ov, cpos.astype(np.int32), crows)
-        self._ov_coff = np.concatenate([coff[: r0 + 1], tcoff[1:]])
+        # read under _BUILD_LOCK only, so a row in the middle that moved
+        # may be rewritten where it lies
+        self._ov_coff, _ = self._extended("_ov_coff", coff[: r0 + 1], tcoff[1:])
         self._inline = (metap, ov)
         return True
 
@@ -748,12 +849,18 @@ class CSRArena:
         """Degree-0 rows before the rows ``at`` (ascending, positions in the
         rows as they are) of an arena whose row keys are the row numbers —
         an index arena taking new tokens.  Every later row is renumbered,
-        so what was derived from the rows is dropped."""
+        so what was derived from the rows is dropped; ``h_offsets`` is
+        copied once (into a buffer with room), the row numbers grow at
+        their end."""
         with _BUILD_LOCK:
-            h_offsets = np.insert(self.h_offsets, at + 1, self.h_offsets[at])
-            self.h_src = np.arange(len(h_offsets) - 1, dtype=np.int64)
-            self.h_offsets, self.n_rows = h_offsets, len(h_offsets) - 1
+            h_offsets = _spliced(self.h_offsets, at + 1, self.h_offsets[at])
+            self._bufs["h_offsets"] = h_offsets.base
+            n = len(h_offsets) - 1
+            self.h_src, _ = self._extended(
+                "h_src", self.h_src, np.arange(self.n_rows, n, dtype=np.int64))
+            self.h_offsets, self.n_rows = h_offsets, n
             self._inline = self._ov_coff = self._lut = None
+            self._bufs.pop("_ov_coff", None)
             self._resident = self._tiles = None
             for attr in ("_topm_ovdeg", "_topm_deg", "_tile_blocks", "_deg_hist"):
                 if hasattr(self, attr):
@@ -1142,7 +1249,20 @@ class IndexArena:
         """The index after (uid, value) pairs were set on uids that held no
         value (``PostingStore.value_delta``): a token that is new gets a
         row at its place, each uid joins its tokens' rows — what a build
-        from the store would give, without the walk over every value."""
+        from the store would give, without the walk over every value.
+
+        ``tokens`` takes a new token where it lies (one ``list.insert``, a
+        shift of references and no copy of the table); the CSR's offsets and
+        targets are copied once each, into buffers with room, and its row
+        numbers grow at their end.  In a server the writer holds the
+        exclusive side, and no reader runs.  A reader that holds no lock
+        against this writer (an embedded engine, a clustered refresh) may
+        call ``row_of`` / ``row_range`` meanwhile: each sees the table before
+        or after a token went in (``list.insert`` and ``bisect`` are atomic
+        under the GIL), and the CSR arrays it holds stay whole (nothing a
+        published view covers is written); but tokens and rows are not
+        published together, and never were, so between the two a row number
+        may be one of the other numbering."""
         pairs = set()
         for uid, val in items:
             try:
@@ -1157,11 +1277,9 @@ class IndexArena:
             if new:
                 at = np.array([bisect.bisect_left(self.tokens, t) for t in new],
                               dtype=np.int64)
-                tokens = list(self.tokens)   # published whole, as the mirrors are
-                for t in new:
-                    bisect.insort(tokens, t)
                 self.csr.insert_empty_rows(at)
-                self.tokens = tokens
+                for t in new:
+                    bisect.insort(self.tokens, t)
             adds = np.array(sorted((self.row_of(t), u) for t, u in pairs),
                             dtype=np.int64).reshape(-1, 2)
             self.csr.apply_delta(adds, np.zeros((0, 2), dtype=np.int64))

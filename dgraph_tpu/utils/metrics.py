@@ -817,6 +817,17 @@ ARENA_LAYOUT_UPDATES = metrics.labeled(
 )
 for _how in ("delta", "rebuild"):
     ARENA_LAYOUT_UPDATES.add(_how, 0)
+# a delta taken into an arena's host mirrors (models/arena.py
+# ``_take_delta_host``), one count an ``apply_delta``: "append" = rows and
+# edges past all the arena held, written into the room at the end of the
+# mirrors' buffers, O(delta); "grow" = the same after a buffer was made or had
+# run out (one copy of that mirror); "copy" = a delete, or an edge of a row
+# that was there: the mirrors copied once through ``_merge``, O(rows + edges)
+ARENA_MIRROR_UPDATES = metrics.labeled(
+    "dgraph_arena_mirror_updates_total", label="how"
+)
+for _how in ("append", "grow", "copy"):
+    ARENA_MIRROR_UPDATES.add(_how, 0)
 # bytes those updates and rebuilds put on the device (they are in
 # dgraph_ledger_bytes_total{dir="h2d"} too, on the writer's account)
 ARENA_REFRESH_H2D_BYTES = metrics.counter("dgraph_arena_refresh_h2d_bytes_total")

@@ -12,6 +12,10 @@ films, ``benchmark/reference.py`` for the generated graph, through ``run.World``
 - the device ``_inline`` / ``_lut`` after deltas are what a build from the host
   mirror gives (the arenas of a served graph, and random deltas on a bare one);
 - a cached answer whose footprint a write touched is never served again;
+- the host mirrors have room at their end: a delta that lies past all the arena
+  holds is written there (``dgraph_arena_mirror_updates_total{append}``), views
+  taken before it stay what they were, anything else is copied once and leaves
+  room; either way the mirrors are what a build from the edges gives;
 - the parts: a merged delta is what a build from the edges gives, the top-m chunk sums are repaired
   exactly, an index arena and the value mirror take a new value in place, the
   store's journals overflow where they must.
@@ -41,6 +45,7 @@ from dgraph_tpu.query import chain  # noqa: E402
 from dgraph_tpu.serve.server import DgraphServer  # noqa: E402
 from dgraph_tpu.utils.metrics import (  # noqa: E402
     ARENA_LAYOUT_UPDATES,
+    ARENA_MIRROR_UPDATES,
     WRITES,
     WRITE_QUADS,
     XLA_COMPILES,
@@ -322,6 +327,203 @@ def test_rows_at_the_end_are_taken_in_place_and_a_bucket_outgrown_is_rebuilt():
     assert int(np.asarray(a.lut(5000))[5000]) == a.n_rows - 1
 
 
+# -- the host mirrors have room at their end ------------------------------------------------------
+
+
+MIRRORS = ("h_src", "h_offsets", "_h_dst")
+
+
+def _pairs(edges):
+    return np.array(sorted(edges), dtype=np.int64).reshape(-1, 2)
+
+
+def _edges_of(a):
+    return set(zip(np.repeat(a.h_src, np.diff(a.h_offsets)).tolist(), a.host_dst().tolist()))
+
+
+def _assert_mirrors_are_a_build_from(a, have):
+    """``a``'s mirrors against ``csr_from_edges`` of the edge set ``have``
+    (a row that deletes emptied stays, with no edge), its chunk offsets
+    against a layout built from that build."""
+    now = _pairs(have)
+    want = A.csr_from_edges(now[:, 0], now[:, 1])
+    rows = np.diff(a.h_offsets) > 0
+    np.testing.assert_array_equal(a.h_src[rows], want.h_src)
+    np.testing.assert_array_equal(a.h_offsets[np.concatenate([[True], rows])], want.h_offsets)
+    np.testing.assert_array_equal(a.host_dst(), want.host_dst())
+    assert (a.n_rows, a.n_edges) == (len(a.h_src), len(a.host_dst()))
+    assert (a.h_src.dtype, a.h_offsets.dtype, a.host_dst().dtype) == (np.int64, np.int64, np.int32)
+    if a._ov_coff is not None and rows.all():
+        want.inline_layout()
+        np.testing.assert_array_equal(a._ov_coff, want._ov_coff)
+
+
+def _film(top, cast):
+    """A new film's edges as one arena sees them: ``cast`` rows past ``top``."""
+    return {(top + 1, top + 2 + j) for j in range(cast)} | \
+           {(top + 2 + j, top + 40 + j) for j in range(cast - 1)}
+
+
+def _grown():
+    return ARENA_MIRROR_UPDATES.snapshot()
+
+
+def _since(was):
+    now = _grown()
+    return {h: now[h] - was[h] for h in ("append", "grow", "copy") if now[h] != was[h]}
+
+
+MIXES = ("tail", "tail_and_middle", "one_delta_holds_both")
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_a_sequence_of_deltas_leaves_mirrors_a_build_from_the_edges(mix):
+    rng = np.random.default_rng(MIXES.index(mix))
+    for trial in range(4):
+        n = int(rng.integers(20, 400))
+        a = A.csr_from_edges(rng.integers(1, 200, n), rng.integers(1, 500, n))
+        if trial % 2:
+            a.inline_layout()
+            a.lut(8000)
+        have, top, was = _edges_of(a), 600, _grown()
+        for step in range(12):
+            adds, dels = set(), set()
+            if mix == "tail" or step % 3 != 2 or mix == "one_delta_holds_both":
+                adds |= _film(top, int(rng.integers(2, 12)))
+                top += 100
+            if mix != "tail" and (step % 3 == 2 or mix == "one_delta_holds_both"):
+                # under the last row: a row that is there, or a new one in the middle
+                adds |= {(int(rng.integers(1, int(a.h_src[-1]))), int(rng.integers(1, 900)))
+                         for _ in range(int(rng.integers(1, 5)))} - have
+                hl = sorted(have)
+                dels = {hl[int(i)] for i in rng.integers(0, len(hl), int(rng.integers(0, 3)))}
+            have = (have | adds) - dels
+            a.apply_delta(_pairs(adds), _pairs(dels))
+            # deletes may leave empty rows: compare the mirrors' own invariants then
+            assert _edges_of(a) == have
+            assert (np.diff(a.h_src) > 0).all() and a.h_offsets[0] == 0 and a.h_offsets[-1] == a.n_edges
+            if not (np.diff(a.h_offsets) == 0).any():
+                _assert_mirrors_are_a_build_from(a, have)
+            if a._inline is not None:
+                _assert_layout_is_a_fresh_build(a)
+        took = _since(was)
+        assert sum(took.values()) == 12
+        if mix == "tail":
+            assert set(took) <= {"append", "grow"} and took["append"] >= 10
+        else:
+            assert took["copy"] == (4 if mix == "tail_and_middle" else 12)
+
+
+@pytest.mark.parametrize("pred, reverse", [("starring", False), ("starring", True),
+                                           ("performance.actor", False),
+                                           ("performance.actor", True)])
+def test_a_served_arenas_mirrors_after_the_writes_are_a_build_from_the_edges(written, pred, reverse):
+    srv = written[0]
+    a = srv.engine.arenas.reverse(pred) if reverse else srv.engine.arenas.data(pred)
+    truth = {(s, d) for s, ds in srv.store.peek(pred).edges.items() for d in ds}
+    _assert_mirrors_are_a_build_from(a, {(d, s) for s, d in truth} if reverse else truth)
+    # twelve films, each past all that was there: the mirrors are the start of buffers with room
+    for name in MIRRORS + ("_ov_coff",):
+        view, buf = getattr(a, name), a._bufs[name]
+        assert view.base is buf and len(buf) > len(view) and np.shares_memory(view, buf)
+
+
+@pytest.mark.parametrize("layouts", [False, True])
+def test_views_taken_before_a_tail_delta_are_what_they_were_after_it(layouts):
+    rng = np.random.default_rng(7)
+    a = A.csr_from_edges(rng.integers(1, 300, 900), rng.integers(1, 700, 900))
+    if layouts:
+        a.inline_layout()
+    a.apply_delta(_pairs(_film(1000, 5)), NONE)            # the buffers are made
+    for step in range(6):
+        held = (a.h_src, a.h_offsets, a.host_dst(), a._ov_coff)
+        copies = [None if v is None else v.copy() for v in held]
+        edges = _edges_of(a)
+        a.apply_delta(_pairs(_film(2000 + 100 * step, 3 + step)), NONE)
+        for v, c in zip(held, copies):
+            if v is not None:
+                np.testing.assert_array_equal(v, c)
+        # together they still describe the arena as it was
+        assert held[1][-1] == len(held[2]) and len(held[1]) == len(held[0]) + 1
+        assert set(zip(np.repeat(held[0], np.diff(held[1])).tolist(), held[2].tolist())) == edges
+        assert len(a.h_src) > len(held[0]) and np.shares_memory(a.h_src, held[0])
+
+
+def test_two_hundred_films_reallocate_each_mirror_a_few_times():
+    rng = np.random.default_rng(3)
+    a = A.csr_from_edges(rng.integers(1, 3000, 9000), rng.integers(1, 7000, 9000))
+    a.inline_layout()
+    have, was = _edges_of(a), _grown()
+    made = dict.fromkeys(MIRRORS + ("_ov_coff",), 0)
+    for k in range(200):
+        found = dict(a._bufs)
+        film = _film(10_000 + 100 * k, 8)
+        have |= film
+        a.apply_delta(_pairs(film), NONE)
+        for name in made:
+            if a._bufs.get(name) is not found.get(name):
+                made[name] += 1         # (a layout built anew, its bucket outgrown, owns its offsets)
+            elif name in found:         # written into the room of the buffer it found
+                assert np.shares_memory(getattr(a, name), found[name])
+    took = _since(was)
+    # 1,600 rows and 3,000 edges in rooms of 1,024 at least: a mirror's buffer is made
+    # once and outgrown twice or thrice, never once a film
+    assert took.get("copy", 0) == 0 and took["append"] + took["grow"] == 200
+    assert 1 <= took["grow"] <= 8 and max(made.values()) <= 4 and min(made.values()) >= 1
+    _assert_mirrors_are_a_build_from(a, have)
+    _assert_layout_is_a_fresh_build(a)
+
+
+@pytest.mark.parametrize("what", ["a_delete", "an_edge_of_a_middle_row", "an_edge_of_the_last_row",
+                                  "a_row_in_the_middle"])
+def test_anything_but_a_tail_delta_is_copied_once_and_leaves_room(what):
+    a = A.csr_from_edges(np.repeat(np.arange(10, 400, 10), 3), np.arange(117) + 1000)
+    have = _edges_of(a)
+    old = (a.h_src, a.h_offsets, a.host_dst())
+    olds = [v.copy() for v in old]
+    adds, dels = set(), set()
+    if what == "a_delete":
+        dels = {sorted(have)[40]}
+    elif what == "an_edge_of_a_middle_row":
+        adds = {(200, 5)}
+    elif what == "an_edge_of_the_last_row":
+        adds = {(390, 5000)}
+    else:
+        adds = {(205, 77), (205, 78)}
+    was = _grown()
+    a.apply_delta(_pairs(adds), _pairs(dels))
+    assert _since(was) == {"copy": 1}
+    have = (have | adds) - dels
+    assert _edges_of(a) == have
+    for v, c in zip(old, olds):                            # the published arrays were not written
+        np.testing.assert_array_equal(v, c)
+    for name in ("h_offsets", "_h_dst"):                   # what was copied has room
+        view, buf = getattr(a, name), a._bufs[name]
+        assert view.base is buf and len(buf) >= len(view) + 1024
+    was = _grown()
+    film = _film(5000, 4)
+    a.apply_delta(_pairs(film), NONE)                      # h_src may still be the build's own
+    assert set(_since(was)) <= {"append", "grow"}
+    was, bufs = _grown(), dict(a._bufs)
+    a.apply_delta(_pairs(_film(6000, 4)), NONE)
+    assert _since(was) == {"append": 1} and all(a._bufs[k] is bufs[k] for k in MIRRORS)
+    if what != "a_delete":
+        _assert_mirrors_are_a_build_from(a, have | film | _film(6000, 4))
+
+
+def test_an_arena_that_took_no_delta_owns_arrays_of_exactly_its_size():
+    rng = np.random.default_rng(5)
+    a = A.csr_from_edges(rng.integers(1, 300, 900), rng.integers(1, 700, 900))
+    a.inline_layout()
+    a.lut(1000)
+    assert a._bufs == {}
+    for v, n in ((a.h_src, a.n_rows), (a.h_offsets, a.n_rows + 1), (a._ov_coff, a.n_rows + 1)):
+        assert v.shape == (n,) and (v.base is None or v.base.shape == (n,))
+    assert a.host_dst().shape == (a.n_edges,)
+    a.apply_delta(NONE, NONE)                              # an empty delta: nothing is made, nothing counted
+    assert a._bufs == {}
+
+
 # -- a cached answer whose footprint a write touched ------------------------------------------
 
 
@@ -418,6 +620,38 @@ def test_an_index_arena_takes_new_values_in_place(tokenizer):
     store.set_value("name", 5, _value("Renamed"))               # an overwrite: rebuilt
     assert store.value_delta["name"] is None
     assert mgr.index("name", tokenizer) is not idx
+
+
+@pytest.mark.parametrize("where", ["front", "middle", "end", "everywhere_twice"])
+def test_an_index_arena_takes_new_tokens_wherever_they_sort(where):
+    store = _named_store(300)                                   # tokens "Actor 1" .. "Actor 300"
+    mgr = A.ArenaManager(store)
+    idx = mgr.index("name", "exact")
+    names = {"front": ["Aardvark 7", "Aardvark 1"], "middle": ["Actor 2000", "Actor 17b"],
+             "end": ["Zoe w-5-1", "Zed"],
+             "everywhere_twice": ["Aardvark", "Actor 150x", "Zoe"]}[where]
+    rounds = 2 if where == "everywhere_twice" else 1
+    uid = 900
+    for r in range(rounds):
+        tokens = idx.tokens
+        store.bulk_set_values("name", [(uid + i, "", _value(n + "!" * r)) for i, n in enumerate(names)]
+                              + [(uid + 50, "", _value("Actor 7"))])       # a token that is there
+        uid += 100
+        assert mgr.index("name", "exact") is idx and idx.tokens is tokens   # in place: no copy of the table
+    fresh = A.ArenaManager(store)._build_index("name", "exact")
+    assert idx.tokens == fresh.tokens and len(idx.tokens) == 300 + rounds * len(names)
+    for name in ("h_src", "h_offsets"):
+        np.testing.assert_array_equal(getattr(idx.csr, name), getattr(fresh.csr, name))
+    np.testing.assert_array_equal(idx.csr.host_dst(), fresh.csr.host_dst())
+    assert idx.csr.n_rows == fresh.csr.n_rows == len(idx.tokens)
+    for t in fresh.tokens[::7] + fresh.tokens[-3:]:
+        assert idx.row_of(t) == fresh.row_of(t) >= 0
+        assert idx.row_range(lo=t) == fresh.row_range(lo=t)
+        assert idx.row_range(hi=t, hi_open=True) == fresh.row_range(hi=t, hi_open=True)
+    assert idx.row_of(fresh.tokens[0][:-1] + "\x00") == -1
+    rows = np.arange(idx.csr.n_rows)
+    for got, want in zip(idx.csr.expand_host(rows), fresh.csr.expand_host(rows)):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_the_value_mirror_takes_a_new_uid_and_drops_on_an_overwrite():

@@ -388,6 +388,41 @@ def test_the_counter_readers(cell):
     assert trafficgen.load_module("metrics", "layout_rebuild_share").read(still) is None
 
 
+def test_mirror_copy_share_is_one_entry_at_the_end_for_the_two_written_cells(cell):
+    bench = cell[0]
+    last = bench["per_layer"][-1]
+    assert last == {"name": "mirror_copy_share", "unit": "%", "better": "lower",
+                    "source": "program_counter", "layer": "arenas", "moves": "query_p50_ms",
+                    "workloads": [CELL, "film-q4-paths-rw.searchwrite"]}
+    assert [m["name"] for m in bench["per_layer"]].count("mirror_copy_share") == 1
+    for c in last["workloads"]:
+        assert "query_p50_ms" in [m["name"] for m in run.metrics_of(bench, "end_to_end", c)]
+
+
+@pytest.mark.parametrize("grown, share", [
+    ({"append": 36.0, "grow": 4.0, "copy": 10.0}, 20.0),     # a film: four arenas at their end, the index's tokens
+    ({"append": 40.0, "grow": 0.0, "copy": 0.0}, 0.0),
+    ({"append": 0.0, "grow": 0.0, "copy": 3.0}, 100.0),
+    ({"append": 0.0, "grow": 0.0, "copy": 0.0}, None),       # no delta reached a mirror
+    ({"append": 5.0, "copy": 1.0}, None),                    # a label missing: not this program's family
+    (None, None),                                            # the parent: no such family
+])
+def test_mirror_copy_share_is_copy_over_all_three(cell, grown, share):
+    read = trafficgen.load_module("metrics", "mirror_copy_share").read
+    obs = _obs(cell[-1])
+    if grown is not None:
+        _grow(obs, dgraph_arena_mirror_updates_total=grown)
+    got = read(obs)
+    assert got is None if share is None else got == pytest.approx(share)
+
+
+def test_the_program_seeds_the_three_labels_mirror_copy_share_reads():
+    from dgraph_tpu.utils.metrics import ARENA_MIRROR_UPDATES
+
+    assert set(ARENA_MIRROR_UPDATES.snapshot()) == {"append", "grow", "copy"}
+    assert ARENA_MIRROR_UPDATES.name == "dgraph_arena_mirror_updates_total"
+
+
 def test_readwrite_roofline_adds_the_writes_least_bytes(cell):
     classes = cell[-1]
     read = trafficgen.load_module("metrics", "readwrite_roofline").read
@@ -434,10 +469,10 @@ def test_the_searchwrite_cell_is_one_chip_one_configuration_five_readers(swcell)
     assert next(m for m in bench["end_to_end"] if m["name"] == "edges_per_s")["workloads"] == \
         ["film-q4-paths.shortest"]
     mine = [m for m in bench["per_layer"] if m.get("workloads") == [SW_CELL]]
-    assert [m["name"] for m in mine] == SW_READERS and bench["per_layer"][-5:] == mine
+    assert [m["name"] for m in mine] == SW_READERS and bench["per_layer"][-6:-1] == mine
     assert all(m["moves"] == "query_p50_ms" for m in mine)
     assert [m["layer"] for m in mine] == ["arenas", "arenas", "arenas", "planner", "kernels"]
-    assert not any(SW_CELL in m.get("workloads", []) for m in bench["per_layer"][:-5])
+    assert not any(SW_CELL in m.get("workloads", []) for m in bench["per_layer"][:-6])
     base = trafficgen.load_json("configs", "film21m-q4.json")
     for key in ("scale", "upstream_scale", "reduced", "schema", "shapes"):
         assert config[key] == base[key]            # the same graph, cut the same way
