@@ -1052,7 +1052,7 @@ class UncheckedHopLoop(Rule):
 # the MetricsRegistry constructor methods (utils/metrics.py) — the only
 # sanctioned way a dgraph_* series comes into existence
 _METRIC_CTORS = {
-    "counter", "gauge", "func_gauge", "labeled", "multilabeled",
+    "counter", "gauge", "func_gauge", "func_counter", "labeled", "multilabeled",
     "labeled_gauge", "multilabeled_gauge", "histogram",
     "labeled_histogram",
 }

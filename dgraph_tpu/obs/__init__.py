@@ -15,6 +15,7 @@ from dgraph_tpu.obs.spans import (
     Sampler,
     Span,
     TraceContext,
+    annotation,
     block_ready_ms,
     child,
     configure,
@@ -22,6 +23,7 @@ from dgraph_tpu.obs.spans import (
     format_traceparent,
     parse_traceparent,
     server_span,
+    span_ns,
     stage,
     start_request,
 )
@@ -41,6 +43,7 @@ __all__ = [
     "Sampler",
     "Span",
     "TraceContext",
+    "annotation",
     "block_ready_ms",
     "child",
     "chrome_trace",
@@ -50,6 +53,7 @@ __all__ = [
     "get_recorder",
     "parse_traceparent",
     "server_span",
+    "span_ns",
     "stage",
     "start_request",
 ]

@@ -62,6 +62,7 @@ from dgraph_tpu.utils.metrics import (
     LEDGER_HOPS,
     LEDGER_STAGE_US,
     LEDGERS_CREATED,
+    SCHED_WAIT_US,
 )
 
 _current: "contextvars.ContextVar[Optional[Ledger]]" = contextvars.ContextVar(
@@ -92,6 +93,12 @@ for _s in STAGES:
     LEDGER_STAGE_US.add(_s, 0)
 for _d in ("h2d", "d2h"):
     LEDGER_BYTES.add(_d, 0)
+# what stage ``queue`` waited for (sched/cohort.py ``SchedRequest``
+# ``_book_waits``): a partition of it, not stages of their own —
+# dgraph_sched_wait_us_total{cause}, docs/deploy.md "Stage catalogue"
+WAIT_CAUSES = ("batch", "worker", "writer", "start", "twin")
+for _c in WAIT_CAUSES:
+    SCHED_WAIT_US.add(_c, 0)
 
 
 def enabled() -> bool:
@@ -121,7 +128,7 @@ class Ledger:
         "device_sync_ms", "bytes_h2d", "bytes_d2h", "compiles",
         "cache_hits", "cache_misses", "cache_hit_bytes", "repairs",
         "coalesced", "exchange_bytes", "mesh_ms", "mesh_chips", "stages",
-        "_race_serial",
+        "waits", "_race_serial",
     )
 
     # graftcheck tier 3: the pooled ledger is the engine's flagship
@@ -151,6 +158,7 @@ class Ledger:
         self.hops: Dict[str, int] = {}
         self.hop_edges: Dict[str, int] = {}
         self.stages: Dict[str, float] = {}
+        self.waits: Dict[str, float] = {}
         self.reset()
 
     def reset(self) -> None:
@@ -181,6 +189,9 @@ class Ledger:
         # place — the pooled struct allocates nothing per request
         for s in STAGES:
             self.stages[s] = 0.0
+        # stage ``queue`` by cause (WAIT_CAUSES), milliseconds
+        for c in WAIT_CAUSES:
+            self.waits[c] = 0.0
 
     # -- instrumentation sites (callers checked current() is not None) ------
 
@@ -355,6 +366,7 @@ def finish(led: Ledger) -> dict:
     for s, ms in led.stages.items():
         if ms:
             LEDGER_STAGE_US.add(s, int(ms * 1e3))
+    _drain_waits(led)
     if led.bytes_h2d:
         LEDGER_BYTES.add("h2d", led.bytes_h2d)
     if led.bytes_d2h:
@@ -368,6 +380,21 @@ def finish(led: Ledger) -> dict:
         if len(_pool) < _POOL_CAP:
             _pool.append(led)
     return out
+
+
+def _drain_waits(led: Ledger) -> None:
+    """The causes of stage ``queue`` into dgraph_sched_wait_us_total, in
+    integer µs that sum to exactly the µs the stage itself drained: the
+    few µs truncation takes off the parts go back to the largest."""
+    queue_us = int(led.stages["queue"] * 1e3)
+    if not queue_us:
+        return
+    us = {c: int(ms * 1e3) for c, ms in led.waits.items()}
+    top = max(us, key=us.get)
+    us[top] = max(0, us[top] + queue_us - sum(us.values()))
+    for c, n in us.items():
+        if n:
+            SCHED_WAIT_US.add(c, n)
 
 
 def aggregate_summary() -> dict:

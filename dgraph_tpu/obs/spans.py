@@ -169,6 +169,21 @@ class Sampler:
 
 # ------------------------------------------------------------------- span
 
+# ``time.monotonic()`` stamps taken elsewhere (sched/cohort.py's queue
+# wait) can bound a span only where that clock IS the spans' one
+# (CLOCK_MONOTONIC under both on Linux); elsewhere a span keeps its own
+_MONOTONIC_IS_SPAN_CLOCK = (
+    time.get_clock_info("monotonic").implementation
+    == time.get_clock_info("perf_counter").implementation
+)
+
+
+def span_ns(t: float) -> Optional[int]:
+    """A ``time.monotonic()`` stamp on the spans' ``perf_counter_ns``
+    clock, or None where the two clocks differ."""
+    return int(t * 1e9) if _MONOTONIC_IS_SPAN_CLOCK else None
+
+
 class Span:
     """One timed operation in a trace.  Only ever constructed on the
     SAMPLED side — the unsampled path sees None and a shared no-op.
@@ -230,12 +245,13 @@ class Span:
 
     # -- lifecycle ----------------------------------------------------------
 
-    def finish(self) -> None:
-        """Idempotent: the first call stamps t1 and lands the span in
-        its trace buffer; roots publish the whole trace to the ring."""
+    def finish(self, t1: Optional[int] = None) -> None:
+        """Idempotent: the first call stamps t1 (``t1``, where the caller
+        measured the end itself) and lands the span in its trace buffer;
+        roots publish the whole trace to the ring."""
         if self.t1 is not None:
             return
-        self.t1 = time.perf_counter_ns()
+        self.t1 = time.perf_counter_ns() if t1 is None else t1
         self._buf.append(self)
         if self._root:
             recorder.publish(self)
@@ -294,7 +310,7 @@ class _NoopSpan:
     def link(self, other):
         pass
 
-    def finish(self):
+    def finish(self, t1=None):
         pass
 
 
@@ -314,11 +330,13 @@ def child(name: str):
 _TraceAnnotation = None  # jax.profiler.TraceAnnotation, bound on first use
 
 
-def _annotation(name: str):
-    """``dgraph.<stage>`` on the profiler's own host plane — the clock
-    the device's ``XLA Ops`` line is on.  One inactive TraceMe (~0.5us)
-    when no profiler session is open.  Bound lazily: importing obs must
-    not import JAX (the platform is still the user's to choose)."""
+def annotation(name: str):
+    """``dgraph.<name>`` on the profiler's own host plane — the clock
+    the device's ``XLA Ops`` line is on: a stage's (``obs.stage``) or a
+    wait's that no stage brackets (``dgraph.read_lock``).  One inactive
+    TraceMe (~0.5us) when no profiler session is open.  Bound lazily:
+    importing obs must not import JAX (the platform is still the user's
+    to choose)."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation
@@ -340,7 +358,7 @@ class _Stage:
     attributable and greppable.
 
     - the interval is a ``dgraph.<stage>`` TraceAnnotation (see
-      ``_annotation``), whenever a profiler session is open;
+      ``annotation``), whenever a profiler session is open;
     - handed a ``stats`` dict (the engine's per-request stats: the
       coarse route keys ``chain_ms``/``device_expand_ms``/... and the
       engine-side catalogue stages ``plan_ms``/``host_expand_ms``/
@@ -369,7 +387,7 @@ class _Stage:
         self.key = key
 
     def __enter__(self) -> "_Stage":
-        self._ann = _annotation(self.key[:-3])
+        self._ann = annotation(self.key[:-3])
         self._ann.__enter__()
         # the coarse route keys (``chain_ms``, ...) are parents by design
         # and keep their whole time; only catalogue stages carve

@@ -127,7 +127,7 @@ class SchedRequest:
         "parsed", "debug", "deadline", "enqueued", "key",
         "_done", "result", "stats", "error", "span", "queue_span",
         "tenant", "cancel", "ledger", "slot_held", "slot_released",
-        "queue_s", "done_at",
+        "queue_s", "done_at", "cohort", "twin", "b_enq",
     )
 
     def __init__(self, parsed, debug: bool = False,
@@ -175,6 +175,14 @@ class SchedRequest:
         # 504 surfaces without the flush finally double-releasing it
         self.slot_held = False
         self.slot_released = False
+        # what the queue wait waited for (``_book_waits``): the cohort it
+        # joined (None: attached to a twin in flight, or shed at the
+        # door), whether a twin's execution serves it, and the engine
+        # lock's barred clock at admission (read only where a ledger
+        # books the causes)
+        self.cohort: Optional["Cohort"] = None
+        self.twin = False
+        self.b_enq: Optional[float] = None
 
     def expired(self, now: Optional[float] = None) -> bool:
         return self.deadline is not None and (
@@ -184,21 +192,85 @@ class SchedRequest:
     def end_queue_wait(self, outcome: str) -> None:
         """Close the queue wait; first closer's outcome wins (execution
         start beats the completion fallback).  THE definition of queue
-        wait: admission to the start of execution — the cohort's flush
-        deadline, the engine-lock and worker waits included — or, for a
-        request that never executes (shed, dealt a twin's result), to
-        its verdict.  It crosses threads, so it is two monotonic stamps
-        and no profiler annotation; the histogram and the request's
-        ``queue`` stage both take it from here."""
-        if self.queue_s is None:
-            self.queue_s = time.monotonic() - self.enqueued
-            SCHED_QUEUE_WAIT.observe(self.queue_s)
-            if self.ledger is not None:
-                self.ledger.stages["queue"] += self.queue_s * 1e3
+        wait: admission to the start of execution — or, for a request
+        that never executes (shed, dealt a twin's result), to its
+        verdict.  It crosses threads, so it is two monotonic stamps; the
+        histogram, the request's ``queue`` stage and the ``sched.queue``
+        span all take them from here.  What the wait was spent on is
+        booked here too, into the request's ledger (``_book_waits``;
+        drained to ``dgraph_sched_wait_us_total{cause}``) and onto the
+        span as attrs.  The one part of it a thread performs — a flush
+        worker blocked on the engine lock's shared side — is on the
+        profiler's clock as ``dgraph.read_lock`` (sched/scheduler.py);
+        the rest no thread performs, and no annotation can show it."""
+        if self.queue_s is not None:
+            return
+        end = time.monotonic()
+        self.queue_s = end - self.enqueued
+        SCHED_QUEUE_WAIT.observe(self.queue_s)
+        waits = None
+        if self.ledger is not None:
+            self.ledger.stages["queue"] += self.queue_s * 1e3
+            waits = self._book_waits(end)
         qs = self.queue_span
         if qs is not None and qs.t1 is None:
             qs.set_attr("outcome", outcome)
-            qs.finish()
+            if waits is not None:
+                for cause, ms in waits.items():
+                    qs.set_attr(cause + "_ms", round(ms, 3))
+            t0 = obs.span_ns(self.enqueued)
+            if t0 is not None:
+                qs.t0 = t0
+            qs.finish(obs.span_ns(end))
+
+    def _book_waits(self, end: float) -> Dict[str, float]:
+        """Split the queue wait, ``enqueued`` to ``end``, by what it was
+        spent on into the ledger's ``waits`` (ms; the parts sum to the
+        ``queue`` stage) and return the parts.  The cohort came due
+        (``Cohort.due``: filled, its flush deadline or an idle beat; for
+        this request never before its admission), a flush worker popped
+        it (``popped``) and took the engine lock's shared side
+        (``held``); the lock's barred clock was read at this admission
+        and at both.
+
+        - ``batch``: admission to due;
+        - ``worker``: due to the pop, less the barred time in it;
+        - ``writer``: the barred time from due to the shared side held —
+          a writer held or awaited the exclusive side meanwhile;
+        - ``start``: the pop to this request's start, less the lock's
+          wait: the flush's preamble, its waves, a thread's start;
+        - ``twin``: a singleflight follower's wait after the pop, or from
+          admission where it attached to a twin already executing.
+
+        A request never popped (shed at admission, the scheduler
+        stopping) books its wait under ``batch``, or ``twin``.  No thread
+        sees the moment a cohort comes due, so the clock is not read
+        there: barred time inside the batch phase may count as
+        ``writer``, at most that phase (``flush_s``)."""
+        enq, c = self.enqueued, self.cohort
+        if c is None or c.popped is None:
+            parts = {"twin" if self.twin else "batch": end - enq}
+        else:
+            pop = min(c.popped, end)
+            due = min(max(c.due, enq), pop)
+            b_enq = c.b_pop if self.b_enq is None else self.b_enq
+            writer = min(max(c.b_pop - b_enq, 0.0), pop - due)
+            parts = {"batch": due - enq, "worker": pop - due - writer}
+            after = end - pop
+            if self.twin:
+                parts["twin"] = after
+            else:
+                lock = 0.0
+                if c.held is not None:
+                    lock = min(max(c.b_held - c.b_pop, 0.0), after)
+                writer += lock
+                parts["start"] = after - lock
+            parts["writer"] = writer
+        waits = self.ledger.waits
+        for cause, s in parts.items():
+            parts[cause] = s * 1e3
+            waits[cause] += s * 1e3
+        return parts
 
     def complete(self, result, stats: dict) -> None:
         self.end_queue_wait("done")
@@ -232,13 +304,26 @@ class Cohort:
     tenant — fairness picks BETWEEN tenants, so cohorts never mix
     scopes), awaiting a flush."""
 
-    __slots__ = ("sig", "reqs", "born", "tenant")
+    __slots__ = (
+        "sig", "reqs", "born", "tenant", "due", "popped", "b_pop", "held",
+        "b_held",
+    )
 
     def __init__(self, sig: tuple, tenant: str = ""):
         self.sig = sig
         self.reqs: List[SchedRequest] = []
         self.born = time.monotonic()
         self.tenant = tenant
+        # the stamps a member's queue wait is split by
+        # (SchedRequest._book_waits): when the cohort came due — its
+        # earliest trigger, clipped to the pop — the pop by a flush
+        # worker and the engine lock's shared side held, with the lock's
+        # barred clock (utils/rwlock.py) at the last two
+        self.due: Optional[float] = None
+        self.popped: Optional[float] = None
+        self.b_pop = 0.0
+        self.held: Optional[float] = None
+        self.b_held = 0.0
 
 
 # ---------------------------------------------------------------- merging

@@ -77,7 +77,6 @@ cache gate and byte budget, cache/result.py).
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
@@ -107,6 +106,8 @@ from dgraph_tpu.utils.metrics import (
     SCHED_FLUSHES,
     SCHED_QUEUE_DEPTH,
     SCHED_SHED,
+    SCHED_WORKER_BUSY_US,
+    SCHED_WORKERS,
     SEGMENT_PREEMPT_US,
     SEGMENT_YIELDS,
     TENANT_SHED,
@@ -237,8 +238,28 @@ class CohortScheduler:
             )
             for i in range(max(1, n_workers))
         ]
+        # the pool's busy time (dgraph_sched_worker_busy_us_total): closed
+        # flushes' seconds, and the pop of each flush still running by
+        # worker thread — a scrape counts those up to itself
+        self._busy_total = 0.0
+        self._busy_since: Dict[int, float] = {}
+        SCHED_WORKER_BUSY_US.bind(self._busy_s)
+        SCHED_WORKERS.set(len(self._workers))
         for t in self._workers:
             t.start()
+
+    def _busy_s(self) -> float:
+        """Seconds the flush workers spent in flushes, each from its pop
+        to its end, those still running up to now."""
+        with self._cond:
+            now = time.monotonic()
+            return self._busy_total + sum(
+                now - t for t in self._busy_since.values()
+            )
+
+    def _barred_s(self) -> float:
+        """The engine lock's readers-barred clock (utils/rwlock.py)."""
+        return self._server._engine_lock.barred_s()
 
     # -- admission ---------------------------------------------------------
 
@@ -353,6 +374,8 @@ class CohortScheduler:
         # handler thread owns it again once wait() returns (obs/ledger.py
         # single-writer hand-off)
         req.ledger = _ledgermod.current()
+        if req.ledger is not None:
+            req.b_enq = self._barred_s()
         try:
             self._admit(req, hop_signature(parsed, store_ver or 0), key)
         except SchedOverloadError:
@@ -417,18 +440,30 @@ class CohortScheduler:
             if ent is not None and ent[0] == sig[0]:
                 # an identical request is executing over the same
                 # snapshot right now: attach and share its result
+                req.twin = True
                 ent[2].append(req)
                 self._note_admitted(req)
                 SCHED_QUEUE_DEPTH.set(self._depth)
                 SCHED_COALESCED.add(1)
             else:
+                now = time.monotonic()
+                if self._queues and now - self._last_arrival >= self.idle_beat_s:
+                    # a quiet beat ended before this arrival: the idle
+                    # trigger made every cohort pending then due at its end
+                    quiet = self._last_arrival + self.idle_beat_s
+                    for c in self._queues.values():
+                        if c.due is None:
+                            c.due = quiet
                 qkey = (req.tenant, sig)
                 c = self._queues.get(qkey)
                 if c is None:
                     c = self._queues[qkey] = Cohort(sig, tenant=req.tenant)
                 c.reqs.append(req)
+                req.cohort = c
+                if c.due is None and len(c.reqs) >= self.max_batch:
+                    c.due = now     # filled
                 self._note_admitted(req)
-                self._last_arrival = time.monotonic()
+                self._last_arrival = now
                 SCHED_QUEUE_DEPTH.set(self._depth)
                 self._cond.notify_all()
 
@@ -488,7 +523,13 @@ class CohortScheduler:
             cohort, reason = self._next_cohort()
             if cohort is None:
                 return
-            self._flush(cohort, reason)
+            try:
+                self._flush(cohort, reason)
+            finally:
+                with self._cond:
+                    self._busy_total += time.monotonic() - self._busy_since.pop(
+                        threading.get_ident()
+                    )
 
     def _next_cohort(self):
         """Block until some cohort is due, pop and return it.  Priority:
@@ -509,6 +550,8 @@ class CohortScheduler:
                 if due is not None:
                     key, reason = due
                     cohort = self._queues.pop(key)
+                    self._came_due(cohort, now)
+                    self._busy_since[threading.get_ident()] = now
                     if self.qos is not None:
                         # reserve the in-flight slots HERE, in the same
                         # lock hold as the admissibility check — a
@@ -537,6 +580,18 @@ class CohortScheduler:
                         # bounded fallback — never a spin
                         wait = self.idle_beat_s
                     self._cond.wait(max(wait, 1e-4))
+
+    def _came_due(self, c: Cohort, now: float) -> None:
+        """Stamp when ``c`` came due (caller holds self._cond): the
+        earliest of its filling (stamped at admission), its flush
+        deadline and an idle beat, and not after ``now`` — a cohort
+        popped early (preemption) was due when it was popped."""
+        due = c.born + self.flush_s
+        if c.due is not None:
+            due = min(due, c.due)
+        if now - self._last_arrival >= self.idle_beat_s:
+            due = min(due, self._last_arrival + self.idle_beat_s)
+        c.due = min(due, now)
 
     def _due_cohort(self, now: float):
         """(queue key, reason) of the cohort to flush now, or None.
@@ -604,9 +659,12 @@ class CohortScheduler:
         already holds the engine read lock (it is mid-query at a segment
         seam) and utils/rwlock.py is NOT reentrant, so the donated flush
         must run under the donor's hold instead of re-acquiring."""
+        now = cohort.popped = time.monotonic()
+        if cohort.due is None:      # flushed without a pop (tests)
+            cohort.due = now
+        cohort.b_pop = self._barred_s()
         SCHED_FLUSHES.add(reason)
         SCHED_COHORT_OCCUPANCY.observe(len(cohort.reqs))
-        now = time.monotonic()
         live: List[SchedRequest] = []
         shed: List[SchedRequest] = []
         max_wait = 0.0
@@ -654,6 +712,7 @@ class CohortScheduler:
         for req in live:
             k = req.key
             if k is not None and k in seen:
+                req.twin = True
                 dups.setdefault(k, []).append(req)
             else:
                 if k is not None:
@@ -692,12 +751,13 @@ class CohortScheduler:
             # lands INSIDE the try, so every member fails cleanly through
             # req.fail below instead of killing the worker loop
             fail.point("sched.flush")
-            lock_cm = (
-                contextlib.nullcontext()
-                if have_engine_lock
-                else srv._engine_lock.read()
-            )
-            with lock_cm:  # ONE read acquisition per cohort
+            if have_engine_lock:
+                cohort.held, cohort.b_held = cohort.popped, cohort.b_pop
+            else:
+                self._acquire_shared()  # ONE read acquisition per cohort
+                cohort.held = time.monotonic()
+                cohort.b_held = self._barred_s()
+            try:
                 # tenant in-flight cap bounds EXECUTION concurrency, not
                 # just cohort pick: a batch-class tenant with
                 # max_inflight=1 runs its cohort's leaders in waves of 1
@@ -754,6 +814,9 @@ class CohortScheduler:
                             self._run_one(req, merger, flush_span)
                         else:
                             req.fail(lead.error)
+            finally:
+                if not have_engine_lock:
+                    srv._engine_lock.release_read()
         except BaseException as e:  # noqa: BLE001 — lock failure etc.: fail, never hang
             for req in live:
                 if req.result is None and req.error is None:
@@ -835,15 +898,24 @@ class CohortScheduler:
             # leader ran out of budget but this twin still has some: run
             # it for real (rare — needs its own read hold, unless the
             # donation path's donor already holds one)
-            lock_cm = (
-                contextlib.nullcontext()
-                if have_engine_lock
-                else self._server._engine_lock.read()
-            )
-            with lock_cm:
+            if not have_engine_lock:
+                self._acquire_shared()
+            try:
                 self._run_one(req, merger)
+            finally:
+                if not have_engine_lock:
+                    self._server._engine_lock.release_read()
         else:
             req.fail(lead.error)
+
+    def _acquire_shared(self) -> None:
+        """Take the engine lock's shared side for a flush, the wait on
+        the profiler's clock as ``dgraph.read_lock`` — the wait and not
+        the hold (the caller releases): the one part of a request's queue
+        wait a thread performs, blocked while a writer holds or awaits
+        the exclusive side."""
+        with obs.annotation("read_lock"):
+            self._server._engine_lock.acquire_read()
 
     def _shed_deadline(self, req: SchedRequest, now: float) -> None:
         SCHED_SHED.add("deadline")
@@ -989,6 +1061,7 @@ class CohortScheduler:
             if best_key is None:
                 return
             cohort = self._queues.pop(best_key)
+            self._came_due(cohort, time.monotonic())
             # reserve the in-flight slots in the same hold as the
             # admissibility check, exactly like _next_cohort
             self._tenant_inflight[cohort.tenant] = (

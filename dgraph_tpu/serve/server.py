@@ -36,6 +36,7 @@ from dgraph_tpu.serve.export import export as export_rdf
 from dgraph_tpu.utils import HealthGate, Latency
 from dgraph_tpu.utils.rwlock import RWLock
 from dgraph_tpu.utils.metrics import (
+    ENGINE_LOCK_BARRED_US,
     NUM_QUERIES,
     PENDING_QUERIES,
     QUERY_CANCELLED,
@@ -178,6 +179,10 @@ class DgraphServer:
         # utils/rwlock.py).  Kept under the old name so operators' mental
         # model ("the engine lock") still holds for the write side.
         self._engine_lock = RWLock()
+        # the lock's busy time beside the readers' waits for it
+        # (dgraph_sched_wait_us_total{writer}): the seconds new readers
+        # were barred, a scrape counting an open interval up to itself
+        ENGINE_LOCK_BARRED_US.bind(self._engine_lock.barred_s)
         self._stop_lock = threading.Lock()
         # exports write a minute-stamped file; two concurrent exports
         # would interleave gzip streams into one path — serialize them

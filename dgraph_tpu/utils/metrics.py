@@ -123,6 +123,33 @@ class FuncGauge:
         return float(self._fn())
 
 
+class FuncCounter:
+    """Counter whose value a live clock gives at scrape time, so an
+    interval still open counts up to the scrape.  ``bind`` hands it the
+    clock (seconds); a rebind keeps what the old clock had counted, so the
+    series never runs backwards when a process builds a second owner."""
+
+    __slots__ = ("name", "_fn", "_base", "_lock")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._fn = None
+        self._base = 0.0
+        self._lock = threading.Lock()
+
+    def bind(self, fn) -> None:
+        with self._lock:
+            if self._fn is not None:
+                self._base += self._fn()
+            self._fn = fn
+
+    def value(self) -> int:
+        """Integer microseconds."""
+        with self._lock:
+            s = self._base + (self._fn() if self._fn is not None else 0.0)
+        return int(s * 1e6)
+
+
 class MultiLabeledGauge:
     """Gauge family keyed by a label TUPLE — ``dgraph_build_info`` is
     the canonical user: a constant-1 gauge whose labels carry the
@@ -295,6 +322,7 @@ class MetricsRegistry:
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._func_gauges: Dict[str, FuncGauge] = {}
+        self._func_counters: Dict[str, FuncCounter] = {}
         self._labeled: Dict[str, LabeledCounter] = {}
         self._multilabeled: Dict[str, MultiLabeledCounter] = {}
         self._labeled_gauges: Dict[str, LabeledGauge] = {}
@@ -322,6 +350,13 @@ class MetricsRegistry:
             if g is None:
                 g = self._func_gauges[name] = FuncGauge(name, fn)
             return g
+
+    def func_counter(self, name: str) -> FuncCounter:
+        with self._lock:
+            c = self._func_counters.get(name)
+            if c is None:
+                c = self._func_counters[name] = FuncCounter(name)
+            return c
 
     def labeled(self, name: str, label: str = "predicate") -> LabeledCounter:
         with self._lock:
@@ -379,6 +414,7 @@ class MetricsRegistry:
             counters = list(self._counters.values())
             gauges = list(self._gauges.values())
             func_gauges = list(self._func_gauges.values())
+            func_counters = list(self._func_counters.values())
             labeled = list(self._labeled.values())
             multilabeled = list(self._multilabeled.values())
             labeled_gauges = list(self._labeled_gauges.values())
@@ -398,6 +434,9 @@ class MetricsRegistry:
         for fg in sorted(func_gauges, key=lambda g: g.name):
             lines.append(f"# TYPE {fg.name} gauge")
             lines.append(f"{fg.name} {fg.value():g}")
+        for fc in sorted(func_counters, key=lambda c: c.name):
+            lines.append(f"# TYPE {fc.name} counter")
+            lines.append(f"{fc.name} {fc.value()}")
         for l in sorted(labeled, key=lambda l: l.name):
             lines.append(f"# TYPE {l.name} counter")
             for k, v in sorted(l.snapshot().items()):
@@ -527,6 +566,17 @@ SCHED_SHED = metrics.labeled("dgraph_sched_shed_total", label="reason")
 SCHED_MERGED_HOPS = metrics.counter("dgraph_sched_merged_hops_total")
 SCHED_COALESCED = metrics.counter("dgraph_sched_coalesced_requests_total")
 SCHED_QUEUE_DEPTH = metrics.gauge("dgraph_sched_queue_depth")
+# what the ``queue`` stage waited for (sched/cohort.py
+# SchedRequest.end_queue_wait; obs/ledger.py WAIT_CAUSES, each label at 0
+# from boot): integer µs by cause, summing to the stage's own µs; the two
+# resources' busy time beside it — the flush workers' (each flush from its
+# pop to its end; the pool's size in SCHED_WORKERS) and the engine lock's
+# readers-barred clock — clocks bound by their owners (sched/scheduler.py,
+# the server's utils/rwlock.py), so a scrape counts an open interval
+SCHED_WAIT_US = metrics.labeled("dgraph_sched_wait_us_total", label="cause")
+SCHED_WORKER_BUSY_US = metrics.func_counter("dgraph_sched_worker_busy_us_total")
+SCHED_WORKERS = metrics.gauge("dgraph_sched_workers")
+ENGINE_LOCK_BARRED_US = metrics.func_counter("dgraph_engine_lock_barred_us_total")
 
 # multi-tenant QoS surface (sched/qos.py): every cancelled query lands
 # in QUERY_CANCELLED with {reason ∈ deadline/disconnect/admin, tenant};

@@ -390,7 +390,8 @@ def test_the_counter_readers(cell):
 
 def test_mirror_copy_share_is_one_entry_at_the_end_for_the_two_written_cells(cell):
     bench = cell[0]
-    last = bench["per_layer"][-1]
+    # appended at the end by PR 37; later PRs append after it
+    last = next(m for m in bench["per_layer"] if m["name"] == "mirror_copy_share")
     assert last == {"name": "mirror_copy_share", "unit": "%", "better": "lower",
                     "source": "program_counter", "layer": "arenas", "moves": "query_p50_ms",
                     "workloads": [CELL, "film-q4-paths-rw.searchwrite"]}
@@ -469,10 +470,11 @@ def test_the_searchwrite_cell_is_one_chip_one_configuration_five_readers(swcell)
     assert next(m for m in bench["end_to_end"] if m["name"] == "edges_per_s")["workloads"] == \
         ["film-q4-paths.shortest"]
     mine = [m for m in bench["per_layer"] if m.get("workloads") == [SW_CELL]]
-    assert [m["name"] for m in mine] == SW_READERS and bench["per_layer"][-6:-1] == mine
+    at = bench["per_layer"].index(mine[0])      # appended together, after the older
+    assert [m["name"] for m in mine] == SW_READERS and bench["per_layer"][at:at + 5] == mine
     assert all(m["moves"] == "query_p50_ms" for m in mine)
     assert [m["layer"] for m in mine] == ["arenas", "arenas", "arenas", "planner", "kernels"]
-    assert not any(SW_CELL in m.get("workloads", []) for m in bench["per_layer"][:-6])
+    assert not any(SW_CELL in m.get("workloads", []) for m in bench["per_layer"][:at])
     base = trafficgen.load_json("configs", "film21m-q4.json")
     for key in ("scale", "upstream_scale", "reduced", "schema", "shapes"):
         assert config[key] == base[key]            # the same graph, cut the same way
