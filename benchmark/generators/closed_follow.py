@@ -16,22 +16,22 @@ How to use it: name it as a mix's ``generator`` and give the mix a ``follow``
 map; list the follower among ``classes`` with weight 0, after the class it
 follows, so that the warm-up ladder knows it and the deck never deals it.
 
-``mix["needs"]`` (optional): counter families the server has to expose before
-a loop is driven at it.  A program whose arenas cannot take a write without a
-rebuild and a compile (every commit before PR 34) would spend its warm-up
-rounds compiling until ``max_rounds`` ran out, minutes later; asked first, it
-fails at once and cleanly, with the reason.
+A mix's ``needs`` (counter families the program has to expose) are read at
+boot by ``run.set_up``, for a mix of any generator, before the load and the
+warm-up ladder; ``drive`` asks again, through the same reader
+(``harness.unmet_needs``), before it drives a loop, so none is ever driven at
+a program that lacks them.
 """
 
 from __future__ import annotations
 
 import http.client
 import itertools
-import re
 import threading
 import time
-import urllib.request
 from urllib.parse import urlparse
+
+import harness
 
 STRAGGLER_S = 60.0   # how long past the close an answer is waited for
 
@@ -41,14 +41,9 @@ def drive(addr: str, path: str, plan: list, texts, seconds: float, mix: dict) ->
     "records"}, a record being (client, class, root, t_send, t_done, status,
     body); a request that got no answer has status 0 and the error's text."""
     u = urlparse(addr)
-    if mix.get("needs"):
-        with urllib.request.urlopen(addr + "/debug/prometheus_metrics", timeout=60) as r:
-            exposed = r.read().decode()
-        lacking = [f for f in mix["needs"]
-                   if not re.search(rf"^{re.escape(f)}[{{ ]", exposed, re.M)]
-        if lacking:
-            raise RuntimeError(f"the server exposes no {lacking}: this mix needs a program "
-                               f"that has them (benchmark/traffic: `needs`)")
+    why = harness.unmet_needs(addr, mix)
+    if why:
+        raise RuntimeError(why)
     follow = dict(mix.get("follow") or {})
     start = threading.Event()
     bounds = {}

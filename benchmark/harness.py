@@ -164,6 +164,25 @@ def counters(addr: str) -> dict:
     return parse_metrics(http(addr, "/debug/prometheus_metrics"))
 
 
+def lacking(exposition: str, needs: list) -> list:
+    """The families of a mix's ``needs`` that a Prometheus exposition does not
+    hold.  A family counts as exposed where it has its ``# TYPE`` line or any
+    sample: a labelled counter that nothing has incremented, and whose labels
+    the program does not seed at 0, prints its ``# TYPE`` line alone."""
+    return [f for f in needs
+            if not re.search(rf"^(?:# TYPE )?{re.escape(f)}[{{ ]", exposition, re.M)]
+
+
+def unmet_needs(addr: str, mix: dict) -> str:
+    """Why the server at ``addr`` cannot take the mix — the families of its
+    ``needs`` that the server does not expose — or '' where it can.  A mix
+    that names none costs no scrape."""
+    needs = mix.get("needs")
+    missing = lacking(http(addr, "/debug/prometheus_metrics", timeout=60), needs) if needs else []
+    return f"the server exposes no {missing}: this mix needs a program that has them" \
+        if missing else ""
+
+
 def delta(before: dict, after: dict, family: str) -> dict:
     """{label: after - before} of one family between two ``counters()``."""
     b, a = before.get(family, {}), after.get(family, {})
@@ -200,10 +219,31 @@ def memory_peak_bytes(dev: dict) -> int | None:
     return int(max(peaks)) if peaks else None
 
 
-def unfit(addr: str, chips: int) -> list:
+def guard(addr: str) -> dict:
+    """The device guard as the server reports it: ``counts``, what it has
+    counted since boot by name (each domain's faults by kind, its wedged
+    dispatches, the failovers by route; nonzero ones only), and ``sick``,
+    the domains that are not healthy now."""
+    dev = http_json(addr, "/debug/device")
+    counts = {f"failover {route}": v for route, v in
+              counters(addr).get("dgraph_device_failover_total", {}).items()}
+    for name, st in dev["guard"]["domains"].items():
+        counts.update({f"{name} fault {kind}": v for kind, v in st["faults"].items()})
+        counts[f"{name} wedged"] = st["wedged_workers"]
+    return {"counts": {k: v for k, v in counts.items() if v},
+            "sick": {name: st["state"] for name, st in dev["guard"]["domains"].items()
+                     if st["state"] != "healthy"}}
+
+
+def unfit(addr: str, chips: int, since: dict | None = None) -> list:
     """Why this server's answers may not be reported as the chip's: no TPU,
-    too few chips, a device failover, a guard domain not healthy, or a
-    calibration taken from priors.  Empty where all is well."""
+    too few chips, or a calibration taken from priors; and where ``since``
+    is the ``guard`` read at the window's open, a guard domain that was
+    not healthy then or is not now, or a device fault, a wedged dispatch
+    or a failover counted since.  What the guard counted before the
+    window is set-up's: on an empty compile cache a caller that waits for
+    another's compile of the same program reads as a hang.  Empty where
+    all is well."""
     dev = http_json(addr, "/debug/device")
     plan = http_json(addr, "/debug/planner")
     why = []
@@ -211,12 +251,14 @@ def unfit(addr: str, chips: int) -> list:
         why.append(f"the server runs on {dev['backend']!r}, not a TPU")
     if dev["devices"] < chips:
         why.append(f"{dev['devices']} chips, the cell asks for {chips}")
-    failovers = counters(addr).get("dgraph_device_failover_total", {})
-    if any(v != 0 for v in failovers.values()):
-        why.append(f"device failover: {failovers}")
-    for name, st in dev["guard"]["domains"].items():
-        if st["state"] != "healthy" or st["faults"] or st["wedged_workers"]:
-            why.append(f"guard domain {name}: {st['state']}")
+    if since is not None:
+        now = guard(addr)
+        grown = {k: v - since["counts"].get(k, 0) for k, v in now["counts"].items()
+                 if v != since["counts"].get(k, 0)}
+        if grown:
+            why.append(f"device guard in the window: {grown}")
+        for when, sick in (("at the window's open", since["sick"]), ("at its close", now["sick"])):
+            why += [f"guard domain {name} {when}: {state}" for name, state in sick.items()]
     if plan["calibration"]["source"] == "prior":
         why.append("the planner's calibration is a prior, not measured on this backend")
     return why

@@ -46,23 +46,30 @@ import stats  # noqa: E402
 import tracered  # noqa: E402
 import trafficgen  # noqa: E402
 
-TRACE_WINDOW_S = 15.0    # a one-chip traced run's window: the trace comes back whole
+TRACE_WINDOW_S = 15.0    # a one-chip traced run's window where the mix sets none
 KILL_GRACE_S = 10.0      # a server whose profiler does not return is not waited for
+READMIT_WAIT_S = 30.0    # a window's open waits this long for a sick guard domain's re-admission
 
 
-def trace_rule(chips: int) -> dict:
+def trace_rule(chips: int, mix: dict) -> dict:
     """What a traced run does with the profiler, from the cell's ``chips``
-    and nothing else (PERF.md section 2 has the measured seconds behind it).
+    and its mix (PERF.md section 2 has the measured seconds behind it).
 
     ``window_s``: the profiler's stop takes about 120 us a device event of
     the trace, and the events are chips x seconds x what the cell's programs
-    run a second — so the window shrinks as the chips grow, and a one-chip
-    cell keeps its 15 s.  ``budget_s``: the seconds the profiler's start and
-    stop may take TOGETHER; the paths cell's 15 s on one chip need 57-60 of
-    them, four chips' 3.75 s of the traverse mix 64.  Set-up has no budget
-    (a first run in a checkout compiles for minutes), and the reading of the
-    file none either: it is 3-7 s of this process's own, timed beside them."""
-    return {"window_s": TRACE_WINDOW_S / max(1, int(chips)), "budget_s": 120.0}
+    run a second.  So a mix whose programs keep the device busy sets its own
+    window (``trace_s`` in ``traffic/<mix>.json``); any other keeps 15 s on
+    one chip and 15 / ``chips`` on more.  ``budget_s``: the seconds the
+    profiler's start and stop may take TOGETHER.  At 15 s on one chip the
+    traverse mix's stop took 12.9-14.6 s, the paths mix's 88.9-94.6 at
+    333-346 searches a window and the searchwrite mix's 90.8, so both path
+    mixes set 7.5 s: the paths mix's stop then took 42.9-46.9 s at 161-167
+    searches.  Four chips' 3.75 s of the traverse mix took 64.
+    Set-up has no budget (a first run in a checkout compiles for minutes),
+    and the reading of the file none either: it is 3-7 s of this process's
+    own, timed beside them."""
+    window_s = mix.get("trace_s") or TRACE_WINDOW_S / max(1, int(chips))
+    return {"window_s": float(window_s), "budget_s": 120.0}
 
 
 class ProfilerOverBudget(RuntimeError):
@@ -193,7 +200,8 @@ def set_up(server, workdir: str, quads: int, seed: int, mix: dict, chips: int,
            rehearsal: bool, split: dict):
     """Boot (the server calibrates while the parent generates), load, warm
     up.  Returns None where the server is not on the chips the cell asks for
-    (and this is no rehearsal), else a ``Ready``."""
+    (and this is no rehearsal) or lacks a counter family of the mix's
+    ``needs``, else a ``Ready``."""
     t0 = time.monotonic()
     g = filmgen.generate(quads, seed)
     rdf, schema = os.path.join(workdir, "film.rdf.gz"), os.path.join(workdir, "film.schema")
@@ -208,6 +216,12 @@ def set_up(server, workdir: str, quads: int, seed: int, mix: dict, chips: int,
     say(f"boot {identity} compile cache {dev['compile_cache']}")
     if (identity["platform"] != "tpu" or identity["count"] < chips) and not rehearsal:
         say(f"no result: the cell needs {chips} TPU chip(s), the server has {identity}")
+        return None
+    # a program that lacks what the mix needs would answer it on a slower path,
+    # or spend every warm-up round compiling: it is asked before any load
+    why = harness.unmet_needs(server.addr, mix)
+    if why:
+        say(f"no result: {why}")
         return None
 
     t0 = time.monotonic()
@@ -245,10 +259,16 @@ def measure(server, control_dir: str, clock, ready, seconds, mix) -> dict:
     its own, so the result cache is as cold as before; after
     ``window_retries`` of those the run gives no result.  A traced run's
     window is profiled once and is not run again: ``compile_s_in_window`` is
-    one of its metrics.  ``clock`` is a traced run's ``TraceClock``, else None."""
+    one of its metrics.  The device guard is read at each window's open
+    (``_guard_at_open``) and judged by ``harness.unfit`` over the window
+    that counts; what it counted before is printed as set-up's.  ``clock``
+    is a traced run's ``TraceClock``, else None."""
     w = mix["warm"]
     traced = clock is not None
     attempts = 1 if traced else 1 + int(w["window_retries"])
+    guard_in_set_up = harness.guard(server.addr)["counts"]
+    if guard_in_set_up:
+        say(f"device guard in set-up, before any window: {guard_in_set_up}")
     if traced:
         open(os.path.join(control_dir, "trace.start"), "w").close()
         clock.wait("start", os.path.join(control_dir, "trace.started"))
@@ -262,6 +282,7 @@ def measure(server, control_dir: str, clock, ready, seconds, mix) -> dict:
                 t = memo[(cls, root)] = ready.classes[cls].text(root, tag)
             return t
 
+        at_open = _guard_at_open(server.addr)
         before = harness.counters(server.addr)
         setup_s = time.monotonic() - T_START
         win = ready.gen.drive(server.addr, "/query?ledger=true", ready.plan, texts, seconds, mix)
@@ -279,6 +300,7 @@ def measure(server, control_dir: str, clock, ready, seconds, mix) -> dict:
     return {"win": win, "before": before, "after": after, "setup_s": setup_s,
             "tag": tag, "windows": attempt + 1, "window_compile_s": compile_s,
             "trace_ack": trace_ack, "clock": clock,
+            "guard_at_open": at_open, "guard_in_set_up": guard_in_set_up,
             "dev": harness.http_json(server.addr, "/debug/device"),
             "planner": harness.http_json(server.addr, "/debug/planner").get("counts")}
 
@@ -302,7 +324,7 @@ def main(argv=None) -> int:
     quads = args.quads or int(config["scale"]["quads"])
     chips = int(cell["chips"])
     traced = bool(args.trace)
-    rule = trace_rule(chips)
+    rule = trace_rule(chips, mix)
     seconds = min(args.seconds, rule["window_s"]) if traced else args.seconds
     clock = TraceClock(rule["budget_s"]) if traced else None
 
@@ -325,7 +347,7 @@ def main(argv=None) -> int:
         why = harness.unfit(server.addr, chips)
         if rehearsal or not why:
             m = measure(server, control_dir, clock, ready, seconds, mix)
-            why = harness.unfit(server.addr, chips)   # a failover inside the window
+            why = harness.unfit(server.addr, chips, since=m["guard_at_open"])
         server.stop()               # the program's state is freed before the reference runs
         server = None
         if not why and not traced \
@@ -430,6 +452,7 @@ def report(args, bench, cell, quads, control_dir, ready, split, m) -> int:
         "compile_s_in_window": sum(
             harness.delta(before, after, "dgraph_xla_compile_seconds_sum").values()),
         "planner_counts": m["planner"],
+        "guard_in_set_up": m["guard_in_set_up"],
         "first_words": cmp_["first_words"],
     }
     result["compared"] = shown
@@ -460,6 +483,20 @@ def report(args, bench, cell, quads, control_dir, ready, split, m) -> int:
         return 3 if correct else 4
     print(json.dumps(result), flush=True)
     return 0
+
+
+def _guard_at_open(addr: str) -> dict:
+    """The device guard as a window opens.  A domain that a fault of the
+    set-up (or of a window run again) left sick is re-admitted by the
+    program's own probe, every 2 s by default: the open waits for that up
+    to ``READMIT_WAIT_S``, as set-up, so that the window's requests meet a
+    healthy domain; one still sick then is ``unfit``'s reason."""
+    t0 = time.monotonic()
+    g = harness.guard(addr)
+    while g["sick"] and time.monotonic() - t0 < READMIT_WAIT_S:
+        time.sleep(0.5)
+        g = harness.guard(addr)
+    return g
 
 
 def _wait_for(path: str, timeout_s: float) -> dict:

@@ -8,7 +8,8 @@ that start a whole server through ``run.py`` are marked ``slow``):
   the mean a chip, gaps and operations are a chip's;
 - ``traversal_roofline`` holds the bytes against all the traced chips'
   bandwidth: a quarter of the one-plane share for the same bytes and busy time;
-- the traced window's rule and the profiler's budget read the cell's chips;
+- the traced window's rule and the profiler's budget read the cell's chips
+  and the mix's own ``trace_s``;
   a start or stop that outlasts the budget ends the run with the reason, a
   long SET-UP does not; and the whole of it through ``run.py`` with the stop
   slowed underneath (``faults/slow_trace_stop.py``);
@@ -113,12 +114,34 @@ def test_traversal_roofline_takes_the_traces_devices():
 
 
 def test_the_traced_runs_rule_reads_the_cells_chips():
-    one, four = run.trace_rule(1), run.trace_rule(4)
+    one, four = run.trace_rule(1, {}), run.trace_rule(4, {})
     assert one["window_s"] == run.TRACE_WINDOW_S == 15.0       # a one-chip cell keeps its window
     assert four["window_s"] * 4 <= one["window_s"] + 1e-9     # no more chip-seconds of events than one chip's
     assert four["budget_s"] >= one["budget_s"] > 64           # the stops measured: 55-60 s (paths), 64 s (four chips)
     # set-up 160 s, window, the WHOLE budget spent, read, compare and exit: inside the driver's 360 s
     assert 160 + four["window_s"] + four["budget_s"] + 10 + 30 < 360
+
+
+@pytest.mark.parametrize("traffic, chips, window_s", [
+    ("paths", 1, 7.5), ("searchwrite", 1, 7.5),         # the mixes that keep the device busy
+    ("traverse", 1, 15.0), ("readwrite", 1, 15.0),      # no trace_s: 15 s / chips
+    ("traverse", 4, 3.75),
+])
+def test_the_traced_window_is_the_mixs(traffic, chips, window_s):
+    mix = trafficgen.load_json("traffic", traffic + ".json")
+    assert run.trace_rule(chips, mix) == {"window_s": window_s, "budget_s": 120.0}
+
+
+TRAFFIC = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "traffic")) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("traffic", TRAFFIC)
+def test_a_mixs_own_traced_window_is_shorter_than_the_default(traffic):
+    """A mix sets ``trace_s`` to keep the profiler's stop inside its budget:
+    a window longer than a one-chip cell's default would do the opposite."""
+    mix = trafficgen.load_json("traffic", traffic + ".json")
+    if "trace_s" in mix:
+        assert isinstance(mix["trace_s"], (int, float)) and 0 < mix["trace_s"] < run.TRACE_WINDOW_S
 
 
 def _ack_after(path, seconds):
@@ -165,7 +188,7 @@ def test_a_slow_profiler_stop_ends_the_run_by_itself(monkeypatch, capfd):
     phase and the set-up's split, returns 1 and prints no result — long
     before the stop would have returned."""
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setattr(run, "trace_rule", lambda chips: {"window_s": 2.0, "budget_s": 3.0})
+    monkeypatch.setattr(run, "trace_rule", lambda chips, mix: {"window_s": 2.0, "budget_s": 3.0})
     t0 = time.monotonic()
     rc = run.main(["--workload", "film-q4.traverse", "--seed", "6", "--seconds", "2",
                    "--trace", "1", "--quads", "60000", "--fault", "slow_trace_stop"])
