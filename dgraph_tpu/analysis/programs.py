@@ -957,6 +957,34 @@ def _b_path_search() -> List[ProgramInstance]:
     ]
 
 
+def _b_kpath_search() -> List[ProgramInstance]:
+    """The k-path search's four programs over the path search's 16-uid
+    layout's pairs (models/arena.py KPathTables), at k = 3."""
+    jnp, np = _jnp()
+    from dgraph_tpu.ops import kbfs, sets
+
+    h_src, h_offsets, h_dst, _, _ = _small_csr()
+    deg = np.diff(h_offsets)
+    kdeg = np.bincount(h_src, weights=deg, minlength=16).astype(np.int32)
+    kp = (jnp.asarray(sets.pad_to(h_dst, 32)), jnp.asarray(np.cumsum(kdeg) - kdeg),
+          jnp.asarray(kdeg), jnp.asarray(kdeg))
+    k = 3
+    cap, chunk = kbfs.capacities(len(np.unique(h_dst)), 32, int(deg.max()), k)
+    src, to = jnp.int32(0), jnp.int32(7)
+    st = kbfs.start(kp, src, cap, chunk, k)
+    state = tuple(range(4, 4 + len(st)))   # flat args of the donated state
+    return [
+        ProgramInstance("U16xC%dxK%dxk3" % (cap, chunk), kbfs.start, (kp, src),
+                        {"cap": cap, "chunk": chunk, "k": k}, scan_free=True),
+        ProgramInstance("U16xE32xk3_all", kbfs.run_levels,
+                        (kp, st, to, jnp.int32(1 << 30)), {"chunk": chunk, "k": k},
+                        donate=state),
+        ProgramInstance("U16xk3_walk", kbfs.walk_back, (st["apar"], to), {}),
+        ProgramInstance("U16xk3_finish", kbfs.finish, (kbfs.start(kp, src, cap, chunk, k), to),
+                        {"k": k}),
+    ]
+
+
 def _b_mesh_multi_hop() -> List[ProgramInstance]:
     jnp, np = _jnp()
     import jax
@@ -1400,6 +1428,29 @@ REGISTRY: Dict[str, ProgramContract] = {
                   "a swept one's scatter-min'ed; the path is walked back on "
                   "the device.  The state is donated from segment to "
                   "segment (run_levels' instances declare it).",
+        ),
+        ProgramContract(
+            name="kbfs.kpath_search",
+            covers=(
+                f"{_OPS}/kbfs.py::run_levels",
+                f"{_OPS}/kbfs.py::start",
+                f"{_OPS}/kbfs.py::walk_back",
+                f"{_OPS}/kbfs.py::finish",
+            ),
+            build=_b_kpath_search,
+            scan_free=False,   # the level loop IS the design: lax.while_loop
+            dtypes=_INT,
+            notes="shortest(from:, to:, numpaths: k) at unit cost: level-"
+                  "synchronous over arrivals (up to k a uid, each with its "
+                  "parent arrival) on the path layout's distinct pairs; a "
+                  "level's candidates are taken chunk by chunk in rule order "
+                  "(the path search's windows map the slots): full targets "
+                  "and the simple-path rule (a walk of the parent's prefix) "
+                  "drop theirs, a sort by (target, slot) ranks a target's "
+                  "candidates, the kept are written to the tables before the "
+                  "next chunk and a second sort appends them to the next "
+                  "list in slot order; the k paths are walked back on the "
+                  "device.  The state is donated from segment to segment.",
         ),
         ProgramContract(
             name="spgemm.mask_algebra",

@@ -1082,6 +1082,18 @@ class PathLayout:
         self.key = tuple((id(a), a.epoch) for a in arenas)
         _book_path_h2d((self.off, self.dst, self.esrc))
 
+    def kpath_tables(self) -> "KPathTables":
+        """This layout's pairs as a k-path search walks them
+        (``ops/kbfs.py``), built from it on first use and kept with it: a
+        layout is never changed in place (``take_delta`` makes a new one)."""
+        kt = self.__dict__.get("_kpath")
+        if kt is None:
+            with _BUILD_LOCK:
+                kt = self.__dict__.get("_kpath")
+                if kt is None:
+                    kt = self.__dict__["_kpath"] = KPathTables(self)
+        return kt
+
     def take_delta(self, arenas: List[CSRArena], took: list) -> Optional["PathLayout"]:
         """The layout after ``arenas`` (the ones it was built from, in the
         order listed) took ``took`` — an (adds, dels) pair of (src, dst)
@@ -1126,6 +1138,35 @@ class PathLayout:
         lay.ub, lay.max_degree = self.ub, self.max_degree
         lay.key = tuple((id(a), a.epoch) for a in arenas)
         return lay
+
+
+class KPathTables:
+    """A ``PathLayout``'s pairs for a k-path search (``ops/kbfs.py``): each
+    uid's DISTINCT targets, ascending (``dst``, a pair under two listed
+    predicates once), its first pair slot (``first``) and pair count
+    (``deg``), int32 over the uid space, and its out-degree under the listed
+    predicates (``odeg``: the work a search books, a pair under two
+    predicates twice).  ``targets`` uids are a pair's target — a search can
+    take no other — and the widest uid holds ``max_degree`` pairs.  Built on
+    the host from the layout's own tables."""
+
+    def __init__(self, lay: PathLayout):
+        n = lay.n_edges
+        src = np.asarray(lay.esrc[:n]).astype(np.int64)
+        pairs = np.unique((src << 32) | np.asarray(lay.dst[:n]).astype(np.int64))
+        deg = np.bincount(pairs >> 32, minlength=lay.ub).astype(np.int32)
+        off = np.asarray(lay.off)
+        self.targets = len(np.unique(pairs & 0xFFFFFFFF))
+        self.max_degree = int(deg.max()) if n else 0
+        self.dst = jnp.asarray(ops.pad_to((pairs & 0xFFFFFFFF).astype(np.int32),
+                                          ops.bucket(max(1, len(pairs)))))
+        self.first = jnp.asarray((np.cumsum(deg) - deg).astype(np.int32))
+        self.deg = jnp.asarray(deg)
+        self.odeg = jnp.asarray((off[:, 1] - off[:, 0]).astype(np.int32))
+        _book_h2d((self.dst, self.first, self.deg, self.odeg))
+
+    def arrays(self) -> tuple:
+        return self.dst, self.first, self.deg, self.odeg
 
 
 def _build_csr(rows_to_dsts: Dict[int, np.ndarray]) -> CSRArena:

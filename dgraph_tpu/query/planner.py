@@ -558,33 +558,40 @@ def path_route(
     k: int, decorated: bool, faceted: bool, unusable: bool,
     universe: int = 0, held: int = 0,
 ) -> Tuple[bool, dict]:
-    """A ``shortest`` block: the device's level-synchronous BFS
-    (ops/bfs.py) or the host's Dijkstra (query/shortest.py)?  Decided by
-    what the routes can DO, from what the block and the store show — no
-    rate, no knob: the BFS answers one path at unit cost over plain
-    predicates; k paths, a facet on a listed predicate (a weight, or one
-    the hop has to render) and a decorated child (filter, pagination,
-    order, count, ...) are the Dijkstra's.  So is a store whose uid space
-    is wider than its arenas: the BFS keeps a parent and a frontier mark for EVERY
-    uid up to the largest (``universe``), for each search in flight, and
-    where that outgrows the rows and edges the listed arenas hold
-    (``held``) — one uid near 2^30 in a store of a thousand edges — the
-    tables would cost what no arena does; the Dijkstra touches only what
-    it reaches.  How a level is done once on the device is chosen there,
-    per level (ops/bfs.py)."""
+    """A ``shortest`` block: the device's level-synchronous search or the
+    host's Dijkstra (query/shortest.py)?  Decided by what the routes can DO,
+    from what the block and the store show — no rate, no knob.  The device
+    searches at unit cost over plain predicates: one path (ops/bfs.py) or
+    k paths (ops/kbfs.py: up to k arrivals a uid, under the one rule the
+    Dijkstra also follows — query/shortest.py's docstring).  A facet on a
+    listed predicate (a weight, or one the hop has to render) and a
+    decorated child (filter, pagination, order, count, ...) are the
+    Dijkstra's.  So is a store whose uid space is wider than its arenas:
+    the device keeps a parent and a frontier mark (k parents and a count,
+    for k paths) for EVERY uid up to the largest (``universe``), for each
+    search in flight, and where that outgrows the rows and edges the listed
+    arenas hold (``held``) — one uid near 2^30 in a store of a thousand
+    edges — the tables would cost what no arena does; the Dijkstra touches
+    only what it reaches.  So, last, is a k whose arrivals the device
+    cannot address (``kbfs.fits``).  How a level is done once on the
+    device is chosen there, per level."""
+    from dgraph_tpu.ops import kbfs
+
     why = (
-        "numpaths > 1: k paths are the Dijkstra's" if k > 1
-        else "a listed predicate carries facets (weights, rendered facets)" if faceted
+        "a listed predicate carries facets (weights, rendered facets)" if faceted
         else "a child is decorated (filter, pagination, ...)" if decorated
         else "device unavailable (sick, or an arena is sharded over the mesh)" if unusable
         else "the uid space is wider than the listed arenas hold" if universe > held
+        else "k arrivals a uid outgrow the device's arrival ids"
+        if k > 1 and not kbfs.fits(k, universe)
         else ""
     )
     dec = {
         "kind": "path",
         "route": "host" if why else "device",
         "units": int(k),
-        "reason": why or "one path at unit cost over plain predicates",
+        "reason": why or ("one path at unit cost over plain predicates" if k == 1
+                          else "k paths at unit cost over plain predicates"),
     }
     return not why, dec
 

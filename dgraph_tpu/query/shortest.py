@@ -3,30 +3,47 @@
 Two routes, chosen by ``planner.path_route`` from what the block and the
 store show (no knob):
 
-- **device** (``_device_search``, ops/bfs.py): ``numpaths`` absent or 1,
-  no facet on any listed predicate, children that are plain predicates or
-  ``~predicate``.  A level-synchronous BFS over the listed predicates'
-  merged layout; levels, visited set and parents stay on the device, ONE
-  fetch brings back the distance and the path.
+- **device**: no facet on any listed predicate, children that are plain
+  predicates or ``~predicate``.  ``numpaths`` absent or 1
+  (``_device_search``, ops/bfs.py): a level-synchronous BFS over the listed
+  predicates' merged layout; levels, visited set and parents stay on the
+  device, ONE fetch brings back the distance and the path.  ``numpaths``
+  k > 1 (``_device_kpaths``, ops/kbfs.py): the same layout searched level
+  by level over ARRIVALS — up to k a uid, each with its parent arrival —
+  and ONE fetch brings back the k paths, walked back on the device.  Its
+  lists hold k arrivals of every uid a search can take, so no level
+  outgrows them.  A device fault hands the block to the Dijkstra
+  (``dgraph_kpath_searches_total{route="fallback"}``).
 - **host** (``_dijkstra``): everything else.  Equivalent of
   query/shortest.go: Dijkstra over an adjacency cache built by lazy
   frontier expansion (expandOut:134) — each expansion hop is one batched
   gather per predicate; edge costs come from a "weight" facet when present
   else 1 (getCost:102); k-shortest keeps per-path copies
-  (KShortestPath:274).  Caps mirror shortest.go:214 (10M edges).  It is
-  also the device route's reference in the tests.
+  (KShortestPath:274).  Two listed predicates that join the same pair are
+  ONE hop (the cheaper, else the first listed).  Caps mirror
+  shortest.go:214 (10M edges).  It is also the device routes' reference in
+  the tests.
 
-**Which of several equal paths** (one rule for both routes, ``numpaths``
-1): walking back from ``to``, each uid's predecessor is the LEAST uid among
+**Which of several equal paths** (one rule for both routes).  ``numpaths``
+1: walking back from ``to``, each uid's predecessor is the LEAST uid among
 those one step nearer ``from`` that hold an edge to it under a listed
-predicate; a hop is rendered under the first listed predicate that holds
-it.  ``numpaths`` > 1 keeps the order it always had: cost, then the uid
-sequence from ``from``.
+predicate.  ``numpaths`` k > 1, the rule the Dijkstra's heap key (cost,
+uid, path) has always given: a path never visits a uid twice; a uid is
+taken at most k times, in order of cost and, between equal costs, least
+uid sequence from ``from`` first; the first k arrivals at ``to`` are the
+answer, and an arrival at ``to`` is not expanded.  Where fewer than k
+shortest paths exist this is not Yen's k shortest simple paths: a uid's k
+taken prefixes may all lead into a dead end that another, longer prefix of
+it would have passed.  Either way a hop is rendered under the first listed
+predicate that holds it.
 
-The ledger's ``edges`` of a device search is the sum, over the levels
-expanded and the listed predicates, of the out-degree of every uid of the
-level — the work asked for, whatever did a level; ``rows`` the sum of the
-level sizes (``dgraph_path_frontier_rows_total``).
+The ledger's ``edges`` of a one-path device search is the sum, over the
+levels expanded and the listed predicates, of the out-degree of every uid
+of the level — the work asked for, whatever did a level; ``rows`` the sum
+of the level sizes (``dgraph_path_frontier_rows_total``).  Of a k-path
+search: the out-degree of every expanded ARRIVAL (levels 0..L-1, those at
+``to`` not expanded), and ``rows`` the number of those arrivals.  Both
+book under the ``path`` route.
 """
 
 from __future__ import annotations
@@ -39,10 +56,12 @@ import numpy as np
 from dgraph_tpu import obs
 from dgraph_tpu.models.types import TypedValue, numeric
 from dgraph_tpu.obs import ledger as _ledger
+from dgraph_tpu.ops.sets import SENT
 from dgraph_tpu.query.functions import QueryError
 from dgraph_tpu.query.subgraph import SubGraph
 from dgraph_tpu.utils.metrics import (
-    PATH_CHUNKS, PATH_FRONTIER_ROWS, PATH_LEVEL_WAYS, PATH_LEVELS, PATH_SEARCHES)
+    KPATH_ARRIVALS, KPATH_CANDIDATES, KPATH_LEVELS, KPATH_PATHS, KPATH_SEARCHES, PATH_CHUNKS,
+    PATH_FRONTIER_ROWS, PATH_LEVEL_WAYS, PATH_LEVELS, PATH_SEARCHES)
 
 MAX_EDGES = 10_000_000
 
@@ -62,9 +81,18 @@ def shortest_path(engine, sg: SubGraph, resolver):
         on_device, dec = planner.path_route(
             k, *_what_the_block_shows(engine, preds))
         planner.record(engine.stats, dec)
-    if on_device and _device_search(engine, sg, preds, src, dst):
-        PATH_SEARCHES.add("device")
-        return
+    if k == 1:
+        if on_device and _device_search(engine, sg, preds, src, dst):
+            PATH_SEARCHES.add("device")
+            return
+    elif on_device:
+        if _device_kpaths(engine, sg, preds, src, dst, k):
+            PATH_SEARCHES.add("device")
+            KPATH_SEARCHES.add("device")
+            return
+        KPATH_SEARCHES.add("fallback")
+    else:
+        KPATH_SEARCHES.add("host")
     PATH_SEARCHES.add("host")
     _dijkstra(engine, sg, resolver, preds, src, dst, k)
 
@@ -123,7 +151,7 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
 
     st = engine.stats
     if src == dst:
-        _set_paths(engine, sg, preds, [src])
+        _set_paths(engine, sg, preds, [[src]])
         return True
     # the listed predicates' merged layout: built and put on the device on
     # first use, under its own h2d bracket (models/arena.py)
@@ -132,7 +160,7 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
     if max(src, dst) >= lay.ub:
         # an endpoint no edge knows of: level 0 alone
         _book(engine, 1, 1, 0, 0)
-        _set_paths(engine, sg, preds, None)
+        _set_paths(engine, sg, preds, [])
         return True
     with obs.stage(st, "plan_ms"):
         cap, chunk = bfs.capacities(int(lay.dst.shape[0]), lay.max_degree)
@@ -168,16 +196,7 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
 
     guard = devguard.get()
     try:
-        state = guard.run("device.path", _start)
-        while True:
-            # a level boundary: the request's cancellation is seen here, and
-            # a queued cohort of higher priority may take the seam
-            engine.checkpoint()
-            state, more = guard.run("device.path", lambda s=state: _levels(s))
-            if not more:
-                break
-            segments.seam("path")
-        got = guard.run("device.path", lambda: _fetch(state))
+        state, got = _dispatch(engine, guard, _start, _levels, _fetch)
         d2h = int(got.nbytes)
         found, levels, rows, edges, sweeps, chunks, scatters = (
             int(x) for x in got[:bfs.HEAD])
@@ -185,15 +204,8 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
         if found:
             with obs.stage(st, "convert_ms"):
                 path = [int(u) for u in got[bfs.HEAD:] if u != bfs.SENT]
-            while len(path) <= levels:
-                # a path longer than one walk brings back: go on from its end
-                more = guard.run(
-                    "device.path",
-                    lambda: np.asarray(bfs.walk_back(state["par"], jnp.int32(path[-1]))))
-                d2h += int(more.nbytes)
-                path += [int(u) for u in more[1:] if u != bfs.SENT]
-                if more[1] == bfs.SENT:
-                    break               # the source has no parent: the walk is over
+            d2h += _walk_on(guard, lambda u: bfs.walk_back(state["par"], jnp.int32(u)),
+                            path, levels)
             path.reverse()
     except devguard.DeviceFaultError:
         return False
@@ -203,8 +215,141 @@ def _device_search(engine, sg: SubGraph, preds, src: int, dst: int) -> bool:
         led.bytes_d2h += d2h
     _book(engine, levels, rows, edges, sweeps, chunks, scatters)
     with obs.stage(st, "convert_ms"):
-        _set_paths(engine, sg, preds, path)
+        _set_paths(engine, sg, preds, [path] if path is not None else [])
     return True
+
+
+def _device_kpaths(engine, sg: SubGraph, preds, src: int, dst: int, k: int) -> bool:
+    """The k-path search as ops/kbfs.py runs it; False where the device gave
+    up (a device fault: the Dijkstra answers instead, and the caller counts
+    the fallback)."""
+    import jax.numpy as jnp
+
+    from dgraph_tpu.ops import kbfs
+    from dgraph_tpu.sched import segments
+    from dgraph_tpu.utils import devguard
+    from dgraph_tpu.utils.failpoints import fail
+
+    st = engine.stats
+    if src == dst:
+        _kbook(engine, [1] + [0] * (kbfs.KHEAD - 1))
+        _set_paths(engine, sg, preds, [[src]])
+        return True
+    lay = engine.arenas.path_layout(
+        tuple((c.attr, bool(c.reverse)) for c in preds))
+    if max(src, dst) > lay.universe:
+        # an endpoint no edge knows of: nothing is expanded
+        _kbook(engine, [0] * kbfs.KHEAD)
+        _set_paths(engine, sg, preds, [])
+        return True
+    with obs.stage(st, "h2d_ms"):
+        kt = lay.kpath_tables()         # built on the layout's first k-path search
+    kp = kt.arrays()
+    with obs.stage(st, "plan_ms"):
+        cap, chunk = kbfs.capacities(kt.targets, int(kt.dst.shape[0]), kt.max_degree, k)
+        n_est = max(2, int(lay.ub).bit_length())
+        seg = segments.plan(n_est, max(1, k * lay.n_edges // n_est), "path")
+        steps = seg if seg > 0 else 1 << 30
+    sh = kbfs.shift(k)
+
+    def _start():
+        fail.point("device.path")
+        with obs.stage(st, "h2d_ms"):
+            return kbfs.start(kp, jnp.int32(src), cap, chunk, k)
+
+    def _levels(state):
+        fail.point("device.path")
+        with obs.stage(st, "dispatch_ms"):
+            state = kbfs.run_levels(kp, state, jnp.int32(dst), jnp.int32(steps), chunk, k)
+        if seg <= 0:
+            return state, False
+        with obs.stage(st, "fetch_ms"):
+            return state, bool((state["found"] < k) & (state["m"] > 0))
+
+    def _fetch(state):
+        with obs.stage(st, "dispatch_ms"):
+            dev = kbfs.finish(state, jnp.int32(dst), k)
+        with obs.stage(st, "fetch_ms"):
+            return np.asarray(dev)
+
+    guard = devguard.get()
+    try:
+        state, got = _dispatch(engine, guard, _start, _levels, _fetch)
+        d2h = int(got.nbytes)
+        head = [int(x) for x in got[:kbfs.KHEAD]]
+        paths: List[List[int]] = []
+        with obs.stage(st, "convert_ms"):
+            walks = got[kbfs.KHEAD:].reshape(k, kbfs.PATH_CAP)
+            for s in range(head[0]):
+                ids = [int(a) for a in walks[s] if a != kbfs.SENT]
+                if len(ids) == kbfs.PATH_CAP:
+                    # a walk that came back full: the path goes on past it (one
+                    # that reached the source is whole, however many levels ran)
+                    d2h += _walk_on(
+                        guard, lambda a: kbfs.walk_back(state["apar"], jnp.int32(a)),
+                        ids, head[1])
+                paths.append([a >> sh for a in reversed(ids)])
+    except devguard.DeviceFaultError:
+        return False
+    led = _ledger.current()
+    if led is not None:
+        led.bytes_h2d += 8              # the two endpoints
+        led.bytes_d2h += d2h
+    _kbook(engine, head)
+    with obs.stage(st, "convert_ms"):
+        _set_paths(engine, sg, preds, paths)
+    return True
+
+
+def _dispatch(engine, guard, start, levels, fetch):
+    """A device search's dispatches, each inside the guard's watchdog
+    bracket: ``start``, then ``levels`` — (the state after a segment of
+    levels, whether levels are left) — with a level boundary before each,
+    where the request's cancellation is seen and a queued cohort of higher
+    priority may take the seam, then ``fetch``.  Returns (state, fetched)."""
+    from dgraph_tpu.sched import segments
+
+    state = guard.run("device.path", start)
+    while True:
+        engine.checkpoint()
+        state, more = guard.run("device.path", lambda s=state: levels(s))
+        if not more:
+            break
+        segments.seam("path")
+    return state, guard.run("device.path", lambda: fetch(state))
+
+
+def _walk_on(guard, walk, path: List[int], levels: int) -> int:
+    """Extends ``path`` (walked back from its target, PATH_CAP a walk) by
+    further walks from its last entry while it is shorter than ``levels``
+    hops allow; returns the bytes fetched."""
+    d2h = 0
+    while len(path) <= levels:
+        more = guard.run("device.path", lambda a=path[-1]: np.asarray(walk(a)))
+        d2h += int(more.nbytes)
+        path += [int(u) for u in more[1:] if u != SENT]
+        if more[1] == SENT:
+            break                       # the source has no parent: the walk is over
+    return d2h
+
+
+def _kbook(engine, head) -> None:
+    """A device k-path search's header (ops/kbfs.py ``finish``) to the
+    request's stats (hence the ledger's ``edges``), the ``path`` route and
+    the k-path counters — the one place they are booked."""
+    paths, levels, rows, edges, first, again, kept, full, loop = head[:9]
+    st = engine.stats
+    st["edges"] = st.get("edges", 0) + edges
+    KPATH_LEVELS.add(levels)
+    KPATH_ARRIVALS.add("first", first)
+    KPATH_ARRIVALS.add("again", again)
+    KPATH_CANDIDATES.add("kept", kept)
+    KPATH_CANDIDATES.add("full", full)
+    KPATH_CANDIDATES.add("loop", loop)
+    KPATH_PATHS.add(paths)
+    led = _ledger.current()
+    if led is not None:
+        led.note_hop("path", edges)
 
 
 def _book(engine, levels: int, rows: int, edges: int, sweeps: int,
@@ -228,11 +373,12 @@ def _book(engine, levels: int, rows: int, edges: int, sweeps: int,
         led.note_hop("path", edges)
 
 
-def _set_paths(engine, sg: SubGraph, preds, path: Optional[List[int]]) -> None:
-    """``sg.paths`` / ``sg.dest_uids`` as the Dijkstra leaves them; each hop
-    under the first listed predicate that holds it."""
+def _set_paths(engine, sg: SubGraph, preds, paths: List[List[int]]) -> None:
+    """``sg.paths`` / ``sg.dest_uids`` as the Dijkstra leaves them: the
+    paths in the order given, each hop under the first listed predicate
+    that holds it; the destination uids the union of the paths'."""
     sg.paths = []
-    if path is not None:
+    for path in paths:
         elems = []
         for i, u in enumerate(path):
             attr_out = ""
@@ -246,12 +392,13 @@ def _set_paths(engine, sg: SubGraph, preds, path: Optional[List[int]]) -> None:
                         break
             elems.append({"uid": u, "facets": {}, "attr_out": attr_out or "path"})
         sg.paths.append(elems)
-    sg.dest_uids = np.array(sorted(path or ()), dtype=np.int64)
+    sg.dest_uids = np.array(sorted({u for p in paths for u in p}), dtype=np.int64)
 
 
 def _dijkstra(engine, sg: SubGraph, resolver, preds, src: int, dst: int, k: int):
     # adjacency cache: uid -> list of (neighbor, cost, facets, attr)
     adj: Dict[int, List[Tuple[int, float, dict, str]]] = {}
+    hop: Dict[Tuple[int, int], int] = {}    # (u, v) -> its entry in adj[u]
     expanded: set = set()
     edges = 0
 
@@ -285,8 +432,16 @@ def _dijkstra(engine, sg: SubGraph, resolver, preds, src: int, dst: int, k: int)
                     x = numeric(w)
                     if x is not None:
                         cost = x
-                adj[s].append((int(d), cost, facets, tmpl.attr))
                 edges += 1
+                # two listed predicates joining one pair are ONE hop: the
+                # cheaper, else the first listed (each pushed, a path
+                # came back once a predicate)
+                at = hop.get((s, int(d)))
+                if at is None:
+                    hop[(s, int(d))] = len(adj[s])
+                    adj[s].append((int(d), cost, facets, tmpl.attr))
+                elif cost < adj[s][at][1]:
+                    adj[s][at] = (int(d), cost, facets, tmpl.attr)
 
     # uniform-cost search, expanding lazily per frontier ring.  The heap
     # orders equal costs by (uid, path): with numpaths 1 a path is kept

@@ -821,9 +821,9 @@ LEDGER_BYTES = metrics.labeled("dgraph_ledger_bytes_total", label="dir")
 LEDGERS_CREATED = metrics.counter("dgraph_ledger_structs_total")
 
 # path search (query/shortest.py): PATH_SEARCHES{route} counts shortest
-# blocks by the route that answered them — "device" (ops/bfs.py: unit
-# cost, one path) or "host" (the Dijkstra: numpaths > 1, a weight facet,
-# a decorated child); PATH_LEVELS the levels the device route expanded
+# blocks by the route that answered them — "device" (ops/bfs.py at unit
+# cost, one path; ops/kbfs.py, k paths) or "host" (the Dijkstra: a weight
+# facet, a decorated child, a device fault); PATH_LEVELS the levels the device route expanded
 # and PATH_FRONTIER_ROWS the uids in them, so rows / levels is a level's
 # mean width; PATH_LEVEL_WAYS{way} the same levels by what did them —
 # "gather" (from the frontier list) or "sweep" (every edge of the layout;
@@ -844,6 +844,32 @@ for _w in ("gather", "sweep"):
 for _w in ("window", "scatter"):
     PATH_CHUNKS.add(_w, 0)
 LEDGER_HOP_EDGES.add("path", 0)
+
+# k-path search (query/shortest.py, ``numpaths`` > 1; ops/kbfs.py):
+# KPATH_SEARCHES{route} the k-path blocks by the route that answered —
+# "device" (ops/kbfs.py), "host" (the planner chose the Dijkstra: a facet, a
+# decorated child, ...) or "fallback" (the device was chosen and gave up: a
+# device fault; the Dijkstra answered).
+# The rest is booked from a device search's result header, in one place
+# (``_kbook``): KPATH_LEVELS the levels expanded; KPATH_ARRIVALS{kind} the
+# arrivals taken — "first" at a uid's first level, "again" later (a
+# re-arrival); KPATH_CANDIDATES{fate} the distinct (arrival, target)
+# candidates by fate — "kept", "full" (the target had, or this level gave
+# it, k arrivals) or "loop" (the target is on the candidate's prefix: the
+# simple-path rule); first + again = kept.  KPATH_PATHS the paths returned.
+# A device search's edges also ride PATH_SEARCHES{device} and
+# LEDGER_HOP_EDGES{route="path"}.  Every label is there at zero from boot.
+KPATH_SEARCHES = metrics.labeled("dgraph_kpath_searches_total", label="route")
+KPATH_LEVELS = metrics.counter("dgraph_kpath_levels_total")
+KPATH_ARRIVALS = metrics.labeled("dgraph_kpath_arrivals_total", label="kind")
+KPATH_CANDIDATES = metrics.labeled("dgraph_kpath_candidates_total", label="fate")
+KPATH_PATHS = metrics.counter("dgraph_kpath_paths_total")
+for _r in ("device", "host", "fallback"):
+    KPATH_SEARCHES.add(_r, 0)
+for _w in ("first", "again"):
+    KPATH_ARRIVALS.add(_w, 0)
+for _w in ("kept", "full", "loop"):
+    KPATH_CANDIDATES.add(_w, 0)
 
 # result encoder (query/outputnode.py): result objects emitted, by the
 # path that built them — "level" (a level at a time, the general

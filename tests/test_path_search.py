@@ -624,8 +624,10 @@ def test_levels_swept_between_gathered_ones_keep_distance_parent_and_list(
 
 ROUTES = [
     pytest.param("{ shortest(from: 0x1, to: 0x9) { a ~a b } }", "device", id="plain"),
-    pytest.param("{ shortest(from: 0x1, to: 0x9, numpaths: 2) { a ~a b } }", "host",
+    pytest.param("{ shortest(from: 0x1, to: 0x9, numpaths: 2) { a ~a b } }", "device",
                  id="numpaths-2"),
+    pytest.param("{ shortest(from: 0x1, to: 0x9, numpaths: 2) { w b } }", "host",
+                 id="numpaths-2-weight-facet"),
     pytest.param("{ shortest(from: 0x1, to: 0x9) { a @filter(uid(0x2, 0x3, 0x9)) b } }",
                  "host", id="filtered-child"),
     pytest.param("{ shortest(from: 0x1, to: 0x9) { a (first: 2) b } }", "host",
