@@ -39,13 +39,18 @@ in the order of the paths they extend), and a chunk:
    time;
 4. sorts its candidates by (target, slot): a target's candidates in rule
    order, and one that comes when the target already holds k (counting the
-   ones kept before it) is full, a loop is dropped, any other kept;
-5. writes the kept arrivals' counts into ``info``, a piece at a time — the
-   next chunk sees them — their parents to the level's own list (``apar``
-   takes them once the level is done: a prefix walk reads only earlier
-   levels, and a table that a chunk both read and wrote would be copied
-   whole for it), and appends those that have an edge and are not ``to``,
-   in slot order, to the next list.
+   ones kept before it) is full, a loop is dropped, any other kept.  The
+   sort carries each candidate's parent arrival and its target's ``info``
+   word, and its loop bit below the slot in the second key;
+5. sorts the kept back to slot order, carrying each one's arrival id,
+   parent, ``info`` word (its count with it, the level of its uid's first)
+   and its uid's pair count; writes the words into ``info``, a piece at a
+   time — the next chunk sees them — the parents to the level's own list
+   (``apar`` takes them once the level is done: a prefix walk reads only
+   earlier levels, and a table that a chunk both read and wrote would be
+   copied whole for it), and appends those that have an edge and are not
+   ``to``, in slot order, to the next list.  So a chunk gathers back
+   nothing it already holds.
 
 So the candidates are taken one after the other in rule order, and each
 target's are the first (k - taken) that pass.  The search ends when ``to``
@@ -105,7 +110,6 @@ def _klevel(kp, to, k, chunk, st):
     out-degree) of the layout's pairs (``PathLayout.kpath_tables``)."""
     kdst, kfirst, kdeg, odeg = kp
     sh = shift(k)
-    lanes = (1 << sh) - 1
     C, rows = chunk, chunk // 2
     P = max(8, C // 8)          # the walks and the writes go a piece of this many at a time
     fl, fo, cd, f, lev = st["fl"], st["fo"], st["cd"], st["f"], st["cur"]
@@ -173,13 +177,17 @@ def _klevel(kp, to, k, chunk, st):
             ncond, nbody, (jnp.int32(0), jnp.zeros((C + P,), jnp.int32)))
         loop = need & (_take(hits[:C], jnp.clip(jnp.cumsum(need) - 1, 0, C - 1)) > 0)
 
-        # a target's candidates in rule order: the first that find room are kept
-        tv, ti = jax.lax.sort((jnp.where(live & ~full0, v, SENT), i), num_keys=2,
-                              is_stable=False)
+        # a target's candidates in rule order: the first that find room are
+        # kept.  A slot's parent arrival and ``info`` word ride the sort, its
+        # loop bit below the slot in the second key (a slot is under C <=
+        # 2**17 and unique: the order is the slots')
+        tv, tl, par, ivs = jax.lax.sort(
+            (jnp.where(live & ~full0, v, SENT), (i << 1) | loop.astype(jnp.int32), at, iv),
+            num_keys=2, is_stable=False)
         ok = tv != SENT
-        ivs = _take(iv, ti)
+        ti = tl >> 1
         ts, lows = ivs & 0xFF, ivs >> 8
-        loops = ok & (_take(loop.astype(jnp.int32), ti) > 0)
+        loops = ok & ((tl & 1) > 0)
         passes = (ok & ~loops).astype(jnp.int32)
         before = jnp.cumsum(passes) - passes
         head = ok & jnp.concatenate([jnp.ones((1,), bool), tv[1:] != tv[:-1]])
@@ -188,39 +196,41 @@ def _klevel(kp, to, k, chunk, st):
         keep = ok & ~full & ~loops
         aid = (tv << sh) + ts + before
         arrived = keep & (tv != to)
-        listed = arrived & (_take(kdeg, jnp.where(ok, tv, 0)) > 0)
+        kd = _take(kdeg, jnp.where(ok, tv, 0))
+        listed = arrived & (kd > 0)
+        # what a kept arrival leaves in ``info``: its uid's arrivals with it,
+        # beside the level of the uid's first
+        word = (jnp.where(ts == 0, lev + 1, lows) << 8) | (ts + before + 1)
 
         # the kept, in slot order: first those the next list takes, then the
-        # others (``to``'s, and arrivals without an edge)
-        key, got = jax.lax.sort(
-            (jnp.where(listed, ti, jnp.where(keep, C + ti, SENT)), aid), num_keys=1,
-            is_stable=False)
+        # others (``to``'s, and arrivals without an edge); each with its
+        # parent, its ``info`` word and its uid's pair count
+        _, got, gpar, gword, gdeg = jax.lax.sort(
+            (jnp.where(listed, ti, jnp.where(keep, C + ti, SENT)), aid, par, word, kd),
+            num_keys=1, is_stable=False)
         n_list, n_keep = n32(listed), n32(keep)
-        kept_slot = jnp.concatenate([jnp.where(key < C, key, key - C), pad])
         kept_aid = jnp.concatenate([got, pad])
+        kept_word = jnp.concatenate([gword, pad])
 
-        # their counts into ``info``, a piece at a time: the next chunk sees
+        # their words into ``info``, a piece at a time: the next chunk sees
         # them; their parents go to the level's own list, for ``apar`` after it
         def pcond(q):
             return q[0] < n_keep
 
         def pbody(q):
             b, info = q
-            at_slot = jnp.clip(jax.lax.dynamic_slice(kept_slot, (b,), (P,)), 0, C - 1)
             g = jax.lax.dynamic_slice(kept_aid, (b,), (P,))
-            ivp = _take(iv, at_slot)
-            word = (jnp.where((ivp & 0xFF) == 0, lev + 1, ivp >> 8) << 8) | ((g & lanes) + 1)
             live_p = b + jnp.arange(P, dtype=jnp.int32) < n_keep
-            return b + P, info.at[jnp.where(live_p, g >> sh, ub)].max(word, mode="drop")
+            return b + P, info.at[jnp.where(live_p, g >> sh, ub)].max(
+                jax.lax.dynamic_slice(kept_word, (b,), (P,)), mode="drop")
 
         _, info = jax.lax.while_loop(pcond, pbody, (jnp.int32(0), s["info"]))
         kw = s["k2"]
         na = jax.lax.dynamic_update_slice(s["na"], got, (kw,))
-        npar = jax.lax.dynamic_update_slice(
-            s["np"], _take(at, jnp.clip(kept_slot[:C], 0, C - 1)), (kw,))
+        npar = jax.lax.dynamic_update_slice(s["np"], gpar, (kw,))
         here = i < n_list
         u = jnp.where(here, got >> sh, 0)
-        dd = jnp.where(here, _take(kdeg, u), 0)
+        dd = jnp.where(here, gdeg, 0)
         w = s["f2"]
         first = keep & ((ts == 0) | (lows == lev + 1))
         return a + nb, dict(

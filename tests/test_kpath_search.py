@@ -8,9 +8,11 @@ one-path search's programs, which this route shares a layout and helpers
 with, lowered to the text they had before it.
 """
 
+import collections
 import hashlib
 import json
 import os
+import re
 import sys
 
 import jax
@@ -246,6 +248,71 @@ def test_levels_whose_candidates_outgrow_the_chunk():
     assert list(head[4:9]) == [want[x] for x in ("first", "again", "kept", "full", "loop")]
 
 
+def _device_answer(kp, st, to, chunk, k):
+    """(the nine-word header, the paths as uid lists) of the search ``st``
+    starts, through ``kbfs`` alone, all its levels in one call."""
+    st = kbfs.run_levels(kp, st, jnp.int32(to), jnp.int32(1 << 30), chunk, k)
+    got = np.asarray(kbfs.finish(st, jnp.int32(to), k))
+    walks = got[kbfs.KHEAD:].reshape(k, bfs.PATH_CAP)
+    paths = [[int(a) >> kbfs.shift(k) for a in reversed(w) if a != bfs.SENT]
+             for w in walks[:got[0]]]
+    return [int(x) for x in got[:kbfs.KHEAD]], paths
+
+
+def _header_of(want) -> list:
+    return [len(want["paths"])] + [want[x] for x in (
+        "levels", "rows", "edges", "first", "again", "kept", "full", "loop")]
+
+
+@pytest.mark.parametrize("k", [3, 4])
+def test_one_chunk_splits_a_targets_candidates_every_way(k):
+    """Level 1 is one chunk in which each target has more than k candidates:
+    six back to the source (loops), six to a uid with edges (k listed, the
+    rest full), six to a sink (k kept but not listed, the rest full) and two
+    to ``to``; level 2 re-arrives at a uid of level 1, a loop through it and
+    kept through the others, and fills ``to``.  The answers and all nine
+    words of the header against the reference."""
+    g = {p: set() for p in PREDS}
+    mids = range(10, 16)
+    g["a"] |= {(1, m) for m in mids} | {(m, t) for m in mids for t in (1, 50, 60)}
+    g["a"] |= {(10, 99), (11, 99), (50, 10), (50, 99)}
+    g["a"] |= {(200, 300 + j) for j in range(40)}   # a wide uid: a chunk of 64
+    e = load(g)
+    kp, chunk, st = _kstate(e, "a", 1, k)
+    assert chunk >= 20                              # level 1 fits one chunk
+    want = ref_of(g, k, "a").search(1, 99, with_paths=True)
+    assert want["paths"][:3] == [[1, 10, 99], [1, 11, 99], [1, 10, 50, 99]]
+    assert want["full"] > 0 and want["loop"] > 6 and want["again"] > 0
+    head, paths = _device_answer(kp, st, 99, chunk, k)
+    assert paths == want["paths"]
+    assert head == _header_of(want)
+    assert paths_of(e.run(text(1, 99, k, "a")))[0] == want["paths"]
+
+
+def test_a_chunk_of_131072_slots_whose_last_slot_loops():
+    """The widest slot the loop bit is packed beside: one level-1 chunk of
+    C = 131,072 slots, each uid's pairs (``to``, the source), so that every
+    second slot loops back to the source, the last (slot C - 1) too, and
+    ``to`` takes k of 65,536 candidates."""
+    M, C, k = 65536, 131072, 3
+    T, S = M, M + 1
+    ub = -(-(M + 2) // 128) * 128
+    kdst = np.concatenate([np.tile([T, S], M), np.arange(M)]).astype(np.int32)
+    deg = np.zeros(ub, np.int32)
+    deg[:M], deg[S] = 2, M
+    first = np.full(ub, 3 * M, np.int32)
+    first[:M], first[S] = 2 * np.arange(M), 2 * M
+    kp = tuple(jnp.asarray(x) for x in (kdst, first, deg, deg))
+    cap, _ = kbfs.capacities(M + 2, 3 * M, M, k)
+    head, paths = _device_answer(kp, kbfs.start(kp, jnp.int32(S), cap, C, k), T, C, k)
+    g = {"a": {(m, t) for m in range(M) for t in (T, S)} | {(S, m) for m in range(M)}}
+    want = ref_of(g, k, "a").search(S, T, with_paths=True)
+    assert want["paths"] == [[S, 0, T], [S, 1, T], [S, 2, T]]
+    assert want["loop"] == M and want["full"] == M - k
+    assert paths == want["paths"]
+    assert head == _header_of(want)
+
+
 @pytest.mark.parametrize("route", ["device", "host"])
 def test_a_level_of_uids_without_edges(monkeypatch, route):
     """A level that takes far more uids than hold an edge (299 sinks beside
@@ -402,3 +469,32 @@ def test_the_one_path_programs_lower_to_the_text_they_had():
     got = {name: hashlib.sha256(t.encode()).hexdigest()
            for name, t in _lowered_one_path_programs().items()}
     assert got == saved
+
+
+# -- the k-path chunk, at the k-path cell's shapes -----------------------------------------
+
+# film21m-q4-kpaths' pairs: the paths cell's layout above, whose 2,234,858 uids are
+# a pair's target
+KTARGETS = 2_234_858
+
+
+def test_a_k_path_chunk_gathers_back_nothing_its_sorts_carry():
+    """``run_levels`` at the cell's shapes: a chunk of 131,072 pair slots
+    reads seven tables a slot by ``_take``'s row gather — the pairs' targets,
+    ``info``, the frontier list, the walk's hits, and the pair count, first
+    pair slot and out-degree of the target — and nothing its two sorts
+    carry (a slot's parent, its ``info`` word, its loop bit, its kept
+    arrival's word and pair count).  The 8,192-slot chunk of a tiny level
+    the same; the prefix walk's pieces of a chunk's eighth read four."""
+    k = 3
+    cap, chunk = kbfs.capacities(KTARGETS, SLOTS, WIDEST, k)
+    assert chunk == 131_072
+    i32 = jnp.int32
+    one = jax.ShapeDtypeStruct((), i32)
+    kp = tuple(jax.ShapeDtypeStruct((n,), i32) for n in (SLOTS, UB, UB, UB))
+    st = jax.eval_shape(lambda kp, s: kbfs.start(kp, s, cap, chunk, k), kp, one)
+    t = kbfs.run_levels.lower(kp, st, one, one, chunk, k).as_text()
+    rows = collections.Counter(re.findall(r"stablehlo\.gather.*-> tensor<(\d+)x128xi32>", t))
+    small = bfs.small_chunk(chunk)
+    assert {n: rows[str(n)] for n in (chunk, small, chunk // 8, small // 8)} == {
+        chunk: 7, small: 7, chunk // 8: 4, small // 8: 4}
