@@ -1361,6 +1361,9 @@ class ValueArena:
     langless: bool = True           # no lang-tagged values existed for the
                                     # predicate — untagged host lookup and
                                     # this arena agree uid-for-uid
+    wallclock: bool = True          # no datetime carries a non-zero UTC
+                                    # offset: a datetime tokenizer's fields
+                                    # order as these values do
 
     def device_bytes(self) -> int:
         return sum(
@@ -2216,7 +2219,7 @@ class ArenaManager:
     def _build_values(self, pred: str) -> ValueArena:
         pd = self.store.peek(pred)
         pairs: Dict[int, float] = {}
-        langless = True
+        langless = wallclock = True
         if pd is not None:
             # Deterministic lang choice: untagged value wins, else the
             # lexicographically first language (stable across ingest
@@ -2226,9 +2229,13 @@ class ArenaManager:
                     langless = False
                 if uid in pairs:
                     continue
-                x = numeric(pd.values[(uid, lang)])
+                v = pd.values[(uid, lang)]
+                x = numeric(v)
                 if x is not None:
                     pairs[uid] = x
+                    off = getattr(v.value, "utcoffset", None)
+                    if off is not None and off():
+                        wallclock = False
         uids = np.array(sorted(pairs.keys()), dtype=np.int64)
         vals = np.array([pairs[u] for u in uids], dtype=np.float64)
         S = len(uids)
@@ -2251,5 +2258,6 @@ class ArenaManager:
             h_ranks=rk[:S].copy(),
             n=S,
             langless=langless,
+            wallclock=wallclock,
         )
         return a

@@ -57,6 +57,8 @@ from typing import Dict, Optional
 
 from dgraph_tpu.utils.metrics import (
     EDGES_TRAVERSED,
+    FILTER_PARTS,
+    FILTER_STAGE_US,
     LEDGER_BYTES,
     LEDGER_HOP_EDGES,
     LEDGER_HOPS,
@@ -215,8 +217,10 @@ class Ledger:
         beside.  Chain levels and mxu join programs become hop routes
         here (they bypass the per-level expander entry)."""
         self.edges += int(stats.get("edges", 0))
-        self.host_ms += stats.get("host_expand_ms", 0.0) + stats.get(
-            "resolver_expand_ms", 0.0
+        self.host_ms += (
+            stats.get("host_expand_ms", 0.0)
+            + stats.get("resolver_expand_ms", 0.0)
+            + stats.get("recheck_ms", 0.0)
         )
         self.device_ms += (
             stats.get("device_expand_ms", 0.0)
@@ -234,6 +238,10 @@ class Ledger:
             ms = stats.get(key)
             if ms:
                 self.stages[s] += ms
+        for part in FILTER_PARTS:
+            ms = stats.get(part + "_ms")
+            if ms:
+                FILTER_STAGE_US.add(part, int(ms * 1e3))
         lv = int(stats.get("chain_fused_levels", 0))
         if lv:
             self.hops["chain"] = self.hops.get("chain", 0) + lv

@@ -136,6 +136,7 @@ def _fresh_stats() -> dict:
         "device_expand_ms": 0.0,
         "kway_ms": 0.0,
         "resolver_expand_ms": 0.0,
+        "recheck_ms": 0.0,
         "chain_ms": 0.0,
         "device_order_ms": 0.0,
         "tile_build_ms": 0.0,

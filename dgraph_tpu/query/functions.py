@@ -26,9 +26,11 @@ from dgraph_tpu.models.types import (
     TypedValue,
     compare_vals,
     convert,
+    numeric,
     type_from_name,
 )
 from dgraph_tpu.gql.ast import Function
+from dgraph_tpu.utils.metrics import INDEX_RECHECK_UIDS
 
 class QueryError(ValueError):
     pass
@@ -37,6 +39,19 @@ class QueryError(ValueError):
 _EMPTY = np.empty(0, dtype=np.int64)
 
 _INEQ = {"le", "ge", "lt", "gt", "eq"}
+
+_VEC_CMP = {"eq": np.equal, "le": np.less_equal, "lt": np.less,
+            "ge": np.greater_equal, "gt": np.greater}
+# |seconds from the epoch| below which a float64 timestamp tells every two
+# microseconds apart (its spacing there is 2**-20 s): years 1697-2242
+_TS_EXACT = float(2 ** 33)
+
+
+def _wallclock(v: TypedValue) -> bool:
+    """Does the value's wall clock read as its UTC instant (naive or
+    UTC)?  Then a datetime tokenizer's fields order as the instants do."""
+    off = getattr(v.value, "utcoffset", None)
+    return off is None or not off()
 
 
 class FuncResolver:
@@ -193,21 +208,82 @@ class FuncResolver:
         return convert(TypedValue(TypeID.STRING, raw), tid)
 
     def _host_recheck(self, pred: str, uids: np.ndarray, op: str, val: TypedValue, lang: str = "") -> np.ndarray:
+        """``uids`` whose value compares true, one store probe a uid: the
+        recheck of lang-tagged functions and predicates (a uid's value is
+        chosen along the lang chain), and of the few values the numeric
+        column cannot hold exactly."""
+        st = self.stats if self.stats is not None else {}
+        INDEX_RECHECK_UIDS.add("value", len(uids))
         out = []
         langs = lang.split(",") if lang else [""]
-        for u in uids.tolist():
-            v = None
-            for l in langs:
-                v = (
-                    self.store.any_value(pred, int(u))
-                    if l == "."
-                    else self.store.value(pred, int(u), l)
-                )
-                if v is not None:
-                    break
-            if v is not None and compare_vals(op, v, val):
-                out.append(u)
+        with obs.stage(st, "recheck_ms"):
+            for u in uids.tolist():
+                v = None
+                for l in langs:
+                    v = (
+                        self.store.any_value(pred, int(u))
+                        if l == "."
+                        else self.store.value(pred, int(u), l)
+                    )
+                    if v is not None:
+                        break
+                if v is not None and compare_vals(op, v, val):
+                    out.append(u)
         return np.array(out, dtype=np.int64)
+
+    def _value_recheck(self, pred: str, va, uids: np.ndarray, op: str, val: TypedValue) -> np.ndarray:
+        """``uids`` whose untagged value compares true against ``val``, as
+        one vector compare over ``va``, the predicate's numeric value
+        column (the ValueArena host mirror device ordering sorts by).  A
+        datetime is its UTC timestamp there, exact to the microsecond
+        within ``_TS_EXACT`` seconds of the epoch; the few uids outside
+        that, or missing from the column, are compared one by one."""
+        bound = numeric(val)
+        if bound is None or not va.n:
+            return self._host_recheck(pred, uids, op, val)
+        st = self.stats if self.stats is not None else {}
+        with obs.stage(st, "recheck_ms"):
+            pos = np.minimum(np.searchsorted(va.h_src, uids), va.n - 1)
+            hit = va.h_src[pos] == uids
+            col = va.h_vals[pos]
+            if val.tid in (TypeID.DATETIME, TypeID.DATE):
+                hit &= (np.abs(col) < _TS_EXACT) & (abs(bound) < _TS_EXACT)
+            kept = uids[hit & _VEC_CMP[op](col, bound)]
+        INDEX_RECHECK_UIDS.add("value", int(hit.sum()))
+        odd = uids[~hit]
+        if len(odd):
+            kept = np.union1d(kept, self._host_recheck(pred, odd, op, val))
+        return kept
+
+    def _split_recheck(self, pred: str, idx: IndexArena, lo: int, hi: int,
+                       token, op: str, val: TypedValue) -> np.ndarray:
+        """A lossy sortable index's rows [lo, hi) for ``op`` against
+        ``val``: a row whose token lies strictly inside the range holds
+        only matches and is taken whole; only the bound's own token row
+        can hold near-misses, and it alone is compared against the values
+        (a uid holds one untagged value, so it sits in one row: a miss of
+        that row is a miss).  Where the value column's wall clock is not
+        its UTC instant (a datetime with an offset, in the column or in the
+        bound) tokens do not order as the values do, and every candidate is
+        compared."""
+        cand = self._expand_rows(idx.csr, np.arange(lo, hi))
+        va = self.arenas.values(pred)
+        if not (va.wallclock and _wallclock(val)):
+            return self._value_recheck(pred, va, cand, op, val)
+        b = idx.row_of(token)
+        if not lo <= b < hi:
+            INDEX_RECHECK_UIDS.add("token", len(cand))
+            return cand
+        edge = self._expand_rows(idx.csr, np.array([b]))
+        INDEX_RECHECK_UIDS.add("token", len(cand) - len(edge))
+        missed = np.setdiff1d(
+            edge, self._value_recheck(pred, va, edge, op, val), assume_unique=True
+        )
+        if not len(missed):
+            return cand
+        keep = np.ones(len(cand), dtype=bool)
+        keep[np.searchsorted(cand, missed)] = False
+        return cand[keep]
 
     # -- function families ---------------------------------------------------
 
@@ -267,26 +343,26 @@ class FuncResolver:
                 f"inequality on {pred!r} needs a sortable index (have {idx.tokenizer})"
             )
         token = tk.fn(val)[0]
+        # a lossy token's row holds values on both sides of the bound:
+        # lt/gt keep it, and the recheck decides
         if op == "eq":
             lo, hi = idx.row_range(lo=token, hi=token)
-        elif op == "le":
-            lo, hi = idx.row_range(hi=token)
-        elif op == "lt":
-            lo, hi = idx.row_range(hi=token, hi_open=True)
-        elif op == "ge":
-            lo, hi = idx.row_range(lo=token)
-        else:  # gt
-            lo, hi = idx.row_range(lo=token, lo_open=True)
-        cand = self._expand_rows(idx.csr, np.arange(lo, hi))
-        if tk.lossy or fn.lang or self._pred_has_langs(pred):
-            # lossy buckets include near-misses; lang-tagged functions
-            # must verify the match against the TAGGED value only; and an
-            # UNtagged function over a predicate with tagged values must
-            # re-check against the untagged value — the index spans every
-            # language (task.go:612-661 lang filters), so a tagged token
-            # can land inside the untagged comparison range
-            cand = self._host_recheck(pred, cand, op, val, fn.lang)
-        return cand
+        elif op in ("le", "lt"):
+            lo, hi = idx.row_range(hi=token, hi_open=op == "lt" and not tk.lossy)
+        else:  # ge, gt
+            lo, hi = idx.row_range(lo=token, lo_open=op == "gt" and not tk.lossy)
+        if fn.lang or self._pred_has_langs(pred):
+            # lang-tagged functions must verify the match against the
+            # TAGGED value only; and an UNtagged function over a predicate
+            # with tagged values must re-check against the untagged value
+            # — the index spans every language (task.go:612-661 lang
+            # filters), so a tagged token can land inside the untagged
+            # comparison range
+            cand = self._expand_rows(idx.csr, np.arange(lo, hi))
+            return self._host_recheck(pred, cand, op, val, fn.lang)
+        if tk.lossy:
+            return self._split_recheck(pred, idx, lo, hi, token, op, val)
+        return self._expand_rows(idx.csr, np.arange(lo, hi))
 
     def _pred_has_langs(self, pred: str) -> bool:
         """Does the predicate carry any lang-tagged values?  Cached on the

@@ -45,7 +45,7 @@ from dgraph_tpu import obs, ops
 from dgraph_tpu.ops.sets import SENT
 from dgraph_tpu.utils import devguard, planconfig
 from dgraph_tpu.utils.failpoints import fail
-from dgraph_tpu.utils.metrics import JOIN_ROUTES, KWAY_INTERSECTS
+from dgraph_tpu.utils.metrics import JOIN_ROUTES, KWAY_ELEMENTS, KWAY_INTERSECTS
 
 _EMPTY = np.empty(0, dtype=np.int64)
 
@@ -188,6 +188,7 @@ def kway_intersect(
             devguard.count_failover("host", stats)
         if res is not None:
             KWAY_INTERSECTS.add("device")
+            KWAY_ELEMENTS.add("device", total)
             with _ROUTE_LOCK:
                 _COUNTS["kway_device"] += 1
             if stats is not None:
@@ -199,6 +200,7 @@ def kway_intersect(
         for s in sets[1:]:
             out = np.intersect1d(out, s)
     KWAY_INTERSECTS.add("host")
+    KWAY_ELEMENTS.add("host", total)
     with _ROUTE_LOCK:
         _COUNTS["kway_host"] += 1
     if stats is not None:

@@ -754,6 +754,36 @@ JOIN_ROUTES = metrics.labeled("dgraph_join_route_total", label="route")
 KWAY_INTERSECTS = metrics.labeled(
     "dgraph_kway_intersect_total", label="route"
 )
+# the elements those intersections took in (the k sets' sizes summed), by
+# the same route: what the size gate and the planner's break-even weigh
+KWAY_ELEMENTS = metrics.labeled(
+    "dgraph_kway_intersect_elements_total", label="route"
+)
+for _r in ("host", "device"):
+    KWAY_ELEMENTS.add(_r, 0)
+
+# inequality functions over an index (query/functions.py ``_ineq_one``):
+# the candidate uids a lossy or lang-spanning index gave, by how each was
+# decided — "token" (its token row lies strictly inside the range: taken
+# whole) or "value" (compared against its value: the bound's own token row
+# of a lossy index, or every candidate of a lang-tagged recheck).
+INDEX_RECHECK_UIDS = metrics.labeled(
+    "dgraph_index_recheck_uids_total", label="way"
+)
+for _w in ("token", "value"):
+    INDEX_RECHECK_UIDS.add(_w, 0)
+
+# the engine's filter work a request did, in microseconds, by part: the
+# resolver's index expansions ("resolver_expand"), the recheck of index
+# candidates against values ("recheck") and the k-way intersections
+# ("kway") — the engine stats of the same names, folded in once a request
+# (obs/ledger.py ``merge_engine_stats``).  They nest in the catalogue's
+# stages (a root filter runs inside ``plan``), so they are a family of
+# their own and not stages.
+FILTER_STAGE_US = metrics.labeled("dgraph_filter_stage_us_total", label="part")
+FILTER_PARTS = ("resolver_expand", "recheck", "kway")
+for _p in FILTER_PARTS:
+    FILTER_STAGE_US.add(_p, 0)
 JOIN_TILE_BUILDS = metrics.counter("dgraph_join_tile_builds_total")
 # cumulative bytes densified (a counter, not an occupancy gauge: tiles
 # die with their arena under the HBM budget, and live occupancy is
