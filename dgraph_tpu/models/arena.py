@@ -23,6 +23,7 @@ the gentle-commit + lcache refresh cycle, posting/lists.go:109-215).
 from __future__ import annotations
 
 import bisect
+import math
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -1364,6 +1365,10 @@ class ValueArena:
     wallclock: bool = True          # no datetime carries a non-zero UTC
                                     # offset: a datetime tokenizer's fields
                                     # order as these values do
+    whole: bool = True              # every value of the predicate has a
+                                    # finite number here, as its index
+                                    # holds a token: a uid missing from
+                                    # the column holds no value
 
     def device_bytes(self) -> int:
         return sum(
@@ -2219,7 +2224,7 @@ class ArenaManager:
     def _build_values(self, pred: str) -> ValueArena:
         pd = self.store.peek(pred)
         pairs: Dict[int, float] = {}
-        langless = wallclock = True
+        langless = wallclock = whole = True
         if pd is not None:
             # Deterministic lang choice: untagged value wins, else the
             # lexicographically first language (stable across ingest
@@ -2231,8 +2236,11 @@ class ArenaManager:
                     continue
                 v = pd.values[(uid, lang)]
                 x = numeric(v)
-                if x is not None:
+                if x is None:
+                    whole = False
+                else:
                     pairs[uid] = x
+                    whole = whole and math.isfinite(x)   # no token holds ±inf, nan
                     off = getattr(v.value, "utcoffset", None)
                     if off is not None and off():
                         wallclock = False
@@ -2259,5 +2267,6 @@ class ArenaManager:
             n=S,
             langless=langless,
             wallclock=wallclock,
+            whole=whole,
         )
         return a

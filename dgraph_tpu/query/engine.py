@@ -132,6 +132,7 @@ def _fresh_stats() -> dict:
         "join_routes": [],
         "kway_device": 0,
         "kway_host": 0,
+        "kway_probe": 0,
         "host_expand_ms": 0.0,
         "device_expand_ms": 0.0,
         "kway_ms": 0.0,
@@ -1191,13 +1192,14 @@ class QueryEngine:
             # entry, query/joinplan.py): leaves that resolve WITHOUT the
             # frontier — index funcs, has(), uid sets — intersect with
             # the candidates as ONE k-way pass (size-routed host/device)
-            # instead of k sequential narrowing passes.  AND children
-            # are set filters, so the intersection commutes: frontier-
-            # dependent leaves (val/count/uid_in/checkpwd) and nested
-            # trees apply sequentially on the k-way result, and the
-            # output is byte-identical to the legacy fold.  Each leaf
-            # already resolved its full set before narrowing (resolve →
-            # _bound), so the reorder adds no resolution work.
+            # instead of k sequential narrowing passes.  A leaf whose set
+            # outnumbers the candidates is not built: the candidates are
+            # looked up in it first (FuncResolver.narrow), and only the
+            # rest join the k-way.  AND children are set filters, so the
+            # intersection commutes: frontier-dependent leaves
+            # (val/count/uid_in/checkpwd) and nested trees apply
+            # sequentially on the k-way result, and the output is
+            # byte-identical to the legacy fold.
             from dgraph_tpu.query import joinplan
 
             if joinplan.mxu_mode() != "0":
@@ -1207,10 +1209,9 @@ class QueryEngine:
                     and joinplan.filter_leaf_global(c.func)
                 ]
                 if len(glob) >= 2:
-                    sets = [resolver.resolve(c.func, None) for c in glob]
-                    out = joinplan.kway_intersect(
-                        [candidates] + sets, stats=self.stats
-                    )
+                    out, left = resolver.narrow([c.func for c in glob], candidates)
+                    sets = [resolver.resolve(fn, None) for fn in left]
+                    out = joinplan.kway_intersect([out] + sets, stats=self.stats)
                     gids = {id(c) for c in glob}
                     for c in ft.children:
                         if id(c) not in gids:

@@ -9,9 +9,10 @@ mirroring the reference's post-passes (task.go:473-661).
 
 from __future__ import annotations
 
+import functools
 import json
 import re
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -45,6 +46,42 @@ _VEC_CMP = {"eq": np.equal, "le": np.less_equal, "lt": np.less,
 # |seconds from the epoch| below which a float64 timestamp tells every two
 # microseconds apart (its spacing there is 2**-20 s): years 1697-2242
 _TS_EXACT = float(2 ** 33)
+
+
+def _exact_below(tid: TypeID) -> float:
+    """|value| below which the float64 value column holds a value of type
+    ``tid`` exactly: a datetime's timestamp within ``_TS_EXACT``, an int
+    within 2**53, a float always."""
+    if tid in (TypeID.DATETIME, TypeID.DATE):
+        return _TS_EXACT
+    if tid == TypeID.INT:
+        return float(2 ** 53)
+    return np.inf
+
+
+def _sorted_unique(uids: np.ndarray) -> np.ndarray:
+    """``uids`` as sorted-unique int64; one O(n) check where they already
+    are (a level's uids, a var block's)."""
+    uids = np.asarray(uids, dtype=np.int64)
+    if len(uids) > 1 and not np.all(uids[1:] > uids[:-1]):
+        uids = np.unique(uids)
+    return uids
+
+
+def _member(uids: np.ndarray, of: np.ndarray) -> np.ndarray:
+    """Which of ``uids`` are in ``of`` (sorted), by binary search."""
+    if not len(of):
+        return np.zeros(len(uids), dtype=bool)
+    return of[np.minimum(np.searchsorted(of, uids), len(of) - 1)] == uids
+
+
+class Probe(NamedTuple):
+    """A filter leaf priced for ``FuncResolver.probe``: the size of its set,
+    read without building it, and for an inequality its bounds, typed."""
+
+    fn: Function
+    size: int
+    vals: Optional[List[TypedValue]] = None
 
 
 def _wallclock(v: TypedValue) -> bool:
@@ -91,7 +128,15 @@ class FuncResolver:
         self, fn: Function, candidates: Optional[np.ndarray] = None
     ) -> np.ndarray:
         """uid set satisfying ``fn``; ``candidates`` bounds val()/count()
-        style functions that are only meaningful relative to a set."""
+        style functions that are only meaningful relative to a set.  Where
+        the candidates are fewer than ``fn``'s set holds, they are looked
+        up one by one (``probe``) and the set is never built."""
+        if candidates is not None:
+            plan = self.probe_plan(fn)
+            if plan is not None and len(candidates) < plan.size:
+                got = self.probe(plan, candidates)
+                if got is not None:
+                    return got
         name = fn.name
         if name == "uid":
             out = np.array(sorted(set(fn.uid_args)), dtype=np.int64)
@@ -144,6 +189,121 @@ class FuncResolver:
         if candidates is None:
             return uids
         return np.intersect1d(uids, candidates)
+
+    # -- probing the candidates ---------------------------------------------
+
+    def probe_plan(self, fn: Function) -> Optional[Probe]:
+        """``fn`` priced for ``probe``: the size of its set read without
+        building it — a ``uid()`` leaf's bound arrays, an inequality's index
+        rows (the total ``_expand_rows`` would expand) — or None where
+        ``probe`` cannot answer ``fn``: any other function, a lang tag on
+        the function or the predicate, no sortable index, or a bound the
+        numeric value column cannot hold exactly."""
+        if fn.name == "uid":
+            size = len(fn.uid_args)
+            for ref in fn.needs_vars:
+                if ref.name in self.uid_vars:
+                    size += len(self.uid_vars[ref.name])
+                elif ref.name in self.value_vars:
+                    size += len(self.value_vars[ref.name])
+            return Probe(fn, size)
+        if fn.name not in _INEQ or fn.is_val_var or fn.is_count or fn.lang or not fn.args:
+            return None
+        pred = fn.attr
+        name = self.store.schema.sortable_tokenizer(pred)
+        if name is None or self._pred_has_langs(pred):
+            return None
+        tk = tokmod.get_tokenizer(name)
+        idx = self.arenas.index(pred, name)
+        vals: List[TypedValue] = []
+        size = 0
+        for raw in self._ineq_args(fn):
+            val = self._typed_value(pred, raw)
+            bound = numeric(val)
+            if (val.tid != tk.typ or bound is None or not _wallclock(val)
+                    or abs(bound) >= _exact_below(val.tid)):
+                return None
+            lo, hi = self._ineq_rows(idx, tk, fn.name, tk.fn(val)[0])
+            size += int(idx.csr.h_offsets[hi] - idx.csr.h_offsets[lo])
+            vals.append(val)
+        return Probe(fn, size, vals)
+
+    def probe(self, plan: Probe, candidates: np.ndarray) -> Optional[np.ndarray]:
+        """``plan.fn``'s set within ``candidates``, found by looking each
+        candidate up — in the uid variables' arrays, or in the predicate's
+        numeric value column — where the set is never built: sorted-unique
+        int64, the uids ``_bound`` gives.  Booked as a k-way of route
+        ``probe`` (query/joinplan.py ``note_probe``).  None where the value
+        column cannot stand for the index (``_probe_values``)."""
+        from dgraph_tpu.query.joinplan import note_probe
+
+        cands = _sorted_unique(candidates)
+        if not len(cands):
+            return _EMPTY
+        if plan.vals is None:
+            with obs.stage(self.stats if self.stats is not None else {}, "kway_ms"):
+                out = self._probe_uid(plan.fn, cands)
+        else:
+            out = self._probe_values(plan, cands)
+            if out is None:
+                return None
+        note_probe(self.stats, len(cands))
+        return out
+
+    def narrow(
+        self, fns: List[Function], candidates: np.ndarray
+    ) -> Tuple[np.ndarray, List[Function]]:
+        """An ``and`` of frontier-free leaves, driven by its smallest set:
+        ``candidates`` probed by every leaf whose set outnumbers them, the
+        smallest such leaf first, each narrowing them for the next.  Gives
+        the narrowed candidates and the leaves left to build whole."""
+        plans = sorted(
+            (p for p in map(self.probe_plan, fns) if p is not None),
+            key=lambda p: p.size,
+        )
+        out = candidates
+        done = set()
+        while True:
+            plan = next((p for p in plans if len(out) < p.size), None)
+            if plan is None:
+                break
+            plans.remove(plan)
+            got = self.probe(plan, out)
+            if got is not None:
+                out = got
+                done.add(id(plan.fn))
+        return out, [fn for fn in fns if id(fn) not in done]
+
+    def _probe_uid(self, fn: Function, cands: np.ndarray) -> np.ndarray:
+        """``uid()``'s literal uids and variables, each searched for the
+        candidates; what any of them holds is kept, so nothing is unioned."""
+        keep = np.zeros(len(cands), dtype=bool)
+        if fn.uid_args:
+            keep |= _member(cands, np.unique(np.asarray(fn.uid_args, dtype=np.int64)))
+        for ref in fn.needs_vars:
+            if ref.name in self.uid_vars:
+                of = _sorted_unique(self.uid_vars[ref.name])
+            elif ref.name in self.value_vars:
+                vm = self.value_vars[ref.name]
+                of = np.unique(np.fromiter(vm.keys(), dtype=np.int64, count=len(vm)))
+            else:
+                continue
+            keep |= _member(cands, of)
+        return cands[keep]
+
+    def _probe_values(self, plan: Probe, cands: np.ndarray) -> Optional[np.ndarray]:
+        """The candidates whose untagged value compares true against any of
+        ``plan.vals`` (``_value_recheck``).  The numeric value column stands
+        for the index only where it holds every value of the predicate
+        (``whole``), untagged (``langless``), and values and tokens order
+        alike (``wallclock``); else None."""
+        fn = plan.fn
+        va = self.arenas.values(fn.attr)
+        if not (va.n and va.whole and va.langless and va.wallclock):
+            return None
+        return functools.reduce(np.union1d, [
+            self._value_recheck(fn.attr, va, cands, fn.name, val) for val in plan.vals
+        ])
 
     def _expand_rows(self, arena, rows: np.ndarray) -> np.ndarray:
         """Union of the posting lists at ``rows`` (expand + unique),
@@ -236,21 +396,24 @@ class FuncResolver:
         one vector compare over ``va``, the predicate's numeric value
         column (the ValueArena host mirror device ordering sorts by).  A
         datetime is its UTC timestamp there, exact to the microsecond
-        within ``_TS_EXACT`` seconds of the epoch; the few uids outside
-        that, or missing from the column, are compared one by one."""
+        within ``_TS_EXACT`` seconds of the epoch, an int within 2**53
+        (``_exact_below``); the few uids the column holds inexactly are
+        compared one by one.  A uid missing from the column holds no value
+        where the column holds every value, untagged (``whole`` and
+        ``langless``), and fails; elsewhere it too is compared one by one."""
         bound = numeric(val)
         if bound is None or not va.n:
             return self._host_recheck(pred, uids, op, val)
+        lim = _exact_below(val.tid)
         st = self.stats if self.stats is not None else {}
         with obs.stage(st, "recheck_ms"):
             pos = np.minimum(np.searchsorted(va.h_src, uids), va.n - 1)
             hit = va.h_src[pos] == uids
             col = va.h_vals[pos]
-            if val.tid in (TypeID.DATETIME, TypeID.DATE):
-                hit &= (np.abs(col) < _TS_EXACT) & (abs(bound) < _TS_EXACT)
-            kept = uids[hit & _VEC_CMP[op](col, bound)]
-        INDEX_RECHECK_UIDS.add("value", int(hit.sum()))
-        odd = uids[~hit]
+            exact = hit & (np.abs(col) < lim) & (abs(bound) < lim)
+            kept = uids[exact & _VEC_CMP[op](col, bound)]
+        INDEX_RECHECK_UIDS.add("value", int(exact.sum()))
+        odd = uids[~exact & (hit | (not (va.whole and va.langless)))]
         if len(odd):
             kept = np.union1d(kept, self._host_recheck(pred, odd, op, val))
         return kept
@@ -290,6 +453,14 @@ class FuncResolver:
     def _ineq(self, fn: Function) -> np.ndarray:
         if not fn.args:
             raise QueryError(f"{fn.name} needs a value argument")
+        out = _EMPTY
+        for raw in self._ineq_args(fn):
+            out = np.union1d(out, self._ineq_one(fn, raw))
+        return out
+
+    @staticmethod
+    def _ineq_args(fn: Function) -> List[str]:
+        """The raw values an inequality compares against."""
         # eq may take multiple values — varargs or a bracket list, both
         # meaning "any of" (gql parseFunction list args)
         vals: List[str] = []
@@ -310,10 +481,18 @@ class FuncResolver:
                         vals.append(str(x))
                 continue
             vals.append(raw)
-        out = _EMPTY
-        for raw in vals:
-            out = np.union1d(out, self._ineq_one(fn, raw))
-        return out
+        return vals
+
+    @staticmethod
+    def _ineq_rows(idx: IndexArena, tk, op: str, token) -> Tuple[int, int]:
+        """A sortable index's token rows [lo, hi) that can hold uids
+        comparing true: a lossy token's row holds values on both sides of
+        the bound, so lt/gt keep it and the recheck decides."""
+        if op == "eq":
+            return idx.row_range(lo=token, hi=token)
+        if op in ("le", "lt"):
+            return idx.row_range(hi=token, hi_open=op == "lt" and not tk.lossy)
+        return idx.row_range(lo=token, lo_open=op == "gt" and not tk.lossy)
 
     def _ineq_one(self, fn: Function, raw: str) -> np.ndarray:
         pred, op = fn.attr, fn.name
@@ -343,14 +522,7 @@ class FuncResolver:
                 f"inequality on {pred!r} needs a sortable index (have {idx.tokenizer})"
             )
         token = tk.fn(val)[0]
-        # a lossy token's row holds values on both sides of the bound:
-        # lt/gt keep it, and the recheck decides
-        if op == "eq":
-            lo, hi = idx.row_range(lo=token, hi=token)
-        elif op in ("le", "lt"):
-            lo, hi = idx.row_range(hi=token, hi_open=op == "lt" and not tk.lossy)
-        else:  # ge, gt
-            lo, hi = idx.row_range(lo=token, lo_open=op == "gt" and not tk.lossy)
+        lo, hi = self._ineq_rows(idx, tk, op, token)
         if fn.lang or self._pred_has_langs(pred):
             # lang-tagged functions must verify the match against the
             # TAGGED value only; and an UNtagged function over a predicate

@@ -91,7 +91,7 @@ def mask_max_lanes() -> int:
 
 _ROUTE_LOCK = threading.Lock()
 _RECENT: "deque[dict]" = deque(maxlen=16)
-_COUNTS = {"mxu": 0, "pairwise": 0, "kway_device": 0, "kway_host": 0}
+_COUNTS = {"mxu": 0, "pairwise": 0, "kway_device": 0, "kway_host": 0, "kway_probe": 0}
 
 
 def record_route(stats: Optional[dict], decision: dict) -> None:
@@ -207,6 +207,18 @@ def kway_intersect(
         stats["kway_host"] = stats.get("kway_host", 0) + 1
         planner.note_outcome(dec, (stats["kway_ms"] - k0) * 1e3)
     return out
+
+
+def note_probe(stats: Optional[dict], elements: int) -> None:
+    """Book a filter leaf answered by looking its candidates up
+    (query/functions.py ``FuncResolver.probe``) as a k-way of route
+    ``probe``; its elements are the candidate slots it read."""
+    KWAY_INTERSECTS.add("probe")
+    KWAY_ELEMENTS.add("probe", elements)
+    with _ROUTE_LOCK:
+        _COUNTS["kway_probe"] += 1
+    if stats is not None:
+        stats["kway_probe"] = stats.get("kway_probe", 0) + 1
 
 
 def filter_leaf_global(fn) -> bool:

@@ -755,11 +755,14 @@ KWAY_INTERSECTS = metrics.labeled(
     "dgraph_kway_intersect_total", label="route"
 )
 # the elements those intersections took in (the k sets' sizes summed), by
-# the same route: what the size gate and the planner's break-even weigh
+# the same route: what the size gate and the planner's break-even weigh.
+# Route "probe" (joinplan.note_probe) is a filter leaf answered on its
+# candidates without building its set; its elements are those candidates
 KWAY_ELEMENTS = metrics.labeled(
     "dgraph_kway_intersect_elements_total", label="route"
 )
-for _r in ("host", "device"):
+for _r in ("host", "device", "probe"):
+    KWAY_INTERSECTS.add(_r, 0)
     KWAY_ELEMENTS.add(_r, 0)
 
 # inequality functions over an index (query/functions.py ``_ineq_one``):

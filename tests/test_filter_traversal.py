@@ -67,7 +67,12 @@ def srv(world, tmp_path_factory):
 def _cases(world, kind):
     """(root, genre uid, date) to ask: drawn ids across the pool's sizes,
     and by hand a bound on a year's first day, the first day after the last
-    film (a genre-date pair with no film) and the generator's earliest day."""
+    film (a genre-date pair with no film) and the generator's earliest day.
+    An actor's class adds the two most-cast actors under the smallest of the
+    mix's genres: the first with a date in the last year, a level that
+    outnumbers both leaves, so that neither is probed and the whole k-way
+    runs on the route the knob pins; the second with a date its level falls
+    short of, so that the date is probed and F folds with what is left."""
     ids = np.linspace(0, len(kind.pool()) - 1, 12).astype(int)
     out = [kind.request(int(i)) for i in ids]
     root = out[len(out) // 2][0]
@@ -76,13 +81,25 @@ def _cases(world, kind):
     assert ref.on_or_after("2020-01-01") == 0
     assert any(ref.keeps(np.arange(len(world.g.film)), 2, "1975-01-01")
                & (world.g.film_date[:, 0] == 1975))
+    if kind.shape == "costars":
+        years = world.g.film_date[:, 0]
+        genre = int(kind.spec["root"]["genre"]["last"]) + 1
+        for rank, year in ((0, int(years.max())), (1, 1990)):
+            actor = int(world.actors_by_cast[rank])
+            level = len(np.unique(world.g.perf_film[ref.roles(actor)]))
+            rows = int((years >= year).sum())     # the year index's rows from the bound's year
+            assert (level > max(ref.genre_films[genre], rows) if rank == 0
+                    else ref.genre_films[genre] < level < rows), (level, rows)
+            out.append((actor, genre, f"{year}-03-15"))
     return out
 
 
 @pytest.mark.parametrize("route", ["planner", "device", "host"])
 def test_both_classes_answer_as_the_reference(srv, world, classes, monkeypatch, route):
     """Every answer as the reference's, its ledger's edges by the reference's
-    count, and the k-way elements booked to the route the knob pins."""
+    count, and the k-way elements booked to the route the knob pins, the
+    levels that outnumber their leaves; the others' to the probe, which no
+    knob pins."""
     if route != "planner":
         monkeypatch.setenv("DGRAPH_TPU_KWAY_DEVICE_MIN", "0" if route == "device" else str(1 << 40))
     before = KWAY_ELEMENTS.snapshot()
@@ -97,13 +114,13 @@ def test_both_classes_answer_as_the_reference(srv, world, classes, monkeypatch, 
             if compare.executed(led):
                 assert led["edges"] == exp["edges"], (name, root, genre, date)
             asked += 1
-    grown = {r: KWAY_ELEMENTS.snapshot()[r] - before[r] for r in ("host", "device")}
-    assert sum(grown.values()) > 0
-    if route == "device":
-        assert grown["host"] == 0
-    if route == "host":
-        assert grown["device"] == 0
-    assert asked == 2 * 15
+    grown = {r: KWAY_ELEMENTS.snapshot()[r] - before[r] for r in ("host", "device", "probe")}
+    assert grown["probe"] > 0
+    if route == "planner":
+        assert grown["host"] + grown["device"] > 0
+    else:
+        assert grown[route] > 0 and grown[{"device": "host", "host": "device"}[route]] == 0
+    assert asked == 15 + 17
 
 
 def test_an_empty_level_renders_as_the_reference(srv, world, classes):
@@ -261,7 +278,8 @@ def test_a_lossy_float_index_splits_at_its_bucket():
 def test_the_new_families_are_there_at_zero_from_boot():
     text = metrics.prometheus_text()
     for family, labels in (("dgraph_index_recheck_uids_total", ("token", "value")),
-                           ("dgraph_kway_intersect_elements_total", ("host", "device")),
+                           ("dgraph_kway_intersect_total", ("host", "device", "probe")),
+                           ("dgraph_kway_intersect_elements_total", ("host", "device", "probe")),
                            ("dgraph_filter_stage_us_total", ("resolver_expand", "recheck", "kway"))):
         for label in labels:
             assert f'{family}{{' in text and f'"{label}"' in text, (family, label)
@@ -319,6 +337,10 @@ class _Obs:
     ("kway_device_share", {}, None),
     ("kway_elements_per_query", {"dgraph_kway_intersect_elements_total": {"host": 100, "device": 300}}, 40.0),
     ("kway_elements_per_query", {}, None),
+    ("kway_probe_share", {"dgraph_kway_intersect_total": {"host": 1, "device": 0, "probe": 3}}, 75.0),
+    ("kway_probe_share", {"dgraph_kway_intersect_total": {"host": 4, "device": 0}}, None),
+    ("kway_probe_share", {"dgraph_kway_intersect_total": {"host": 0, "device": 0, "probe": 0}}, None),
+    ("kway_probe_share", {}, None),
     ("recheck_share", {"dgraph_index_recheck_uids_total": {"token": 96, "value": 4}}, 4.0),
     ("recheck_share", {}, None),
     ("filter_ms", {"dgraph_filter_stage_us_total": {"resolver_expand": 5000, "recheck": 1000,

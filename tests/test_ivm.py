@@ -480,6 +480,9 @@ def test_repair_route_modes(monkeypatch):
     # planner on: recorded decision with both estimates; a tiny delta
     # against a warm entry repairs, a delta rivaling the entry rebuilds
     monkeypatch.delenv("DGRAPH_TPU_PLANNER", raising=False)
+    # priced at the priors: the rates that other tests of this process
+    # refined online (process state) are no part of what this checks
+    planner._reset_for_tests()
     ok, dec = planner.repair_route(2, 5_000.0)
     assert ok and dec is not None and dec["route"] == "repair"
     assert dec["est_chosen_us"] > 0 and dec["est_other_us"] > 0
